@@ -329,6 +329,10 @@ class RayContext:
             "gcs_address": f"{worker.gcs.addr[0]}:{worker.gcs.addr[1]}",
             "node_id": worker.node_id,
         }
+        if _global_node is not None:
+            # locally started head: worker logs live under
+            # <session_dir>/logs/worker-*.{out,err}
+            self.address_info["session_dir"] = _global_node.session_dir
 
     def __enter__(self):
         return self

@@ -93,9 +93,10 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "bisecting the validator itself)."),
     _k("TIMELINE", "1", "bool",
        "0 removes chrome-timeline span recording."),
-    _k("DETECT_CHIPS", "0", "bool",
-       "1 lets the raylet probe for real TPU chips at startup "
-       "(subprocess jax.devices())."),
+    _k("DETECT_CHIPS", "1", "bool",
+       "0 stops the raylet probing for local TPU chips at startup (one "
+       "synchronous subprocess jax.devices(), finished before init() "
+       "returns); the default is 0 when RAY_TPU_TESTING=1."),
     # --- tuning ----------------------------------------------------------
     _k("CHECKPOINT_DIR", "", "path",
        "sharded-checkpoint generation root for standalone (non-trainer) "
@@ -107,9 +108,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
     _k("COLLECTIVE_QUANT_BLOCK", "1024", "int",
        "elements per int8 wire-quantization scale block (one float32 "
        "scale per block; sub-block tails travel exact)."),
-    _k("DEVICE_GAUGE_POLL_S", "0", "float",
-       "period of the raylet's per-device HBM gauge poller; 0 = one "
-       "probe at raylet start."),
     _k("EVENT_LOG_SIZE", "4096", "int",
        "bounded structured-event ring size per process (drop-oldest)."),
     _k("FLIGHT_RECORDER_WINDOW_S", "120", "float",

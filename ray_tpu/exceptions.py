@@ -160,6 +160,15 @@ class TrainWorkerGroupError(RayTpuError):
         return (type(self), (errs, self.dead_ranks, str(self)))
 
 
+class TpuBackendError(RayTpuError):
+    """A train worker whose lease holds ``TPU`` chips found its JAX
+    backend on another platform. With ``JAX_PLATFORMS`` unset JAX falls
+    back to the CPU with only a warning when libtpu cannot take the
+    chips (held by another process, driver missing); training on that
+    silently would bill a TPU host for CPU steps, so the worker fails
+    the gang instead."""
+
+
 class JobQuotaError(RayTpuError, ValueError):
     """A job-registry operation carried an invalid quota/priority shape
     (negative amounts, non-numeric values, unknown job on update). Raised

@@ -732,7 +732,8 @@ def summarize_collectives(*, address: str | None = None) -> dict:
                      late ranks with their lags);
     - ``compile``    per-fn pjit compile time + cache hit/miss counts
                      (parallel/compile_watch.py);
-    - ``devices``    per-device HBM gauges (tpu_probe device poller).
+    - ``devices``    per-device HBM gauges (limits from the raylet's chip
+                     probe, live in-use from the owning train workers).
     """
     snaps = {m["name"]: m for m in metrics_summary(address=address)}
 

@@ -165,10 +165,11 @@ def _resolve_attention(cfg: GPT2Config, mesh: Optional[Mesh]) -> str:
     return "reference"
 
 
-def _block_apply(block, x, cfg: GPT2Config, impl: str):
+def _block_apply(block, x, cfg: GPT2Config, impl: str, mesh=None):
     cd = cfg.dtype
     h = L.layer_norm(x, block["ln1"]["scale"], block["ln1"]["bias"])
-    x = x + L.apply_attention(block["attn"], h, causal=True, impl=impl, compute_dtype=cd)
+    x = x + L.apply_attention(block["attn"], h, causal=True, impl=impl,
+                              compute_dtype=cd, mesh=mesh)
     h = L.layer_norm(x, block["ln2"]["scale"], block["ln2"]["bias"])
     if cfg.moe:
         m, aux = L.apply_moe(block["moe"], h, cfg.moe, compute_dtype=cd)
@@ -204,7 +205,7 @@ def forward(params, tokens, cfg: GPT2Config, mesh: Optional[Mesh] = None):
 
     def body(carry, block):
         x, aux = carry
-        x, a = _block_apply(block, x, cfg, impl)
+        x, a = _block_apply(block, x, cfg, impl, mesh)
         if mesh is not None:
             x = sh.constrain(x, mesh, "batch", "seq", "embed")
         return (x, aux + a), None
@@ -241,18 +242,21 @@ def forward_pipelined(
             "pipelined forward does not yet propagate the MoE aux loss; "
             "use pp=1 with MoE or a dense (non-MoE) config with pp>1"
         )
-    n_sp = dict(mesh.shape).get("sp", 1)
+    impl = _resolve_attention(cfg, mesh)
     # pp×sp composition: ONE flat manual region over {pp, sp} with the
     # per-shard ring attention inside stages (a nested sp-shard_map in the
     # pp scan does not differentiate — DuplicateSpecError in transpose).
-    if n_sp > 1:
+    if impl == "ring":
         impl = "ring_local"
         manual_axes = ("sp",)
         from jax.sharding import PartitionSpec as _P
 
         mb_spec = _P(None, None, "sp", None)   # [M, B_mb, S, D]
+    elif dict(mesh.shape).get("sp", 1) > 1:
+        raise ValueError(
+            f"attention={cfg.attention!r} attends within one sequence shard "
+            f"only; a mesh with sp>1 needs attention='ring' (or 'auto')")
     else:
-        impl = "flash" if jax.default_backend() == "tpu" else "reference"
         manual_axes = ()
         mb_spec = None
     per_stage = cfg.n_layer // n_pp

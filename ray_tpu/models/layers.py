@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention_local
 
 Params = Dict[str, Any]
@@ -95,6 +96,7 @@ def apply_attention(
     impl: str = "reference",
     sp_axis: str = "sp",
     compute_dtype=jnp.bfloat16,
+    mesh=None,
 ):
     """x: [B, S, D] -> [B, S, D].
 
@@ -102,6 +104,11 @@ def apply_attention(
     "ring" (context-parallel over the ambient mesh's `sp_axis` — callable
     from inside jit with global arrays), "ring_local" (per-shard body;
     requires already running inside shard_map with sp_axis manual).
+
+    mesh: the mesh q/k/v are sharded over, for "flash". A Mosaic kernel
+    cannot be partitioned automatically (lowering it on sharded operands
+    raises); under shard_map each device runs the kernel on its own batch
+    and head shard.
     """
     cd = compute_dtype
     q = jnp.einsum("bsd,dhk->bshk", x.astype(cd), params["wq"].astype(cd))
@@ -116,7 +123,13 @@ def apply_attention(
     elif impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
 
-        o = flash_attention(q, k, v, causal=causal)
+        attend = functools.partial(flash_attention, causal=causal)
+        if mesh is not None:
+            io_spec = sh.spec("batch", None, "heads", None)
+            attend = jax.shard_map(
+                attend, mesh=mesh, in_specs=(io_spec, io_spec, io_spec),
+                out_specs=io_spec, check_vma=False)
+        o = attend(q, k, v)
     else:
         o = reference_attention(q, k, v, causal=causal)
     out = jnp.einsum("bshk,hkd->bsd", o.astype(cd), params["wo"].astype(cd))

@@ -9,8 +9,9 @@ a dk/dv kernel gridded (BH, KV blocks, Q blocks), each recomputing the
 probability block from the saved logsumexp — no O(S²) tensor is ever
 materialized in HBM, unlike a naive VJP.
 
-On non-TPU backends the kernels run in Pallas interpret mode (tests) or
-callers use parallel.ring_attention.reference_attention.
+The kernels compile for the TPU or raise. ``interpret=True`` (the Pallas
+interpreter) is for tests that ask for it; off-TPU product code uses
+parallel.ring_attention.reference_attention.
 """
 from __future__ import annotations
 
@@ -24,6 +25,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
+# grid = (batch*heads, parallel blocks, sequentially accumulated blocks)
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _fwd_kernel(
@@ -107,12 +111,6 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         seq_len=S, padded=S_pad != S,
     )
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # older/newer param name drift
-        cparams = None
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -134,7 +132,7 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        **({"compiler_params": cparams} if cparams is not None else {}),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v)
     return o[:, :S], lse[:, 0, :S]
@@ -293,13 +291,6 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     nq, nk = S_pad // block_q, S_pad // block_k
     kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
               seq_len=S, padded=S_pad != S)
-    try:
-        cparams = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:
-        cparams = None
-    cp = {"compiler_params": cparams} if cparams is not None else {}
 
     qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
@@ -311,7 +302,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((BH, S_pad, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        **cp,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse8, delta8)[0]
 
@@ -328,7 +319,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                    jax.ShapeDtypeStruct((BH, S_pad, D), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        **cp,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, lse8, delta8)
     return dq[:, :S], dk[:, :S], dv[:, :S]
@@ -355,15 +346,13 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Flash attention over [B, S, H, D] (heads layout matching
     models/layers.apply_attention). Differentiable via custom VJP."""
     B, S, H, D = q.shape
     if scale is None:
         scale = 1.0 / (D**0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)

@@ -16,9 +16,10 @@ classified against a per-signature compile cache:
 Classification is O(1) on the hit path: jitted callables expose
 ``_cache_size()`` (~0.1µs), so a call that grew the cache IS a
 trace+compile — no signature re-derivation duplicating jit's own C++
-dispatch on every training step. Callables without ``_cache_size``
-fall back to a per-signature key at jit's abstraction level ((shape,
-dtype) per array leaf + pytree structure). The measured duration is
+dispatch on every training step. Plain callables that are not jitted
+(serve/batching.py wraps user batch functions) have no ``_cache_size``
+and are classified by a per-signature key at jit's abstraction level
+((shape, dtype) per array leaf + pytree structure). The measured duration is
 trace + compile + first execution (the recompile-attribution signal
 operators need), not a pure XLA compile timer; on the cache-size path
 the COMPILE_BEGIN event is materialized after the fact (the miss is
@@ -35,12 +36,34 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import os
 import threading
 import time
 
 from ray_tpu._private import events as _events
 from ray_tpu._private import profiling as _prof
 from ray_tpu._private import telemetry as _tm
+from ray_tpu._private.native_build import _REPO_ROOT
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a directory that is
+    the same for every process of this checkout, and return it. Call
+    before the first compile of any process that compiles.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and nothing
+    is set here. Unset: ``<checkout>/.jax_cache``, derived from this
+    file's location — the path is part of the cache key, so it must not
+    depend on a pid, a session directory or the working directory, or
+    no later process would ever hit."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    cache_dir = os.path.join(_REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 def _abstract_key(args, kwargs):
@@ -114,9 +137,9 @@ class CompiledFunction:
         try:
             out = self._fn(*args, **kwargs)
         except BaseException:
-            # NOT gated on the cache delta: some jax versions grow the
-            # pjit cache even when tracing raises, so the delta can't
-            # distinguish failure modes — the _seen set can (below)
+            # NOT gated on the cache delta: jax grows the pjit cache
+            # even when tracing raises, so the delta can't distinguish
+            # failure modes — the _seen set can (below)
             self._record_failed_call(args, kwargs, start,
                                      time.perf_counter() - t0, tags)
             raise
@@ -188,8 +211,8 @@ class CompiledFunction:
                                                   "step": step_id})
 
     def _call_classified_by_signature(self, args, kwargs):
-        """Fallback for callables without ``_cache_size``: classify by
-        a per-signature key. The signature is taken BEFORE the call —
+        """Plain (non-jit) callables have no ``_cache_size``: classify
+        by a per-signature key. The signature is taken BEFORE the call —
         donated buffers are unreadable after."""
         key = _abstract_key(args, kwargs)
         with self._seen_lock:

@@ -90,22 +90,13 @@ def create_mesh(
     sizes = config.axis_sizes()
     shape = tuple(sizes[a] for a in AXIS_ORDER)
     if devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(
-                shape, devices=np.asarray(devices, dtype=object)
-            )
-            return Mesh(dev_array, AXIS_ORDER)
-        except Exception as e:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "mesh_utils.create_device_mesh failed (%s: %s); falling back "
-                "to a naive device layout — collectives may cross non-neighbor "
-                "ICI links", type(e).__name__, e,
-            )
-    dev_array = np.asarray(devices, dtype=object).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(
+            shape, devices=np.asarray(devices, dtype=object)
+        )
+    else:
+        dev_array = np.asarray(devices, dtype=object).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 
 
@@ -211,22 +202,15 @@ def create_hybrid_mesh(
     config = config.resolved(per_slice)
 
     if devices[0].platform == "tpu" and slice_assignments is None:
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            inner = tuple(config.axis_sizes()[a] for a in AXIS_ORDER)
-            dcn = tuple(dcn_dp if a == "dp" else 1 for a in AXIS_ORDER)
-            dev_array = mesh_utils.create_hybrid_device_mesh(
-                inner, dcn, devices=devices)
-            return Mesh(dev_array, AXIS_ORDER)
-        except Exception as e:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "create_hybrid_device_mesh failed (%s: %s); using "
-                "slice-major fallback layout", type(e).__name__, e)
-    # Fallback (CPU tests / degraded TPU path): slice-major ordering makes
-    # dp the slowest-varying axis, so dp index = slice for the DCN part.
+        inner = tuple(config.axis_sizes()[a] for a in AXIS_ORDER)
+        dcn = tuple(dcn_dp if a == "dp" else 1 for a in AXIS_ORDER)
+        dev_array = mesh_utils.create_hybrid_device_mesh(
+            inner, dcn, devices=devices)
+        return Mesh(dev_array, AXIS_ORDER)
+    # Off-TPU (tests; virtual CPU devices carry no topology): slice-major
+    # ordering makes dp the slowest-varying axis, so dp index = slice.
     ordered: list = []
     for s in sorted(groups):
         ordered.extend(groups[s])

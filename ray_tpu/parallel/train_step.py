@@ -18,7 +18,19 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel import sharding as sh
-from ray_tpu.parallel.compile_watch import CompiledFunction
+from ray_tpu.parallel.compile_watch import (
+    CompiledFunction,
+    configure_compile_cache,
+)
+
+
+def _jit(fn, name: str, **jit_kwargs) -> CompiledFunction:
+    """Every compiled unit this module hands out: persistent compile
+    cache configured before its first compile, compile observability
+    (cache hit/miss counters, compile timing, COMPILE_BEGIN/END events)
+    around every call."""
+    configure_compile_cache()
+    return CompiledFunction(jax.jit(fn, **jit_kwargs), name)
 
 
 @jax.tree_util.register_dataclass
@@ -72,7 +84,7 @@ def make_train_state(
         opt_state = optimizer.init(params)
         return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=opt_state)
 
-    state = CompiledFunction(jax.jit(init_fn), "train_state_init")(rng)
+    state = _jit(init_fn, "train_state_init")(rng)
     _note_state_bytes(state)
     return state
 
@@ -103,7 +115,7 @@ def make_zero_train_state(
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                           opt_state=())
 
-    state = CompiledFunction(jax.jit(init_fn), "train_state_init")(rng)
+    state = _jit(init_fn, "train_state_init")(rng)
     _note_state_bytes(state)
     return state
 
@@ -197,8 +209,7 @@ def make_train_step(
                 loss_fn, has_aux=True)(params, batch)
             return dict(metrics), grads, optax.global_norm(grads)
 
-        zgrad_fn = CompiledFunction(jax.jit(zgrad_step),
-                                    "train_grad_step")
+        zgrad_fn = _jit(zgrad_step, "train_grad_step")
         box = {"pending": None}
 
         def resolve(state: TrainState) -> TrainState:
@@ -246,12 +257,8 @@ def make_train_step(
                 metrics,
             )
 
-        # compile observability: cache hit/miss counters, compile timing,
-        # COMPILE_BEGIN/END events — a slow step becomes attributable to
-        # recompilation (shape churn) instead of guessed at
-        return CompiledFunction(
-            jax.jit(step, donate_argnums=(0,) if donate else ()),
-            "train_step")
+        return _jit(step, "train_step",
+                    donate_argnums=(0,) if donate else ())
 
     def grad_step(params, batch):
         batch = _constrain_batch(batch)
@@ -272,10 +279,9 @@ def make_train_step(
             optax.global_norm(grads),
         )
 
-    grad_fn = CompiledFunction(jax.jit(grad_step), "train_grad_step")
-    apply_fn = CompiledFunction(
-        jax.jit(apply_step, donate_argnums=(0,) if donate else ()),
-        "train_apply_step")
+    grad_fn = _jit(grad_step, "train_grad_step")
+    apply_fn = _jit(apply_step, "train_apply_step",
+                    donate_argnums=(0,) if donate else ())
 
     def step(state: TrainState, batch):
         metrics, grads = grad_fn(state.params, batch)
@@ -305,4 +311,4 @@ def eval_step(loss_fn, mesh: Optional[Mesh] = None, batch_spec: P = P(("dp",), "
         _, metrics = loss_fn(params, batch)
         return metrics
 
-    return CompiledFunction(jax.jit(step), "eval_step")
+    return _jit(step, "eval_step")

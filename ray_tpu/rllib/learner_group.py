@@ -44,7 +44,6 @@ class LearnerGroup:
         return len(self.devices)
 
     def _build_step(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         axis = self.axis
@@ -65,11 +64,11 @@ class LearnerGroup:
             params = optax.apply_updates(params, updates)
             return params, opt_state, loss, aux
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             per_shard, mesh=self.mesh,
             in_specs=(P(), P(), P(axis)),
             out_specs=(P(), P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(smapped)
 
     def update(self, batch: dict) -> dict:

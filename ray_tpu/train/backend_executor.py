@@ -247,8 +247,7 @@ class JaxBackend(Backend):
             def _init_jax_distributed(rank, world_size, coordinator):
                 import jax
 
-                if not (hasattr(jax.distributed, "is_initialized") and
-                        jax.distributed.is_initialized()):
+                if not jax.distributed.is_initialized():
                     jax.distributed.initialize(
                         coordinator_address=coordinator,
                         num_processes=world_size, process_id=rank)

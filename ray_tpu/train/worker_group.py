@@ -8,6 +8,7 @@ group.
 """
 from __future__ import annotations
 
+import os
 import threading
 import time
 
@@ -18,12 +19,14 @@ from ray_tpu._private import api as _api
 class TrainWorker:
     """Actor body for one training worker."""
 
-    def __init__(self, world_rank: int, world_size: int):
+    def __init__(self, world_rank: int, world_size: int,
+                 num_tpus: float = 0):
         from ray_tpu._private import fault_injection as _fi
         from ray_tpu.air import session as _session
 
         self.world_rank = world_rank
         self.world_size = world_size
+        self.num_tpus = num_tpus     # TPU chips this worker's lease holds
         self.session = _session._Session(world_rank, world_size)
         self._thread = None
         self._device_identity = None
@@ -43,6 +46,26 @@ class TrainWorker:
 
             self._device_identity = local_device_identity()
         return self._device_identity
+
+    def _require_tpu_backend(self):
+        """A worker granted TPU chips computes on them or fails the gang
+        (``TpuBackendError``). A process whose JAX_PLATFORMS names other
+        platforms only was pinned there on purpose — CPU dry runs with
+        injected TPU resources — and is left alone."""
+        pinned = os.environ.get("JAX_PLATFORMS", "")
+        if not self.num_tpus or (pinned and "tpu" not in pinned.split(",")):
+            return
+        import jax
+
+        from ray_tpu import exceptions as exc
+
+        backend = jax.default_backend()
+        if backend != "tpu":
+            raise exc.TpuBackendError(
+                f"train worker rank {self.world_rank} holds "
+                f"{self.num_tpus:g} TPU chip(s) but its JAX backend is "
+                f"{backend!r}: libtpu could not take the chips (another "
+                f"process may hold them)")
 
     def setup_collective_group(self, world_size, rank, backend, group_name):
         from ray_tpu.util import collective as col
@@ -108,6 +131,7 @@ class TrainWorker:
             # gang member fuses by step, not by wall-clock windows
             step_anatomy.start(rank=self.world_rank)
             try:
+                self._require_tpu_backend()
                 train_fn(config) if config is not None else train_fn()
             except BaseException as e:  # noqa: BLE001
                 self.session.error = e
@@ -239,7 +263,8 @@ class WorkerGroup:
                         placement_group=placement_group,
                         placement_group_bundle_index=rank)
             self.workers.append(
-                remote_cls.options(**kwargs).remote(rank, num_workers))
+                remote_cls.options(**kwargs).remote(
+                    rank, num_workers, kwargs.get("num_tpus", 0)))
 
     def __len__(self):
         return len(self.workers)

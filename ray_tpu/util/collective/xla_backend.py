@@ -15,6 +15,10 @@ collective, the rendezvous carries only the coordinator address.
 p2p send/recv are not SPMD ops (only two ranks participate) and ride the
 host mailbox plane — same split as the reference, whose p2p also bypasses
 collective rings (collective.py:531 send / :594 recv are point-to-point).
+
+Not run on a chip: a process that touches JAX takes every local chip, so
+one jax.distributed process per rank needs one host per rank (or per-chip
+isolation the runtime does not have); it runs on CPU process worlds only.
 """
 from __future__ import annotations
 
@@ -36,9 +40,7 @@ class XlaGroup:
         # One jax.distributed world per process (jax constraint); a second
         # xla group in the same process reuses it and must have the same
         # membership shape.
-        already = jax.distributed.is_initialized() \
-            if hasattr(jax.distributed, "is_initialized") else False
-        if world_size > 1 and not already:
+        if world_size > 1 and not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=coordinator,
                 num_processes=world_size,

@@ -67,11 +67,13 @@ def test_flash_attention_interpret():
     k = jax.random.PRNGKey(2)
     B, S, H, D = 2, 256, 2, 32
     q, kk, v = [jax.random.normal(kq, (B, S, H, D)) for kq in jax.random.split(k, 3)]
-    o = flash_attention(q, kk, v, causal=True, block_q=128, block_k=128)
+    o = flash_attention(q, kk, v, causal=True, block_q=128, block_k=128,
+                        interpret=True)
     ref = reference_attention(q, kk, v, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
     g = jax.grad(
-        lambda q: jnp.sum(flash_attention(q, kk, v, block_q=128, block_k=128) ** 2)
+        lambda q: jnp.sum(flash_attention(
+            q, kk, v, block_q=128, block_k=128, interpret=True) ** 2)
     )(q)
     gref = jax.grad(lambda q: jnp.sum(reference_attention(q, kk, v) ** 2))(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gref), atol=5e-5)
@@ -89,7 +91,7 @@ def test_flash_attention_backward_all_grads():
 
         def loss_flash(q, kk, v):
             o = flash_attention(q, kk, v, causal=causal,
-                                block_q=128, block_k=128)
+                                block_q=128, block_k=128, interpret=True)
             return jnp.sum(o * jnp.cos(o))   # non-symmetric cotangents
 
         def loss_ref(q, kk, v):
@@ -102,6 +104,33 @@ def test_flash_attention_backward_all_grads():
             np.testing.assert_allclose(
                 np.asarray(g), np.asarray(gref), atol=1e-4,
                 err_msg=f"d{name} mismatch (S={S}, causal={causal})")
+
+
+def test_flash_attention_sharded_over_mesh():
+    """apply_attention(impl="flash", mesh=...) runs the kernel under
+    shard_map over the batch (dp) and heads (tp) axes — a Mosaic kernel on
+    mesh-sharded operands does not lower otherwise. The product path
+    (interpret=False), driven on CPU by forcing the TPU interpreter."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.models import layers as L
+
+    mesh = create_mesh(MeshConfig(dp=2, tp=2), devices=jax.devices()[:4])
+    p = L.init_attention(jax.random.PRNGKey(0), 64, 4)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 128, 64))
+
+    def loss(impl, mesh):
+        return lambda p, x: jnp.sum(L.apply_attention(
+            p, x, impl=impl, compute_dtype=jnp.float32, mesh=mesh) ** 2)
+
+    ref, gref = jax.value_and_grad(loss("reference", None))(p, x)
+    xs = jax.device_put(x, NamedSharding(mesh, P("dp")))
+    with pltpu.force_tpu_interpret_mode():
+        out, g = jax.jit(jax.value_and_grad(loss("flash", mesh)))(p, xs)
+    np.testing.assert_allclose(float(out), float(ref), rtol=1e-5)
+    for name in gref:
+        np.testing.assert_allclose(np.asarray(g[name]),
+                                   np.asarray(gref[name]), atol=1e-5)
 
 
 def test_moe_matches_per_token_oracle():
@@ -287,7 +316,6 @@ def test_hybrid_mesh_slice_major_dp():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.parallel.mesh import MeshConfig, create_hybrid_mesh
@@ -306,7 +334,7 @@ def test_hybrid_mesh_slice_major_dp():
 
     @jax.jit
     def summed(x):
-        return shard_map(
+        return jax.shard_map(
             lambda s: jax.lax.psum(s, "dp"),
             mesh=mesh, in_specs=P("dp"), out_specs=P(),
         )(x)

@@ -310,8 +310,8 @@ def test_compile_watch_failed_compile_visible_on_cache_size_path():
 def test_publish_local_device_gauges_in_process():
     """Owner-side gauge publish: in-process memory_stats from an
     already-imported jax backend, never a subprocess (the path train
-    workers use per step — the subprocess probe can't run while they
-    own the chips). On backends without memory stats it's a clean 0."""
+    workers use per step — they own the chips, so nothing else may
+    probe them). On backends without memory stats it's a clean 0."""
     import jax
 
     from ray_tpu._private.tpu_probe import publish_local_device_gauges
@@ -325,26 +325,6 @@ def test_publish_local_device_gauges_in_process():
         has_stats = False
     if has_stats:
         assert n == len(jax.local_devices())
-
-
-def test_device_gauge_poller_one_shot_by_default(monkeypatch):
-    """Default RAY_TPU_DEVICE_GAUGE_POLL_S=0: the publisher thread
-    probes once and EXITS — a recurring subprocess probe would contend
-    with training workers for TPU ownership."""
-    import time as _time
-
-    from ray_tpu._private import tpu_probe as tp
-
-    calls = []
-    monkeypatch.setattr(tp, "publish_device_gauges",
-                        lambda *a, **k: calls.append(1))
-    monkeypatch.setattr(tp, "_poller_thread", None)
-    assert tp.start_device_gauge_poller() is True
-    deadline = _time.time() + 5
-    while tp._poller_thread.is_alive() and _time.time() < deadline:
-        _time.sleep(0.02)
-    assert not tp._poller_thread.is_alive(), "poller should be one-shot"
-    assert calls == [1]
 
 
 def test_mesh_build_metric_recorded():
