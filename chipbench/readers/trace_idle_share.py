@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device,
+mean over the chips, in percent."""
+
+
+def read(ctx):
+    if not ctx["trace"]:
+        return None
+    return 100.0 * (1.0 - ctx["trace"]["busy_s"] / ctx["trace"]["window_s"])

@@ -1,0 +1,250 @@
+"""Rehearsals of a benchmark run, without the chip: the ``train_fit`` job's
+loop through ``JaxTrainer.fit()`` at a tiny configuration that lives in THIS
+directory (configuration, traffic mix, one metric and its reader: the
+harness finds them by name and no file under ``chipbench/`` knows them),
+the command's refusal to run without a TPU, and the plain reference against
+the system's ``loss_fn``."""
+import dataclasses
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from chipbench import catalog, compare, generate
+from chipbench.jobs import train_fit
+
+# "No TPU required" is an argument this test passes, not an option of the
+# command. The tiny cell reports what needs no chip and no peak.
+TINY_MANIFEST = {
+    "paths": ["chipbench", "tests/chipbench_tests"],
+    "workloads": [{"name": "tiny", "config": "gpt2-tiny",
+                   "traffic": "fit-tiny", "chips": 1, "why": "rehearsal"},
+                  {"name": "tiny-dp2tp2", "config": "gpt2-tiny",
+                   "traffic": "fit-tiny-dp2tp2", "chips": 4,
+                   "why": "the sharded path on four virtual devices"}],
+    "end_to_end": [
+        {"name": "tokens_per_s_per_chip", "unit": "tokens/s/chip"},
+        {"name": "step_ms_p90", "unit": "ms"},
+        {"name": "setup_s", "unit": "s"}],
+    "per_layer": [
+        {"name": "tiny.epochs", "unit": "count"},
+        {"name": "data.wait_ms", "unit": "ms"},
+        {"name": "train.report_ms", "unit": "ms"},
+        {"name": "train.fit_startup_s", "unit": "s"},
+        {"name": "train_step.compiles_in_window", "unit": "count"},
+        {"name": "train_step.hbm_plan_gb", "unit": "GB"},
+        {"name": "device.idle_share", "unit": "%"},
+        {"name": "collectives.exposed_share", "unit": "%",
+         "workloads": ["another-cell"]}],
+}
+
+
+def test_a_new_cell_is_files_and_entries_only():
+    cell = catalog.resolve_cell(TINY_MANIFEST, "tiny", "per_layer")
+    assert cell["model"]["entry"] == "ray_tpu.models.gpt2:gpt2_tiny"
+    assert cell["traffic"]["batch"] == 8
+    readers = {m["name"]: m["reader"] for m in cell["metrics"]}
+    # found in the tests' own directory / in the benchmark's
+    assert readers["tiny.epochs"] == \
+        "tests.chipbench_tests.readers.steps_per_epoch"
+    assert readers["data.wait_ms"] == "chipbench.readers.span_percentile"
+    # a metric whose `workloads` leaves the cell out is not computed there
+    assert "collectives.exposed_share" not in readers
+    with pytest.raises(KeyError, match="no workload 'nope'"):
+        catalog.resolve_cell(TINY_MANIFEST, "nope", "end_to_end")
+    with pytest.raises(FileNotFoundError, match="chipbench/metrics/ghost"):
+        catalog.resolve_cell(dict(TINY_MANIFEST, end_to_end=[
+            {"name": "ghost", "unit": "s"}]), "tiny", "end_to_end")
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_train_fit_job_through_the_trainer(trace):
+    cell = catalog.resolve_cell(TINY_MANIFEST, "tiny",
+                                "per_layer" if trace else "end_to_end")
+    t_start = time.time()
+    record = train_fit.run(cell, seed=3, seconds=1.5, trace=trace,
+                           t_start=t_start, require_tpu=False)
+    json.dumps(record)                       # plain data all the way down
+    assert {"correct", "attempted", "failed", "metrics", "device"} <= \
+        set(record)
+    assert record["device"]["platform"] == "cpu"
+    assert record["device"]["count"] >= 1
+    assert record["device"]["memory_peak_bytes"] > 0
+    assert record["attempted"] >= 32 and record["failed"] == 0
+    assert record["verdicts"]["every_loss_finite"]
+    assert record["verdicts"]["loss_fell"]
+    assert set(record["losses"]) == {"1", "8", "32"}
+    assert record["clock"]["window_s"] >= 1.5
+    values = {k: v["value"] for k, v in record["metrics"].items()}
+    if not trace:
+        # a 90th percentile only where ten samples lie beyond it
+        tail = {"step_ms_p90"} if record["attempted"] >= 100 else set()
+        assert set(values) == {"tokens_per_s_per_chip", "setup_s"} | tail
+        assert values["tokens_per_s_per_chip"] == pytest.approx(
+            record["attempted"] * 8 * 32 / record["clock"]["window_s"])
+        assert 0 < values["setup_s"] < time.time() - t_start
+    else:
+        # no TPU plane in a CPU trace: trace metrics are left out, not
+        # invented, and the line carries no breakdown
+        assert set(values) == {
+            "tiny.epochs", "data.wait_ms", "train.report_ms",
+            "train.fit_startup_s", "train_step.compiles_in_window",
+            "train_step.hbm_plan_gb"}
+        assert "breakdown" not in record
+        assert values["train_step.compiles_in_window"] == 0
+        assert values["tiny.epochs"] == record["attempted"] / 8
+        assert 0 < values["train.fit_startup_s"]
+        assert not os.path.exists(train_fit.TRACE_DIR)
+
+
+def test_sharded_cell_on_virtual_devices():
+    """dp=2 x tp=2 on four of the CPU's virtual devices: the mesh, the
+    sharded state, the gather of the parameters onto one device for the
+    reference, and agreement with it."""
+    cell = catalog.resolve_cell(TINY_MANIFEST, "tiny-dp2tp2", "end_to_end")
+    record = train_fit.run(cell, seed=4, seconds=1.0, trace=False,
+                           t_start=time.time(), require_tpu=False)
+    assert record["failed"] == 0 and record["verdicts"]["loss_fell"]
+    assert record["check"]["errors"]["loss"] < 1e-3
+    assert record["metrics"]["tokens_per_s_per_chip"]["value"] == \
+        pytest.approx(record["attempted"] * 8 * 32
+                      / record["clock"]["window_s"] / 4)
+
+
+def test_a_failed_loop_is_a_failed_job():
+    cell = catalog.resolve_cell(TINY_MANIFEST, "tiny", "end_to_end")
+    cell["model"] = dict(cell["model"], n_layer=3)   # not what the preset is
+    with pytest.raises(train_fit.JobFailed, match="configuration file says"):
+        train_fit.run(cell, seed=0, seconds=1.0, trace=False,
+                      t_start=time.time(), require_tpu=False)
+
+
+def test_command_exits_nonzero_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("RAY_TPU_TESTING", None)         # the real chip probe must run
+    manifest = catalog.load_manifest()
+    proc = subprocess.run(
+        [sys.executable if w == "python3" else w
+         for w in manifest["command"]]
+        + ["--workload", manifest["workloads"][0]["name"], "--seed", "0",
+           "--seconds", "1", "--trace", "0"],
+        cwd=catalog.ROOT, env=env, capture_output=True, text=True,
+        timeout=180)
+    assert proc.returncode != 0
+    assert "found 0" in proc.stderr
+    assert '"correct"' not in proc.stdout and '"metrics"' not in proc.stdout
+
+
+def test_parent_side_never_imports_jax():
+    code = ("import sys; import chipbench.run, chipbench.jobs.train_fit, "
+            "chipbench.generate, chipbench.trace_reduce, chipbench.flops; "
+            "import ray_tpu, ray_tpu.data; "
+            "from ray_tpu.train.trainer import JaxTrainer; "
+            "sys.exit('jax' in sys.modules)")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=catalog.ROOT,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_command_refuses_a_directory_without_the_program(tmp_path):
+    """BENCHMARK.json and the files under `paths`, nothing else."""
+    import shutil
+
+    manifest = catalog.load_manifest()
+    shutil.copy(os.path.join(catalog.ROOT, "BENCHMARK.json"), tmp_path)
+    for base in manifest["paths"]:
+        shutil.copytree(os.path.join(catalog.ROOT, base), tmp_path / base,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", "gpt2s-b16",
+         "--seed", "0", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=60)
+    assert proc.returncode != 0
+    assert "not the program" in proc.stderr and not proc.stdout.strip()
+
+
+# ------------------------------------------------- reference vs system
+
+def _tiny(dtype):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt2
+
+    cfg = dataclasses.replace(gpt2.gpt2_tiny(), attention="reference",
+                              dtype=getattr(jnp, dtype))
+    params = gpt2.init(jax.random.PRNGKey(0), cfg)
+    # biases and scales off their initial 0 and 1, so that a reference
+    # that dropped one would be caught
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    params = jax.tree_util.tree_unflatten(tree, [
+        leaf + 0.02 * jax.random.normal(k, leaf.shape, leaf.dtype)
+        for leaf, k in zip(leaves, keys)])
+    tokens = generate.token_rows(
+        {"batches": 1, "batch": 2, "seq": 64, "vocab_divisor": 1}, 256, 5)
+    return gpt2, cfg, params, tokens
+
+
+def test_reference_is_the_systems_function_in_float32():
+    """Same parameters, same tokens, float32 on both sides: the loss and
+    the four gradients agree to float32 rounding, so the two compute the
+    same function (tied head, no attention bias, tanh GELU, eps 1e-5)."""
+    import jax
+
+    from chipbench.references import gpt2 as reference
+
+    gpt2, cfg, params, tokens = _tiny("float32")
+    got = compare.compare(
+        lambda p, t: gpt2.loss_fn(p, {"tokens": t}, cfg)[0],
+        reference.loss, params, tokens, jax.devices()[0])
+    assert got["errors"]["loss"] < 1e-6
+    assert max(got["errors"].values()) < 1e-4, got["errors"]
+    assert got["within"]
+
+
+def test_bf16_passes_and_eight_bit_matmuls_would_fail():
+    """At the cell's dtype the system is inside the tolerances; with its
+    matmul inputs rounded to an 8-bit float (what computing in fp8 does to
+    them) it is outside: the comparison would catch a lower precision than
+    the configuration states."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench.references import gpt2 as reference
+
+    gpt2, cfg, params, tokens = _tiny("bfloat16")
+
+    def system(p, t):
+        return gpt2.loss_fn(p, {"tokens": t}, cfg)[0]
+
+    got = compare.compare(system, reference.loss, params, tokens,
+                          jax.devices()[0])
+    assert got["within"], got["errors"]
+
+    def eight_bit(p, t):
+        def cast(a):      # straight-through: the gradient passes unrounded
+            rounded = a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+            return a + jax.lax.stop_gradient(rounded - a)
+        blocks = dict(p["blocks"], mlp=jax.tree_util.tree_map(
+            cast, p["blocks"]["mlp"]), attn=jax.tree_util.tree_map(
+            cast, p["blocks"]["attn"]))
+        return system(dict(p, blocks=blocks), t)
+
+    low = compare.compare(eight_bit, reference.loss, params, tokens,
+                          jax.devices()[0])
+    assert not low["within"], low["errors"]
+
+
+def test_token_rows_come_from_the_seed():
+    traffic = {"batches": 3, "batch": 4, "seq": 16, "vocab_divisor": 16}
+    a = generate.token_rows(traffic, 50304, 7)
+    assert a.shape == (12, 17) and a.dtype == np.int32
+    assert a.min() >= 0 and a.max() < 50304 // 16
+    assert np.array_equal(a, generate.token_rows(traffic, 50304, 7))
+    assert not np.array_equal(a, generate.token_rows(traffic, 50304, 8))
