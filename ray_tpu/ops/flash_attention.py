@@ -1,13 +1,36 @@
 """Fused flash attention (Pallas TPU kernels, forward AND backward).
 
-The hot op of the flagship models. Forward is a Pallas kernel: grid over
-(batch*heads, Q blocks, KV blocks), online-softmax accumulators held in
-VMEM scratch across the sequential KV grid dimension, causal blocks
-skipped at block granularity. Backward is two Pallas kernels (the standard
-flash-attention split): a dq kernel gridded (BH, Q blocks, KV blocks) and
-a dk/dv kernel gridded (BH, KV blocks, Q blocks), each recomputing the
-probability block from the saved logsumexp — no O(S²) tensor is ever
-materialized in HBM, unlike a naive VJP.
+The hot op of the flagship models. Three kernels — forward, dq, dk/dv (the
+standard flash-attention split; each recomputes the probability tile from
+the saved logsumexp, so no O(S²) tensor ever reaches HBM) — share one tile
+schedule, derived from ``(S, D, dtype)`` by :func:`tile_plan`:
+
+* **Grid** ``(B·H, q-major blocks, kv-major blocks)`` (dk/dv: kv-major
+  parallel, q-major sequential). A major block is as much of the sequence
+  as a stated VMEM budget holds — the whole head at S = 1,024 — so K/V (or
+  Q/dO) are fetched once per head and no grid point is spent on a block the
+  causal mask empties; with several major blocks the index maps clamp to
+  the last needed block, so skipped ones are not fetched either.
+* **Inner loops** over ``tile_q × tile_k`` score tiles inside the kernel
+  (each kernel has its own tile, ``_TILES``). The forward and dq walk each
+  q tile's kv tiles, dk/dv walks each kv tile's q tiles (on the TRANSPOSED
+  score tile ``k·qᵀ``, so that ``pᵀ·dO`` and ``dSᵀ·q`` are plain
+  contractions and lse/delta are used as the rows they are stored as). Trip
+  counts come from the diagonal (:func:`_kv_tiles`, :func:`_q_tiles`):
+  tiles wholly above it are never issued. With one major block the counts
+  are static and the loops unrolled; with several they are traced.
+* **Masks only where the diagonal is.** Each loop is split: tiles wholly
+  below the diagonal run with no iota/compare/select; only the tiles that
+  cross it (or, non-causal, the ones holding padded columns) build a mask.
+* **Softmax state that never changes layout.** Running max and sum are
+  ``[tile_q, 128]`` loop carries (the sum lane-partial: its cross-lane
+  reduction happens once per q tile), ``scale`` is folded into the
+  stationary operand once per tile row, lse/delta are turned from stored
+  rows into columns once per q tile (dq) or never (dk/dv).
+
+Precision is unchanged: operands in the input dtype, f32 scores, f32
+softmax statistics and accumulators, ``p``/``dS`` cast to the input dtype
+for their matmuls.
 
 The kernels compile for the TPU or raise. ``interpret=True`` (the Pallas
 interpreter) is for tests that ask for it; off-TPU product code uses
@@ -16,7 +39,7 @@ parallel.ring_attention.reference_attention.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,113 +52,332 @@ _LANES = 128
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+# What one grid step may hold in VMEM (double-buffered blocks + scratch), of
+# the 16 MiB a Mosaic kernel gets by default; the rest is the compiler's.
+VMEM_BUDGET_BYTES = 10 * 1024 * 1024
+# Score-tile (rows, columns) of each kernel, swept on the v5e at
+# [192, 1024, 64] bf16 (PR 26): the forward likes a wide tile (fewer softmax
+# row statistics per score element), dq a square one, dk/dv — three live
+# tiles and two accumulators — the smallest.
+_TILES = {"fwd": (128, 256), "dq": (256, 256), "dkv": (128, 128)}
 
-def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *, scale, causal,
-    block_q, block_k, seq_len, padded,
-):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
 
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+# ------------------------------------------------------------------ tile plan
+class TilePlan(NamedTuple):
+    tile_q: int   # rows of a score tile (columns of dk/dv's transposed one)
+    tile_k: int   # columns of a score tile
+    major: int    # rows of a grid block (q and kv alike); divides s_pad
+    s_pad: int    # S padded up to a multiple of every tile
 
-    q_start = iq * block_q
-    k_start = ik * block_k
 
-    def _compute():
-        q = q_ref[0]  # [block_q, D]
-        k = k_ref[0]  # [block_k, D]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * scale  # [block_q, block_k]
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        if padded:
-            # Mask KV padding columns (inputs padded up to the block size).
-            s = jnp.where(cols < seq_len, s, NEG_INF)
-        m_prev = m_ref[:, 0]  # [block_q]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+class TilePlans(NamedTuple):
+    fwd: TilePlan
+    dq: TilePlan
+    dkv: TilePlan
 
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def vmem_bytes(major: int, head_dim: int, itemsize: int) -> int:
+    """VMEM one grid step of the hungriest kernel holds for a major block:
+    six [major, D] operand/result blocks and two [8, major] f32 rows, double-
+    buffered by the pipeline, plus the forward's m, l, acc (or the backward's
+    two f32 accumulators). The minor dimension occupies whole 128-lane tiles."""
+    lanes = _round_up(head_dim, _LANES)
+    blocks = 2 * (6 * major * lanes * itemsize + 2 * 8 * major * 4)
+    scratch = major * (2 * _LANES + lanes) * 4
+    return blocks + scratch
+
+
+def tile_plan(seq_len: int, head_dim: int, dtype,
+              block_q: Optional[int] = None,
+              block_k: Optional[int] = None) -> TilePlans:
+    """The schedule of the three kernels for ``[B·H, seq_len, head_dim]``.
+
+    Tiles are ``_TILES`` (128 · 2^n: a score tile's lane dimension and the
+    lane dimension of an lse row block need multiples of 128), never larger
+    than the sequence rounded up to 128; ``block_q``/``block_k`` override
+    the rows/columns of all three (tests). The kernels share the padded
+    length — the next multiple of the largest tile — and the major block:
+    the largest multiple of the tiles that divides the padded length and
+    fits ``VMEM_BUDGET_BYTES``.
+    """
+    cap = _round_up(seq_len, _LANES)
+    tiles = {name: (min(_round_up(block_q or tq, _LANES), cap),
+                    min(_round_up(block_k or tk, _LANES), cap))
+             for name, (tq, tk) in _TILES.items()}
+    sides = {side for pair in tiles.values() for side in pair}
+    step = max(sides)
+    if any(step % side for side in sides):
+        raise ValueError(f"tiles {tiles} do not nest")
+    s_pad = _round_up(seq_len, step)
+    itemsize = jnp.dtype(dtype).itemsize
+    major = max((m for m in range(step, s_pad + 1, step)
+                 if s_pad % m == 0
+                 and vmem_bytes(m, head_dim, itemsize) <= VMEM_BUDGET_BYTES),
+                default=step)
+    return TilePlans(**{name: TilePlan(tq, tk, major, s_pad)
+                        for name, (tq, tk) in tiles.items()})
+
+
+def _clamp(x, lo, hi):
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _div(x, d: int):
+    """x // d for a Python int or a traced scalar. A traced negative rounds
+    toward zero, not down: every caller clamps the quotient at 0."""
+    return x // d if isinstance(x, int) else jax.lax.div(x, d)
+
+
+def _kv_tiles(row0, col0, n_tiles: int, *, plan: TilePlan, causal: bool,
+              seq_len: int):
+    """For the q tile whose first row is ``row0`` and a kv-major block whose
+    first column is ``col0``: ``(n_plain, n_issued)`` — kv tiles
+    ``[0, n_plain)`` need no mask, ``[n_plain, n_issued)`` cross the diagonal
+    (or hold padded columns), the rest are never issued."""
+    tq, tk = plan.tile_q, plan.tile_k
     if causal:
-        # Skip KV blocks entirely in the future of this Q block.
-        pl.when(k_start <= q_start + block_q - 1)(_compute)
-    else:
-        _compute()
+        # issued while the tile's first column <= the q tile's last row;
+        # plain while its last column <= the q tile's first row
+        n_issued = _clamp(_div(row0 + tq - col0 + tk - 1, tk), 0, n_tiles)
+        n_plain = _clamp(_div(row0 - col0 + 1, tk), 0, n_issued)
+        return n_plain, n_issued
+    if plan.s_pad == seq_len:
+        return n_tiles, n_tiles
+    return _clamp(_div(seq_len - col0, tk), 0, n_tiles), n_tiles
 
-    @pl.when(ik == nk - 1)
+
+def _q_tiles(col0, row0, n_tiles: int, *, plan: TilePlan, causal: bool,
+             seq_len: int):
+    """For the kv tile whose first column is ``col0`` and a q-major block
+    whose first row is ``row0``: ``(first, n_masked_end)`` — q tiles
+    ``[first, n_masked_end)`` cross the diagonal (or, non-causal, meet padded
+    kv rows), ``[n_masked_end, n_tiles)`` need no mask, earlier ones are
+    never issued."""
+    tq, tk = plan.tile_q, plan.tile_k
+    if causal:
+        # issued once the tile's last row >= the kv tile's first column;
+        # plain once its first row >= the kv tile's last column
+        first = _clamp(_div(col0 - row0, tq), 0, n_tiles)
+        plain_from = _clamp(_div(col0 + tk - 1 - row0 + tq - 1, tq),
+                            first, n_tiles)
+        return first, plain_from
+    if plan.s_pad == seq_len:
+        return 0, 0
+    has_padding = col0 + tk > seq_len
+    if isinstance(has_padding, bool):
+        return 0, (n_tiles if has_padding else 0)
+    return 0, jnp.where(has_padding, n_tiles, 0)
+
+
+def issued_area_ratio(plan: TilePlan, seq_len: int) -> float:
+    """Score elements the causal forward issues ÷ elements the mask keeps:
+    the engagement counter of the schedule (1.5 with 512 × 512 blocks at
+    S = 1,024; 1.0 would be element granularity)."""
+    issued = 0
+    for row0 in range(0, plan.s_pad, plan.tile_q):
+        for col0 in range(0, plan.s_pad, plan.major):
+            _, n = _kv_tiles(row0, col0, plan.major // plan.tile_k, plan=plan,
+                             causal=True, seq_len=seq_len)
+            issued += n * plan.tile_q * plan.tile_k
+    return issued / (seq_len * (seq_len + 1) / 2)
+
+
+# -------------------------------------------------------------------- helpers
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scaled(x, scale):
+    """x · scale in x's dtype, via f32 (exact for the power-of-two scales of
+    head_dim 64 and 256)."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _lanes(x, n: int):
+    """A lane-broadcast [rows, 128] value widened (or cut) to n lanes."""
+    if n <= _LANES:
+        return x[:, :n]
+    return jnp.tile(x, (1, -(-n // _LANES)))[:, :n]
+
+
+def _lane_partial_sum(x):
+    """[rows, n·128] → [rows, 128]: adds the 128-lane column chunks (whole
+    vector registers); the cross-lane reduction is left to the caller."""
+    out = x[:, :_LANES]
+    for c in range(1, x.shape[1] // _LANES):
+        out = out + x[:, c * _LANES:(c + 1) * _LANES]
+    return out
+
+
+def _rows_to_cols(rows8):
+    """[8, n] (8 identical rows, as lse/delta are stored) → [n, 128]
+    lane-broadcast column."""
+    return jnp.transpose(jnp.tile(rows8, (_LANES // 8, 1)))
+
+
+def _cols_to_rows(cols):
+    """[n, 128] lane-broadcast column → [8, n] identical rows."""
+    return jnp.transpose(cols)[:8]
+
+
+def _block_start(axis: int, n_blocks: int, size: int):
+    """First row of this grid step's block: a Python int when there is one
+    block, so the loops it bounds have static trip counts."""
+    return 0 if n_blocks == 1 else pl.program_id(axis) * size
+
+
+def _keep(shape, row0, col0, *, causal, seq_len, transposed=False):
+    """The mask of a tile that crosses the diagonal (causal) or holds padded
+    kv positions (non-causal). ``transposed``: the tile is k·qᵀ, kv on
+    rows."""
+    q_dim, k_dim = (1, 0) if transposed else (0, 1)
+    kv = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, k_dim)
+    if causal:
+        return row0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim) >= kv
+    return kv < seq_len
+
+
+def _two_phase(lo, mid, hi, body, carry, *, masked_first: bool):
+    """fori_loop over [lo, mid) and [mid, hi) with body(i, carry, masked)."""
+    first = functools.partial(body, masked=masked_first)
+    second = functools.partial(body, masked=not masked_first)
+    carry = _loop(lo, mid, first, carry)
+    return _loop(mid, hi, second, carry)
+
+
+def _loop(lo, hi, body, carry):
+    """fori_loop; unrolled when the bounds are static (one major block: at
+    most major / tile trips), so that the scheduler overlaps one tile's
+    softmax with the next tile's matmul — 1.8× on the v5e (PR 26)."""
+    if all(isinstance(b, int) for b in (lo, hi)):
+        return jax.lax.fori_loop(lo, hi, body, carry, unroll=True)
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _ds(i, size: int):
+    return pl.ds(pl.multiple_of(i * size, size), size)
+
+
+# -------------------------------------------------------------------- forward
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+                *, scale, causal, plan, seq_len):
+    tq, tk, major = plan.tile_q, plan.tile_k, plan.major
+    n_major = plan.s_pad // major
+    head_dim = q_ref.shape[-1]
+    j = pl.program_id(2)
+    row0_major = _block_start(1, n_major, major)
+    col0_major = _block_start(2, n_major, major)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    for qi in range(major // tq):
+        rows = slice(qi * tq, (qi + 1) * tq)
+        row0 = row0_major + qi * tq
+        q = _scaled(q_ref[0, rows, :], scale)
+        n_plain, n_issued = _kv_tiles(row0, col0_major, major // tk, plan=plan,
+                                      causal=causal, seq_len=seq_len)
+
+        def body(t, carry, masked):
+            m, l, acc = carry               # [tq,128], [tq,128], [tq,D]
+            cols = _ds(t, tk)
+            s = _dot(q, k_ref[0, cols, :], _NT)              # [tq, tk]
+            if masked:
+                s = jnp.where(_keep(s.shape, row0, col0_major + t * tk,
+                                    causal=causal, seq_len=seq_len),
+                              s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - _lanes(m_new, tk))
+            l = alpha * l + _lane_partial_sum(p)
+            v = v_ref[0, cols, :]
+            acc = acc * _lanes(alpha, head_dim) + _dot(p.astype(v.dtype), v, _NN)
+            return m_new, l, acc
+
+        m, l, acc = _two_phase(
+            0, n_plain, n_issued, body,
+            (m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :]),
+            masked_first=False)
+        m_scr[rows, :] = m
+        l_scr[rows, :] = l
+        acc_scr[rows, :] = acc
+
+    @pl.when(j == n_major - 1)
     def _finish():
-        l = l_ref[:, 0]
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        # lse is materialized as [BH, 8, S] (8 broadcast sublanes) to satisfy
-        # the TPU (8, 128) block-tiling constraint; callers slice [:, 0, :].
-        lse = m_ref[:, 0] + jnp.log(jnp.maximum(l, 1e-30))
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], (8, lse.shape[0]))
+        for qi in range(major // tq):
+            rows = slice(qi * tq, (qi + 1) * tq)
+            l = jnp.maximum(jnp.sum(l_scr[rows, :], axis=1, keepdims=True),
+                            1e-30)
+            o_ref[0, rows, :] = (acc_scr[rows, :] / l).astype(o_ref.dtype)
+            # lse is materialized as [BH, q tiles, 8, tile_q] (8 broadcast
+            # sublanes) to satisfy the TPU (8, 128) block-tiling constraint.
+            lse_ref[0, qi] = _cols_to_rows(m_scr[rows, :] + jnp.log(l))
+
+
+def _row_spec(plan: TilePlan, index_map):
+    """lse/delta rows: [BH, q tiles, 8, tile_q], a major block's tiles."""
+    return pl.BlockSpec((1, plan.major // plan.tile_q, 8, plan.tile_q),
+                        index_map)
+
+
+def _kv_index(causal: bool):
+    """Index map of the forward's and dq's K/V blocks. Causal: kv-major
+    blocks past the q block's diagonal are never needed; clamping their
+    index keeps the pipeline from fetching them."""
+    if causal:
+        return lambda b, i, j: (b, jnp.minimum(j, i), 0)
+    return lambda b, i, j: (b, j, 0)
 
 
 def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
     """q,k,v: [BH, S, D] -> (o [BH,S,D], lse [BH,S]).
 
-    Sequence lengths that don't divide the block size are zero-padded up to
-    the next block multiple; padded KV columns are masked inside the kernel
-    and padded Q rows sliced off the output.
+    Sequence lengths that don't divide the tiles are zero-padded up to the
+    next multiple; padded KV columns are masked inside the kernel and padded
+    Q rows sliced off the output.
     """
     BH, S, D = q.shape
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    S_pad = -(-S // block_q) * block_q
-    S_pad = -(-S_pad // block_k) * block_k
+    plan = tile_plan(S, D, q.dtype, block_q, block_k).fwd
+    S_pad, major = plan.s_pad, plan.major
     if S_pad != S:
         pad = [(0, 0), (0, S_pad - S), (0, 0)]
         q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
-    grid = (BH, S_pad // block_q, S_pad // block_k)
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        seq_len=S, padded=S_pad != S,
-    )
+    n_major = S_pad // major
+    qspec = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
+    kspec = pl.BlockSpec((1, major, D), _kv_index(causal))
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
-        ],
+        functools.partial(_fwd_kernel, scale=scale, causal=causal, plan=plan,
+                          seq_len=S),
+        grid=(BH, n_major, n_major),
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, _row_spec(plan, lambda b, i, j: (b, i, 0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S_pad, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, 8, S_pad), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S_pad // plan.tile_q, 8, plan.tile_q),
+                                 jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((major, _LANES), jnp.float32),
+            pltpu.VMEM((major, _LANES), jnp.float32),
+            pltpu.VMEM((major, D), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v)
-    return o[:, :S], lse[:, 0, :S]
+    return o[:, :S], lse[:, :, 0, :].reshape(BH, S_pad)[:, :S]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -155,173 +397,159 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc,
-    *, scale, causal, block_q, block_k, seq_len, padded,
-):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+# ------------------------------------------------------------------- backward
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dq_scr, *, scale, causal, plan, seq_len):
+    tq, tk, major = plan.tile_q, plan.tile_k, plan.major
+    n_major = plan.s_pad // major
+    j = pl.program_id(2)
+    row0_major = _block_start(1, n_major, major)
+    col0_major = _block_start(2, n_major, major)
 
-    @pl.when(ik == 0)
+    @pl.when(j == 0)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
+    for qi in range(major // tq):
+        rows = slice(qi * tq, (qi + 1) * tq)
+        row0 = row0_major + qi * tq
+        q = _scaled(q_ref[0, rows, :], scale)
+        do = do_ref[0, rows, :]
+        lse = _lanes(_rows_to_cols(lse_ref[0, qi]), tk)       # [tq, tk]
+        delta = _lanes(_rows_to_cols(delta_ref[0, qi]), tk)
+        n_plain, n_issued = _kv_tiles(row0, col0_major, major // tk, plan=plan,
+                                      causal=causal, seq_len=seq_len)
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0]      # [block_q]
-        delta = delta_ref[0, 0]  # [block_q]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        if padded:
-            s = jnp.where(cols < seq_len, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                       # [bq, bk]
-        dp = jax.lax.dot_general(                           # do @ v^T
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        dq_acc[:] += jax.lax.dot_general(                   # ds @ k
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def body(t, dq, masked):
+            cols = _ds(t, tk)
+            k = k_ref[0, cols, :]
+            s = _dot(q, k, _NT)                                # [tq, tk]
+            if masked:
+                s = jnp.where(_keep(s.shape, row0, col0_major + t * tk,
+                                    causal=causal, seq_len=seq_len),
+                              s, NEG_INF)
+            p = jnp.exp(s - lse)
+            dp = _dot(do, v_ref[0, cols, :], _NT)              # do · vᵀ
+            ds = p * (dp - delta)
+            return dq + _dot(ds.astype(k.dtype), k, _NN)       # ds · k
 
-    if causal:
-        pl.when(k_start <= q_start + block_q - 1)(_compute)
-    else:
-        _compute()
+        dq_scr[rows, :] = _two_phase(0, n_plain, n_issued, body,
+                                     dq_scr[rows, :], masked_first=False)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(j == n_major - 1)
     def _finish():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, scale, causal, block_q, block_k, seq_len, padded,
-):
-    ikb = pl.program_id(1)   # KV block (parallel)
-    iqb = pl.program_id(2)   # Q block (sequential accumulation)
-    nq = pl.num_programs(2)
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                    dv_ref, dk_scr, dv_scr, *, scale, causal, plan, seq_len):
+    tq, tk, major = plan.tile_q, plan.tile_k, plan.major
+    n_major = plan.s_pad // major
+    j = pl.program_id(2)
+    col0_major = _block_start(1, n_major, major)   # kv-major: parallel
+    row0_major = _block_start(2, n_major, major)   # q-major: accumulated
 
-    @pl.when(iqb == 0)
+    @pl.when(j == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q_start = iqb * block_q
-    k_start = ikb * block_k
+    for ki in range(major // tk):
+        cols = slice(ki * tk, (ki + 1) * tk)
+        col0 = col0_major + ki * tk
+        k = _scaled(k_ref[0, cols, :], scale)
+        v = v_ref[0, cols, :]
+        first, plain_from = _q_tiles(col0, row0_major, major // tq, plan=plan,
+                                     causal=causal, seq_len=seq_len)
 
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        if padded:
-            s = jnp.where(cols < seq_len, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                       # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(                   # p^T @ do
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(                           # do @ v^T
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None]) * scale
-        dk_acc[:] += jax.lax.dot_general(                   # ds^T @ q
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        def body(t, carry, masked):
+            dk, dv = carry
+            rows = _ds(t, tq)
+            q = q_ref[0, rows, :]
+            do = do_ref[0, rows, :]
+            st = _dot(k, q, _NT)                               # [tk, tq]
+            if masked:
+                st = jnp.where(_keep(st.shape, row0_major + t * tq, col0,
+                                     causal=causal, seq_len=seq_len,
+                                     transposed=True),
+                               st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[0, t, 0:1, :])           # row lse
+            dv = dv + _dot(pt.astype(do.dtype), do, _NN)       # pᵀ · do
+            dpt = _dot(v, do, _NT)                             # v · doᵀ
+            dst = pt * (dpt - delta_ref[0, t, 0:1, :])
+            dk = dk + _dot(dst.astype(q.dtype), q, _NN)        # dsᵀ · q
+            return dk, dv
 
-    if causal:
-        # Q blocks strictly before this KV block contribute nothing.
-        pl.when(q_start + block_q - 1 >= k_start)(_compute)
-    else:
-        _compute()
+        dk, dv = _two_phase(first, plain_from, major // tq, body,
+                            (dk_scr[cols, :], dv_scr[cols, :]),
+                            masked_first=True)
+        dk_scr[cols, :] = dk
+        dv_scr[cols, :] = dv
 
-    @pl.when(iqb == nq - 1)
+    @pl.when(j == n_major - 1)
     def _finish():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _broadcast8(x):
-    """[BH, S] → [BH, 8, S] so the (8, 128) TPU tile constraint holds for
-    row-vector inputs (same trick the forward uses for its lse output)."""
-    return jnp.broadcast_to(x[:, None, :], (x.shape[0], 8, x.shape[1]))
+def _tile_rows(x, plan: TilePlan):
+    """[BH, S_pad] → [BH, q tiles, 8, tile_q]: each q tile's values as 8
+    identical rows, so the (8, 128) TPU tile constraint holds for row-vector
+    inputs and a tile is picked by a leading index (the same layout the
+    forward writes its lse in)."""
+    BH, S_pad = x.shape
+    x = x.reshape(BH, S_pad // plan.tile_q, 1, plan.tile_q)
+    return jnp.broadcast_to(x, (BH, x.shape[1], 8, plan.tile_q))
 
 
 def _flash_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
                interpret):
     """Pallas backward: returns (dq, dk, dv), each [BH, S, D]."""
     BH, S, D = q.shape
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    S_pad = -(-S // block_q) * block_q
-    S_pad = -(-S_pad // block_k) * block_k
+    plans = tile_plan(S, D, q.dtype, block_q, block_k)
+    S_pad, major = plans.dq.s_pad, plans.dq.major
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if S_pad != S:
         pad = [(0, 0), (0, S_pad - S), (0, 0)]
-        q, k, v, o, do = (jnp.pad(x, pad) for x in (q, k, v, o, do))
+        q, k, v, do = (jnp.pad(x, pad) for x in (q, k, v, do))
         lse = jnp.pad(lse, [(0, 0), (0, S_pad - S)])
         delta = jnp.pad(delta, [(0, 0), (0, S_pad - S)])
-    lse8 = _broadcast8(lse)
-    delta8 = _broadcast8(delta)
-    nq, nk = S_pad // block_q, S_pad // block_k
-    kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-              seq_len=S, padded=S_pad != S)
+    n_major = S_pad // major
+    kw = dict(scale=scale, causal=causal, seq_len=S)
+    scratch = pltpu.VMEM((major, D), jnp.float32)
 
-    qspec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0))
-    row_q = pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i))
+    # dq: q-major blocks parallel, kv-major sequential, as the forward
+    qspec = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
+    kspec = pl.BlockSpec((1, major, D), _kv_index(causal))
+    row_q = _row_spec(plans.dq, lambda b, i, j: (b, i, 0, 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kw),
-        grid=(BH, nq, nk),
+        functools.partial(_bwd_dq_kernel, plan=plans.dq, **kw),
+        grid=(BH, n_major, n_major),
         in_specs=[qspec, kspec, kspec, qspec, row_q, row_q],
         out_specs=[qspec],
         out_shape=[jax.ShapeDtypeStruct((BH, S_pad, D), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        scratch_shapes=[scratch],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(q, k, v, do, lse8, delta8)[0]
+    )(q, k, v, do, _tile_rows(lse, plans.dq), _tile_rows(delta, plans.dq))[0]
 
-    # dk/dv: grid transposed — KV blocks parallel, Q blocks sequential
-    qspec2 = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0))
-    kspec2 = pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0))
-    row_q2 = pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, j))
+    # dk/dv: grid transposed — kv-major blocks parallel, q-major sequential;
+    # causal: q-major blocks before the kv block's diagonal are never needed
+    q_index = (lambda i, j: jnp.maximum(j, i)) if causal else (lambda i, j: j)
+    qspec2 = pl.BlockSpec((1, major, D), lambda b, i, j: (b, q_index(i, j), 0))
+    kspec2 = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
+    row_q2 = _row_spec(plans.dkv, lambda b, i, j: (b, q_index(i, j), 0, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kw),
-        grid=(BH, nk, nq),
+        functools.partial(_bwd_dkv_kernel, plan=plans.dkv, **kw),
+        grid=(BH, n_major, n_major),
         in_specs=[qspec2, kspec2, kspec2, qspec2, row_q2, row_q2],
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct((BH, S_pad, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, S_pad, D), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+        scratch_shapes=[scratch, scratch],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(q, k, v, do, lse8, delta8)
+    )(q, k, v, do, _tile_rows(lse, plans.dkv), _tile_rows(delta, plans.dkv))
     return dq[:, :S], dk[:, :S], dv[:, :S]
 
 
@@ -344,12 +572,17 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Flash attention over [B, S, H, D] (heads layout matching
-    models/layers.apply_attention). Differentiable via custom VJP."""
+    models/layers.apply_attention). Differentiable via custom VJP.
+
+    The schedule (score-tile shape, major block, padding) is derived from
+    ``(S, D, dtype)`` by :func:`tile_plan`; ``block_q``/``block_k`` override
+    the score tile's rows/columns (multiples of 128) and exist for tests.
+    """
     B, S, H, D = q.shape
     if scale is None:
         scale = 1.0 / (D**0.5)

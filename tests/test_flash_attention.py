@@ -1,0 +1,138 @@
+"""The flash-attention kernels' tile schedule (ops/flash_attention.py): the
+pure plan function, and o / dq / dk / dv against reference_attention through
+the Pallas interpreter on CPU, with the derived tiles and the 128 override."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import flash_attention as fa
+from ray_tpu.parallel.ring_attention import reference_attention
+
+
+def _out_and_grads(attend, q, k, v, w):
+    o, vjp = jax.vjp(attend, q, k, v)
+    return (o, *vjp(w))          # w: a non-symmetric cotangent
+
+
+def _inputs(S, D, dtype=jnp.float32, heads=1):
+    keys = jax.random.split(jax.random.PRNGKey(S * 131 + D), 4)
+    return [jax.random.normal(key, (1, S, heads, D), dtype) for key in keys]
+
+
+@pytest.mark.parametrize("blocks", [None, 128], ids=["derived", "b128"])
+@pytest.mark.parametrize("S,D", [(128, 32), (128, 64), (192, 32), (192, 64),
+                                 (256, 32), (256, 64), (1024, 64)])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_matches_reference(causal, S, D, blocks):
+    """S = 192 is padded; S = 1,024 at D = 64 is the benchmark's shape:
+    several kv tiles to a q tile, split into plain and diagonal ones (the
+    interpreter makes it the slow case, so D = 32 is left to the short
+    ones)."""
+    q, k, v, w = _inputs(S, D)
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, block_q=blocks, block_k=blocks,
+            interpret=True), q, k, v, w)
+    ref = _out_and_grads(
+        lambda q, k, v: reference_attention(q, k, v, causal=causal),
+        q, k, v, w)
+    for name, g, r, atol in zip(("o", "dq", "dk", "dv"), got, ref,
+                                (2e-5, 1e-4, 1e-4, 1e-4)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=atol,
+                                   err_msg=f"{name} (S={S}, D={D})")
+
+
+def test_flash_bf16_within_the_chip_smoke_tolerance():
+    """bf16 operands, f32 scores and statistics: the relative error
+    chip_smoke.py allows the compiled kernels (0.02)."""
+    q, k, v, w = _inputs(256, 64, jnp.bfloat16, heads=2)
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(q, k, v, interpret=True),
+        q, k, v, w)
+    ref = _out_and_grads(
+        reference_attention, *(x.astype(jnp.float32) for x in (q, k, v, w)))
+    for name, g, r in zip(("o", "dq", "dk", "dv"), got, ref):
+        g, r = np.asarray(g, np.float32), np.asarray(r)
+        err = np.max(np.abs(g - r)) / max(1.0, np.max(np.abs(r)))
+        assert err <= 0.02, (name, err)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_several_major_blocks(monkeypatch, causal):
+    """A VMEM budget that holds a quarter of the sequence: four q-major and
+    four kv-major grid blocks, traced loop bounds, clamped index maps; the
+    padded tail (S = 450 of 512) sits in the last one."""
+    monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 1_100_000)
+    S, D = 450, 64
+    assert fa.tile_plan(S, D, jnp.float32, 128, 128).fwd == \
+        fa.TilePlan(128, 128, 128, 512)
+    q, k, v, w = _inputs(S, D, heads=2)
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            interpret=True), q, k, v, w)
+    ref = _out_and_grads(
+        lambda q, k, v: reference_attention(q, k, v, causal=causal),
+        q, k, v, w)
+    for name, g, r in zip(("o", "dq", "dk", "dv"), got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-4,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("S,D", [(1024, 64), (2048, 64), (4096, 128),
+                                 (192, 32)])
+def test_tile_plan_shapes(S, D):
+    plans = fa.tile_plan(S, D, jnp.bfloat16)
+    assert len({(p.major, p.s_pad) for p in plans}) == 1   # shared
+    for plan in plans:
+        assert plan.s_pad >= S and plan.s_pad - S < max(plan.tile_q,
+                                                        plan.tile_k)
+        # (8, 128) tiling: a tile side is the lane dimension of a score tile
+        # or an lse row block, and a sublane multiple of any dtype
+        assert plan.tile_q % 128 == 0 and plan.tile_k % 128 == 0
+        assert plan.major % plan.tile_q == 0 and plan.major % plan.tile_k == 0
+        assert plan.s_pad % plan.major == 0
+        assert fa.vmem_bytes(plan.major, D, 2) <= fa.VMEM_BUDGET_BYTES
+    if S <= 2048:
+        assert plans.fwd.major == plans.fwd.s_pad   # one block: K/V once a head
+
+
+def test_tile_plan_issued_area():
+    """The engagement counter: score elements the causal forward issues over
+    those the mask keeps, at the benchmark's S = 1,024."""
+    derived = fa.tile_plan(1024, 64, jnp.bfloat16)
+    assert fa.issued_area_ratio(derived.fwd, 1024) <= 1.25
+    assert fa.issued_area_ratio(derived.dkv, 1024) <= 1.25
+    # the fixed 512 x 512 blocks this schedule replaced
+    old = fa.tile_plan(1024, 64, jnp.bfloat16, 512, 512).fwd
+    assert fa.issued_area_ratio(old, 1024) == pytest.approx(1.5, abs=0.01)
+
+
+def test_tile_ranges_cover_exactly_the_unmasked_tiles():
+    """_kv_tiles / _q_tiles against the mask itself: a tile is issued iff the
+    causal mask keeps one of its elements, and runs unmasked iff it keeps
+    all of them; both views of the triangle agree."""
+    plan = fa.TilePlan(128, 256, 1024, 1024)
+    keep = np.tril(np.ones((1024, 1024), bool))
+    issued_by_rows = set()
+    for qi in range(1024 // plan.tile_q):
+        n_plain, n_issued = fa._kv_tiles(qi * plan.tile_q, 0, 4, plan=plan,
+                                         causal=True, seq_len=1024)
+        for t in range(4):
+            tile = keep[qi * 128:(qi + 1) * 128, t * 256:(t + 1) * 256]
+            assert (t < n_issued) == bool(tile.any())
+            assert (t < n_plain) == bool(tile.all())
+            if t < n_issued:
+                issued_by_rows.add((qi, t))
+    issued_by_cols = set()
+    for t in range(4):
+        first, plain_from = fa._q_tiles(t * plan.tile_k, 0, 8, plan=plan,
+                                        causal=True, seq_len=1024)
+        for qi in range(8):
+            tile = keep[qi * 128:(qi + 1) * 128, t * 256:(t + 1) * 256]
+            assert (qi >= first) == bool(tile.any())
+            assert (qi >= plain_from) == bool(tile.all())
+            if qi >= first:
+                issued_by_cols.add((qi, t))
+    assert issued_by_rows == issued_by_cols
