@@ -276,9 +276,12 @@ def train_loop(config: dict):
             k: getattr(mem, f"{k}_size_in_bytes")
             for k in ("argument", "output", "alias", "temp")}
         hlo = compiled.as_text()
+        # a device's share of the batch, which the tp region of the layer
+        # loop runs as two half-batch chains (one kernel call each)
+        local_batch = config["batch"] // mesh.shape["dp"]
+        chains = gpt2.tp_exchange_plan(cfg, mesh, local_batch)[2]
         report["flash_partitioning"] = flash_operand_report(hlo, (
-            config["batch"] // mesh.shape["dp"]
-            * cfg.n_head // mesh.shape["tp"],
+            local_batch // chains * cfg.n_head // mesh.shape["tp"],
             config["seq"], cfg.d_model // cfg.n_head))
         if config["out_dir"]:
             os.makedirs(config["out_dir"], exist_ok=True)

@@ -6,6 +6,17 @@ Pure-JAX pytree model, TPU-first: bf16 compute / f32 params, einsum-only
 sharding by logical axes (parallel/sharding.py) so the same forward runs
 dp/tp/sp/ep on any mesh; pipeline-parallel forward via parallel/pipeline.py.
 
+Where the `tp` reductions come from: not from the partitioner. On a mesh
+with `tp` > 1 the dense layer loop of `forward` is per-device code
+(`_tp_blocks`, one `jax.shard_map` around the scan): the model issues each
+block's reductions itself, as neighbour exchanges (`layers.exchange_sum`:
+`ppermute` over `tp` + add) on two independent half-batch chains, because
+the TPU compiler runs a `collective-permute` beside the other chain's
+matmuls and blocks on an `all-reduce`. `tp_exchange_plan` counts them from
+shapes. Outside the loop (embedding, vocabulary projection, loss), with
+`tp` == 1, in `forward_pipelined` and for MoE blocks, collectives are still
+what the sharding rules imply.
+
 Equivalent reference workload: Ray Train GPT-2 fine-tune
 (/root/reference/release/train_tests/, BASELINE.json configs); the model
 itself is new — the reference contains no model implementations, it wraps
@@ -15,6 +26,7 @@ positional embeddings, pre-LN blocks, GELU MLP, tied LM head).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -165,17 +177,97 @@ def _resolve_attention(cfg: GPT2Config, mesh: Optional[Mesh]) -> str:
     return "reference"
 
 
-def _block_apply(block, x, cfg: GPT2Config, impl: str, mesh=None):
+def _block_apply(block, x, cfg: GPT2Config, impl: str, mesh=None,
+                 reduce=None):
+    """reduce: given by `_tp_blocks`, where `block` is this device's shard
+    and x its share of the batch; None wherever the partitioner (or nobody)
+    splits the weights."""
     cd = cfg.dtype
     h = L.layer_norm(x, block["ln1"]["scale"], block["ln1"]["bias"])
     x = x + L.apply_attention(block["attn"], h, causal=True, impl=impl,
-                              compute_dtype=cd, mesh=mesh)
+                              compute_dtype=cd, mesh=mesh, reduce=reduce)
     h = L.layer_norm(x, block["ln2"]["scale"], block["ln2"]["bias"])
     if cfg.moe:
         m, aux = L.apply_moe(block["moe"], h, cfg.moe, compute_dtype=cd)
     else:
-        m, aux = L.apply_mlp(block["mlp"], h, compute_dtype=cd), jnp.float32(0)
+        m = L.apply_mlp(block["mlp"], h, compute_dtype=cd, reduce=reduce)
+        aux = jnp.float32(0)
     return x + m, aux
+
+
+def _tp_size(cfg: GPT2Config, mesh: Optional[Mesh]) -> int:
+    """Over how many devices the model itself reduces a block's row-parallel
+    outputs: the mesh's `tp` size for a dense stack; 1 (the partitioner
+    reduces, or nobody has to) for no mesh and for MoE."""
+    if mesh is None or cfg.moe:
+        return 1
+    return sh.axis_size(mesh, "tp")
+
+
+def _chains(local_batch: int) -> int:
+    """Independent chains a block runs its local batch as: two halves, so
+    that one half's exchange is in flight beside the other half's matmuls;
+    one where the batch does not halve (the exchange is then exposed)."""
+    return 2 if local_batch % 2 == 0 else 1
+
+
+def tp_exchange_plan(cfg: GPT2Config, mesh: Optional[Mesh], local_batch: int,
+                     seq: Optional[int] = None):
+    """(exchanges, bytes, chains) of one training step's layer loops on one
+    device: how often `_tp_blocks` engages, from shapes alone.
+
+    A chain exchanges twice a layer forward (attention and MLP outputs),
+    twice backward (their cotangents), and once more under remat: the
+    recomputed attention output; the recomputed MLP output is dead code. A
+    reduction over `tp` devices is tp − 1 exchanges of the chain's whole
+    [batch, seq, d_model] activation. `seq` is the global sequence length
+    (default `cfg.max_seq`)."""
+    tp = _tp_size(cfg, mesh)
+    if tp == 1:
+        return 0, 0, 1
+    chains = _chains(local_batch)
+    per_layer = 5 if cfg.remat else 4
+    exchanges = cfg.n_layer * chains * per_layer * (tp - 1)
+    seq = (seq or cfg.max_seq) // sh.axis_size(mesh, "sp")
+    size = (local_batch // chains) * seq * cfg.d_model \
+        * jnp.dtype(cfg.dtype).itemsize
+    return exchanges, exchanges * size, chains
+
+
+def _tp_blocks(blocks, x, cfg: GPT2Config, impl: str, mesh: Mesh):
+    """The layer loop where `tp` > 1, as per-device code over the whole mesh.
+
+    Each device holds its head and MLP shard of every block and its share of
+    the batch (and, under `sp`, of the sequence). The reduction after each
+    row-parallel matmul (`wo`, `w2`) is `L.exchange_sum` — issued here, not
+    implied by the sharding rules — and the local batch runs as two
+    independent half-batch chains, so the compiler has the other half's
+    matmuls and flash call to run while a half's exchange is in flight.
+    Column-parallel inputs need nothing forward. Backward is JAX's own
+    transpose: the same exchange on each reduced output's cotangent, so a
+    device carries its share of the residual stream's cotangent, and the
+    shares (and the gradients of what `tp` replicates) are summed once, at
+    the region's edge, with the `dp` sum of the stacked weight gradients."""
+    reduce = functools.partial(L.exchange_sum, axis_name="tp")
+    if impl == "ring":
+        impl = "ring_local"    # `sp` is manual here too
+
+    def local(blocks, x):
+        chains = tuple(jnp.split(x, _chains(x.shape[0])))
+
+        def body(chains, block):
+            return tuple(_block_apply(block, c, cfg, impl, reduce=reduce)[0]
+                         for c in chains), None
+
+        if cfg.remat:
+            body = jax.checkpoint(body)
+        chains, _ = jax.lax.scan(body, chains, blocks)
+        return jnp.concatenate(chains)
+
+    x_spec = sh.spec("batch", "seq", "embed")
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(partition_specs(cfg)["blocks"], x_spec),
+        out_specs=x_spec, check_vma=False)(blocks, x)
 
 
 def embed(params, tokens, cfg: GPT2Config):
@@ -203,16 +295,20 @@ def forward(params, tokens, cfg: GPT2Config, mesh: Optional[Mesh] = None):
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
 
-    def body(carry, block):
-        x, aux = carry
-        x, a = _block_apply(block, x, cfg, impl, mesh)
-        if mesh is not None:
-            x = sh.constrain(x, mesh, "batch", "seq", "embed")
-        return (x, aux + a), None
+    if _tp_size(cfg, mesh) > 1:
+        x, aux = _tp_blocks(params["blocks"], x, cfg, impl, mesh), jnp.float32(0)
+    else:
+        def body(carry, block):
+            x, aux = carry
+            x, a = _block_apply(block, x, cfg, impl, mesh)
+            if mesh is not None:
+                x = sh.constrain(x, mesh, "batch", "seq", "embed")
+            return (x, aux + a), None
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0)), params["blocks"])
+        if cfg.remat:
+            body = jax.checkpoint(body)
+        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0)),
+                                   params["blocks"])
     logits = unembed(params, x, cfg)
     if mesh is not None:
         logits = sh.constrain(logits, mesh, "batch", "seq", "vocab")

@@ -68,6 +68,29 @@ def _layer_norm_bwd(eps, res, dy):
 layer_norm.defvjp(_layer_norm_fwd, _layer_norm_bwd)
 
 
+# ------------------------------------------------- tensor-parallel reduction
+def exchange_sum(partial, axis_name: str):
+    """Sum `partial` over the manual mesh axis `axis_name` by neighbour
+    exchanges: `p + ppermute(p)` at size 2, a ring of size − 1 hops beyond.
+    MUST run in per-device code (`jax.shard_map`, `check_vma=False`).
+
+    This is the `tp` reduction of a row-parallel matmul, written as the one
+    collective the TPU compiler runs asynchronously: a `collective-permute`
+    is a start/done pair with compute scheduled between, where an
+    `all-reduce` (what `psum` or the partitioner gives) blocks. The sum is
+    taken in the partials' dtype, as the all-reduce took it. Its transpose
+    is the same exchange on the cotangent, so the backward pass needs no
+    rule of its own. Beyond size 2 each device adds in ring order from its
+    own rank, so replicas agree to rounding, not to the bit."""
+    n = jax.lax.axis_size(axis_name)
+    perm = [(i, (i + 1) % n) for i in range(n)]
+    total = moving = partial
+    for _ in range(n - 1):
+        moving = jax.lax.ppermute(moving, axis_name, perm)
+        total = total + moving
+    return total
+
+
 # ---------------------------------------------------------------- attention
 def init_attention(key, d_model, n_head, dtype=jnp.float32):
     head_dim = d_model // n_head
@@ -97,6 +120,7 @@ def apply_attention(
     sp_axis: str = "sp",
     compute_dtype=jnp.bfloat16,
     mesh=None,
+    reduce=None,
 ):
     """x: [B, S, D] -> [B, S, D].
 
@@ -109,6 +133,10 @@ def apply_attention(
     cannot be partitioned automatically (lowering it on sharded operands
     raises); under shard_map each device runs the kernel on its own batch
     and head shard.
+
+    reduce: set by per-device callers (gpt2's `tp` region), whose params are
+    the local head shard: sums the row-parallel partial output over `tp`.
+    The kernel then runs on the local shard as it is, with no wrap.
     """
     cd = compute_dtype
     q = jnp.einsum("bsd,dhk->bshk", x.astype(cd), params["wq"].astype(cd))
@@ -133,6 +161,8 @@ def apply_attention(
     else:
         o = reference_attention(q, k, v, causal=causal)
     out = jnp.einsum("bshk,hkd->bsd", o.astype(cd), params["wo"].astype(cd))
+    if reduce is not None:
+        out = reduce(out)
     return out.astype(x.dtype)
 
 
@@ -217,9 +247,15 @@ def _lean_mlp_bwd(cd, res, do):
 _lean_mlp.defvjp(_lean_mlp_fwd, _lean_mlp_bwd)
 
 
-def apply_mlp(params: Params, x, compute_dtype=jnp.bfloat16):
-    out = _lean_mlp(x, params["w1"], params["b1"], params["w2"],
-                    params["b2"], compute_dtype)
+def apply_mlp(params: Params, x, compute_dtype=jnp.bfloat16, reduce=None):
+    """reduce: as in apply_attention — w1/b1/w2 are the local `mlp` shard,
+    the partial product is summed over `tp` and b2 added once, after."""
+    w1, b1, w2, b2 = (params[k] for k in ("w1", "b1", "w2", "b2"))
+    if reduce is None:
+        out = _lean_mlp(x, w1, b1, w2, b2, compute_dtype)
+    else:
+        partial = _lean_mlp(x, w1, b1, w2, jnp.zeros_like(b2), compute_dtype)
+        out = reduce(partial) + b2.astype(compute_dtype)
     return out.astype(x.dtype)
 
 

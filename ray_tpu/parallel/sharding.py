@@ -18,6 +18,11 @@ AxisVal = Union[None, str, Tuple[str, ...]]
 # Default logical→mesh rules for transformer LMs. "seq" rides the sp axis
 # (sequence/context parallelism); "heads"/"mlp"/"vocab" ride tp; "experts"
 # ride ep; "layers" ride pp when pipelining is on; "batch" rides dp.
+# These rules place the arrays. They imply the collectives too, except the
+# `tp` reductions of a dense block's row-parallel matmuls in gpt2.forward's
+# layer loop: the model issues those itself (models/gpt2.py:_tp_blocks,
+# explicit neighbour exchanges in per-device code), so do not look for them
+# in the partitioner's output.
 DEFAULT_RULES: Dict[str, AxisVal] = {
     "batch": "dp",
     "seq": "sp",
