@@ -3,9 +3,12 @@
 Every directory in the manifest's ``paths`` is searched, in order, for
 ``<path>/<kind>/<name><suffix>``: ``configs/<config>.json``,
 ``traffic/<traffic>.json``, ``metrics/<metric>.json``, and the modules
-``jobs/<kind>.py``, ``references/<module>.py``, ``readers/<reader>.py``.
-So a new configuration, traffic mix, job kind or metric is a new file and a
-new entry in the manifest; nothing here or in ``run.py`` lists them.
+``jobs/<kind>.py``, ``readers/<reader>.py`` and, both under the name the
+configuration file gives as its ``reference``, ``references/<module>.py``
+(the plain model) and ``accounting/<module>.py`` (its sizes, FLOPs a token
+and compared leaves). So a new configuration, architecture, traffic mix,
+job kind or metric is new files and new entries in the manifest; nothing
+here or in ``run.py`` lists them.
 """
 import importlib
 import json
@@ -62,8 +65,8 @@ def resolve_cell(manifest: dict, cell: str, group: str,
                  root: str = ROOT) -> dict:
     """Everything one run of ``cell`` needs, as plain data that can be
     sent to a worker: the workload entry, its configuration and traffic
-    files, and for each metric of ``group`` its reader's module and
-    arguments."""
+    files, its architecture's reference and accounting modules, and for
+    each metric of ``group`` its reader's module and arguments."""
     entries = [w for w in manifest["workloads"] if w["name"] == cell]
     if not entries:
         known = ", ".join(w["name"] for w in manifest["workloads"])
@@ -84,5 +87,7 @@ def resolve_cell(manifest: dict, cell: str, group: str,
         "traffic": load_json(manifest, "traffic", workload["traffic"], root),
         "reference": module_name(manifest, "references",
                                  model["reference"], root),
+        "accounting": module_name(manifest, "accounting",
+                                  model["reference"], root),
         "metrics": metrics,
     }

@@ -1,11 +1,10 @@
 """The comparison that decides ``correct``: the system's loss and gradients
 against the plain reference, on the same parameters and the same tokens.
 
-Compared are the loss and the gradients of four leaves: the embedding
-(which is also the output head, so it sees the loss tail and every layer
-below it), and the middle block's ``mlp.w1``, ``attn.wq`` and ``attn.wv``
-(which see the MLP's backward pass and all three flash kernels, dq through
-``wq`` and dk/dv through ``wv``, through half the stack).
+Compared are the loss and the gradients of a few leaves. Which leaves, and
+how they go back into the tree, is the architecture's to say: ``pick`` and
+``put`` come from its ``accounting/<reference>.py``. The tolerances are
+the same for every architecture.
 """
 import jax
 import numpy as np
@@ -26,31 +25,12 @@ LOSS_RTOL = 3e-4
 GRAD_RTOL = 8e-2
 
 
-def pick(params):
-    mid = params["blocks"]["mlp"]["w1"].shape[0] // 2
-    return {"wte": params["wte"],
-            "w1": params["blocks"]["mlp"]["w1"][mid],
-            "wq": params["blocks"]["attn"]["wq"][mid],
-            "wv": params["blocks"]["attn"]["wv"][mid]}
-
-
-def _put(params, leaves):
-    mid = params["blocks"]["mlp"]["w1"].shape[0] // 2
-    blocks = dict(params["blocks"])
-    blocks["mlp"] = dict(blocks["mlp"],
-                         w1=blocks["mlp"]["w1"].at[mid].set(leaves["w1"]))
-    blocks["attn"] = dict(blocks["attn"],
-                          wq=blocks["attn"]["wq"].at[mid].set(leaves["wq"]),
-                          wv=blocks["attn"]["wv"].at[mid].set(leaves["wv"]))
-    return dict(params, wte=leaves["wte"], blocks=blocks)
-
-
-def loss_and_grads(loss_fn):
+def loss_and_grads(loss_fn, pick, put):
     """``loss_fn(params, tokens) -> scalar`` -> a function giving the loss
-    and its gradients with respect to the four leaves alone."""
+    and its gradients with respect to the picked leaves alone."""
     def run(params, tokens):
         return jax.value_and_grad(
-            lambda leaves: loss_fn(_put(params, leaves), tokens))(
+            lambda leaves: loss_fn(put(params, leaves), tokens))(
                 pick(params))
     return run
 
@@ -61,15 +41,17 @@ def rel_l2(got, want) -> float:
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def compare(system_fn, reference_fn, params, tokens, device) -> dict:
+def compare(system_fn, reference_fn, params, tokens, device, *, pick,
+            put) -> dict:
     """Errors of the system against the reference. ``system_fn`` runs as
     the cell runs it (its mesh, its dtype, its kernels) on all of
     ``tokens``; the reference runs on ``device`` alone, one sequence at a
     time, and its results are averaged (equal-length sequences: the mean
     of means is the mean)."""
-    loss, grads = jax.jit(loss_and_grads(system_fn))(params, tokens)
+    loss, grads = jax.jit(
+        loss_and_grads(system_fn, pick, put))(params, tokens)
     params0 = jax.device_put(params, device)
-    ref = jax.jit(loss_and_grads(reference_fn))
+    ref = jax.jit(loss_and_grads(reference_fn, pick, put))
     ref_loss, ref_grads = 0.0, None
     with jax.default_matmul_precision("highest"):
         for row in np.asarray(tokens):

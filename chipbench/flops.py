@@ -1,10 +1,7 @@
-"""Operations and bytes, computed from shapes: the arithmetic side of the
-yardstick. Nothing here imports JAX or the program.
-
-Model FLOPs follow the PaLM appendix (the accounting ``bench.py`` used):
-6 per parameter per token for the matmuls of forward and backward, plus
-6·L·S·d_model per token for causal attention (QK^T and PV, forward and
-backward, halved by the mask). Recomputed operations never count.
+"""Operations and bytes, computed from shapes: what belongs to the chip and
+to the program's kernels. Nothing here imports JAX or the program, and
+nothing here knows a model: a model's parameters and FLOPs a token are in
+its architecture's module, ``accounting/<reference>.py``.
 """
 import json
 import math
@@ -29,24 +26,6 @@ def peaks_for(device_kind: str) -> dict:
 
 def padded_vocab(vocab_size: int, multiple: int = 128) -> int:
     return -(-vocab_size // multiple) * multiple
-
-
-def gpt2_params(config: dict) -> int:
-    """Parameters the system trains for a GPT-2 configuration file: the
-    embedding padded to a multiple of 128 rows and counted once (the head
-    is tied), learned positions, and per block the four attention
-    projections WITHOUT biases (the system's departure), the MLP with
-    biases, and two layer norms."""
-    d, n_layer = config["n_embd"], config["n_layer"]
-    ff = config.get("n_inner") or 4 * d
-    block = 4 * d * d + (2 * d * ff + d + ff) + 4 * d
-    return (padded_vocab(config["vocab_size"]) * d
-            + config["n_positions"] * d + n_layer * block + 2 * d)
-
-
-def train_flops_per_token(config: dict, seq: int) -> int:
-    return (6 * gpt2_params(config)
-            + 6 * config["n_layer"] * seq * config["n_embd"])
 
 
 # ------------------------------------------------------- flash kernels

@@ -11,6 +11,20 @@ ended by its loss on the host and a ``session.report``. After the window,
 outside every timing: the compiled step's memory plan, the comparison with
 the plain reference on freshly made parameters, and in a traced run the
 reduction of the trace.
+
+The job knows no architecture. The configuration file names two things.
+Its ``entry``, ``<module>:<preset>``, is the program's model: the preset
+takes no argument and returns a dataclass with the fields ``attention``,
+``remat`` (both replaced by the traffic file's) and ``vocab_size`` (the
+ids the check's tokens are drawn from); the module has ``init(rng, cfg)``
+-> parameters, ``partition_specs(cfg)`` -> a tree of ``PartitionSpec`` like
+them, and ``loss_fn(params, {"tokens": [B, S+1]}, cfg, mesh) -> (loss,
+metrics)``, a mean next-token loss with ``metrics["loss"]`` a scalar. Its
+``reference`` names the architecture's plain model
+(``references/<reference>.py``: ``loss(params, tokens, config)`` in
+float32, ``config`` being the configuration file as this cell runs it) and
+its accounting (``accounting/<reference>.py``: the sizes the preset must
+have, the FLOPs a token, the compared leaves).
 """
 import dataclasses
 import glob
@@ -96,23 +110,17 @@ def _worker_log_tail(session_dir: str, n_bytes: int = 6000) -> str:
 
 # ------------------------------------------------------------ worker side
 
-def _model(model: dict, traffic: dict):
+def _model(config: dict):
     """The program's model module and its configuration for this cell, and
     a refusal if the preset's sizes are not the configuration file's."""
+    model, traffic = config["model"], config["traffic"]
     module_name, preset = model["entry"].split(":")
     module = importlib.import_module(module_name)
     cfg = dataclasses.replace(getattr(module, preset)(),
                               attention=traffic["attention"],
                               remat=traffic["remat"])
-    ran = {"n_layer": cfg.n_layer, "n_head": cfg.n_head,
-           "n_embd": cfg.d_model, "n_positions": cfg.max_seq,
-           "n_inner": cfg.ff,
-           "padded_vocab": cfg.vocab_size, "n_params": cfg.n_params}
-    filed = {"n_layer": model["n_layer"], "n_head": model["n_head"],
-             "n_embd": model["n_embd"], "n_positions": model["n_positions"],
-             "n_inner": model.get("n_inner") or 4 * model["n_embd"],
-             "padded_vocab": flops.padded_vocab(model["vocab_size"]),
-             "n_params": flops.gpt2_params(model)}
+    accounting = importlib.import_module(config["accounting"])
+    ran, filed = accounting.ran_sizes(cfg), accounting.filed_sizes(model)
     if ran != filed:
         raise ValueError(f"{model['entry']} runs {ran}, the configuration "
                          f"file says {filed}")
@@ -166,7 +174,7 @@ def train_loop(config: dict):
     else:
         peaks = None
 
-    module, cfg = _model(config["model"], traffic)
+    module, cfg = _model(config)
     n_mesh = math.prod(traffic["mesh"].values())
     mesh = create_mesh(MeshConfig(**traffic["mesh"]),
                        devices=devices[:n_mesh])
@@ -250,8 +258,8 @@ def train_loop(config: dict):
             "compiles_in_window": sum(t0 <= t <= t0 + window_s
                                       for t in compiles)},
         "plan": plan, "trace": summary, "peaks": peaks, "device": device,
-        "model": config["model"], "traffic": traffic,
-        "chips": n_mesh,
+        "model": config["model"], "accounting": config["accounting"],
+        "traffic": traffic, "chips": n_mesh,
     }
     values = {}
     for spec in config["metrics"]:
@@ -270,11 +278,13 @@ def train_loop(config: dict):
         "check": check, "plan": plan,
         "setup_cache": setup_cache, "clock": context["clock"],
         # [step, seconds of step, data_next, step_dispatch, loss_fetch,
-        # report]: where a stall was, if there was one
+        # report, seconds into the window at which it began]: where and
+        # when a stall was, if there was one
         "longest_steps": [
             [i] + [spans[name][i] for name in ("step",) + SPANS]
+            + [sum(spans["step"][:i])]
             for i in sorted(range(len(losses)),
-                            key=lambda i: -spans["step"][i])[:3]],
+                            key=lambda i: -spans["step"][i])[:6]],
     }
     if summary is not None:
         device["busy_s"] = summary["busy_s"]
@@ -314,10 +324,12 @@ def _reference_check(config, module, cfg, mesh, device) -> dict:
     tokens = generate.token_rows(
         dict(traffic, batches=1, batch=traffic["check_sequences"]),
         cfg.vocab_size, seed)
+    accounting = importlib.import_module(config["accounting"])
+    reference = importlib.import_module(config["reference"])
     return compare.compare(
         lambda p, t: module.loss_fn(p, {"tokens": t}, cfg, mesh)[0],
-        importlib.import_module(config["reference"]).loss, params, tokens,
-        device)
+        lambda p, t: reference.loss(p, t, config["model"]), params, tokens,
+        device, pick=accounting.pick, put=accounting.put)
 
 
 def _read_trace(keep):

@@ -21,9 +21,12 @@ import re
 Event = collections.namedtuple("Event", "name start_ns end_ns")
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
-_COLLECTIVE = re.compile(
-    r"^%?(all-reduce|all-gather|reduce-scatter|all-to-all|"
-    r"collective-permute|collective-broadcast)")
+_COLLECTIVES = ("all-reduce|all-gather|reduce-scatter|all-to-all|"
+                "collective-permute|collective-broadcast")
+_COLLECTIVE = re.compile(rf"^({_COLLECTIVES})(-start|-update|-done)?$")
+# opcodes that only wrap a computation, and what the text names it by
+_WRAPPER = re.compile(r"^async-(start|update|done)$")
+_WRAPPED = re.compile(rf"^({_COLLECTIVES})")
 
 
 # ------------------------------------------------------------- intervals
@@ -97,13 +100,12 @@ def self_segments(events) -> list:
 
 # ------------------------------------------------------------ operations
 
-def short_op_name(text: str, limit: int = 96) -> str:
-    """``%fusion.217 = f32[50304,768]{1,0:T(8,128)} fusion(...), kind=kOutput``
-    -> ``fusion.217 fusion:kOutput f32[50304,768]``: the instruction, what
-    it is and what it produces, without layouts or operands."""
+def _parse(text: str):
+    """HLO instruction text -> (instruction name, result, opcode, what
+    follows the opcode), or None where the text is no instruction."""
     instr, sep, rest = text.partition(" = ")
     if not sep:
-        return text[:limit]
+        return None
     if rest.startswith("("):
         depth = 0
         for i, ch in enumerate(rest):
@@ -114,16 +116,49 @@ def short_op_name(text: str, limit: int = 96) -> str:
         result, rest = rest[:i + 1], rest[i + 1:]
     else:
         result, _, rest = rest.partition(" ")
-    opcode = rest.strip().partition("(")[0]
+    opcode, _, rest = rest.strip().partition("(")
+    return instr.lstrip("%"), result, opcode, rest
+
+
+def short_op_name(text: str, limit: int = 96) -> str:
+    """``%fusion.217 = f32[50304,768]{1,0:T(8,128)} fusion(...), kind=kOutput``
+    -> ``fusion.217 fusion:kOutput f32[50304,768]``: the instruction, what
+    it is and what it produces, without layouts or operands."""
+    parsed = _parse(text)
+    if parsed is None:
+        return text[:limit]
+    instr, result, opcode, rest = parsed
     detail = re.search(r'custom_call_target="([^"]+)"|kind=(\w+)', rest)
     if detail:
         opcode += ":" + (detail.group(1) or detail.group(2))
     result = re.sub(r"\{[^}]*\}", "", result)
-    return f"{instr.lstrip('%')} {opcode} {result}"[:limit]
+    return f"{instr} {opcode} {result}"[:limit]
 
 
 def is_collective(text: str) -> bool:
-    return bool(_COLLECTIVE.match(text))
+    """By the instruction's opcode, ``-start``, ``-update`` and ``-done``
+    forms included, not by its name: ``jax.lax.psum`` names its all-reduce
+    ``psum.N``, and a fusion may be named after anything. Two opcodes only
+    wrap a computation, and the text has nothing but names to say which: an
+    ``async-start`` / ``-update`` / ``-done`` that is not printed as
+    ``<opcode>-start``, and a ``kind=kCustom`` fusion, which is how the
+    TPU compiler is remembered to emit a fused reduce-scatter (PERF.md §7:
+    neither form has been seen in a trace of this repo). Those count where
+    the instruction or the computation it ``calls=`` is named after a
+    collective; the gathers and scatters that are ``kCustom`` fusions too
+    are named ``fusion.N`` / ``fused_computation.N`` and stay compute."""
+    parsed = _parse(text)
+    if parsed is None:
+        return False
+    instr, _, opcode, rest = parsed
+    if _COLLECTIVE.match(opcode):
+        return True
+    if not (_WRAPPER.match(opcode)
+            or (opcode == "fusion" and "kind=kCustom" in rest)):
+        return False
+    called = re.search(r"calls=%?([\w.\-]+)", rest)
+    return bool(_WRAPPED.match(instr)
+                or (called and _WRAPPED.match(called.group(1))))
 
 
 # ------------------------------------------------------- one device
@@ -160,11 +195,14 @@ def reduce_device(ops, async_ops, modules) -> dict:
         per_op[name] += e - s
     calls = collections.Counter(
         ev.name for ev in ops if lo <= ev.start_ns < hi)
-    compute = [(s, e) for n, s, e in segments if not is_collective(n)]
+    # an instruction's text is parsed once, not once an event
+    collectives = {name for name in per_op.keys()
+                   | {ev.name for ev in async_ops} if is_collective(name)}
+    compute = [(s, e) for n, s, e in segments if n not in collectives]
     collective = clip(
-        [(s, e) for n, s, e in segments if is_collective(n)]
+        [(s, e) for n, s, e in segments if n in collectives]
         + [(ev.start_ns, ev.end_ns) for ev in async_ops
-           if is_collective(ev.name)], lo, hi)
+           if ev.name in collectives], lo, hi)
     step_busy = [total(clip(busy, st.start_ns, st.end_ns))
                  for st in steps[:-1]]
     return {

@@ -3,8 +3,11 @@ loop through ``JaxTrainer.fit()`` at a tiny configuration that lives in THIS
 directory (configuration, traffic mix, one metric and its reader: the
 harness finds them by name and no file under ``chipbench/`` knows them),
 the command's refusal to run without a TPU, and the plain reference against
-the system's ``loss_fn``."""
+the system's ``loss_fn``. Beside it a second architecture that is files
+only: ``gated-tiny`` (model, preset, configuration, traffic mix, accounting
+and reference, all in this directory) goes through the same job."""
 import dataclasses
+import importlib
 import json
 import os
 import subprocess
@@ -16,6 +19,7 @@ import pytest
 
 from chipbench import catalog, compare, generate
 from chipbench.jobs import train_fit
+from chipbench.readers import mfu
 
 # "No TPU required" is an argument this test passes, not an option of the
 # command. The tiny cell reports what needs no chip and no peak.
@@ -25,7 +29,10 @@ TINY_MANIFEST = {
                    "traffic": "fit-tiny", "chips": 1, "why": "rehearsal"},
                   {"name": "tiny-dp2tp2", "config": "gpt2-tiny",
                    "traffic": "fit-tiny-dp2tp2", "chips": 4,
-                   "why": "the sharded path on four virtual devices"}],
+                   "why": "the sharded path on four virtual devices"},
+                  {"name": "gated-tiny", "config": "gated-tiny",
+                   "traffic": "fit-gated-tiny", "chips": 1,
+                   "why": "an architecture that is not GPT-2's"}],
     "end_to_end": [
         {"name": "tokens_per_s_per_chip", "unit": "tokens/s/chip"},
         {"name": "step_ms_p90", "unit": "ms"},
@@ -66,7 +73,9 @@ def test_train_fit_job_through_the_trainer(trace):
     cell = catalog.resolve_cell(TINY_MANIFEST, "tiny",
                                 "per_layer" if trace else "end_to_end")
     t_start = time.time()
-    record = train_fit.run(cell, seed=3, seconds=1.5, trace=trace,
+    # a window long enough for the 32 steps asserted below on a host that
+    # five other test processes share (1.5 s gave 31 once)
+    record = train_fit.run(cell, seed=3, seconds=2.5, trace=trace,
                            t_start=t_start, require_tpu=False)
     json.dumps(record)                       # plain data all the way down
     assert {"correct", "attempted", "failed", "metrics", "device"} <= \
@@ -78,7 +87,7 @@ def test_train_fit_job_through_the_trainer(trace):
     assert record["verdicts"]["every_loss_finite"]
     assert record["verdicts"]["loss_fell"]
     assert set(record["losses"]) == {"1", "8", "32"}
-    assert record["clock"]["window_s"] >= 1.5
+    assert record["clock"]["window_s"] >= 2.5
     values = {k: v["value"] for k, v in record["metrics"].items()}
     if not trace:
         # a 90th percentile only where ten samples lie beyond it
@@ -101,6 +110,59 @@ def test_train_fit_job_through_the_trainer(trace):
         assert not os.path.exists(train_fit.TRACE_DIR)
 
 
+@pytest.mark.parametrize("trace", [False, True])
+def test_another_architecture_is_files_only(trace):
+    """RMSNorm, rotary positions, a gated MLP, an untied head, public key
+    names: through the same job, correct against its own float32
+    reference on its own leaves, its MFU from its own accounting."""
+    cell = catalog.resolve_cell(TINY_MANIFEST, "gated-tiny",
+                                "per_layer" if trace else "end_to_end")
+    assert cell["accounting"] == "tests.chipbench_tests.accounting.gated_lm"
+    assert cell["reference"] == "tests.chipbench_tests.references.gated_lm"
+    record = train_fit.run(cell, seed=11, seconds=1.0, trace=trace,
+                           t_start=time.time(), require_tpu=False)
+    json.dumps(record)
+    assert record["correct"], (record["verdicts"], record["check"])
+    assert set(record["check"]["errors"]) == {
+        "loss", "grad_head", "grad_embed", "grad_gate", "grad_wq", "grad_wv"}
+    assert record["failed"] == 0 and record["attempted"] >= 8
+    values = {k: v["value"] for k, v in record["metrics"].items()}
+    if trace:
+        assert values["train_step.compiles_in_window"] == 0
+        assert values["tiny.epochs"] == record["attempted"] / 16
+    else:
+        assert values["tokens_per_s_per_chip"] == pytest.approx(
+            record["attempted"] * 4 * 48 / record["clock"]["window_s"])
+    # the mfu reader, given a peak (the CPU has none on file): 6 FLOPs for
+    # each parameter of the head and the two layers' seven matrices, none
+    # for the embedding's lookup, plus causal attention; by hand
+    per_token = (6 * (64 * 256 + 2 * (4 * 64 * 64 + 3 * 64 * 160))
+                 + 6 * 2 * 48 * 64)
+    assert per_token == 700_416
+    ctx = {"accounting": cell["accounting"], "model": cell["model"],
+           "traffic": cell["traffic"], "chips": 1, "clock": record["clock"],
+           "counters": {"steps": record["attempted"]},
+           "peaks": {"bf16_flops_per_s": 1e12}}
+    assert mfu.read(ctx) == pytest.approx(
+        100 * record["attempted"] * 4 * 48 / record["clock"]["window_s"]
+        * per_token / 1e12, rel=1e-12)
+
+
+def test_nothing_under_chipbench_knows_the_fixture():
+    """The second architecture added files here and not a line there. Only
+    the fixture's own names are looked for: a published configuration
+    filed under ``chipbench/configs/`` has the public keys the fixture
+    borrows, and may well be gated."""
+    for folder, _, files in os.walk(os.path.join(catalog.ROOT,
+                                                 "chipbench")):
+        for name in files:
+            if name.endswith((".py", ".json")):
+                with open(os.path.join(folder, name)) as f:
+                    text = f.read()
+                for word in ("gated_lm", "gated-tiny", "GatedConfig"):
+                    assert word not in text, (name, word)
+
+
 def test_sharded_cell_on_virtual_devices():
     """dp=2 x tp=2 on four of the CPU's virtual devices: the mesh, the
     sharded state, the gather of the parameters onto one device for the
@@ -121,6 +183,34 @@ def test_a_failed_loop_is_a_failed_job():
     with pytest.raises(train_fit.JobFailed, match="configuration file says"):
         train_fit.run(cell, seed=0, seconds=1.0, trace=False,
                       t_start=time.time(), require_tpu=False)
+
+
+@pytest.mark.parametrize("name, key", [("tiny", "n_layer"),
+                                       ("gated-tiny", "num_hidden_layers")])
+def test_filed_sizes_that_are_not_the_presets_raise(name, key):
+    cell = catalog.resolve_cell(TINY_MANIFEST, name, "end_to_end")
+    train_fit._model(cell)                   # as filed: accepted
+    cell["model"] = dict(cell["model"], **{key: 3})
+    with pytest.raises(ValueError, match=f"'{key}': 3"):
+        train_fit._model(cell)
+
+
+def test_a_configuration_without_accounting_names_the_file(tmp_path):
+    """No default stands in for a missing module: the error is
+    ``catalog.find``'s, with every path it looked for."""
+    for kind, name, text in (
+            ("configs", "orphan.json", '{"reference": "orphan"}'),
+            ("traffic", "none.json", "{}"),
+            ("references", "orphan.py", "")):
+        (tmp_path / "bench" / kind).mkdir(parents=True)
+        (tmp_path / "bench" / kind / name).write_text(text)
+    manifest = {"paths": ["bench", "more"], "end_to_end": [], "workloads": [
+        {"name": "cell", "config": "orphan", "traffic": "none"}]}
+    with pytest.raises(FileNotFoundError) as e:
+        catalog.resolve_cell(manifest, "cell", "end_to_end", str(tmp_path))
+    assert ("no accounting file for 'orphan': looked for "
+            "bench/accounting/orphan.py, more/accounting/orphan.py"
+            ) in str(e.value)
 
 
 def test_command_exits_nonzero_without_a_chip():
@@ -174,6 +264,7 @@ def _tiny(dtype):
     import jax
     import jax.numpy as jnp
 
+    from chipbench.references import gpt2 as reference
     from ray_tpu.models import gpt2
 
     cfg = dataclasses.replace(gpt2.gpt2_tiny(), attention="reference",
@@ -188,7 +279,8 @@ def _tiny(dtype):
         for leaf, k in zip(leaves, keys)])
     tokens = generate.token_rows(
         {"batches": 1, "batch": 2, "seq": 64, "vocab_divisor": 1}, 256, 5)
-    return gpt2, cfg, params, tokens
+    filed = catalog.load_json(TINY_MANIFEST, "configs", "gpt2-tiny")
+    return gpt2, cfg, params, tokens, lambda p, t: reference.loss(p, t, filed)
 
 
 def test_reference_is_the_systems_function_in_float32():
@@ -197,12 +289,13 @@ def test_reference_is_the_systems_function_in_float32():
     same function (tied head, no attention bias, tanh GELU, eps 1e-5)."""
     import jax
 
-    from chipbench.references import gpt2 as reference
+    from chipbench.accounting import gpt2 as accounting
 
-    gpt2, cfg, params, tokens = _tiny("float32")
+    gpt2, cfg, params, tokens, reference = _tiny("float32")
     got = compare.compare(
         lambda p, t: gpt2.loss_fn(p, {"tokens": t}, cfg)[0],
-        reference.loss, params, tokens, jax.devices()[0])
+        reference, params, tokens, jax.devices()[0],
+        pick=accounting.pick, put=accounting.put)
     assert got["errors"]["loss"] < 1e-6
     assert max(got["errors"].values()) < 1e-4, got["errors"]
     assert got["within"]
@@ -216,15 +309,16 @@ def test_bf16_passes_and_eight_bit_matmuls_would_fail():
     import jax
     import jax.numpy as jnp
 
-    from chipbench.references import gpt2 as reference
+    from chipbench.accounting import gpt2 as accounting
 
-    gpt2, cfg, params, tokens = _tiny("bfloat16")
+    gpt2, cfg, params, tokens, reference = _tiny("bfloat16")
+    leaves = {"pick": accounting.pick, "put": accounting.put}
 
     def system(p, t):
         return gpt2.loss_fn(p, {"tokens": t}, cfg)[0]
 
-    got = compare.compare(system, reference.loss, params, tokens,
-                          jax.devices()[0])
+    got = compare.compare(system, reference, params, tokens,
+                          jax.devices()[0], **leaves)
     assert got["within"], got["errors"]
 
     def eight_bit(p, t):
@@ -236,9 +330,38 @@ def test_bf16_passes_and_eight_bit_matmuls_would_fail():
             cast, p["blocks"]["attn"]))
         return system(dict(p, blocks=blocks), t)
 
-    low = compare.compare(eight_bit, reference.loss, params, tokens,
-                          jax.devices()[0])
+    low = compare.compare(eight_bit, reference, params, tokens,
+                          jax.devices()[0], **leaves)
     assert not low["within"], low["errors"]
+
+
+@pytest.mark.parametrize("name, key, other", [
+    ("tiny", "layer_norm_epsilon", 1e-2),
+    ("gated-tiny", "num_attention_heads", 2),
+    ("gated-tiny", "rope_theta", 100.0),
+    ("gated-tiny", "rms_norm_eps", 1e-2)])
+def test_a_reference_reads_the_configuration_it_is_handed(name, key, other):
+    """What no leaf's shape gives reaches the reference through its third
+    argument, the configuration file as the cell runs it, and through
+    nothing else: another value there is another function."""
+    import jax
+
+    cell = catalog.resolve_cell(TINY_MANIFEST, name, "end_to_end")
+    _, cfg = train_fit._model(cell)
+    module = importlib.import_module(cell["model"]["entry"].split(":")[0])
+    reference = importlib.import_module(cell["reference"])
+    # weights eight times their initial size, so that attention is far
+    # from uniform and the rotation's base shows
+    params = jax.tree_util.tree_map(
+        lambda a: 8 * a if a.ndim > 1 else a,
+        module.init(jax.random.PRNGKey(2), cfg))
+    tokens = generate.token_rows(
+        {"batches": 1, "batch": 2, "seq": 32, "vocab_divisor": 1}, 256, 9)
+    filed = float(reference.loss(params, tokens, cell["model"]))
+    assert filed == float(reference.loss(params, tokens, dict(cell["model"])))
+    changed = float(reference.loss(params, tokens,
+                                   dict(cell["model"], **{key: other})))
+    assert abs(changed - filed) > 1e-4 * filed
 
 
 def test_token_rows_come_from_the_seed():

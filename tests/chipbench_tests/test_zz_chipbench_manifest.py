@@ -86,6 +86,34 @@ def test_names_are_identifiers_and_unique(group):
         assert len(set(every)) == len(every)
 
 
+def test_the_name_rule_is_the_drivers():
+    """At most 64 of letters, digits, ``_``, ``.`` and ``-``, the first
+    none of ``.`` and ``-``."""
+    for name in ("a" * 64, "_x", "9.cell-b_2"):
+        assert NAME.match(name), name
+    for name in ("a" * 65, ".hidden", "-flag", "two words", "a/b", "a,b", ""):
+        assert not NAME.match(name), name
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
+def test_every_configuration_names_an_architecture_that_is_there(config):
+    """Its ``reference`` resolves to the plain model and to the accounting
+    module, each with what the harness calls."""
+    filed = catalog.load_json(MANIFEST, "configs", config)
+    assert NAME.match(filed["reference"])
+    reference = catalog.load_module(MANIFEST, "references",
+                                    filed["reference"])
+    assert callable(reference.loss)
+    accounting = catalog.load_module(MANIFEST, "accounting",
+                                     filed["reference"])
+    for function in ("filed_sizes", "ran_sizes", "train_flops_per_token",
+                     "pick", "put"):
+        assert callable(getattr(accounting, function)), function
+    sizes = accounting.filed_sizes(filed)
+    assert sizes["n_params"] > 0
+    assert sizes["padded_vocab"] == flops.padded_vocab(filed["vocab_size"])
+
+
 def test_configs():
     used = {w["config"] for w in MANIFEST["workloads"]}
     files = [c["file"] for c in MANIFEST["configs"]]
@@ -133,8 +161,11 @@ def test_every_file_a_cell_names_exists(cell):
             assert os.path.isfile(os.path.join(
                 catalog.ROOT, spec["reader"].replace(".", "/") + ".py"))
     catalog.find(MANIFEST, "jobs", resolved["traffic"]["job"], ".py")
-    catalog.find(MANIFEST, "references", resolved["model"]["reference"],
-                 ".py")
+    architecture = resolved["model"]["reference"]
+    assert resolved["reference"] == catalog.module_name(
+        MANIFEST, "references", architecture)
+    assert resolved["accounting"] == catalog.module_name(
+        MANIFEST, "accounting", architecture)
 
 
 def test_metrics():
@@ -180,8 +211,10 @@ def test_each_cell_reports_what_the_contract_asks(cell):
 ])
 def test_params_and_flops_a_token(config, params, per_token):
     filed = catalog.load_json(MANIFEST, "configs", config)
-    assert flops.gpt2_params(filed) == params
-    got = flops.train_flops_per_token(filed, 1024)
+    accounting = catalog.load_module(MANIFEST, "accounting",
+                                     filed["reference"])
+    assert accounting.params(filed) == params
+    got = accounting.train_flops_per_token(filed, 1024)
     assert got == 6 * params + 6 * filed["n_layer"] * 1024 * filed["n_embd"]
     assert abs(got - per_token) / per_token < 5e-4
     # the program counts the same parameters

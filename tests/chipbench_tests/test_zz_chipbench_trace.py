@@ -123,6 +123,68 @@ def test_short_op_names():
     assert not tr.is_collective("%fusion.1 = f32[8] fusion()")
 
 
+def test_a_collective_is_told_by_its_opcode_not_its_name():
+    """As a sharded step has them (PR 27's trace): an all-reduce that
+    ``jax.lax.psum`` named, an asynchronous neighbour exchange whose
+    ``-start`` is on the async line and whose ``-done`` waits on the
+    compute line, and a fusion that is only NAMED after a collective."""
+    psum = "%psum.101 = f32[36,1280,2560]{2,1,0} all-reduce(f32[8]{0} %x)"
+    named = ("%all-reduce-scatter.3 = f32[8]{0} fusion(f32[8]{0} %x), "
+             "kind=kLoop")
+    start = ("%collective-permute-start.6 = (bf16[8]{0}, bf16[8]{0}) "
+             "collective-permute-start(bf16[8]{0} %y)")
+    done = ("%collective-permute-done.6 = bf16[8]{0} "
+            "collective-permute-done((bf16[8]{0}, bf16[8]{0}) %z)")
+    assert tr.is_collective(psum)
+    assert tr.is_collective(start) and tr.is_collective(done)
+    assert not tr.is_collective(named)
+    assert not tr.is_collective("all-reduce.1")       # no instruction text
+    assert tr.is_collective(
+        "%a2a.1 = (f32[8]{0}, f32[8]{0}) all-to-all(f32[8]{0} %x)")
+    # periods of 1,000 ns: the fusion 0-400, the exchange in flight
+    # 300-600 and waited for 400-600 (200 exposed, 100 hidden), the psum
+    # 600-700 (exposed), idle after
+    ops, async_ops, modules = [], [], []
+    for t in (0, 1000, 2000):
+        modules.append(E("jit_step(1)", t, t + 700))
+        ops += [E(named, t, t + 400), E(done, t + 400, t + 600),
+                E(psum, t + 600, t + 700)]
+        async_ops.append(E(start, t + 300, t + 600))
+    summary = tr.reduce_trace({"devices": {"/device:TPU:0": {
+        "ops": ops, "async": async_ops, "modules": modules}}, "host": []},
+        SPANS)
+    dev = summary["devices"]["/device:TPU:0"]
+    assert dev["collective_ns"] == 2 * 400
+    assert dev["collective_exposed_ns"] == 2 * 300
+    assert trace_collective_exposed.read({"trace": summary}) == \
+        pytest.approx(30.0)
+
+
+@pytest.mark.parametrize("text, collective", [
+    # a fused reduce-scatter as the TPU compiler is remembered to emit it
+    ("%all-reduce-scatter.3 = f32[8]{0} fusion(f32[32]{0} %x), kind=kCustom, "
+     "calls=%all-reduce-scatter.3.computation", True),
+    ("%fusion.9 = f32[8]{0} fusion(f32[32]{0} %x), kind=kCustom, "
+     "calls=%reduce-scatter.clone", True),
+    # the gather and the scatter of the recorded v5e trace: kCustom, compute
+    ("%fusion.2 = f32[50304,768]{1,0:T(8,128)} fusion(f32[16384]{0} %x), "
+     "kind=kCustom, calls=%fused_computation.299", False),
+    # named after a collective, an ordinary fusion
+    ("%all-reduce-scatter.3 = f32[8]{0} fusion(f32[8]{0} %x), kind=kOutput, "
+     "calls=%fused_computation.4", False),
+    # an asynchronous wrapper that is not printed as <opcode>-start
+    ("%all-to-all-start.1 = ((f32[8]{0}), f32[8]{0}, u32[]) "
+     "async-start(f32[8]{0} %x), calls=%async_computation.1", True),
+    ("%async-done.2 = f32[8]{0} async-done(((f32[8]{0}), f32[8]{0}, u32[]) "
+     "%s), calls=%all-gather.7.wrapped", True),
+    ("%async-start.5 = ((f32[8]{0}), f32[8]{0}, u32[]) "
+     "async-start(f32[8]{0} %x), calls=%wrapped_sort", False),
+    ("%ag.1 = f32[8]{0} all-gather-update((f32[8]{0}, f32[8]{0}) %s)", True),
+])
+def test_a_wrapped_collective_is_told_by_what_it_wraps(text, collective):
+    assert tr.is_collective(text) == collective
+
+
 def test_recorded_v5e_trace():
     """Numbers of the recorded trace, as read by hand from the same file:
     five periods of about 158 ms, the device busy for all but a gap of
