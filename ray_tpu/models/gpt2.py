@@ -14,8 +14,9 @@ block's reductions itself, as neighbour exchanges (`layers.exchange_sum`:
 the TPU compiler runs a `collective-permute` beside the other chain's
 matmuls and blocks on an `all-reduce`. `tp_exchange_plan` counts them from
 shapes. Outside the loop (embedding, vocabulary projection, loss), with
-`tp` == 1, in `forward_pipelined` and for MoE blocks, collectives are still
-what the sharding rules imply.
+`tp` == 1 and in `forward_pipelined`, collectives are still what the
+sharding rules imply; a routed block (`layers.apply_moe`) sums its experts'
+parts over `ep` and `tp` itself.
 
 Equivalent reference workload: Ray Train GPT-2 fine-tune
 (/root/reference/release/train_tests/, BASELINE.json configs); the model
@@ -188,7 +189,9 @@ def _block_apply(block, x, cfg: GPT2Config, impl: str, mesh=None,
                               compute_dtype=cd, mesh=mesh, reduce=reduce)
     h = L.layer_norm(x, block["ln2"]["scale"], block["ln2"]["bias"])
     if cfg.moe:
-        m, aux = L.apply_moe(block["moe"], h, cfg.moe, compute_dtype=cd)
+        m, stats = L.apply_moe(block["moe"], h, cfg.moe, compute_dtype=cd,
+                               mesh=mesh)
+        aux = stats["load_balance"]
     else:
         m = L.apply_mlp(block["mlp"], h, compute_dtype=cd, reduce=reduce)
         aux = jnp.float32(0)

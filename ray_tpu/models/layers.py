@@ -68,6 +68,47 @@ def _layer_norm_bwd(eps, res, dy):
 layer_norm.defvjp(_layer_norm_fwd, _layer_norm_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def rms_norm(x, scale, eps=1e-5):
+    """RMSNorm over the last axis, float32 inside, with `layer_norm`'s lean
+    VJP: the backward keeps (x, rstd) and recomputes x̂."""
+    return _rms_norm_fwd(x, scale, eps)[0]
+
+
+def _rms_norm_fwd(x, scale, eps=1e-5):
+    x32 = x.astype(jnp.float32)
+    rstd = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * rstd * scale).astype(x.dtype), (x, rstd, scale)
+
+
+def _rms_norm_bwd(eps, res, dy):
+    x, rstd, scale = res
+    xhat = x.astype(jnp.float32) * rstd
+    dy32 = dy.astype(jnp.float32)
+    dscale = jnp.sum(dy32 * xhat, axis=tuple(range(x.ndim - 1)))
+    t = dy32 * scale
+    dx = rstd * (t - xhat * jnp.mean(t * xhat, axis=-1, keepdims=True))
+    return dx.astype(x.dtype), dscale.astype(scale.dtype)
+
+
+rms_norm.defvjp(_rms_norm_fwd, _rms_norm_bwd)
+
+
+def rope(x, theta: float = 10000.0):
+    """Rotary positions on x [B, S, H, K], float32 inside: the half-split
+    pairing (i, i + K/2) of the public `rotate_half` models, pair i turned
+    by pos · theta^(−2i/K). Positions are 0..S−1: not for a sequence that
+    `sp` splits."""
+    seq, half = x.shape[1], x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
 # ------------------------------------------------- tensor-parallel reduction
 def exchange_sum(partial, axis_name: str):
     """Sum `partial` over the manual mesh axis `axis_name` by neighbour
@@ -111,6 +152,33 @@ ATTENTION_LOGICAL = {
 }
 
 
+def _project(eq, x, w, out_dtype, *, cd, three_pass):
+    """einsum(x, w) on the MXU, operands rounded to `cd`. `three_pass` adds,
+    to the FORWARD value only, the two products with the operands' rounding
+    errors (x_hi·w_lo + x_lo·w_hi, the `lo` parts themselves in `cd`): the
+    result is the float32 product to ~2^-16. The backward pass is the
+    single product's, as without it."""
+    hi_x, hi_w = x.astype(cd), w.astype(cd)
+    out = jnp.einsum(eq, hi_x, hi_w, preferred_element_type=out_dtype)
+    if not three_pass:
+        return out
+
+    def lo(a):
+        # `reduce_precision`, not a cast there and back: the compiler may
+        # drop such a pair (`xla_allow_excess_precision`), and with it `lo`
+        info = jnp.finfo(cd)
+        a = a.astype(jnp.float32)
+        return (a - jax.lax.reduce_precision(a, info.nexp, info.nmant)
+                ).astype(cd)
+
+    more = jnp.einsum(eq, hi_x, lo(w), preferred_element_type=jnp.float32)
+    if x.dtype != cd:
+        more += jnp.einsum(eq, lo(x), hi_w,
+                           preferred_element_type=jnp.float32)
+    return (out.astype(jnp.float32)
+            + jax.lax.stop_gradient(more)).astype(out.dtype)
+
+
 def apply_attention(
     params: Params,
     x: jnp.ndarray,
@@ -121,6 +189,8 @@ def apply_attention(
     compute_dtype=jnp.bfloat16,
     mesh=None,
     reduce=None,
+    qk_fn=None,
+    three_pass: bool = False,
 ):
     """x: [B, S, D] -> [B, S, D].
 
@@ -137,11 +207,25 @@ def apply_attention(
     reduce: set by per-device callers (gpt2's `tp` region), whose params are
     the local head shard: sums the row-parallel partial output over `tp`.
     The kernel then runs on the local shard as it is, with no wrap.
+
+    qk_fn: what a model does to the projected q and k [B, S, H, K] before
+    the kernel sees them (a norm, a rotation): (q, k) -> (q, k). It is
+    handed the projections' float32 accumulators and its results are
+    rounded to the compute dtype once, for the kernel.
+
+    three_pass: the four projections' forward values to float32 accuracy
+    (`_project`), for a model whose later layers are discontinuous in them.
     """
     cd = compute_dtype
-    q = jnp.einsum("bsd,dhk->bshk", x.astype(cd), params["wq"].astype(cd))
-    k = jnp.einsum("bsd,dhk->bshk", x.astype(cd), params["wk"].astype(cd))
-    v = jnp.einsum("bsd,dhk->bshk", x.astype(cd), params["wv"].astype(cd))
+    project = functools.partial(_project, cd=cd, three_pass=three_pass)
+    # float32 out of the MXU's accumulator where something is still to be
+    # done to q and k; the compute dtype's own result type otherwise
+    qk_dtype = None if qk_fn is None else jnp.float32
+    q = project("bsd,dhk->bshk", x, params["wq"], qk_dtype)
+    k = project("bsd,dhk->bshk", x, params["wk"], qk_dtype)
+    v = project("bsd,dhk->bshk", x, params["wv"], None)
+    if qk_fn is not None:
+        q, k = (t.astype(cd) for t in qk_fn(q, k))
     if impl == "ring":
         from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -160,10 +244,11 @@ def apply_attention(
         o = attend(q, k, v)
     else:
         o = reference_attention(q, k, v, causal=causal)
-    out = jnp.einsum("bshk,hkd->bsd", o.astype(cd), params["wo"].astype(cd))
+    # into the residual stream's dtype straight from the accumulator
+    out = project("bshk,hkd->bsd", o.astype(cd), params["wo"], x.dtype)
     if reduce is not None:
         out = reduce(out)
-    return out.astype(x.dtype)
+    return out
 
 
 # ---------------------------------------------------------------- dense MLP
@@ -264,76 +349,218 @@ def apply_mlp(params: Params, x, compute_dtype=jnp.bfloat16, reduce=None):
 class MoEConfig:
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # the chosen gates rescaled to sum to 1 (GShard); False uses the router's
+    # probabilities as they are (OLMoE)
+    norm_topk_prob: bool = True
 
 
-def init_moe(key, d_model, d_ff, cfg: MoEConfig, dtype=jnp.float32):
-    kg, k1, k2 = jax.random.split(key, 3)
+def init_moe(key, d_model, d_ff, cfg: MoEConfig, dtype=jnp.float32,
+             gated: bool = False):
+    """Router `wg` and the experts, stacked over a leading E axis: two
+    matrices with a GELU between (`w1`, `w2`), or, `gated`, three with a
+    SiLU gate (`w_gate`, `w_up`, `w_down`). `apply_moe` tells the form by
+    the leaves."""
+    kg, k1, k2, k3 = jax.random.split(key, 4)
     E = cfg.n_experts
+    wide, narrow = (E, d_model, d_ff), (E, d_ff, d_model)
+    experts = ({"w_gate": _init_dense(k1, wide, dtype=dtype),
+                "w_up": _init_dense(k3, wide, dtype=dtype),
+                "w_down": _init_dense(k2, narrow, dtype=dtype)} if gated else
+               {"w1": _init_dense(k1, wide, dtype=dtype),
+                "w2": _init_dense(k2, narrow, dtype=dtype)})
+    return {"wg": _init_dense(kg, (d_model, E), dtype=dtype), **experts}
+
+
+_WIDE = ("experts", "embed", "expert_mlp")
+_NARROW = ("experts", "expert_mlp", "embed")
+MOE_LOGICAL = {"wg": ("embed", None), "w1": _WIDE, "w2": _NARROW}
+GATED_MOE_LOGICAL = {"wg": ("embed", None), "w_gate": _WIDE, "w_up": _WIDE,
+                     "w_down": _NARROW}
+
+# Rows of a tile of the grouped-matmul kernel the TPU compiler makes of
+# `lax.ragged_dot` (read from the compiled v5e step, PR 29: its metadata
+# operand holds rows / 512 + E − 1 tile visits). A tile that holds the end
+# of one expert's rows and the start of the next's is visited once for
+# each, so the FLOPs issued exceed the needed by at most E − 1 tiles of rows.
+GROUP_ROW_TILE = 512
+
+
+def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
+             gated: bool, itemsize: int = 2, ep: int = 1) -> dict:
+    """What one forward pass of `apply_moe` does on one device, from shapes
+    alone (`tokens` there; `ep` devices share the experts): the rows
+    gathered, the grouped matmuls' FLOPs needed (every assignment through
+    its expert once; a device's share under even routing) and the most the
+    tiled kernel issues under ANY routing (each local expert's group may
+    end inside a row tile, which is then visited twice), and the bytes that
+    dispatch and combine move. The backward pass is twice the FLOPs (one
+    product for the rows, one for the weights) and the same bytes again."""
+    rows = tokens * cfg.top_k
+    per_row = (3 if gated else 2) * 2 * d_model * d_ff
+    tiles = -(-rows // GROUP_ROW_TILE)
+    visits = min(tiles + cfg.n_experts // ep - 1, 2 * tiles)
     return {
-        "wg": _init_dense(kg, (d_model, E), dtype=dtype),
-        "w1": _init_dense(k1, (E, d_model, d_ff), dtype=dtype),
-        "w2": _init_dense(k2, (E, d_ff, d_model), dtype=dtype),
+        "rows": rows,
+        "flops_needed": rows * per_row // ep,
+        "flops_issued_max": visits * GROUP_ROW_TILE * per_row,
+        # each row read from its token and written in expert order
+        "dispatch_bytes": 2 * rows * d_model * itemsize,
+        # each row read back in token order, a token's K summed into one
+        "combine_bytes": (rows + tokens) * d_model * itemsize,
     }
 
 
-MOE_LOGICAL = {
-    "wg": ("embed", None),
-    "w1": ("experts", "embed", "expert_mlp"),
-    "w2": ("experts", "expert_mlp", "embed"),
-}
+@jax.custom_vjp
+def _take_assignments(x2, order, inverse):
+    """x2 [T, D] -> [T·K, D], row j the token of the j-th assignment in
+    expert order (`order`: positions in the token-major [T·K] list; `inverse`
+    its inverse permutation). Every token is taken K times, so the
+    transpose is a gather too: K rows a token, summed."""
+    return x2[order // (order.shape[0] // x2.shape[0])]
 
 
-def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16):
-    """GShard-style top-k routed MoE with capacity, dense-dispatch einsums.
+def _take_assignments_fwd(x2, order, inverse):
+    return _take_assignments(x2, order, inverse), (inverse, x2.shape[0])
 
-    Experts (leading E dim of w1/w2) are sharded over the `ep` mesh axis;
-    the dispatch/combine einsums below are exactly the contractions XLA
-    turns into all_to_all over `ep` when tokens and experts live on
-    different devices — expert parallelism without hand-written comms.
-    Returns (output [B,S,D], aux_loss scalar).
+
+def _take_assignments_bwd(res, d):
+    inverse, tokens = res
+    dx = jnp.sum(d[inverse].reshape(tokens, -1, d.shape[-1])
+                 .astype(jnp.float32), axis=1)
+    return dx.astype(d.dtype), None, None
+
+
+_take_assignments.defvjp(_take_assignments_fwd, _take_assignments_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(y, perm, inverse):
+    """y[perm] for a permutation; the transpose is the gather by `inverse`
+    (a scatter to XLA, which knows no permutation when it sees one)."""
+    return y[perm]
+
+
+_permute_rows.defvjp(lambda y, perm, inverse: (y[perm], (inverse,)),
+                     lambda res, d: (d[res[0]], None, None))
+
+
+def _grouped_matmul(lhs, rhs, sizes, cd):
+    """lhs [M, k] rows in group order, rhs [G, k, n], sizes [G]: each group's
+    rows times its own matrix; rows past sum(sizes) come out zero. On the
+    TPU `lax.ragged_dot` is a Mosaic grouped-matmul kernel of the
+    compiler's own (`ragged-dot-*` in the HLO), its two backward products
+    too; it issues the groups' row tiles, not a dense product over every
+    group (measured, PERF.md §6 PR 29)."""
+    return jax.lax.ragged_dot(lhs, rhs.astype(cd), sizes,
+                              preferred_element_type=cd)
+
+
+def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
+                   first, cd):
+    """One device's part of the routed layer: x [b, s, D] its tokens, gates
+    [b, s, K] their chosen experts and weights, `experts` the leaves of the
+    E_local experts it holds, `first` the id of the first of them. Returns
+    [b, s, D] float32: for each token the weighted outputs of those of its
+    experts that live here (all of them where nothing splits the experts)."""
+    d_model, top_k = x.shape[-1], gate_idx.shape[-1]
+    x2 = x.reshape(-1, d_model)
+    gate_vals, gate_idx = (g.reshape(-1, top_k) for g in (gate_vals, gate_idx))
+    tokens = x2.shape[0]
+    rows = tokens * top_k
+    local = next(iter(experts.values())).shape[0]
+    with jax.named_scope("dispatch"):
+        # this device's experts first, in order; the others' rows behind them
+        key = (gate_idx.reshape(rows) - first) % n_experts
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros((rows,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+        sizes = jnp.bincount(key, length=n_experts)[:local].astype(jnp.int32)
+        taken = _take_assignments(x2.astype(cd), order, inverse)
+    with jax.named_scope("experts"):
+        if "w_gate" in experts:
+            gate = _grouped_matmul(taken, experts["w_gate"], sizes, cd)
+            up = _grouped_matmul(taken, experts["w_up"], sizes, cd)
+            hidden = (jax.nn.silu(gate.astype(jnp.float32))
+                      * up.astype(jnp.float32)).astype(cd)
+            y = _grouped_matmul(hidden, experts["w_down"], sizes, cd)
+        else:
+            hidden = jax.nn.gelu(
+                _grouped_matmul(taken, experts["w1"], sizes, cd))
+            y = _grouped_matmul(hidden, experts["w2"], sizes, cd)
+    with jax.named_scope("combine"):
+        y = _permute_rows(y, inverse, order).reshape(
+            tokens, top_k, d_model)
+        here = ((gate_idx - first) % n_experts) < local
+        weighted = jnp.where(here[..., None], y.astype(jnp.float32)
+                             * gate_vals[..., None], 0.0)
+        return jnp.sum(weighted, axis=1).reshape(x.shape)
+
+
+def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
+              mesh=None):
+    """Top-k routed experts, dropless: x [B, S, D] -> (y [B, S, D], stats).
+
+    Router and softmax in float32; `lax.top_k`; the T·K assignments sorted
+    by expert (stable), their rows gathered in that order, the experts'
+    matrices applied to the ragged groups by grouped matmuls, and each
+    token's K outputs weighted by its gates and summed. No capacity: every
+    assignment is computed whatever the routing, and all shapes are static
+    (`moe_plan` gives them).
+
+    mesh: as in `apply_attention` — the grouped matmul is a Mosaic kernel on
+    the TPU, so dispatch, experts and combine run as per-device code. Each
+    device takes its share of the batch and the experts `ep` gives it
+    (their `expert_mlp` slice under `tp`), computes its experts' part of
+    its tokens' outputs, and the parts are summed over `ep` and `tp`.
+
+    stats (float32 scalars but `counts`): `load_balance` = E · Σ_e f_e · P_e
+    with f_e the share of a sequence's S·K assignments that went to expert
+    e and P_e its mean router probability, taken a sequence at a time and
+    averaged — so that, like the cross-entropy, a batch's value is the mean
+    of its sequences' whatever `dp` does with them; `z` = mean
+    logsumexp(logits)²; `counts` [E] the batch's assignments by expert.
     """
     cd = compute_dtype
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
-    C = max(1, int(cfg.capacity_factor * K * B * S / E))
+    experts = {k: v for k, v in params.items() if k != "wg"}
 
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32), params["wg"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [B,S,E]
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)  # [B,S,K]
-    # Renormalize the chosen gates.
-    gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            params["wg"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, K)      # [B,S,K]
+        if cfg.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+        # [B, E]: a sequence's assignments by expert
+        counts = jnp.sum(jax.nn.one_hot(gate_idx, E, dtype=jnp.int32),
+                         axis=(1, 2))
+        stats = {
+            "load_balance": jnp.mean(E * jnp.sum(
+                counts.astype(jnp.float32) / (S * K)
+                * jnp.mean(probs, axis=1), axis=-1)),
+            "z": jnp.mean(jnp.square(
+                jax.scipy.special.logsumexp(logits, axis=-1))),
+            "counts": jnp.sum(counts, axis=0),
+        }
 
-    # Load-balancing auxiliary loss (Switch-style): fraction of tokens per
-    # expert × mean router prob per expert.
-    me = jnp.mean(probs, axis=(0, 1))  # [E]
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(gate_idx[..., 0], E), axis=1) / S, axis=0
-    )  # top-1 token fraction per expert
-    aux_loss = E * jnp.sum(me * ce)
+    local = functools.partial(_local_experts, n_experts=E, cd=cd)
+    if mesh is None:
+        out = local(x, gate_vals, gate_idx, experts, first=0)
+    else:
+        def per_device(x, gate_vals, gate_idx, experts):
+            held = next(iter(experts.values())).shape[0]
+            return jax.lax.psum(
+                local(x, gate_vals, gate_idx, experts,
+                      first=jax.lax.axis_index("ep") * held), ("ep", "tp"))
 
-    # Position of each (token, k) within its expert's capacity buffer.
-    # Positions are assigned over the WHOLE token stream (B*S*K flattened):
-    # the dispatch einsum below sums over both b and s, so a slot (e, c)
-    # must be unique across the entire batch, not per row.
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # [B,S,K,E]
-    flat = onehot.reshape(B * S * K, E)
-    pos = jnp.cumsum(flat, axis=0) - 1  # [B*S*K, E]
-    pos = pos.reshape(B, S, K, E)
-    in_cap = (pos < C) & (onehot > 0)
-    # dispatch [B,S,E,C]: 1 where token (b,s) occupies slot c of expert e.
-    disp = jnp.sum(
-        jax.nn.one_hot(jnp.where(in_cap, pos, -1), C, dtype=cd)
-        * onehot.astype(cd)[..., None],
-        axis=2,
-    )  # sum over K -> [B,S,E,C]
-    gates_per_e = jnp.sum(
-        gate_vals[..., None].astype(cd) * onehot.astype(cd), axis=2
-    )  # [B,S,E]
-    combine = disp * gates_per_e[..., None]  # weight by gate prob
-
-    expert_in = jnp.einsum("bsec,bsd->ecd", disp, x.astype(cd))  # a2a over ep
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in, params["w1"].astype(cd)))
-    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w2"].astype(cd))
-    out = jnp.einsum("bsec,ecd->bsd", combine, expert_out)  # a2a back
-    return out.astype(x.dtype), aux_loss
+        logical = GATED_MOE_LOGICAL if "w_gate" in experts else MOE_LOGICAL
+        tok = sh.spec("batch", "seq", None)
+        out = jax.shard_map(
+            per_device, mesh=mesh,
+            in_specs=(tok, tok, tok,
+                      {k: sh.spec(*logical[k]) for k in experts}),
+            out_specs=tok, check_vma=False)(x, gate_vals, gate_idx, experts)
+    return out.astype(x.dtype), stats

@@ -80,6 +80,48 @@ def test_flash_several_major_blocks(monkeypatch, causal):
                                    err_msg=name)
 
 
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_head_dim_128_several_major_blocks(monkeypatch, causal):
+    """The OLMoE cell's form at a size the interpreter can run: heads of
+    128 and more than one major block (three of 128 rows under a budget
+    that holds a third of S = 384), so every kernel's loops are traced and
+    its index maps clamped, as at [32, 4096, 128] on the chip."""
+    monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 1_100_000)
+    S, D = 384, 128
+    assert fa.tile_plan(S, D, jnp.float32, 128, 128).dkv == \
+        fa.TilePlan(128, 128, 128, 384)
+    q, k, v, w = _inputs(S, D, heads=2)
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, block_q=128, block_k=128,
+            interpret=True), q, k, v, w)
+    ref = _out_and_grads(
+        lambda q, k, v: reference_attention(q, k, v, causal=causal),
+        q, k, v, w)
+    for name, g, r in zip(("o", "dq", "dk", "dv"), got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-4,
+                                   err_msg=name)
+
+
+def test_tile_plan_at_the_olmoe_cells_shape():
+    """[32, 4096, 128] bf16 (olmoe1l-b2s4k): the budget holds half the
+    sequence, so each kernel has two major blocks a side and traced loops;
+    the causal schedule issues 6 % (3 % in dk/dv's smaller tile) more score
+    elements than the mask keeps."""
+    plans = fa.tile_plan(4096, 128, jnp.bfloat16)
+    assert plans == fa.TilePlans(fwd=fa.TilePlan(128, 256, 2048, 4096),
+                                 dq=fa.TilePlan(256, 256, 2048, 4096),
+                                 dkv=fa.TilePlan(128, 128, 2048, 4096))
+    assert fa.vmem_bytes(2048, 128, 2) <= fa.VMEM_BUDGET_BYTES \
+        < fa.vmem_bytes(4096, 128, 2)
+    assert fa.issued_area_ratio(plans.fwd, 4096) == pytest.approx(1.0622,
+                                                                  abs=1e-4)
+    assert fa.issued_area_ratio(plans.dq, 4096) == pytest.approx(1.0622,
+                                                                 abs=1e-4)
+    assert fa.issued_area_ratio(plans.dkv, 4096) == pytest.approx(1.0310,
+                                                                  abs=1e-4)
+
+
 @pytest.mark.parametrize("S,D", [(1024, 64), (2048, 64), (4096, 128),
                                  (192, 32)])
 def test_tile_plan_shapes(S, D):

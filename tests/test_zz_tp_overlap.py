@@ -122,7 +122,8 @@ NO_TP = {
     "no_mesh": (None, {}),
     "dp4": ({"dp": 4}, {}),
     "dp2_sp2": ({"dp": 2, "sp": 2}, {"remat": True}),
-    # apply_moe keeps the partitioner's path (ROADMAP S9 rewrites it)
+    # a routed block sums its experts' parts itself (layers.apply_moe), by
+    # psum over ep and tp, not by the dense loop's exchanges
     "dp2_tp2_moe": ({"dp": 2, "tp": 2},
                     {"moe": L.MoEConfig(n_experts=4, top_k=2)}),
 }
