@@ -30,6 +30,8 @@ from ray_tpu._private.protocol import ConnectionLost, RpcClient, RpcServer
 from ray_tpu._private.store_client import StoreClient
 
 _LEASE_QUEUE_POLL = 0.02
+# how long `Raylet.stop` waits for a child it sent SIGKILL to be reaped
+_REAP_TIMEOUT_S = 60.0
 
 
 def _chip_detection_enabled() -> bool:
@@ -172,6 +174,11 @@ class Raylet:
             self.data_port = None
         self._lock = threading.RLock()
         self._workers: dict[str, WorkerHandle] = {}    # worker_id -> handle
+        # every child not yet reaped, whether or not a handle still names
+        # it (a killed or disconnected worker leaves `_workers` while its
+        # process is still dying): `stop()` waits for all of these
+        self._procs: list[subprocess.Popen] = []
+        self._starting = 0          # `Popen`s in flight
         self._idle: list[WorkerHandle] = []
         self._leases: dict[str, Lease] = {}
         self._pending: list[dict] = []                 # queued lease requests
@@ -434,15 +441,28 @@ class Raylet:
         # the driver over pubsub.
         out_path = os.path.join(self.logs_dir, f"worker-{worker_id}.out")
         err_path = os.path.join(self.logs_dir, f"worker-{worker_id}.err")
-        with open(out_path, "ab") as out_f, open(err_path, "ab") as err_f:
-            proc = subprocess.Popen(
-                [sys.executable, "-m", "ray_tpu._private.worker_main"],
-                env=env, cwd=os.getcwd(),
-                stdout=out_f, stderr=err_f)
-        handle = WorkerHandle(proc, worker_id)
-        self._log_monitor.track(worker_id, proc.pid, out_path, err_path)
         with self._lock:
-            self._workers[worker_id] = handle
+            if self._stopped:
+                raise RuntimeError("raylet is stopped")
+            self._starting += 1     # `stop()` waits for this Popen
+        proc = None
+        try:
+            with open(out_path, "ab") as out_f, \
+                    open(err_path, "ab") as err_f:
+                proc = subprocess.Popen(
+                    [sys.executable, "-m", "ray_tpu._private.worker_main"],
+                    env=env, cwd=os.getcwd(),
+                    stdout=out_f, stderr=err_f)
+            handle = WorkerHandle(proc, worker_id)
+        finally:
+            with self._lock:
+                self._starting -= 1
+                if proc is not None:
+                    self._procs = [p for p in self._procs
+                                   if p.poll() is None]
+                    self._procs.append(proc)
+                    self._workers[worker_id] = handle
+        self._log_monitor.track(worker_id, proc.pid, out_path, err_path)
         return handle
 
     def _pop_worker(self, timeout: float | None = None) -> WorkerHandle:
@@ -1370,7 +1390,17 @@ class Raylet:
     # ---- lifecycle ----------------------------------------------------------
 
     def stop(self, kill_workers: bool = True):
-        self._stopped = True
+        """With `kill_workers`, returns only when every process this raylet
+        ever spawned is dead AND reaped — also those no handle names any
+        more (an actor `rpc_kill_actor` sent SIGKILL, a worker whose
+        connection dropped first) and one a refill thread was starting."""
+        while True:
+            with self._lock:
+                self._stopped = True    # no `Popen` starts from now on,
+                if not self._starting:  # and those in flight have registered
+                    procs = list(self._procs)
+                    break
+            time.sleep(0.01)
         self._mem_monitor.stop()
         try:
             self._log_monitor.stop()   # final drain rides the live GCS conn
@@ -1384,26 +1414,32 @@ class Raylet:
         except Exception:
             pass
         if kill_workers:
-            with self._lock:
-                workers = list(self._workers.values())
-            for h in workers:
-                if h.proc is not None and h.proc.poll() is None:
+            for proc in procs:
+                if proc.poll() is None:
                     try:
-                        h.proc.terminate()
+                        proc.terminate()
                     except OSError:
                         pass
+            # 2 s in all to exit on SIGTERM, then SIGKILL and as long as the
+            # kernel takes to tear the process down (one that mapped four
+            # chips' HBM takes its time)
             deadline = time.time() + 2.0
-            for h in workers:
-                if h.proc is None:
-                    continue
-                remaining = max(0.05, deadline - time.time())
+            for proc in procs:
                 try:
-                    h.proc.wait(remaining)
+                    proc.wait(max(0.05, deadline - time.time()))
                 except subprocess.TimeoutExpired:
                     try:
-                        h.proc.kill()
+                        proc.kill()
                     except OSError:
                         pass
+            deadline = time.time() + _REAP_TIMEOUT_S
+            for proc in procs:
+                try:
+                    proc.wait(max(0.05, deadline - time.time()))
+                except subprocess.TimeoutExpired:
+                    print(f"raylet: worker pid {proc.pid} not reaped "
+                          f"{_REAP_TIMEOUT_S:.0f} s after SIGKILL",
+                          file=sys.stderr, flush=True)
         self._server.stop()
         try:
             self.store.close()

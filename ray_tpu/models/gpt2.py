@@ -2,7 +2,8 @@
 GPT-2-1.5B ≥40% MFU on v5e-64).
 
 Pure-JAX pytree model, TPU-first: bf16 compute / f32 params, einsum-only
-(MXU), `lax.scan` over layers (one compiled block), optional remat,
+(MXU), `lax.scan` over layers (one compiled block), optional remat that
+keeps the flash kernel's outputs and the attention sub-layer's (`L.remat`),
 sharding by logical axes (parallel/sharding.py) so the same forward runs
 dp/tp/sp/ep on any mesh; pipeline-parallel forward via parallel/pipeline.py.
 
@@ -50,6 +51,8 @@ class GPT2Config:
     moe: Optional[L.MoEConfig] = None  # if set, every block's MLP is routed
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # recompute each block in the backward pass from its input and the few
+    # values `L.remat` keeps (flash `o`/`lse`, the attention output)
     remat: bool = True
     attention: str = "auto"  # auto | flash | reference | ring
     aux_loss_weight: float = 0.01
@@ -219,22 +222,45 @@ def tp_exchange_plan(cfg: GPT2Config, mesh: Optional[Mesh], local_batch: int,
     """(exchanges, bytes, chains) of one training step's layer loops on one
     device: how often `_tp_blocks` engages, from shapes alone.
 
-    A chain exchanges twice a layer forward (attention and MLP outputs),
-    twice backward (their cotangents), and once more under remat: the
-    recomputed attention output; the recomputed MLP output is dead code. A
-    reduction over `tp` devices is tp − 1 exchanges of the chain's whole
-    [batch, seq, d_model] activation. `seq` is the global sequence length
-    (default `cfg.max_seq`)."""
+    A chain exchanges twice a layer forward (attention and MLP outputs) and
+    twice backward (their cotangents), with remat or without: the
+    checkpoint keeps the reduced attention output (`L.remat`), so the
+    recompute issues no exchange for it, and the recomputed MLP output is
+    dead code. A reduction over `tp` devices is tp − 1 exchanges of the
+    chain's whole [batch, seq, d_model] activation. `seq` is the global
+    sequence length (default `cfg.max_seq`)."""
     tp = _tp_size(cfg, mesh)
     if tp == 1:
         return 0, 0, 1
     chains = _chains(local_batch)
-    per_layer = 5 if cfg.remat else 4
-    exchanges = cfg.n_layer * chains * per_layer * (tp - 1)
+    exchanges = cfg.n_layer * chains * 4 * (tp - 1)
     seq = (seq or cfg.max_seq) // sh.axis_size(mesh, "sp")
     size = (local_batch // chains) * seq * cfg.d_model \
         * jnp.dtype(cfg.dtype).itemsize
     return exchanges, exchanges * size, chains
+
+
+def remat_saved_plan(cfg: GPT2Config, mesh: Optional[Mesh], local_batch: int,
+                     seq: Optional[int] = None, *, flash: bool = True):
+    """{name: bytes} of what one layer's checkpoint keeps on one device
+    besides the block's input (`L.remat`), from shapes alone: the attention
+    sub-layer's output, whole on every `tp` device, and, where the flash
+    kernels run (`flash`), their `o` and float32 `lse` over the device's
+    heads. Times `cfg.n_layer` it is what remat no longer saves of the
+    step's memory. `seq` as in `tp_exchange_plan`."""
+    sp, tp = (1, 1) if mesh is None else (sh.axis_size(mesh, "sp"),
+                                          sh.axis_size(mesh, "tp"))
+    rows = local_batch * ((seq or cfg.max_seq) // sp)
+    width = cfg.d_model // tp       # the device's heads × head_dim
+    item = jnp.dtype(cfg.dtype).itemsize
+    plan = {L.ATTENTION_OUT: rows * cfg.d_model * item}
+    if flash:
+        from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+        o, lse = RESIDUAL_NAMES
+        plan[o] = rows * width * item
+        plan[lse] = rows * (cfg.n_head // tp) * 4
+    return plan
 
 
 def _tp_blocks(blocks, x, cfg: GPT2Config, impl: str, mesh: Mesh):
@@ -250,7 +276,9 @@ def _tp_blocks(blocks, x, cfg: GPT2Config, impl: str, mesh: Mesh):
     transpose: the same exchange on each reduced output's cotangent, so a
     device carries its share of the residual stream's cotangent, and the
     shares (and the gradients of what `tp` replicates) are summed once, at
-    the region's edge, with the `dp` sum of the stacked weight gradients."""
+    the region's edge, with the `dp` sum of the stacked weight gradients.
+    Under remat the checkpoint keeps each chain's reduced attention output
+    (`L.remat`), so what the backward pass recomputes holds no exchange."""
     reduce = functools.partial(L.exchange_sum, axis_name="tp")
     if impl == "ring":
         impl = "ring_local"    # `sp` is manual here too
@@ -263,7 +291,7 @@ def _tp_blocks(blocks, x, cfg: GPT2Config, impl: str, mesh: Mesh):
                          for c in chains), None
 
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = L.remat(body)
         chains, _ = jax.lax.scan(body, chains, blocks)
         return jnp.concatenate(chains)
 
@@ -309,7 +337,7 @@ def forward(params, tokens, cfg: GPT2Config, mesh: Optional[Mesh] = None):
             return (x, aux + a), None
 
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = L.remat(body)
         (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0)),
                                    params["blocks"])
     logits = unembed(params, x, cfg)
@@ -370,7 +398,7 @@ def forward_pipelined(
             return y, None
 
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = L.remat(body)
         x, _ = jax.lax.scan(body, x, stage_blocks)
         return x
 

@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention_local
@@ -179,6 +180,11 @@ def _project(eq, x, w, out_dtype, *, cd, three_pass):
             + jax.lax.stop_gradient(more)).astype(out.dtype)
 
 
+# `checkpoint_name` of the attention sub-layer's output, [B, S, d_model] as
+# it is added to the residual stream (after `reduce`).
+ATTENTION_OUT = "attention_out"
+
+
 def apply_attention(
     params: Params,
     x: jnp.ndarray,
@@ -248,7 +254,24 @@ def apply_attention(
     out = project("bshk,hkd->bsd", o.astype(cd), params["wo"], x.dtype)
     if reduce is not None:
         out = reduce(out)
-    return out
+    return checkpoint_name(out, ATTENTION_OUT)
+
+
+def remat(body):
+    """`jax.checkpoint` for a layer loop's scan body that keeps, besides the
+    block's input, what is dear to recompute: the flash forward kernel's `o`
+    and `lse` (the backward kernels' residuals: without them the kernel
+    runs twice a step) and the attention sub-layer's output (without it the
+    recompute needs the `wo` product and, under `tp`, its exchange, only to
+    rebuild the second norm's input). Everything else in the block — norms,
+    q/k/v products, the MLP's first product — is recomputed. A block that
+    names none of these (ring or reference attention has no `o`/`lse`)
+    keeps what it does name."""
+    from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    return jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES, ATTENTION_OUT))
 
 
 # ---------------------------------------------------------------- dense MLP

@@ -18,7 +18,8 @@ Written from the public `olmoe` implementation's equations:
   paper's 0.01 and 0.001).
 
 Same shape as `models/gpt2.py`: a pure pytree model, bf16 matmuls over
-float32 parameters, `lax.scan` over stacked blocks with optional remat,
+float32 parameters, `lax.scan` over stacked blocks with optional remat
+(`L.remat`: the flash kernel's outputs and the attention output are kept),
 sharding by logical axes — it runs on any `dp` × `ep` mesh the rules give.
 Norms, rotation, router, softmax and loss are float32, and so is the
 RESIDUAL STREAM, which `gpt2.py` carries in bf16: the router's top-k is a
@@ -64,7 +65,7 @@ class OlmoeConfig:
     z_loss_weight: float = 0.001
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    remat: bool = False
+    remat: bool = False     # as `GPT2Config.remat`
     attention: str = "auto"  # auto | flash | reference
 
     @property
@@ -197,7 +198,7 @@ def forward(params, tokens, cfg: OlmoeConfig, mesh: Optional[Mesh] = None):
         return x, stats
 
     if cfg.remat:
-        body = jax.checkpoint(body)
+        body = L.remat(body)
     x, stats = jax.lax.scan(body, x, params["blocks"])
     with jax.named_scope("loss_tail"):
         # nothing behind the last block is discontinuous: the head reads

@@ -43,11 +43,17 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128
+# `checkpoint_name`s of the forward kernel's two outputs as the backward
+# pass keeps them (o [B, S, H, D], lse [B·H, S] f32). A `jax.checkpoint`
+# whose policy saves them (`models.layers.remat`) does not run the forward
+# kernel a second time; outside a checkpoint a name lowers to nothing.
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
 # grid = (batch*heads, parallel blocks, sequentially accumulated blocks)
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -380,20 +386,33 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
     return o[:, :S], lse[:, :, 0, :].reshape(BH, S_pad)[:, :S]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    o, _ = _flash_fwd(
-        q, k, v, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
-    return o
+def _to_bh(x):
+    """[B, S, H, D] → [B·H, S, D], the kernels' layout."""
+    B, S, H, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, heads, scale, causal, block_q, block_k, interpret):
+    """q, k, v [B·H, S, D] → o [B, S, H, D]."""
+    return _flash_vjp_fwd(q, k, v, heads, scale, causal, block_q, block_k,
+                          interpret)[0]
+
+
+def _flash_vjp_fwd(q, k, v, heads, scale, causal, block_q, block_k,
+                   interpret):
+    """The backward pass keeps `o` as the model reads it, [B, S, H, D], not
+    as the kernel wrote it: [B·H, S, D] with D = 64 is padded to 128 lanes
+    in HBM, twice the bytes, and the backward needs `o` only for the row
+    sums `delta`, which it takes in either layout."""
     o, lse = _flash_fwd(
         q, k, v, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
+    BH, S, D = o.shape
+    o = o.reshape(BH // heads, heads, S, D).transpose(0, 2, 1, 3)
+    o, lse = (checkpoint_name(x, name)
+              for x, name in zip((o, lse), RESIDUAL_NAMES))
     return o, (q, k, v, o, lse)
 
 
@@ -502,13 +521,13 @@ def _tile_rows(x, plan: TilePlan):
     return jnp.broadcast_to(x, (BH, x.shape[1], 8, plan.tile_q))
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
+def _flash_bwd(q, k, v, lse, delta, do, *, scale, causal, block_q, block_k,
                interpret):
-    """Pallas backward: returns (dq, dk, dv), each [BH, S, D]."""
+    """Pallas backward: returns (dq, dk, dv), each [BH, S, D]. `delta`
+    [BH, S]: the row sums of do · o."""
     BH, S, D = q.shape
     plans = tile_plan(S, D, q.dtype, block_q, block_k)
     S_pad, major = plans.dq.s_pad, plans.dq.major
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if S_pad != S:
         pad = [(0, 0), (0, S_pad - S), (0, 0)]
         q, k, v, do = (jnp.pad(x, pad) for x in (q, k, v, do))
@@ -553,11 +572,14 @@ def _flash_bwd(q, k, v, o, lse, do, *, scale, causal, block_q, block_k,
     return dq[:, :S], dk[:, :S], dv[:, :S]
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_vjp_bwd(heads, scale, causal, block_q, block_k, interpret, res,
+                   do):
     q, k, v, o, lse = res
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dq, dk, dv = _flash_bwd(
-        q, k, v, o, lse, do, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        q, k, v, lse, delta.transpose(0, 2, 1).reshape(lse.shape), _to_bh(do),
+        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret,
     )
     return dq, dk, dv
 
@@ -583,14 +605,8 @@ def flash_attention(
     ``(S, D, dtype)`` by :func:`tile_plan`; ``block_q``/``block_k`` override
     the score tile's rows/columns (multiples of 128) and exist for tests.
     """
-    B, S, H, D = q.shape
+    H, D = q.shape[2:]
     if scale is None:
         scale = 1.0 / (D**0.5)
-
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-
-    o = _flash(
-        to_bh(q), to_bh(k), to_bh(v), scale, causal, block_q, block_k, interpret
-    )
-    return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    return _flash(_to_bh(q), _to_bh(k), _to_bh(v), H, scale, causal, block_q,
+                  block_k, interpret)

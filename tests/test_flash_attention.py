@@ -43,6 +43,30 @@ def test_flash_matches_reference(causal, S, D, blocks):
                                    err_msg=f"{name} (S={S}, D={D})")
 
 
+@pytest.mark.parametrize("S,D", [(128, 64), (192, 64), (128, 128),
+                                 (192, 128)])
+def test_vjp_keeps_o_as_the_model_reads_it(S, D):
+    """The backward keeps `o` as [B, S, H, D] (dense in HBM at D = 64, where
+    the kernels' [B·H, S, D] is padded to 128 lanes) and takes the row sums
+    of do · o in that layout: with several batch rows and heads a wrong
+    order of the two would show in every gradient. S = 192 is padded."""
+    B, H = 2, 3
+    keys = jax.random.split(jax.random.PRNGKey(S + D), 4)
+    q, k, v, w = (jax.random.normal(key, (B, S, H, D)) for key in keys)
+    o, res = fa._flash_vjp_fwd(*(fa._to_bh(x) for x in (q, k, v)), H,
+                               D ** -0.5, True, None, None, True)
+    assert o.shape == (B, S, H, D) and res[3] is o
+    assert res[4].shape == (B * H, S)           # lse, as the kernels read it
+    got = _out_and_grads(
+        lambda q, k, v: fa.flash_attention(q, k, v, interpret=True),
+        q, k, v, w)
+    ref = _out_and_grads(reference_attention, q, k, v, w)
+    for name, g, r, atol in zip(("o", "dq", "dk", "dv"), got, ref,
+                                (2e-5, 1e-4, 1e-4, 1e-4)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=atol,
+                                   err_msg=f"{name} (S={S}, D={D})")
+
+
 def test_flash_bf16_within_the_chip_smoke_tolerance():
     """bf16 operands, f32 scores and statistics: the relative error
     chip_smoke.py allows the compiled kernels (0.02)."""

@@ -106,8 +106,9 @@ def test_tp_stack_matches_unsharded_and_exchanges_as_planned(case):
     exchanges, size, chains = gpt2.tp_exchange_plan(cfg, mesh, local_batch,
                                                     seq=SEQ)
     assert chains == (1 if local_batch % 2 else 2)
-    per_layer = 5 if cfg.remat else 4
-    assert exchanges == cfg.n_layer * chains * per_layer * (axes["tp"] - 1)
+    # with remat too: the checkpoint keeps the reduced attention output, so
+    # the backward pass recomputes no exchange (jaxpr and plan agree below)
+    assert exchanges == cfg.n_layer * chains * 4 * (axes["tp"] - 1)
     assert size == exchanges * (local_batch // chains) * (
         SEQ // axes.get("sp", 1)) * cfg.d_model * 4
     counted, sums = _tp_collectives(cfg, mesh, params, tokens)
@@ -116,6 +117,28 @@ def test_tp_stack_matches_unsharded_and_exchanges_as_planned(case):
     # outside is the region's edge (the stack input's cotangent shares)
     assert [s for s in sums if s[0] and s[1] >= 3] == []
     assert len([s for s in sums if s[1] >= 3]) <= 1
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_compiled_loops_hold_four_exchanges_a_layer_and_chain(remat):
+    """The compiled 2 x 2 step, one body a layer loop: two exchanges a chain
+    in the forward body and two in the backward one, under remat as
+    without (a checkpoint with no policy put a third in the backward's)."""
+    axes = {"dp": 2, "tp": 2}
+    cfg, params, tokens = _setup(8, remat=remat)
+    mesh = _mesh(axes)
+    with jax.set_mesh(mesh):
+        sharded = jax.tree_util.tree_map(
+            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params,
+            gpt2.partition_specs(cfg))
+        hlo = jax.jit(_loss_and_grads(cfg, mesh)).lower(
+            sharded, tokens).compile().as_text()
+    exchanges, _, chains = gpt2.tp_exchange_plan(cfg, mesh, 4, seq=SEQ)
+    assert chains == 2 and exchanges == cfg.n_layer * chains * 4
+    permutes = [line for line in hlo.splitlines()
+                if " collective-permute(" in line
+                or " collective-permute-start(" in line]
+    assert len(permutes) == exchanges // cfg.n_layer
 
 
 NO_TP = {
@@ -145,18 +168,18 @@ def test_no_exchange_where_the_model_does_not_reduce(case):
 
 def test_tp_exchange_plan_at_the_four_chip_cells_shapes():
     """gpt2l-dp2tp2: gpt2-large under remat, 32 x 1,024 over dp=2 x tp=2:
-    36 layers x 2 chains x 5 exchanges of one bf16 [8, 1024, 1280]."""
+    36 layers x 2 chains x 4 exchanges of one bf16 [8, 1024, 1280]."""
     cfg = dataclasses.replace(gpt2.gpt2_large(), remat=True)
     mesh = _mesh({"dp": 2, "tp": 2})
     one = 8 * 1024 * 1280 * 2
     assert one == 20_971_520
-    assert gpt2.tp_exchange_plan(cfg, mesh, 16) == (360, 360 * one, 2)
+    assert gpt2.tp_exchange_plan(cfg, mesh, 16) == (288, 288 * one, 2)
     assert gpt2.tp_exchange_plan(cfg, _mesh({"dp": 4}), 16) == (0, 0, 1)
     assert gpt2.tp_exchange_plan(cfg, None, 16) == (0, 0, 1)
     # one chain where the local batch does not halve: half the exchanges,
     # each twice the size
     assert gpt2.tp_exchange_plan(cfg, mesh, 1, seq=1024) == (
-        180, 180 * 1024 * 1280 * 2, 1)
+        144, 144 * 1024 * 1280 * 2, 1)
     assert gpt2.tp_exchange_plan(
         dataclasses.replace(cfg, remat=False), _mesh({"tp": 4}), 16)[0] \
         == 36 * 2 * 4 * 3
