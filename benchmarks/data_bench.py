@@ -5,14 +5,17 @@ train run over a dataset larger than the prefetch budget where per-step
 data wait is <5% of step time, measured by the
 `ray_tpu_data_wait_seconds` telemetry the plane stamps.
 
-Two phases, both comparing streaming (default) vs the legacy
-materialize-then-iterate path (`RAY_TPU_DATA_STREAMING=0`), with and
-without `device_put`:
+Three phases (the comparison with the materialize-then-iterate iterator
+this plane replaced is on record in
+`benchmarks/results/cpu_rounds/BENCH_r09.json` and CHANGES.md, PR 9):
 
   ingest   driver-side iteration with a simulated per-batch train step
-           (`--step-ms` busy wait): reports rows/s, MB/s, and the
-           data-wait fraction wait/(wait+step) per config, plus a
-           bit-equality check between the two paths.
+           (`--step-ms` busy wait), with and without `device_put`:
+           reports rows/s, MB/s, and the data-wait fraction
+           wait/(wait+step) per config.
+
+  bounded  peak object-store occupancy while a transformed dataset is
+           iterated.
 
   train    a real 2-worker Train gang: each rank iterates its shard via
            `session.get_dataset_shard` (consumer-tagged
@@ -22,7 +25,7 @@ without `device_put`:
            the acceptance ratio.
 
 Usage:
-  python benchmarks/data_bench.py --json-out BENCH_r09.json
+  python benchmarks/data_bench.py --json-out /tmp/data_bench.json
   python benchmarks/data_bench.py --phase ingest --rows 200000
 """
 from __future__ import annotations
@@ -44,7 +47,7 @@ def emit(result: dict):
 
 def _busy_wait(seconds: float):
     """Spin (not sleep): a sleeping consumer yields its core to the
-    prefetch threads, which would flatter the legacy path's overlap."""
+    prefetch threads, which would flatter the overlap."""
     end = time.perf_counter() + seconds
     while time.perf_counter() < end:
         pass
@@ -58,26 +61,19 @@ def _make_dataset(rows: int, dim: int, blocks: int):
 
 
 def bench_ingest(args) -> list[dict]:
-    import ray_tpu
-
     ds, nbytes = _make_dataset(args.rows, args.dim, args.blocks)
     step_s = args.step_ms / 1000.0
     out = []
-    configs = [(s, d) for s in ("streaming", "legacy")
-               for d in ((False, True) if args.device_put else (False,))]
+    configs = (False, True) if args.device_put else (False,)
     if args.device_put:
         import jax
 
         jax.device_put(np.zeros(8, dtype=np.float32)).block_until_ready()
-    digests: dict = {}
-    for mode, device_put in configs:
-        os.environ["RAY_TPU_DATA_STREAMING"] = (
-            "1" if mode == "streaming" else "0")
+    for device_put in configs:
         for repeat in range(args.repeats):
             wait_s = 0.0
             n_rows = 0
             n_batches = 0
-            digest = 0
             t_start = time.perf_counter()
             it = ds.iter_batches(batch_size=args.batch_size,
                                  device_put=device_put)
@@ -94,14 +90,12 @@ def bench_ingest(args) -> list[dict]:
                 else:
                     n_rows += len(batch)
                 n_batches += 1
-                if repeat == 0 and not device_put:
-                    digest ^= hash(np.asarray(batch).tobytes())
                 if step_s:
                     _busy_wait(step_s)
             total_s = time.perf_counter() - t_start
             step_total = n_batches * step_s
             row = {
-                "phase": "ingest", "mode": mode,
+                "phase": "ingest",
                 "device_put": device_put, "repeat": repeat,
                 "rows": n_rows, "batches": n_batches,
                 "total_s": round(total_s, 4),
@@ -112,50 +106,32 @@ def bench_ingest(args) -> list[dict]:
                     wait_s / (wait_s + step_total), 4)
                 if step_total else None,
             }
-            if repeat == 0 and not device_put:
-                digests[mode] = digest
             emit(row)
             out.append(row)
-    os.environ["RAY_TPU_DATA_STREAMING"] = "1"
-    if len(digests) == 2:
-        match = digests["streaming"] == digests["legacy"]
-        row = {"phase": "ingest", "check": "bit_equality",
-               "streaming_equals_legacy": bool(match)}
-        emit(row)
-        out.append(row)
-        assert match, "streaming output diverged from legacy!"
-    _ = ray_tpu
     return out
 
 
 def bench_bounded(args) -> list[dict]:
-    """Peak object-store occupancy of a transformed dataset: the legacy
-    path materializes every map-stage output block up front, streaming
-    submits tasks on demand and frees consumed blocks — store growth is
-    ~the prefetch budget instead of the whole transformed dataset."""
+    """Peak object-store occupancy of a transformed dataset: map-stage
+    tasks are submitted on demand and consumed blocks freed, so store
+    growth is ~the prefetch budget, not the whole transformed dataset."""
     from ray_tpu._private.worker_runtime import current_worker
 
     ds, nbytes = _make_dataset(args.rows, args.dim, args.blocks)
     mapped = ds.map_batches(lambda a: a * 2)
     store = current_worker().store
-    out = []
-    for mode in ("streaming", "legacy"):
-        os.environ["RAY_TPU_DATA_STREAMING"] = (
-            "1" if mode == "streaming" else "0")
-        time.sleep(0.3)   # let the ref reaper settle between modes
-        base = store.stats()["bytes_used"]
-        peak = base
-        n_rows = 0
-        for batch in mapped.iter_batches(batch_size=args.batch_size):
-            n_rows += len(batch)
-            peak = max(peak, store.stats()["bytes_used"])
-        row = {"phase": "bounded", "mode": mode, "rows": n_rows,
-               "dataset_mb": round(nbytes / 1e6, 1),
-               "peak_extra_mb": round((peak - base) / 1e6, 1)}
-        emit(row)
-        out.append(row)
-    os.environ["RAY_TPU_DATA_STREAMING"] = "1"
-    return out
+    time.sleep(0.3)   # let the ref reaper settle after the ingest phase
+    base = store.stats()["bytes_used"]
+    peak = base
+    n_rows = 0
+    for batch in mapped.iter_batches(batch_size=args.batch_size):
+        n_rows += len(batch)
+        peak = max(peak, store.stats()["bytes_used"])
+    row = {"phase": "bounded", "rows": n_rows,
+           "dataset_mb": round(nbytes / 1e6, 1),
+           "peak_extra_mb": round((peak - base) / 1e6, 1)}
+    emit(row)
+    return [row]
 
 
 def _train_loop(config):

@@ -158,7 +158,7 @@ def train_loop(config: dict):
     import numpy as np
 
     from ray_tpu.air import session
-    from ray_tpu.models import gpt2
+    from ray_tpu.models import gpt2, layers
     from ray_tpu.parallel.compile_watch import configure_compile_cache
     from ray_tpu.parallel.mesh import (
         MeshConfig,
@@ -204,7 +204,7 @@ def train_loop(config: dict):
     mesh = create_mesh(MeshConfig(**config["mesh"]),
                        devices=devices[:n_mesh])
     report["mesh"] = mesh_shape_summary(mesh)
-    report["attention"] = gpt2._resolve_attention(cfg, mesh)
+    report["attention"] = layers.resolve_attention(cfg.attention, mesh)
 
     t_compile = time.perf_counter()
     if report["attention"] == "flash":

@@ -178,7 +178,7 @@ _CONFIG_DEFS: Dict[str, Any] = {
     # one-way pushes). 0 = unbounded. 1F1B ignores it — its warmup
     # depth (<= P - stage) is the inherent bound.
     "pipeline_inflight_window": 0,
-    # --- step anatomy (parallel/step_anatomy.py) ---
+    # --- step anatomy (_private/step_anatomy.py) ---
     # Rolling-baseline step-time regression detector: compare p50 of the
     # last `window` steps against p50 of the window before it; fire a
     # STEP_REGRESSION event + counter when recent > multiple * baseline.

@@ -72,7 +72,7 @@ Event kinds recorded by the runtime:
                      the replica drains inside the window and routers
                      drop it from selection.
 - ``STEP_REGRESSION`` — the step-anatomy rolling-baseline detector
-                     fired (parallel/step_anatomy.py): rank, step_id,
+                     fired (_private/step_anatomy.py): rank, step_id,
                      recent/baseline p50 step time, the knobbed
                      multiple.
 - ``FLIGHT_RECORDER_DUMP`` — a black-box dump directory was written

@@ -129,7 +129,7 @@ def local_snapshot(window: float | None = None) -> dict:
     except Exception:
         out["spans"] = []
     try:
-        from ray_tpu.parallel import step_anatomy as _sa
+        from ray_tpu._private import step_anatomy as _sa
 
         out["steps"] = _sa.local_records()
     except Exception:

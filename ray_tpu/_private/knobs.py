@@ -64,9 +64,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "0 skips the fsync-file + fsync-dir calls in the atomic-write "
        "durability idiom — TEST-ONLY kill switch; production crash "
        "consistency requires it on."),
-    _k("DATA_STREAMING", "1", "bool",
-       "0 restores the legacy materialize-then-iterate dataset path "
-       "(bit-identical kill switch for the streaming data plane)."),
     _k("DATA_SHUFFLE_COLLECTIVE", "0", "bool",
        "1 routes random_shuffle's partition all-to-all over the "
        "pipelined host-collective plane (actor gang exchange) instead "

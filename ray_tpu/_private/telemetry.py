@@ -528,7 +528,7 @@ CATALOG: dict[str, dict] = {
                        "verify digests + param reassembly + elastic "
                        "opt-state re-slice)",
     },
-    # --- step anatomy + flight recorder (parallel/step_anatomy.py,
+    # --- step anatomy + flight recorder (_private/step_anatomy.py,
     # _private/flight_recorder.py) ---
     "ray_tpu_step_seconds": {
         "kind": "Histogram", "tags": (),

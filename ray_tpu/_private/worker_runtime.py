@@ -1772,7 +1772,7 @@ class CoreWorker:
     def rpc_step_records(self, conn):
         """This process's step-anatomy export (steps + activities +
         drop counts) for summarize_steps()'s cluster fan-out."""
-        from ray_tpu.parallel import step_anatomy
+        from ray_tpu._private import step_anatomy
 
         return [step_anatomy.local_records()]
 

@@ -11,6 +11,8 @@ from __future__ import annotations
 import queue
 import threading
 
+from ray_tpu._private import step_anatomy
+
 
 class _Session:
     def __init__(self, world_rank: int, world_size: int, local_rank: int = 0,
@@ -34,12 +36,7 @@ class _Session:
         # step, and the report's iteration number its monotonically
         # increasing step_id. No-op outside an instrumented train loop
         # (e.g. Tune function trainables reporting on the driver).
-        try:
-            from ray_tpu.parallel import step_anatomy
-
-            step_anatomy.advance(self.iteration)
-        except Exception:
-            pass
+        step_anatomy.advance(self.iteration)
         self.results.put({"metrics": dict(metrics),
                           "checkpoint": checkpoint,
                           "iteration": self.iteration,

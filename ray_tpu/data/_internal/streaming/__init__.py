@@ -3,13 +3,10 @@ executor, _internal/execution/streaming_executor.py — the Dataset layer
 of the Ray paper, arXiv:1712.05889, with the "keep the chips busy"
 discipline of arXiv:2011.03641).
 
-`Dataset.iter_batches` / `DatasetPipeline` ride this by default; the
-legacy materialize-then-iterate path is the bit-identical kill switch
-``RAY_TPU_DATA_STREAMING=0`` (cataloged in `_private/knobs.py`).
+`Dataset.iter_batches` and `DatasetPipeline.iter_batches` ride this.
 """
 from ray_tpu.data._internal.streaming.executor import (  # noqa: F401
     StreamingExecutor,
     last_executor,
     prefetch_budget,
-    streaming_enabled,
 )

@@ -57,11 +57,6 @@ STAGE_MIN_BYTES = 32 * 1024
 _STAGE_PREFIX = b"dstrm"
 
 
-def streaming_enabled() -> bool:
-    """RAY_TPU_DATA_STREAMING=0 is the legacy-path kill switch."""
-    return os.environ.get("RAY_TPU_DATA_STREAMING", "1") != "0"
-
-
 def prefetch_budget() -> int:
     try:
         v = int(os.environ.get("RAY_TPU_DATA_PREFETCH_BLOCKS",

@@ -1,12 +1,10 @@
 """Batch assembly over a block stream + the device-put double buffer.
 
-One batching loop serves every path — legacy (``RAY_TPU_DATA_STREAMING=0``)
-and streaming, Dataset and DatasetPipeline — so streaming output is
-bit-identical to the legacy path by construction, and a pipeline carries
-its batch remainder across window boundaries (only the final batch may be
-short, honoring ``drop_last``).
+One batching loop serves Dataset and DatasetPipeline, so a pipeline
+carries its batch remainder across window boundaries (only the final batch
+may be short, honoring ``drop_last``).
 
-With ``device_put=True`` the streaming path double-buffers: a producer
+With ``device_put=True`` the stream double-buffers: a producer
 thread assembles batch k+1 (block fetch is already overlapped by the
 executor) and dispatches its ``jax.device_put`` while the caller consumes
 batch k, so the host→HBM transfer rides under the train step.
@@ -22,22 +20,17 @@ import queue as _queue
 import threading
 import time
 
+# the step-anatomy stamps below cost one tuple read per batch when no
+# train step is active
+from ray_tpu._private import step_anatomy as _sa
 from ray_tpu._private import telemetry as _tm
 from ray_tpu.data import block as B
-# jax-free module (parallel/__init__ is empty): the step-anatomy stamps
-# below cost one tuple read per batch when no train step is active
-from ray_tpu.parallel import step_anatomy as _sa
-from ray_tpu.data._internal.streaming.executor import (
-    StreamingExecutor,
-    streaming_enabled,
-)
+from ray_tpu.data._internal.streaming.executor import StreamingExecutor
 
 
 def iter_batch_blocks(blocks, batch_size: int, drop_last: bool):
     """Slice a block stream into batch-sized blocks: numpy views + one
-    concat per batch, zero per-row Python for columnar blocks (the exact
-    assembly the legacy ``iter_batches`` loop used — kept verbatim so
-    both paths produce identical bytes)."""
+    concat per batch, zero per-row Python for columnar blocks."""
     pending: list = []       # partial blocks carried across block refs
     pending_n = 0
     for blk in blocks:
@@ -163,20 +156,6 @@ def _double_buffered(batch_blocks, to_batch):
         stop.set()
 
 
-def _one_batch_lookahead(batch_blocks, to_batch):
-    """The legacy device-feed overlap: convert (and dispatch the device
-    transfer of) batch k+1 before yielding batch k. Order and content
-    are unchanged — only the conversion timing moves."""
-    prev = None
-    for bb in batch_blocks:
-        batch = to_batch(bb)
-        if prev is not None:
-            yield prev
-        prev = batch
-    if prev is not None:
-        yield prev
-
-
 def stream_items(ds):
     """(stages, ref) sources for one Dataset, drawn lazily so the
     executor submits map-stage tasks on demand. ActorPoolStrategy
@@ -206,13 +185,13 @@ def _make_submit():
     return submit
 
 
-def dataset_iter_batches(ds, *, batch_size: int, batch_format: str,
-                         device_put: bool, drop_last: bool):
-    """The streaming implementation behind ``Dataset.iter_batches``."""
-    consumer = getattr(ds, "_consumer", None) or "default"
+def _stream_batches(owner, items, *, batch_size: int, batch_format: str,
+                    device_put: bool, drop_last: bool):
+    """Batches over one executor's block stream, each stamped with the
+    time `owner`'s consumer waited for it."""
+    consumer = getattr(owner, "_consumer", None) or "default"
     to_batch = make_to_batch(batch_format, device_put)
-    ex = StreamingExecutor(stream_items(ds), _make_submit(),
-                           consumer=consumer)
+    ex = StreamingExecutor(items, _make_submit(), consumer=consumer)
     batch_blocks = iter_batch_blocks(ex.iter_blocks(), batch_size,
                                      drop_last)
     if device_put:
@@ -225,44 +204,16 @@ def dataset_iter_batches(ds, *, batch_size: int, batch_format: str,
         ex.close()
 
 
-def pipeline_iter_batches(pipe, *, batch_size: int, batch_format: str,
-                          device_put: bool, drop_last: bool):
+def dataset_iter_batches(ds, **batching):
+    """``Dataset.iter_batches``."""
+    return _stream_batches(ds, stream_items(ds), **batching)
+
+
+def pipeline_iter_batches(pipe, **batching):
     """``DatasetPipeline.iter_batches``: one batch stream over ALL
-    windows, carrying the remainder across window boundaries. Streaming
-    mode runs one executor over the concatenated window sources (window
-    i+1's tasks submit while window i is consumed, bounded by the same
-    budget); the kill-switch path fetches window blocks with the legacy
-    one-window lookahead — both feed the same batcher, so their batches
-    are identical."""
-    consumer = getattr(pipe, "_consumer", None) or "default"
-    to_batch = make_to_batch(batch_format, device_put)
-    ex = None
-    if streaming_enabled():
-        def items():
-            for w in pipe._window_iter():
-                yield from stream_items(w)
-
-        ex = StreamingExecutor(items(), _make_submit(), consumer=consumer)
-        blocks = ex.iter_blocks()
-    else:
-        def legacy_blocks():
-            import ray_tpu
-
-            for ds in pipe.iter_datasets():
-                for ref in ds._materialized_refs():
-                    yield ray_tpu.get(ref)
-
-        blocks = legacy_blocks()
-    batch_blocks = iter_batch_blocks(blocks, batch_size, drop_last)
-    if device_put and ex is not None:
-        gen = _double_buffered(batch_blocks, to_batch)
-    elif device_put:
-        # kill-switch path keeps the legacy one-batch device lookahead
-        gen = _one_batch_lookahead(batch_blocks, to_batch)
-    else:
-        gen = (to_batch(bb) for bb in batch_blocks)
-    try:
-        yield from stamp_wait(gen, consumer)
-    finally:
-        if ex is not None:
-            ex.close()
+    windows, carrying the remainder across window boundaries. One
+    executor runs over the concatenated window sources, so window i+1's
+    tasks submit while window i is consumed, bounded by the same
+    budget."""
+    items = (item for w in pipe._window_iter() for item in stream_items(w))
+    return _stream_batches(pipe, items, **batching)

@@ -453,76 +453,13 @@ class Dataset:
         shm store with per-consumer backpressure, and with device_put a
         double-buffer thread overlaps fetch + slice + ``jax.device_put``
         of batch k+1 with the caller consuming batch k (the TPU host→HBM
-        feed pipeline). ``RAY_TPU_DATA_STREAMING=0`` restores the legacy
-        materialize-then-iterate path bit-for-bit. Per-batch consumer
-        wait lands in ``ray_tpu_data_wait_seconds{consumer}``."""
-        from ray_tpu.data._internal.streaming import (
-            executor as _sx,
-            iterator as _si,
-        )
+        feed pipeline). Per-batch consumer wait lands in
+        ``ray_tpu_data_wait_seconds{consumer}``."""
+        from ray_tpu.data._internal.streaming import iterator as _si
 
-        if _sx.streaming_enabled():
-            yield from _si.dataset_iter_batches(
-                self, batch_size=batch_size, batch_format=batch_format,
-                device_put=device_put, drop_last=drop_last)
-            return
-        yield from _si.stamp_wait(
-            self._iter_batches_legacy(batch_size=batch_size,
-                                      batch_format=batch_format,
-                                      device_put=device_put,
-                                      drop_last=drop_last),
-            getattr(self, "_consumer", None) or "default")
-
-    def _iter_batches_legacy(self, *, batch_size, batch_format,
-                             device_put, drop_last):
-        """The pre-streaming path (``RAY_TPU_DATA_STREAMING=0``): one
-        blocking get per block with one-batch lookahead; with device_put
-        the next batch is already on its way to the device while the
-        caller consumes the current one."""
-        def to_batch(blk):
-            if batch_format == "numpy":
-                batch = B.to_numpy_batch(blk)
-            else:
-                batch = B.to_rows(blk)
-            if device_put:
-                import jax
-
-                batch = jax.device_put(batch)
-            return batch
-
-        # Batches slice straight out of blocks (columnar: numpy views +
-        # one concat per batch — zero per-row Python).
-        pending: list = []       # partial blocks carried across block refs
-        pending_n = 0
-        prev = None
-        for ref in self._materialized_refs():
-            blk = ray_tpu.get(ref)
-            pending.append(blk)
-            pending_n += B.num_rows(blk)
-            while pending_n >= batch_size:
-                take, taken = [], 0
-                while taken < batch_size:
-                    head = pending[0]
-                    hn = B.num_rows(head)
-                    need = batch_size - taken
-                    if hn <= need:
-                        take.append(head)
-                        taken += hn
-                        pending.pop(0)
-                    else:
-                        take.append(B.slice_block(head, 0, need))
-                        pending[0] = B.slice_block(head, need, hn)
-                        taken += need
-                pending_n -= batch_size
-                batch = to_batch(B.concat_blocks(take)
-                                 if len(take) > 1 else take[0])
-                if prev is not None:
-                    yield prev
-                prev = batch    # lookahead: device transfer overlaps consume
-        if prev is not None:
-            yield prev
-        if pending_n and not drop_last:
-            yield to_batch(B.concat_blocks(pending))
+        return _si.dataset_iter_batches(
+            self, batch_size=batch_size, batch_format=batch_format,
+            device_put=device_put, drop_last=drop_last)
 
     def to_numpy(self) -> np.ndarray:
         return _rows_to_numpy(self.take_all())

@@ -74,14 +74,12 @@ class DatasetPipeline:
         """One batch stream over ALL windows: the batch remainder is
         carried across window boundaries, so only the FINAL batch may be
         short (honoring ``drop_last``) — per-window batching used to emit
-        a partial batch at every window edge. Streaming mode (default)
-        runs one bounded-prefetch executor across windows, so window
-        i+1's stage tasks execute while window i's batches are consumed;
-        ``RAY_TPU_DATA_STREAMING=0`` keeps the legacy one-window
-        lookahead with identical batch output."""
+        a partial batch at every window edge. One bounded-prefetch
+        executor runs across windows, so window i+1's stage tasks
+        execute while window i's batches are consumed."""
         from ray_tpu.data._internal.streaming import iterator as _si
 
-        yield from _si.pipeline_iter_batches(
+        return _si.pipeline_iter_batches(
             self, batch_size=batch_size, batch_format=batch_format,
             device_put=device_put, drop_last=drop_last)
 

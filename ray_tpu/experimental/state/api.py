@@ -854,7 +854,7 @@ def summarize_steps(*, address: str | None = None,
                     last: int | None = None) -> dict:
     """Step-anatomy rollup: per-step, per-rank wall-clock attribution
     fused ACROSS the cluster by ``step_id`` (never by wall-clock
-    windows — parallel/step_anatomy.py). Collects every process's step
+    windows — _private/step_anatomy.py). Collects every process's step
     + activity records (driver-local plus a raylet→worker fan-out,
     like the other telemetry RPCs) and returns::
 
@@ -872,7 +872,7 @@ def summarize_steps(*, address: str | None = None,
     the 2011.03641 metric that says whether pipelining paid off;
     ``critical_path`` names the rank and phase that bounded each step.
     """
-    from ray_tpu.parallel import step_anatomy
+    from ray_tpu._private import step_anatomy
 
     exports = [step_anatomy.local_records()]
     with _gcs(address) as call:

@@ -171,16 +171,6 @@ def partition_specs(cfg: GPT2Config, rules=None):
 
 
 # ----------------------------------------------------------------- forward
-def _resolve_attention(cfg: GPT2Config, mesh: Optional[Mesh]) -> str:
-    if cfg.attention != "auto":
-        return cfg.attention
-    if mesh is not None and dict(mesh.shape).get("sp", 1) > 1:
-        return "ring"
-    if jax.default_backend() == "tpu":
-        return "flash"
-    return "reference"
-
-
 def _block_apply(block, x, cfg: GPT2Config, impl: str, mesh=None,
                  reduce=None):
     """reduce: given by `_tp_blocks`, where `block` is this device's shard
@@ -321,7 +311,7 @@ def unembed(params, x, cfg: GPT2Config):
 
 def forward(params, tokens, cfg: GPT2Config, mesh: Optional[Mesh] = None):
     """tokens [B, S] -> (logits [B, S, V] f32, moe aux loss scalar)."""
-    impl = _resolve_attention(cfg, mesh)
+    impl = L.resolve_attention(cfg.attention, mesh)
     x = embed(params, tokens, cfg)
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
@@ -369,7 +359,7 @@ def forward_pipelined(
             "pipelined forward does not yet propagate the MoE aux loss; "
             "use pp=1 with MoE or a dense (non-MoE) config with pp>1"
         )
-    impl = _resolve_attention(cfg, mesh)
+    impl = L.resolve_attention(cfg.attention, mesh)
     # pp×sp composition: ONE flat manual region over {pp, sp} with the
     # per-shard ring attention inside stages (a nested sp-shard_map in the
     # pp scan does not differentiate — DuplicateSpecError in transpose).

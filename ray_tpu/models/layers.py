@@ -31,7 +31,7 @@ def layer_norm(x, scale, bias, eps=1e-5):
     XLA's autodiff residuals for the naive f32 LN cost ~2 f32 copies of x
     per call; saving (x, mu, rstd) and recomputing x̂ in the backward cut
     GPT-2-small step time measurably on v5e (part of the 0.34→0.42 MFU fix,
-    see bench.py history) and, with the lean MLP below, lets batch 16-24
+    round 5, before this benchmark) and, with the lean MLP below, lets batch 16-24
     train without remat on one 16 GiB chip."""
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
@@ -178,6 +178,19 @@ def _project(eq, x, w, out_dtype, *, cd, three_pass):
                            preferred_element_type=jnp.float32)
     return (out.astype(jnp.float32)
             + jax.lax.stop_gradient(more)).astype(out.dtype)
+
+
+def resolve_attention(attention: str, mesh=None) -> str:
+    """A config's `attention` as `apply_attention`'s `impl`: "auto" is ring
+    attention where the mesh splits the sequence, the Pallas kernel on a
+    TPU, the plain reference elsewhere."""
+    if attention != "auto":
+        return attention
+    if mesh is not None and dict(mesh.shape).get("sp", 1) > 1:
+        return "ring"
+    if jax.default_backend() == "tpu":
+        return "flash"
+    return "reference"
 
 
 # `checkpoint_name` of the attention sub-layer's output, [B, S, d_model] as

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from ray_tpu.models import layers as L
-from ray_tpu.models.gpt2 import _resolve_attention
 from ray_tpu.parallel import sharding as sh
 
 
@@ -186,7 +185,7 @@ def _block_apply(block, x, cfg: OlmoeConfig, impl: str, mesh=None):
 def forward(params, tokens, cfg: OlmoeConfig, mesh: Optional[Mesh] = None):
     """tokens [B, S] -> (logits [B, S, V] f32, router stats: `load_balance`
     and `z` averaged over layers, `counts` [L, E])."""
-    impl = _resolve_attention(cfg, mesh)
+    impl = L.resolve_attention(cfg.attention, mesh)
     x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")

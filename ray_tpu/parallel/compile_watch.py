@@ -185,7 +185,7 @@ class CompiledFunction:
         active train step additionally lands in the step-anatomy ring
         (and stamps the events) — a recompiling step must show up as a
         compile-bounded step, not unexplained "compute"."""
-        from ray_tpu.parallel import step_anatomy as _sa
+        from ray_tpu._private import step_anatomy as _sa
         from ray_tpu.util import tracing
 
         step_id = _sa.current_step_id()
@@ -224,7 +224,7 @@ class CompiledFunction:
             _tm.counter_inc("ray_tpu_pjit_cache_total",
                             tags={**tags, "result": "hit"})
             return self._fn(*args, **kwargs)
-        from ray_tpu.parallel import step_anatomy as _sa
+        from ray_tpu._private import step_anatomy as _sa
         from ray_tpu.util import tracing
 
         _tm.counter_inc("ray_tpu_pjit_cache_total",

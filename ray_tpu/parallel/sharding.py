@@ -79,7 +79,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 # --------------------------------------------------- gradient bucket plumbing
 #
-# Pytree plumbing for the bucketed-DDP gradient sync (train/ddp.py):
+# Pytree plumbing for the host trainer's bucketed gradient sync:
 # flatten a grad pytree in jax's canonical deterministic order, plan
 # size-targeted buckets over the leaves, and pack/unpack each bucket as
 # one contiguous array the collective plane can move. Planning depends

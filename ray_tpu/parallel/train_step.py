@@ -17,6 +17,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu._private import memory_anatomy as _ma
+from ray_tpu._private import step_anatomy as _sa
+from ray_tpu._private import telemetry as _tm
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.compile_watch import (
     CompiledFunction,
@@ -89,64 +92,35 @@ def make_train_state(
     return state
 
 
-def make_zero_train_state(
-    init_params_fn: Callable[[jax.Array], Any],
-    rng: jax.Array,
-    mesh: Optional[Mesh] = None,
-    param_specs: Any = None,
-) -> TrainState:
-    """ZeRO variant of :func:`make_train_state`: no on-device optimizer
-    state. The state lives in a ``train.ddp.ZeroOptimizer`` instead —
-    sharded over the bucket plan, materialized per rank, and stamped
-    into the ``opt_state`` gauge at shard granularity — so
-    ``TrainState.opt_state`` is the empty tuple and this process's
-    replicated-state footprint is params only."""
-
-    def init_fn(rng):
-        params = init_params_fn(rng)
-        if mesh is not None and param_specs is not None:
-            params = jax.tree_util.tree_map(
-                lambda x, s: jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, s)
-                ),
-                params,
-                param_specs,
-            )
-        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                          opt_state=())
-
-    state = _jit(init_fn, "train_state_init")(rng)
-    _note_state_bytes(state)
-    return state
-
-
 def _note_state_bytes(state: TrainState):
     """Stamp ``ray_tpu_train_state_bytes{kind=params|opt_state,rank}``
     from the deterministic flatten — the exact resident footprint of the
     state this process just materialized (memory-anatomy plane)."""
-    try:
-        from ray_tpu._private import memory_anatomy as _ma
-        from ray_tpu._private import telemetry as _tm
+    if not _tm.ENABLED:
+        return
+    # the rank the TrainWorker opened its loop with; 0 outside one
+    cur = _sa.current()
+    rank = cur[1] if cur is not None else 0
+    for kind, tree in (("params", state.params),
+                       ("opt_state", state.opt_state)):
+        leaves, _ = sh.flatten_tree(tree)
+        _ma.LEDGER.note_train_state(
+            kind, rank, sum(int(l.nbytes) for l in leaves))
 
-        if not _tm.ENABLED:
-            return
-        rank = 0
-        try:
-            from ray_tpu.util import collective as col
 
-            for g in ("train_dp", "default"):
-                if col.is_group_initialized(g):
-                    rank = col.get_rank(g)
-                    break
-        except Exception:
-            rank = 0
-        for kind, tree in (("params", state.params),
-                           ("opt_state", state.opt_state)):
-            leaves, _ = sh.flatten_tree(tree)
-            _ma.LEDGER.note_train_state(
-                kind, rank, sum(int(l.nbytes) for l in leaves))
-    except Exception:
-        pass
+# rows over the data-parallel axis, the sequence over `sp`
+BATCH_SPEC = P(("dp",), "sp")
+
+
+def _constrain_batch(batch, mesh: Optional[Mesh], batch_spec: P):
+    if mesh is not None:
+        batch = jax.tree_util.tree_map(
+            lambda x: jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, batch_spec)
+            ),
+            batch,
+        )
+    return batch
 
 
 def make_train_step(
@@ -154,160 +128,39 @@ def make_train_step(
     optimizer: optax.GradientTransformation,
     mesh: Optional[Mesh] = None,
     *,
-    batch_spec: P = P(("dp",), "sp"),
+    batch_spec: P = BATCH_SPEC,
     donate: bool = True,
-    host_grad_sync: Optional[Callable[[Any], Any]] = None,
-    host_optimizer: Any = None,
 ):
     """loss_fn(params, batch) -> (scalar_loss, metrics_dict).
 
-    Returns jitted step(state, batch) -> (state, metrics).
-
-    ``host_grad_sync`` (optional) is the host-DP hook: a callable
-    ``grads_pytree -> synced_grads_pytree`` (canonically
-    ``ray_tpu.train.ddp.sync_gradients``) run OUTSIDE the compiled
-    program, between a jitted grad computation and a jitted optimizer
-    apply. This is the regime where each gang member owns its local
-    devices and grads cross hosts over the collective plane (the
-    reference's torch-DDP shape) instead of an XLA psum — the step
-    splits into two compiled functions so the host collective can run
-    in the middle, and the bucketed-DDP plane can overlap that comm
-    with the unpack/pack work around it.
-
-    ``host_optimizer`` (a ``train.ddp.ZeroOptimizer``; mutually
-    exclusive with ``host_grad_sync`` and ``optimizer``-driven apply)
-    selects the ZeRO-sharded host path: the jitted function computes
-    grads only, the sharded optimizer reducescatters them, applies this
-    rank's shards, and allgathers updated params ASYNC — the returned
-    ``step`` waits those gathers at the START of the next call (first
-    use), so everything between steps overlaps the gather comm. The
-    step function exposes ``step.finalize(state)`` — call it once after
-    the loop to fold the last step's in-flight params into the state.
-    ``metrics["grad_norm"]`` in this mode is the LOCAL pre-sync norm
-    (the synced grads exist only as shards).
+    Returns jitted step(state, batch) -> (state, metrics): one program,
+    gradients reduced over the mesh by the partitioner. The steps whose
+    gradients cross hosts over the collective plane are built on this
+    module by `ray_tpu.train`.
     """
 
-    def _constrain_batch(batch):
-        if mesh is not None:
-            batch = jax.tree_util.tree_map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, batch_spec)
-                ),
-                batch,
-            )
-        return batch
-
-    if host_optimizer is not None:
-        if host_grad_sync is not None:
-            raise ValueError("host_optimizer and host_grad_sync are "
-                             "mutually exclusive — the sharded "
-                             "optimizer owns the gradient sync")
-
-        def zgrad_step(params, batch):
-            batch = _constrain_batch(batch)
-            (_loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, batch)
-            return dict(metrics), grads, optax.global_norm(grads)
-
-        zgrad_fn = _jit(zgrad_step, "train_grad_step")
-        box = {"pending": None}
-
-        def resolve(state: TrainState) -> TrainState:
-            pending = box["pending"]
-            if pending is None:
-                return state
-            box["pending"] = None
-            # first use of the previous step's params: the allgathers
-            # rode the issue thread through everything the caller did
-            # since step_async returned; only the residue blocks here.
-            # timeout=None defers to the per-op collective deadline so
-            # a dead peer surfaces as CollectiveGroupError, not a hang
-            return dataclasses.replace(
-                state, params=pending.result(timeout=None))
-
-        def step(state: TrainState, batch):
-            state = resolve(state)
-            metrics, grads, grad_norm = zgrad_fn(state.params, batch)
-            box["pending"] = host_optimizer.step_async(state.params,
-                                                       grads)
-            metrics = dict(metrics)
-            metrics["grad_norm"] = grad_norm
-            return (
-                TrainState(step=state.step + 1, params=state.params,
-                           opt_state=state.opt_state),
-                metrics,
-            )
-
-        step.finalize = resolve
-        return step
-
-    if host_grad_sync is None:
-        def step(state: TrainState, batch):
-            batch = _constrain_batch(batch)
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params, batch)
-            updates, opt_state = optimizer.update(
-                grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            metrics = dict(metrics)
-            metrics["grad_norm"] = optax.global_norm(grads)
-            return (
-                TrainState(step=state.step + 1, params=params,
-                           opt_state=opt_state),
-                metrics,
-            )
-
-        return _jit(step, "train_step",
-                    donate_argnums=(0,) if donate else ())
-
-    def grad_step(params, batch):
-        batch = _constrain_batch(batch)
-        # metrics pass through exactly as loss_fn returned them — the
-        # no-hook path adds only grad_norm, and the two modes must
-        # expose the same metric schema for the same loss_fn
-        (_loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
-        return dict(metrics), grads
-
-    def apply_step(state: TrainState, grads):
+    def step(state: TrainState, batch):
+        batch = _constrain_batch(batch, mesh, batch_spec)
+        (loss, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params, batch)
         updates, opt_state = optimizer.update(
             grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)
+        metrics = dict(metrics)
+        metrics["grad_norm"] = optax.global_norm(grads)
         return (
             TrainState(step=state.step + 1, params=params,
                        opt_state=opt_state),
-            optax.global_norm(grads),
+            metrics,
         )
 
-    grad_fn = _jit(grad_step, "train_grad_step")
-    apply_fn = _jit(apply_step, "train_apply_step",
-                    donate_argnums=(0,) if donate else ())
-
-    def step(state: TrainState, batch):
-        metrics, grads = grad_fn(state.params, batch)
-        # the hook receives the device grads pytree; the bucketed sync
-        # materializes leaves per bucket (np.asarray is the device→host
-        # fetch), so later buckets' transfers overlap earlier buckets'
-        # allreduce. grad_norm is computed from the SYNCED grads — the
-        # quantity the optimizer actually applies.
-        synced = host_grad_sync(grads)
-        state, grad_norm = apply_fn(state, synced)
-        metrics = dict(metrics)
-        metrics["grad_norm"] = grad_norm
-        return state, metrics
-
-    return step
+    return _jit(step, "train_step",
+                donate_argnums=(0,) if donate else ())
 
 
-def eval_step(loss_fn, mesh: Optional[Mesh] = None, batch_spec: P = P(("dp",), "sp")):
+def eval_step(loss_fn, mesh: Optional[Mesh] = None, batch_spec: P = BATCH_SPEC):
     def step(params, batch):
-        if mesh is not None:
-            batch = jax.tree_util.tree_map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, batch_spec)
-                ),
-                batch,
-            )
+        batch = _constrain_batch(batch, mesh, batch_spec)
         _, metrics = loss_fn(params, batch)
         return metrics
 

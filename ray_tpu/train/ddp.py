@@ -963,3 +963,142 @@ class _DoneHandle:
 
     def result(self, timeout: float | None = None):
         return self._value
+
+
+# ------------------------------------------------------------ step builders
+#
+# The host-DP train steps: each gang member owns its local devices and
+# gradients cross hosts over the collective plane (the reference's
+# torch-DDP shape), not as an XLA psum, so the step is compiled halves
+# with the host collective between them. They are built on the compute
+# layer's pieces (`parallel/train_step.py`), imported at call time: the
+# driver imports this module and must stay off jax.
+
+
+def make_ddp_train_step(loss_fn, optimizer, grad_sync, mesh=None, *,
+                        donate: bool = True):
+    """loss_fn(params, batch) -> (scalar_loss, metrics_dict).
+
+    Returns step(state, batch) -> (state, metrics): a jitted grad
+    computation, then ``grad_sync`` — a callable
+    ``grads_pytree -> synced_grads_pytree``, canonically
+    :func:`sync_gradients` — run OUTSIDE the compiled programs, then a
+    jitted optimizer apply. The bucketed sync overlaps its comm with
+    the unpack/pack work around it."""
+    import jax
+    import optax
+
+    from ray_tpu.parallel import train_step as ts
+
+    def grad_step(params, batch):
+        batch = ts._constrain_batch(batch, mesh, ts.BATCH_SPEC)
+        # metrics pass through exactly as loss_fn returned them — the
+        # in-mesh step adds only grad_norm, and the two must expose the
+        # same metric schema for the same loss_fn
+        (_loss, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, batch)
+        return dict(metrics), grads
+
+    def apply_step(state, grads):
+        updates, opt_state = optimizer.update(
+            grads, state.opt_state, state.params)
+        params = optax.apply_updates(state.params, updates)
+        return (
+            ts.TrainState(step=state.step + 1, params=params,
+                          opt_state=opt_state),
+            optax.global_norm(grads),
+        )
+
+    grad_fn = ts._jit(grad_step, "train_grad_step")
+    apply_fn = ts._jit(apply_step, "train_apply_step",
+                       donate_argnums=(0,) if donate else ())
+
+    def step(state, batch):
+        metrics, grads = grad_fn(state.params, batch)
+        # the hook receives the device grads pytree; the bucketed sync
+        # materializes leaves per bucket (np.asarray is the device→host
+        # fetch), so later buckets' transfers overlap earlier buckets'
+        # allreduce. grad_norm is computed from the SYNCED grads — the
+        # quantity the optimizer actually applies.
+        synced = grad_sync(grads)
+        state, grad_norm = apply_fn(state, synced)
+        metrics = dict(metrics)
+        metrics["grad_norm"] = grad_norm
+        return state, metrics
+
+    return step
+
+
+def make_zero_train_state(init_params_fn, rng, mesh=None,
+                          param_specs=None):
+    """The state :func:`make_zero_train_step` steps: params only. The
+    optimizer state lives in the :class:`ZeroOptimizer` instead —
+    sharded over the bucket plan, materialized per rank, and stamped
+    into the ``opt_state`` gauge at shard granularity — so
+    ``TrainState.opt_state`` is the empty tuple and this process's
+    replicated-state footprint is params only."""
+    import optax
+
+    from ray_tpu.parallel.train_step import make_train_state
+
+    # init is all make_train_state asks of an optimizer
+    stateless = optax.GradientTransformation(
+        init=lambda params: (),
+        update=lambda updates, state, params=None: (updates, state))
+    return make_train_state(init_params_fn, rng, stateless, mesh,
+                            param_specs)
+
+
+def make_zero_train_step(loss_fn, zero_optimizer: ZeroOptimizer,
+                         mesh=None):
+    """The ZeRO-sharded host step: a jitted function computes grads
+    only; ``zero_optimizer`` reducescatters them, applies this rank's
+    shards, and allgathers updated params ASYNC — the returned ``step``
+    waits those gathers at the START of the next call (first use), so
+    everything between steps overlaps the gather comm. Call
+    ``step.finalize(state)`` once after the loop to fold the last
+    step's in-flight params into the state. ``metrics["grad_norm"]`` is
+    the LOCAL pre-sync norm (the synced grads exist only as shards)."""
+    import dataclasses
+
+    import jax
+    import optax
+
+    from ray_tpu.parallel import train_step as ts
+
+    def zgrad_step(params, batch):
+        batch = ts._constrain_batch(batch, mesh, ts.BATCH_SPEC)
+        (_loss, metrics), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, batch)
+        return dict(metrics), grads, optax.global_norm(grads)
+
+    zgrad_fn = ts._jit(zgrad_step, "train_grad_step")
+    box = {"pending": None}
+
+    def resolve(state):
+        pending = box["pending"]
+        if pending is None:
+            return state
+        box["pending"] = None
+        # first use of the previous step's params: the allgathers
+        # rode the issue thread through everything the caller did
+        # since step_async returned; only the residue blocks here.
+        # timeout=None defers to the per-op collective deadline so
+        # a dead peer surfaces as CollectiveGroupError, not a hang
+        return dataclasses.replace(
+            state, params=pending.result(timeout=None))
+
+    def step(state, batch):
+        state = resolve(state)
+        metrics, grads, grad_norm = zgrad_fn(state.params, batch)
+        box["pending"] = zero_optimizer.step_async(state.params, grads)
+        metrics = dict(metrics)
+        metrics["grad_norm"] = grad_norm
+        return (
+            ts.TrainState(step=state.step + 1, params=state.params,
+                          opt_state=state.opt_state),
+            metrics,
+        )
+
+    step.finalize = resolve
+    return step

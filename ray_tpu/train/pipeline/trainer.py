@@ -114,7 +114,7 @@ def _pipeline_worker_loop(config: dict):
     from ray_tpu._private.config import get_config
     from ray_tpu.air import session
     from ray_tpu.air.checkpoint import Checkpoint
-    from ray_tpu.parallel import step_anatomy
+    from ray_tpu._private import step_anatomy
     from ray_tpu.util import collective as col
 
     spec = config["_pipeline_spec"]
@@ -287,7 +287,7 @@ def _pipeline_worker_loop(config: dict):
         if want_ckpt:
             import pickle as _pickle
 
-            from ray_tpu.parallel import step_anatomy as _sa
+            from ray_tpu._private import step_anatomy as _sa
 
             # checkpoint assembly is a step-loop stall: attribute it in
             # the same anatomy lane the sharded writer uses, so "why was

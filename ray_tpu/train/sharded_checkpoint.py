@@ -149,7 +149,7 @@ def _file_sha256(path: str) -> str:
 
 def _record_anatomy(start_m: float, end_m: float, blocking: bool, **meta):
     try:
-        from ray_tpu.parallel import step_anatomy
+        from ray_tpu._private import step_anatomy
 
         step_anatomy.record_activity("checkpoint", start_m, end_m,
                                      blocking=blocking, **meta)

@@ -123,7 +123,7 @@ class TrainWorker:
         _session._set_session(self.session)
 
         def _run():
-            from ray_tpu.parallel import step_anatomy
+            from ray_tpu._private import step_anatomy
 
             # step 1 opens when the train function starts; each
             # session.report advances it (iteration == step_id), so

@@ -103,7 +103,7 @@ class CollectiveHandle:
             if stamp:
                 import time as _time
 
-                from ray_tpu.parallel import step_anatomy as _sa
+                from ray_tpu._private import step_anatomy as _sa
 
                 t0 = _time.monotonic()
             with self._cond:

@@ -76,7 +76,7 @@ def run_op(g, op: str, seq, body, payload=None,
     the kill-switch check, so a disabled op pays only the bool."""
     if not _tm.ENABLED:
         return body()
-    from ray_tpu.parallel import step_anatomy as _sa
+    from ray_tpu._private import step_anatomy as _sa
     from ray_tpu.util import tracing
 
     nbytes = payload_nbytes(payload) if payload is not None else 0

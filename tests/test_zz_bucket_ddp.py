@@ -21,7 +21,7 @@ Covers the tentpole's two halves and their acceptance criteria:
   bound (queued handles too, no serialized op timeouts), leaving zero
   stranded shm segments; a seeded dropped frame surfaces as a timeout,
   never a hang;
-- cluster acceptance: a 2-worker gang on a REAL make_train_step loop
+- cluster acceptance: a 2-worker gang on a REAL make_ddp_train_step loop
   (jitted grad step -> ddp.sync_gradients -> jitted apply) yields a
   summarize_steps() report with comm_hidden > 0 and
   overlap_fraction > 0, and both ranks end byte-identical.
@@ -86,7 +86,7 @@ def test_hidden_union_not_double_counted_for_concurrent_comm():
     inside another bucket's exposed wait() window is hidden only where
     no one was blocked. Per-kind fields may overlap each other (they
     are attribution); overlap_fraction must use real coverage."""
-    from ray_tpu.parallel import step_anatomy as sa
+    from ray_tpu._private import step_anatomy as sa
 
     step = {"step_id": 1, "rank": 0, "node": "n0", "pid": 1,
             "start": 0.0, "end": 1.0}
@@ -436,10 +436,7 @@ def _bucketed_train_loop(config):
     import optax
 
     from ray_tpu.air import session
-    from ray_tpu.parallel.train_step import (
-        make_train_state,
-        make_train_step,
-    )
+    from ray_tpu.parallel.train_step import make_train_state
     from ray_tpu.train import ddp
 
     rank = session.get_world_rank()
@@ -459,10 +456,11 @@ def _bucketed_train_loop(config):
 
     opt = optax.sgd(0.05)
     state = make_train_state(init_params, jax.random.PRNGKey(0), opt)
-    step_fn = make_train_step(
-        loss_fn, opt, donate=False,
-        host_grad_sync=lambda g: ddp.sync_gradients(
-            g, "zzbd_gang", average=True, bucket_bytes=64 * 1024))
+    step_fn = ddp.make_ddp_train_step(
+        loss_fn, opt,
+        lambda g: ddp.sync_gradients(
+            g, "zzbd_gang", average=True, bucket_bytes=64 * 1024),
+        donate=False)
     for step in range(6):
         srng = _np.random.RandomState(1000 * rank + step)
         batch = (jnp.asarray(srng.standard_normal((32, 192))
@@ -478,8 +476,8 @@ def _bucketed_train_loop(config):
 
 
 def test_overlap_proof_bucketed_train(ray_start_regular):
-    """Acceptance: a 2-worker gang running a REAL make_train_step loop
-    with host_grad_sync=ddp.sync_gradients shows background bucket comm
+    """Acceptance: a 2-worker gang running a REAL ddp.make_ddp_train_step loop
+    over ddp.sync_gradients shows background bucket comm
     genuinely hidden under the step (comm_hidden > 0 with
     overlap_fraction > 0 in the fused step-anatomy report), and both
     ranks' final params are byte-identical."""

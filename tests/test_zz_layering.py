@@ -1,0 +1,121 @@
+"""The arrows of `ray_tpu/` point one way: kernels at the bottom, then the
+compiled step, then the models; the runtime (`_private`, `util`) beside
+them and knowing none of them; data and air over the runtime; train on top.
+
+Pure `ast` over the package's sources, function-level imports included, so
+a lazy import cannot hide an edge. One case a box: a box is every module
+whose dotted name starts with one of its prefixes, and it may import no
+module that starts with one of the names it is denied.
+"""
+import ast
+import pathlib
+
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+PKG = "ray_tpu"
+
+
+def _module_name(path: pathlib.Path) -> str:
+    parts = path.relative_to(ROOT).with_suffix("").parts
+    return ".".join(parts[:-1] if parts[-1] == "__init__" else parts)
+
+
+def _imports(path: pathlib.Path):
+    """(line, absolute dotted name) of every `ray_tpu` import in a file.
+    `from a.b import c` yields `a.b.c`: `c` may be a module."""
+    module = _module_name(path)
+    package = module if path.name == "__init__.py" else \
+        module.rpartition(".")[0]
+    for node in ast.walk(ast.parse(path.read_text(), str(path))):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            base = node.module or ""
+            if node.level:
+                up = package.split(".")[:len(package.split(".")) -
+                                        node.level + 1]
+                base = ".".join(up + ([base] if base else []))
+            names = [f"{base}.{a.name}" for a in node.names]
+        else:
+            continue
+        for name in names:
+            if name == PKG or name.startswith(PKG + "."):
+                yield node.lineno, name
+
+
+def _under(name: str, prefix: str) -> bool:
+    return name == prefix or name.startswith(prefix + ".")
+
+
+def _edges(box, denied=(), allowed=None):
+    """Imports out of `box` into `denied`, or, with `allowed`, into
+    anything of the package that is neither the box nor allowed."""
+    box = [f"{PKG}.{b}" for b in box]
+    denied = [f"{PKG}.{d}" for d in denied]
+    found = []
+    for path in sorted((ROOT / PKG).rglob("*.py")):
+        module = _module_name(path)
+        if not any(_under(module, b) for b in box):
+            continue
+        for line, name in _imports(path):
+            if allowed is not None:
+                ok = [f"{PKG}.{a}" for a in allowed] + box
+                bad = not any(_under(name, a) for a in ok)
+            else:
+                bad = any(_under(name, d) for d in denied)
+            if bad:
+                found.append(f"{path.relative_to(ROOT)}:{line} imports "
+                             f"{name}")
+    return found
+
+
+BOXES = {
+    "ops": dict(box=["ops"], allowed=[]),
+    "parallel": dict(box=["parallel"],
+                     denied=["models", "data", "air", "train", "serve",
+                             "util.collective"]),
+    "models": dict(box=["models"], allowed=["ops", "parallel"]),
+    "runtime": dict(box=["_private", "util"],
+                    denied=["ops", "parallel", "models", "data", "air",
+                            "train"]),
+    "data": dict(box=["data"],
+                 denied=["parallel", "models", "air", "train"]),
+    "air": dict(box=["air"], denied=["parallel", "models", "train"]),
+}
+
+
+@pytest.mark.parametrize("box", sorted(BOXES))
+def test_box_imports_nothing_above_it(box):
+    assert _edges(**BOXES[box]) == []
+
+
+def test_a_model_imports_no_other_model():
+    """What two models share lives in `models.layers`."""
+    found = []
+    for path in sorted((ROOT / PKG / "models").glob("*.py")):
+        for line, name in _imports(path):
+            if _under(name, f"{PKG}.models") and \
+                    not _under(name, f"{PKG}.models.layers") and \
+                    not _under(name, _module_name(path)):
+                found.append(f"{path.relative_to(ROOT)}:{line} imports "
+                             f"{name}")
+    assert found == []
+
+
+def test_the_walk_sees_function_level_and_relative_imports(tmp_path):
+    """The check's own guard: an edge inside a function, and one spelled
+    relatively, are both found."""
+    pkg = tmp_path / PKG / "ops"
+    pkg.mkdir(parents=True)
+    src = pkg / "k.py"
+    src.write_text("def f():\n    from ray_tpu.train import ddp\n"
+                   "from ..models import gpt2\n")
+    global ROOT
+    real, ROOT = ROOT, tmp_path
+    try:
+        assert sorted(n for _, n in _imports(src)) == [
+            "ray_tpu.models.gpt2", "ray_tpu.train.ddp"]
+        assert len(_edges(box=["ops"], allowed=[])) == 2
+    finally:
+        ROOT = real

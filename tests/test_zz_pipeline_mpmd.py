@@ -20,6 +20,7 @@ Covers the tentpole end to end on a simulated >=2-slice cluster:
 - the streaming data plane feeds stage 0 from a ``ray_tpu.data``
   Dataset shard.
 """
+import json
 import os
 import threading
 import time
@@ -347,28 +348,36 @@ def chaos_cluster_env(ray_start_cluster):
 
 @pytest.mark.chaos
 @pytest.mark.fault_injection
-def test_stage_rank_death_checkpoint_resume(chaos_cluster_env):
+def test_stage_rank_death_checkpoint_resume(chaos_cluster_env, tmp_path,
+                                            monkeypatch):
     """ACCEPTANCE (CI/chaos satellite): a seeded kill_actor schedule
-    shoots stage 1's rank while it serves its 3rd next_result —
-    mid-training, after checkpointed steps. The death must poison the
-    gang fast (stage 0's pending send/recv windows raise instead of
-    wedging until the 300s op timeout), fit() tears down + rebuilds
-    once, and the resumed pipeline finishes on the oracle trajectory."""
+    shoots stage 1's rank while it serves its 4th next_result —
+    mid-training, after checkpointed steps. Held to the ORDER of what
+    follows, not to how long it took: the death poisons the gang (stage
+    0's pending send/recv windows raise instead of wedging until the
+    300s op timeout), fit() tears down and rebuilds once, the rebuilt
+    gang restores the newest persisted checkpoint, and every step it
+    then runs is the oracle's.
+
+    5 steps and the 4th call: the kill races rank 0's 4th result to the
+    driver, so 3 or 4 iterations are persisted and the rebuilt stage 1
+    serves 3 or 2 calls (steps, then the end) — under the 4 that would
+    shoot it again, since `#N` counts per process."""
     from ray_tpu._private import events
+    from ray_tpu._private import flight_recorder as fr
     from ray_tpu.air.config import FailureConfig, RunConfig
     from ray_tpu.train.pipeline import (PipelineConfig, PipelineTrainer,
                                         reference_run)
 
-    chaos_cluster_env(7, "kill_actor:stage1-rank0.next_result:#3")
+    # before any node starts: every process writes its dumps here
+    monkeypatch.setenv("RAY_TPU_FLIGHT_RECORDER_DIR", str(tmp_path))
+    monkeypatch.setattr(fr, "_last_auto_dump_ts", 0.0)
+    chaos_cluster_env(7, "kill_actor:stage1-rank0.next_result:#4")
     stages = _stages()
-    kw = dict(_KW, num_steps=4)
+    kw = dict(_KW, num_steps=5)
     ref = reference_run(stages, num_microbatches=4, **kw)
 
-    def count(kind):
-        return sum(1 for e in events.snapshot() if e["kind"] == kind)
-
-    base_restarted = count("GANG_RESTARTED")
-    t0 = time.monotonic()
+    seq0 = max((e["seq"] for e in events.snapshot()), default=0)
     result = PipelineTrainer(
         stages,
         pipeline_config=PipelineConfig(num_microbatches=4,
@@ -376,23 +385,44 @@ def test_stage_rank_death_checkpoint_resume(chaos_cluster_env):
                                        group_name="zzp_chaos"),
         run_config=RunConfig(failure_config=FailureConfig(max_failures=2)),
         **kw).fit()
-    elapsed = time.monotonic() - t0
-    # detection + teardown + rebuild + resume: nowhere near the 300s
-    # collective op timeout a hung send/recv window would burn
-    assert elapsed < 120, f"pipeline gang restart took {elapsed:.0f}s"
     assert result.error is None, result.error
-    hist = [r["loss"] for r in result.metrics_history]
-    assert hist[-1] == ref["losses"][-1], "resume diverged from oracle"
-    # resumed from a checkpoint: the final attempt replayed only the
-    # remaining step(s), not the whole run
-    assert len(hist) < kw["num_steps"]
-    assert count("GANG_RESTARTED") - base_restarted == 1
-    # both gang incarnations announced their slice layout
-    ev = [e for e in events.snapshot()
-          if e["kind"] == "PIPELINE_GANG_STARTED"
-          and e.get("group") == "zzp_chaos"]
-    assert len(ev) == 2
-    assert all(len(e["stage_slices"]) == 2 for e in ev)
+
+    # the driver's own ring, in the order it recorded: one gang, its
+    # death, one rebuild — each incarnation announcing its slice layout
+    kinds = ("PIPELINE_GANG_STARTED", "GANG_FAILED", "GANG_RESTARTED")
+    mine = sorted((e for e in events.snapshot()
+                   if e["seq"] > seq0 and e["kind"] in kinds
+                   and e.get("group") == "zzp_chaos"),
+                  key=lambda e: e["seq"])
+    assert [e["kind"] for e in mine] == [
+        "PIPELINE_GANG_STARTED", "GANG_FAILED", "GANG_RESTARTED",
+        "PIPELINE_GANG_STARTED"], [e["kind"] for e in mine]
+    started, failed, restarted, rebuilt = mine
+    assert failed["dead_ranks"] == [1], failed
+    assert all(len(e["stage_slices"]) == 2 for e in (started, rebuilt))
+
+    # death -> poison: the rendezvous actor's record of it, out of the
+    # dumps the failure wrote while that process still lived; it names
+    # the rank that died, and the rebuild came after it
+    poisons = []
+    for path in tmp_path.glob("blackbox_*/*.jsonl"):
+        for line in path.read_text().splitlines():
+            if '"COLLECTIVE_GROUP_POISONED"' in line:
+                poisons.append(json.loads(line))
+    poisons = [e for e in poisons if e.get("group") == "zzp_chaos"]
+    assert poisons, sorted(p.name for p in tmp_path.iterdir())
+    assert all(e["dead_ranks"] == [1] for e in poisons), poisons
+    assert min(e["ts"] for e in poisons) <= restarted["ts"]
+
+    # restore -> resumed steps: the rebuilt gang took up after the last
+    # persisted iteration and ran the rest of the oracle's trajectory
+    resumed_at = restarted["resume_iteration"]
+    assert resumed_at in (3, 4), restarted
+    hist = result.metrics_history
+    assert [r["step"] for r in hist] == \
+        list(range(resumed_at, kw["num_steps"]))
+    assert [r["loss"] for r in hist] == ref["losses"][resumed_at:], \
+        "resume diverged from oracle"
 
 
 # ------------------------------------------------------- data-plane feed

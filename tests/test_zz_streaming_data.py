@@ -1,7 +1,7 @@
 """Streaming data plane (ray_tpu/data/_internal/streaming/): bounded-
 memory pull-based ingest, backpressure, locality-ordered prefetch,
-device-put double buffering, task-side re-blocking, the collective
-shuffle exchange, and the `RAY_TPU_DATA_STREAMING=0` kill switch.
+device-put double buffering, task-side re-blocking and the collective
+shuffle exchange.
 
 Late-alphabet by design: the tier-1 duration guard keeps early files
 fast; this whole suite stays well inside the per-file budget.
@@ -63,55 +63,44 @@ def test_backpressure_parks_producer(ds_env, monkeypatch):
     assert seen == 10
 
 
-def test_streaming_equals_legacy_across_boundaries(ds_env, monkeypatch):
-    """Batch contents are identical with streaming on vs off across
-    block/batch-size boundaries, dict columns, and drop_last."""
+def _slices(n_rows: int, batch_size: int, drop_last: bool):
+    """Plain slicing of the concatenated rows: what a batch stream is."""
+    stops = range(0, n_rows, batch_size)
+    out = [(lo, min(lo + batch_size, n_rows)) for lo in stops]
+    if drop_last and out and out[-1][1] - out[-1][0] < batch_size:
+        out.pop()
+    return out
+
+
+@pytest.mark.parametrize("source,kwargs", [
+    ("plain", dict(batch_size=64)),
+    ("plain", dict(batch_size=64, drop_last=True)),
+    ("plain", dict(batch_size=1000)),       # one short batch
+    ("cols", dict(batch_size=77)),
+], ids=["b64", "b64_drop_last", "one_short_batch", "dict_columns_b77"])
+def test_batches_are_slices_of_the_rows(ds_env, source, kwargs):
+    """Across block and batch-size boundaries, dict columns and
+    drop_last, batch k is rows [k*B, (k+1)*B) of the dataset."""
     from ray_tpu import data
 
-    plain = data.from_numpy(np.arange(500.0), parallelism=7)
-    cols = data.from_items(
-        [{"x": float(i), "y": i % 5} for i in range(300)], parallelism=4)
-
-    def snap(ds, **kw):
-        out = []
-        for b in ds.iter_batches(**kw):
-            if isinstance(b, dict):
-                out.append({k: v.tobytes() for k, v in sorted(b.items())})
-            else:
-                out.append(b.tobytes())
-        return out
-
-    for ds, kwargs in [
-        (plain, dict(batch_size=64)),
-        (plain, dict(batch_size=64, drop_last=True)),
-        (plain, dict(batch_size=1000)),       # one short batch
-        (cols, dict(batch_size=77)),
-    ]:
-        monkeypatch.setenv("RAY_TPU_DATA_STREAMING", "1")
-        on = snap(ds, **kwargs)
-        monkeypatch.setenv("RAY_TPU_DATA_STREAMING", "0")
-        off = snap(ds, **kwargs)
-        assert on == off, kwargs
-
-
-def test_kill_switch_legacy_path_runs(ds_env, monkeypatch):
-    """RAY_TPU_DATA_STREAMING=0 really takes the legacy path (no
-    streaming executor is constructed)."""
-    from ray_tpu import data
-    from ray_tpu.data._internal.streaming import executor as sx
-
-    monkeypatch.setenv("RAY_TPU_DATA_STREAMING", "0")
-    built = []
-    orig = sx.StreamingExecutor.__init__
-
-    def spy(self, *a, **kw):
-        built.append(1)
-        return orig(self, *a, **kw)
-
-    monkeypatch.setattr(sx.StreamingExecutor, "__init__", spy)
-    ds = data.from_numpy(np.arange(100.0), parallelism=4)
-    assert sum(len(b) for b in ds.iter_batches(batch_size=32)) == 100
-    assert not built
+    if source == "plain":
+        rows = {"v": np.arange(500.0)}
+        ds = data.from_numpy(rows["v"], parallelism=7)
+    else:
+        rows = {"x": np.arange(300.0), "y": np.arange(300) % 5}
+        ds = data.from_items(
+            [{"x": float(i), "y": i % 5} for i in range(300)],
+            parallelism=4)
+    n = len(next(iter(rows.values())))
+    batches = _collect(ds, **kwargs)
+    want = _slices(n, kwargs["batch_size"], kwargs.get("drop_last", False))
+    assert len(batches) == len(want)
+    for batch, (lo, hi) in zip(batches, want):
+        got = batch if isinstance(batch, dict) else {"v": batch}
+        assert sorted(got) == sorted(rows)
+        for k, col in rows.items():
+            assert got[k].dtype == col.dtype, (k, got[k].dtype)
+            np.testing.assert_array_equal(got[k], col[lo:hi])
 
 
 # ------------------------------------------------------- pipeline windows
@@ -139,19 +128,20 @@ def test_pipeline_carries_remainder_across_windows(ds_env):
     assert sizes == [10] * 7
 
 
-def test_pipeline_streaming_equals_legacy(ds_env, monkeypatch):
+def test_pipeline_batches_are_slices_of_the_windows_rows(ds_env):
+    """A mapped pipeline's batches are slices of its windows' rows laid
+    end to end: 113 rows in windows of 2 blocks, batches of 25."""
     from ray_tpu import data
 
-    def snap():
-        pipe = data.from_numpy(np.arange(113.0), parallelism=6).window(
-            blocks_per_window=2).map_batches(lambda a: a * 3)
-        return [b.tobytes() for b in pipe.iter_batches(batch_size=25)]
-
-    monkeypatch.setenv("RAY_TPU_DATA_STREAMING", "1")
-    on = snap()
-    monkeypatch.setenv("RAY_TPU_DATA_STREAMING", "0")
-    off = snap()
-    assert on == off and len(on) == 5
+    pipe = data.from_numpy(np.arange(113.0), parallelism=6).window(
+        blocks_per_window=2).map_batches(lambda a: a * 3)
+    batches = list(pipe.iter_batches(batch_size=25))
+    want = _slices(113, 25, False)
+    assert len(batches) == len(want) == 5
+    rows = np.arange(113.0) * 3
+    for batch, (lo, hi) in zip(batches, want):
+        assert batch.dtype == rows.dtype
+        np.testing.assert_array_equal(batch, rows[lo:hi])
 
 
 # ------------------------------------------------------------- locality
