@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention_local
 
@@ -413,12 +414,12 @@ MOE_LOGICAL = {"wg": ("embed", None), "w1": _WIDE, "w2": _NARROW}
 GATED_MOE_LOGICAL = {"wg": ("embed", None), "w_gate": _WIDE, "w_up": _WIDE,
                      "w_down": _NARROW}
 
-# Rows of a tile of the grouped-matmul kernel the TPU compiler makes of
-# `lax.ragged_dot` (read from the compiled v5e step, PR 29: its metadata
-# operand holds rows / 512 + E − 1 tile visits). A tile that holds the end
-# of one expert's rows and the start of the next's is visited once for
-# each, so the FLOPs issued exceed the needed by at most E − 1 tiles of rows.
-GROUP_ROW_TILE = 512
+# Rows of a row tile of the grouped-matmul kernel (`ops.grouped_matmul`'s
+# `row_tile`, wherever a tile that size divides the rows). A tile that holds
+# the end of one expert's rows and the start of the next's is visited once
+# for each, so the FLOPs issued exceed the needed by at most E − 1 tiles of
+# rows; `grouped_matmul.issued_ratio` gives the ratio of a given routing.
+GROUP_ROW_TILE = grouped_matmul.ROW_TILE
 
 
 def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
@@ -433,12 +434,13 @@ def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
     product for the rows, one for the weights) and the same bytes again."""
     rows = tokens * cfg.top_k
     per_row = (3 if gated else 2) * 2 * d_model * d_ff
-    tiles = -(-rows // GROUP_ROW_TILE)
+    tile = grouped_matmul.row_tile(rows) or GROUP_ROW_TILE
+    tiles = -(-rows // tile)
     visits = min(tiles + cfg.n_experts // ep - 1, 2 * tiles)
     return {
         "rows": rows,
         "flops_needed": rows * per_row // ep,
-        "flops_issued_max": visits * GROUP_ROW_TILE * per_row,
+        "flops_issued_max": visits * tile * per_row,
         # each row read from its token and written in expert order
         "dispatch_bytes": 2 * rows * d_model * itemsize,
         # each row read back in token order, a token's K summed into one
@@ -480,22 +482,37 @@ _permute_rows.defvjp(lambda y, perm, inverse: (y[perm], (inverse,)),
                      lambda res, d: (d[res[0]], None, None))
 
 
-def _grouped_matmul(lhs, rhs, sizes, cd):
-    """lhs [M, k] rows in group order, rhs [G, k, n], sizes [G]: each group's
-    rows times its own matrix; rows past sum(sizes) come out zero. On the
-    TPU `lax.ragged_dot` is a Mosaic grouped-matmul kernel of the
-    compiler's own (`ragged-dot-*` in the HLO), its two backward products
-    too; it issues the groups' row tiles, not a dense product over every
-    group (measured, PERF.md §6 PR 29)."""
-    return jax.lax.ragged_dot(lhs, rhs.astype(cd), sizes,
+def _use_kernel(platform: str, rows: int, d_model: int, d_ff: int,
+                cd) -> bool:
+    """Whether the routed layer's products go through the Pallas kernels
+    (`ops.grouped_matmul`): on a TPU, where tiles divide the layer's shapes
+    — all of its products or none, decided once from what can be observed,
+    as `resolve_attention` decides for flash. Elsewhere (the CPU, shapes no
+    tile divides) they are XLA's grouped product (`_grouped_matmul`)."""
+    return (platform == "tpu"
+            and grouped_matmul.tile_plan(rows, d_model, d_ff, cd) is not None)
+
+
+def _grouped_matmul(lhs, rhs, sizes, cd, kernel: bool):
+    """lhs [M, k] rows in group order, rhs [G, k, n], sizes [E ≥ G] every
+    row's group: each of the first G groups' rows times its own matrix, the
+    other groups' rows zero. `kernel`: JAX's Pallas `gmm` / `tgmm`
+    (`ops.grouped_matmul`: the weight cast once and read as it lies by the
+    forward and by the product to the rows); otherwise `lax.ragged_dot`,
+    plain XLA off the TPU."""
+    rhs = rhs.astype(cd)
+    if kernel:
+        return grouped_matmul.grouped_matmul(lhs, rhs, sizes)
+    return jax.lax.ragged_dot(lhs, rhs, sizes[:rhs.shape[0]],
                               preferred_element_type=cd)
 
 
 def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
-                   first, cd):
+                   first, cd, platform: str):
     """One device's part of the routed layer: x [b, s, D] its tokens, gates
     [b, s, K] their chosen experts and weights, `experts` the leaves of the
-    E_local experts it holds, `first` the id of the first of them. Returns
+    E_local experts it holds, `first` the id of the first of them,
+    `platform` what the device is (`_use_kernel`). Returns
     [b, s, D] float32: for each token the weighted outputs of those of its
     experts that live here (all of them where nothing splits the experts)."""
     d_model, top_k = x.shape[-1], gate_idx.shape[-1]
@@ -510,19 +527,24 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inverse = jnp.zeros((rows,), jnp.int32).at[order].set(
             jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
-        sizes = jnp.bincount(key, length=n_experts)[:local].astype(jnp.int32)
+        # every row's group, the local experts' first: what lies behind
+        # them belongs to no matrix here and comes out of a product zero
+        sizes = jnp.bincount(key, length=n_experts).astype(jnp.int32)
         taken = _take_assignments(x2.astype(cd), order, inverse)
     with jax.named_scope("experts"):
+        wide = experts["w_gate" if "w_gate" in experts else "w1"]
+        product = functools.partial(
+            _grouped_matmul, sizes=sizes, cd=cd,
+            kernel=_use_kernel(platform, rows, *wide.shape[1:], cd))
         if "w_gate" in experts:
-            gate = _grouped_matmul(taken, experts["w_gate"], sizes, cd)
-            up = _grouped_matmul(taken, experts["w_up"], sizes, cd)
+            gate = product(taken, experts["w_gate"])
+            up = product(taken, experts["w_up"])
             hidden = (jax.nn.silu(gate.astype(jnp.float32))
                       * up.astype(jnp.float32)).astype(cd)
-            y = _grouped_matmul(hidden, experts["w_down"], sizes, cd)
+            y = product(hidden, experts["w_down"])
         else:
-            hidden = jax.nn.gelu(
-                _grouped_matmul(taken, experts["w1"], sizes, cd))
-            y = _grouped_matmul(hidden, experts["w2"], sizes, cd)
+            hidden = jax.nn.gelu(product(taken, experts["w1"]))
+            y = product(hidden, experts["w2"])
     with jax.named_scope("combine"):
         y = _permute_rows(y, inverse, order).reshape(
             tokens, top_k, d_model)
@@ -544,8 +566,10 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
     (`moe_plan` gives them).
 
     mesh: as in `apply_attention` — the grouped matmul is a Mosaic kernel on
-    the TPU, so dispatch, experts and combine run as per-device code. Each
-    device takes its share of the batch and the experts `ep` gives it
+    the TPU (`ops.grouped_matmul`, wherever the mesh's devices — without a
+    mesh the default backend's — are TPUs and its tiles divide the shapes:
+    `_use_kernel`), so dispatch, experts and combine run as per-device code.
+    Each device takes its share of the batch and the experts `ep` gives it
     (their `expert_mlp` slice under `tp`), computes its experts' part of
     its tokens' outputs, and the parts are summed over `ep` and `tp`.
 
@@ -582,7 +606,10 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
             "counts": jnp.sum(counts, axis=0),
         }
 
-    local = functools.partial(_local_experts, n_experts=E, cd=cd)
+    platform = (jax.default_backend() if mesh is None
+                else mesh.devices.flat[0].platform)
+    local = functools.partial(_local_experts, n_experts=E, cd=cd,
+                              platform=platform)
     if mesh is None:
         out = local(x, gate_vals, gate_idx, experts, first=0)
     else:

@@ -15,6 +15,7 @@ from chipbench.accounting import olmoe as accounting
 from chipbench.references import olmoe as reference
 from ray_tpu.models import layers as L
 from ray_tpu.models import olmoe
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.parallel.train_step import (
@@ -251,15 +252,76 @@ def test_moe_plan_counts_what_a_real_call_does():
     assert plan["flops_needed"] * 3 == accounting.grouped_matmul_cost(
         {"num_experts_per_tok": 8, "hidden_size": 2048,
          "intermediate_size": 1024, "num_experts": 64}, 8192)[0]
-    # 128 row tiles of 512 and at most 63 more that two experts share
+    # the kernel's row tile is `tile_plan`'s: 65,536 / 512 = 128 row tiles
+    # and at most 63 more that two experts share
+    tile = grouped_matmul.tile_plan(65_536, 2048, 1024, jnp.bfloat16)[0]
+    assert tile == grouped_matmul.row_tile(65_536) == L.GROUP_ROW_TILE
+    # and on a TPU the cell's layer takes the kernels, the layer above not
+    assert L._use_kernel("tpu", 65_536, 2048, 1024, jnp.bfloat16)
+    assert not L._use_kernel("tpu", 512, 64, 32, jnp.bfloat16)
+    assert 65_536 % tile == 0
+    tiles = 65_536 // tile
     assert plan["flops_issued_max"] / plan["flops_needed"] == \
-        pytest.approx(191 / 128)
+        pytest.approx((tiles + 63) / tiles)
+    # which no routing passes: the worst one ends every expert inside a tile
+    worst = [1024 - 24] + [1024] * 62 + [1024 + 24]
+    assert grouped_matmul.issued_ratio(worst, tile) == pytest.approx(
+        (tiles + 63) / tiles)
+    assert grouped_matmul.issued_ratio([1024] * 64, tile) == 1.0
     assert plan["dispatch_bytes"] == 2 * 65_536 * 2048 * 2
     assert plan["combine_bytes"] == (65_536 + 8192) * 2048 * 2
     # four devices sharing the experts: each a quarter of the FLOPs
     shared = L.moe_plan(8192, 2048, 1024, olmoe_moe, gated=True, ep=4)
     assert shared["flops_needed"] * 4 == plan["flops_needed"]
-    assert shared["flops_issued_max"] == (128 + 15) * 512 * 6 * 2048 * 1024
+    assert shared["flops_issued_max"] == \
+        (tiles + 15) * tile * 6 * 2048 * 1024
+
+
+@pytest.mark.parametrize("gated, norm", [(True, False), (False, True)],
+                         ids=["olmoe_form", "gpt2_form"])
+def test_local_experts_on_the_pallas_kernels_against_the_oracle(
+        gated, norm, monkeypatch):
+    """What a TPU runs, here in the Pallas interpreter: `_local_experts`
+    told its device is a TPU, at shapes the tiles divide, as two devices
+    that hold four of the eight experts each (`first` 0 and 4). Each
+    device's rows for the other's experts lie behind its own and come out
+    zero; the two parts sum to every token's full output."""
+    calls = []
+
+    def interpreted(lhs, rhs, sizes):
+        calls.append((lhs.shape, rhs.shape, sizes.shape))
+        return kernel(lhs, rhs, sizes, interpret=True)
+
+    kernel = grouped_matmul.grouped_matmul
+    monkeypatch.setattr(grouped_matmul, "grouped_matmul", interpreted)
+    cfg = L.MoEConfig(n_experts=8, top_k=2, norm_topk_prob=norm)
+    params = _skewed(cfg, 128, 128, gated)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 64, 128))
+    probs = jax.nn.softmax(x @ params["wg"], axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, 2)
+    if norm:
+        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+    experts = {k: v for k, v in params.items() if k != "wg"}
+    assert L._use_kernel("tpu", 256, 128, 128, jnp.float32)
+    assert not L._use_kernel("cpu", 256, 128, 128, jnp.float32)
+    parts = [
+        L._local_experts(
+            x, gate_vals, gate_idx,
+            {k: v[first:first + 4] for k, v in experts.items()},
+            n_experts=8, first=first, cd=jnp.float32, platform="tpu")
+        for first in (0, 4)]
+    # every product of both devices went through the kernel, with all
+    # eight groups' sizes and four matrices
+    assert len(calls) == 2 * (3 if gated else 2)
+    assert {(c[1][0], c[2]) for c in calls} == {(4, (8,))}
+    want, chosen = _per_token_oracle(params, x, cfg)
+    np.testing.assert_allclose(
+        np.asarray(parts[0] + parts[1]).reshape(-1, 128), want, atol=1e-5)
+    # a token none of whose experts lives on the first device gets nothing
+    # from it: exactly zero
+    elsewhere = (chosen >= 4).all(axis=-1)
+    assert elsewhere.any() and not elsewhere.all()
+    assert not np.asarray(parts[0]).reshape(-1, 128)[elsewhere].any()
 
 
 # ------------------------------------------------------- the shared parts
