@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops import grouped_matmul, mxu, ssd
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention_local
 
@@ -135,13 +135,20 @@ def exchange_sum(partial, axis_name: str):
 
 
 # ---------------------------------------------------------------- attention
-def init_attention(key, d_model, n_head, dtype=jnp.float32):
-    head_dim = d_model // n_head
+def init_attention(key, d_model, n_head, dtype=jnp.float32, *,
+                   n_kv_head: Optional[int] = None,
+                   head_dim: Optional[int] = None):
+    """`n_kv_head` (default `n_head`): K and V heads, each read by
+    `n_head // n_kv_head` query heads (query head i by KV head i // group).
+    `head_dim` (default `d_model // n_head`): the q width `n_head ·
+    head_dim` need not be `d_model`."""
+    head_dim = head_dim or d_model // n_head
+    kv = n_kv_head or n_head
     ks = jax.random.split(key, 4)
     return {
         "wq": _init_dense(ks[0], (d_model, n_head, head_dim), dtype=dtype),
-        "wk": _init_dense(ks[1], (d_model, n_head, head_dim), dtype=dtype),
-        "wv": _init_dense(ks[2], (d_model, n_head, head_dim), dtype=dtype),
+        "wk": _init_dense(ks[1], (d_model, kv, head_dim), dtype=dtype),
+        "wv": _init_dense(ks[2], (d_model, kv, head_dim), dtype=dtype),
         "wo": _init_dense(ks[3], (n_head, head_dim, d_model), dtype=dtype),
     }
 
@@ -152,33 +159,10 @@ ATTENTION_LOGICAL = {
     "wv": ("embed", "heads", "head_dim"),
     "wo": ("heads", "head_dim", "embed"),
 }
-
-
-def _project(eq, x, w, out_dtype, *, cd, three_pass):
-    """einsum(x, w) on the MXU, operands rounded to `cd`. `three_pass` adds,
-    to the FORWARD value only, the two products with the operands' rounding
-    errors (x_hi·w_lo + x_lo·w_hi, the `lo` parts themselves in `cd`): the
-    result is the float32 product to ~2^-16. The backward pass is the
-    single product's, as without it."""
-    hi_x, hi_w = x.astype(cd), w.astype(cd)
-    out = jnp.einsum(eq, hi_x, hi_w, preferred_element_type=out_dtype)
-    if not three_pass:
-        return out
-
-    def lo(a):
-        # `reduce_precision`, not a cast there and back: the compiler may
-        # drop such a pair (`xla_allow_excess_precision`), and with it `lo`
-        info = jnp.finfo(cd)
-        a = a.astype(jnp.float32)
-        return (a - jax.lax.reduce_precision(a, info.nexp, info.nmant)
-                ).astype(cd)
-
-    more = jnp.einsum(eq, hi_x, lo(w), preferred_element_type=jnp.float32)
-    if x.dtype != cd:
-        more += jnp.einsum(eq, lo(x), hi_w,
-                           preferred_element_type=jnp.float32)
-    return (out.astype(jnp.float32)
-            + jax.lax.stop_gradient(more)).astype(out.dtype)
+# fewer KV heads than query heads: the few are held by every `tp` rank
+GROUPED_ATTENTION_LOGICAL = dict(ATTENTION_LOGICAL,
+                                 wk=("embed", "kv", "head_dim"),
+                                 wv=("embed", "kv", "head_dim"))
 
 
 def resolve_attention(attention: str, mesh=None) -> str:
@@ -234,10 +218,14 @@ def apply_attention(
     rounded to the compute dtype once, for the kernel.
 
     three_pass: the four projections' forward values to float32 accuracy
-    (`_project`), for a model whose later layers are discontinuous in them.
+    (`mxu.einsum`), for a model whose later layers are discontinuous in them.
+
+    Grouped KV heads (`wk`, `wv` with fewer heads than `wq`): the flash
+    kernels read each query head's KV head where it lies; every other path
+    repeats K and V to the query heads first (plain XLA, off the TPU).
     """
     cd = compute_dtype
-    project = functools.partial(_project, cd=cd, three_pass=three_pass)
+    project = functools.partial(mxu.einsum, cd=cd, three_pass=three_pass)
     # float32 out of the MXU's accumulator where something is still to be
     # done to q and k; the compute dtype's own result type otherwise
     qk_dtype = None if qk_fn is None else jnp.float32
@@ -246,6 +234,9 @@ def apply_attention(
     v = project("bsd,dhk->bshk", x, params["wv"], None)
     if qk_fn is not None:
         q, k = (t.astype(cd) for t in qk_fn(q, k))
+    group = q.shape[2] // k.shape[2]
+    if group > 1 and impl != "flash":
+        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
     if impl == "ring":
         from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -258,8 +249,12 @@ def apply_attention(
         attend = functools.partial(flash_attention, causal=causal)
         if mesh is not None:
             io_spec = sh.spec("batch", None, "heads", None)
+            # grouped KV heads are not split with the query heads (a model
+            # with them refuses a `tp` that would split either)
+            kv_spec = io_spec if group == 1 else sh.spec(
+                "batch", None, "kv", None)
             attend = jax.shard_map(
-                attend, mesh=mesh, in_specs=(io_spec, io_spec, io_spec),
+                attend, mesh=mesh, in_specs=(io_spec, kv_spec, kv_spec),
                 out_specs=io_spec, check_vma=False)
         o = attend(q, k, v)
     else:
@@ -286,6 +281,108 @@ def remat(body):
     return jax.checkpoint(
         body, policy=jax.checkpoint_policies.save_only_these_names(
             *RESIDUAL_NAMES, ATTENTION_OUT))
+
+
+# ------------------------------------------------------------ Mamba-2 mixer
+@dataclasses.dataclass(frozen=True)
+class MambaConfig:
+    n_heads: int = 64
+    head_dim: int = 64
+    n_groups: int = 8         # B and C are shared by n_heads // n_groups heads
+    d_state: int = 128
+    d_conv: int = 4
+    chunk: int = 128
+    # Δ at initialisation: log-uniform in [dt_min, dt_max], floored
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    @property
+    def inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:        # x, B and C go through the conv
+        return self.inner + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_proj(self) -> int:         # [z | xBC | dt]
+        return self.inner + self.conv_dim + self.n_heads
+
+
+def init_mamba(key, d_model, cfg: MambaConfig, dtype=jnp.float32):
+    """Mamba-2's leaves: `w_in` [d, z | xBC | dt], the depthwise conv
+    (`conv_w` [d_conv, conv_dim], uniform ±d_conv^-½ as a framework's
+    default; `conv_b`), a head's `dt_bias` = softplus⁻¹(Δ₀), `A_log` =
+    log U[1, 16], `D` = 1, the gated norm's scale, `w_out`."""
+    k_in, k_out, k_conv, k_dt, k_a = jax.random.split(key, 5)
+    dt0 = jnp.maximum(jnp.exp(
+        jax.random.uniform(k_dt, (cfg.n_heads,))
+        * (jnp.log(cfg.dt_max) - jnp.log(cfg.dt_min)) + jnp.log(cfg.dt_min)),
+        cfg.dt_floor)
+    bound = cfg.d_conv ** -0.5
+    return {
+        "w_in": _init_dense(k_in, (d_model, cfg.in_proj), dtype=dtype),
+        "conv_w": jax.random.uniform(
+            k_conv, (cfg.d_conv, cfg.conv_dim), minval=-bound,
+            maxval=bound).astype(dtype),
+        "conv_b": jnp.zeros((cfg.conv_dim,), dtype),
+        "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
+        "A_log": jnp.log(jax.random.uniform(
+            k_a, (cfg.n_heads,), minval=1.0, maxval=16.0)).astype(dtype),
+        "D": jnp.ones((cfg.n_heads,), dtype),
+        "norm": jnp.ones((cfg.inner,), dtype),
+        "w_out": _init_dense(k_out, (cfg.inner, d_model), dtype=dtype),
+    }
+
+
+# every leaf whole on every `tp` rank: a model with a mixer refuses `tp` > 1
+MAMBA_LOGICAL = {
+    "w_in": ("embed", None), "conv_w": (None, None), "conv_b": (None,),
+    "dt_bias": (None,), "A_log": (None,), "D": (None,), "norm": (None,),
+    "w_out": (None, "embed"),
+}
+
+
+def apply_mamba(params: Params, u, cfg: MambaConfig, *,
+                compute_dtype=jnp.bfloat16, eps: float = 1e-5,
+                three_pass: bool = False):
+    """u [B, T, d] -> [B, T, d]: ``[z | xBC | dt] = u·W_in``; ``xBC ←
+    SiLU(causal depthwise conv(xBC) + b)``, split into x [T, H, P] and B, C
+    [T, G, N]; ``Δ = softplus(dt + dt_bias)``, ``A = −exp(A_log)``; the
+    state-space scan (`ops.ssd`); ``y ← RMSNorm_groups(y ⊙ SiLU(z))`` (a norm
+    over each of the G groups' share of the inner width, one learned scale);
+    ``·W_out``. Conv, softplus, decays and the norm in float32; the two
+    projections and the scan's products on the MXU in `compute_dtype`.
+    `three_pass` as in `apply_attention`; the one model with a mixer
+    (`nemotron_h`) always sets it, and off is the single-pass control its
+    tests and chip probe compare with."""
+    B, T, _ = u.shape
+    H, P, G, N = cfg.n_heads, cfg.head_dim, cfg.n_groups, cfg.d_state
+    project = functools.partial(mxu.einsum, cd=compute_dtype,
+                                three_pass=three_pass)
+    zxbcdt = project("btd,de->bte", u, params["w_in"], jnp.float32)
+    z, xbc, dt = jnp.split(zxbcdt, [cfg.inner, cfg.inner + cfg.conv_dim],
+                           axis=-1)
+    with jax.named_scope("conv"):
+        w = params["conv_w"].astype(jnp.float32)
+        padded = jnp.pad(xbc, [(0, 0), (cfg.d_conv - 1, 0), (0, 0)])
+        xbc = jax.nn.silu(
+            sum(padded[:, i:i + T] * w[i] for i in range(cfg.d_conv))
+            + params["conv_b"].astype(jnp.float32))
+    x, b_in, c_out = jnp.split(xbc, [cfg.inner, cfg.inner + G * N], axis=-1)
+    y = ssd.ssd(
+        x.reshape(B, T, H, P),
+        jax.nn.softplus(dt + params["dt_bias"].astype(jnp.float32)),
+        -jnp.exp(params["A_log"].astype(jnp.float32)),
+        b_in.reshape(B, T, G, N), c_out.reshape(B, T, G, N), params["D"],
+        chunk=cfg.chunk, compute_dtype=compute_dtype, three_pass=three_pass)
+    with jax.named_scope("gate_norm"):
+        y = (y.reshape(B, T, cfg.inner) * jax.nn.silu(z)).reshape(
+            B, T, G, cfg.inner // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+        y = y.reshape(B, T, cfg.inner) * params["norm"].astype(jnp.float32)
+    return project("bte,ed->btd", y, params["w_out"], u.dtype)
 
 
 # ---------------------------------------------------------------- dense MLP
@@ -389,23 +486,57 @@ class MoEConfig:
     # the chosen gates rescaled to sum to 1 (GShard); False uses the router's
     # probabilities as they are (OLMoE)
     norm_topk_prob: bool = True
+    # "softmax" over all experts, or "sigmoid": independent scores, the
+    # top-k chosen on score + a selection bias (the leaf `bias`, behind a
+    # stop_gradient) and weighted by the scores themselves
+    score: str = "softmax"
+    # what the chosen gates are multiplied by, after any renormalisation
+    scale: float = 1.0
+    # between the two matrices of the `w1` / `w2` form: "gelu" | "relu2"
+    activation: str = "gelu"
+    # width of a shared expert every token goes through (the `w1` / `w2`
+    # form without a gate, the routed experts' activation); 0: none
+    d_shared: int = 0
+    # THE CHIP'S SHARE of a deployment that spreads the experts: the
+    # stacked leaves hold `held` experts (None: all `n_experts`), the first
+    # of them expert `first` of the `n_experts` the router scores. The
+    # layer computes its own experts' part of the result; what the others
+    # would add is computed where they live, which may be nowhere.
+    held: Optional[int] = None
+    first: int = 0
+
+    @property
+    def stacked(self) -> int:
+        return self.n_experts if self.held is None else self.held
 
 
 def init_moe(key, d_model, d_ff, cfg: MoEConfig, dtype=jnp.float32,
              gated: bool = False):
-    """Router `wg` and the experts, stacked over a leading E axis: two
-    matrices with a GELU between (`w1`, `w2`), or, `gated`, three with a
-    SiLU gate (`w_gate`, `w_up`, `w_down`). `apply_moe` tells the form by
-    the leaves."""
+    """Router `wg` (as wide as the experts it scores) and the experts held
+    here (`cfg.stacked`), stacked over a leading axis: two matrices with an
+    activation between (`w1`, `w2`), or, `gated`, three with a SiLU gate
+    (`w_gate`, `w_up`, `w_down`). `apply_moe` tells the form by the leaves.
+    With sigmoid scores the selection `bias` [E], zero; with `d_shared` the
+    shared expert's `shared_w1`, `shared_w2`."""
     kg, k1, k2, k3 = jax.random.split(key, 4)
-    E = cfg.n_experts
+    E = cfg.stacked
     wide, narrow = (E, d_model, d_ff), (E, d_ff, d_model)
     experts = ({"w_gate": _init_dense(k1, wide, dtype=dtype),
                 "w_up": _init_dense(k3, wide, dtype=dtype),
                 "w_down": _init_dense(k2, narrow, dtype=dtype)} if gated else
                {"w1": _init_dense(k1, wide, dtype=dtype),
                 "w2": _init_dense(k2, narrow, dtype=dtype)})
-    return {"wg": _init_dense(kg, (d_model, E), dtype=dtype), **experts}
+    params = {"wg": _init_dense(kg, (d_model, cfg.n_experts), dtype=dtype),
+              **experts}
+    if cfg.score == "sigmoid":
+        params["bias"] = jnp.zeros((cfg.n_experts,), dtype)
+    if cfg.d_shared:
+        k4, k5 = jax.random.split(k3)
+        params["shared_w1"] = _init_dense(k4, (d_model, cfg.d_shared),
+                                          dtype=dtype)
+        params["shared_w2"] = _init_dense(k5, (cfg.d_shared, d_model),
+                                          dtype=dtype)
+    return params
 
 
 _WIDE = ("experts", "embed", "expert_mlp")
@@ -413,6 +544,9 @@ _NARROW = ("experts", "expert_mlp", "embed")
 MOE_LOGICAL = {"wg": ("embed", None), "w1": _WIDE, "w2": _NARROW}
 GATED_MOE_LOGICAL = {"wg": ("embed", None), "w_gate": _WIDE, "w_up": _WIDE,
                      "w_down": _NARROW}
+# the leaves some routers and layers have besides
+MOE_EXTRA_LOGICAL = {"bias": (None,), "shared_w1": ("embed", "mlp"),
+                     "shared_w2": ("mlp", "embed")}
 
 # Rows of a row tile of the grouped-matmul kernel (`ops.grouped_matmul`'s
 # `row_tile`, wherever a tile that size divides the rows). A tile that holds
@@ -425,9 +559,10 @@ GROUP_ROW_TILE = grouped_matmul.ROW_TILE
 def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
              gated: bool, itemsize: int = 2, ep: int = 1) -> dict:
     """What one forward pass of `apply_moe` does on one device, from shapes
-    alone (`tokens` there; `ep` devices share the experts): the rows
-    gathered, the grouped matmuls' FLOPs needed (every assignment through
-    its expert once; a device's share under even routing) and the most the
+    alone (`tokens` there; `ep` devices share the `cfg.stacked` experts the
+    leaves hold): the rows gathered, the grouped matmuls' FLOPs needed
+    (every assignment through its expert once; a device's share under even
+    routing over all `n_experts`) and the most the
     tiled kernel issues under ANY routing (each local expert's group may
     end inside a row tile, which is then visited twice), and the bytes that
     dispatch and combine move. The backward pass is twice the FLOPs (one
@@ -436,10 +571,10 @@ def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
     per_row = (3 if gated else 2) * 2 * d_model * d_ff
     tile = grouped_matmul.row_tile(rows) or GROUP_ROW_TILE
     tiles = -(-rows // tile)
-    visits = min(tiles + cfg.n_experts // ep - 1, 2 * tiles)
+    visits = min(tiles + cfg.stacked // ep - 1, 2 * tiles)
     return {
         "rows": rows,
-        "flops_needed": rows * per_row // ep,
+        "flops_needed": rows * per_row * cfg.stacked // (cfg.n_experts * ep),
         "flops_issued_max": visits * tile * per_row,
         # each row read from its token and written in expert order
         "dispatch_bytes": 2 * rows * d_model * itemsize,
@@ -493,22 +628,57 @@ def _use_kernel(platform: str, rows: int, d_model: int, d_ff: int,
             and grouped_matmul.tile_plan(rows, d_model, d_ff, cd) is not None)
 
 
-def _grouped_matmul(lhs, rhs, sizes, cd, kernel: bool):
+def _kernel_width(platform: str, rows: int, d_model: int, d_ff: int, cd):
+    """The expert width at which the routed layer's products go through the
+    Pallas kernels: `d_ff` where `_use_kernel` says so; its next multiple of
+    128 where tiles divide THAT (the experts' compute-dtype copies are then
+    zero-padded to it: every activation here maps 0 to 0, so the padding
+    computes zeros, adds nothing, and takes no gradient); None where the
+    products are XLA's."""
+    for width in (d_ff, -(-d_ff // 128) * 128):
+        if _use_kernel(platform, rows, d_model, width, cd):
+            return width
+    return None
+
+
+# the axis of each expert leaf that is the expert's width
+_WIDTH_AXIS = {"w_gate": 2, "w_up": 2, "w1": 2, "w_down": 1, "w2": 1}
+
+
+def _grouped_matmul(lhs, rhs, sizes, cd, kernel: bool, widen=(0, 0)):
     """lhs [M, k] rows in group order, rhs [G, k, n], sizes [E ≥ G] every
     row's group: each of the first G groups' rows times its own matrix, the
     other groups' rows zero. `kernel`: JAX's Pallas `gmm` / `tgmm`
     (`ops.grouped_matmul`: the weight cast once and read as it lies by the
     forward and by the product to the rows); otherwise `lax.ragged_dot`,
-    plain XLA off the TPU."""
+    plain XLA off the TPU. `widen` (axis, by): zeros appended to the cast
+    weight (`_kernel_width`)."""
     rhs = rhs.astype(cd)
+    if widen[1]:
+        rhs = jnp.pad(rhs, [(0, widen[1] if axis == widen[0] else 0)
+                            for axis in range(rhs.ndim)])
     if kernel:
         return grouped_matmul.grouped_matmul(lhs, rhs, sizes)
-    return jax.lax.ragged_dot(lhs, rhs, sizes[:rhs.shape[0]],
-                              preferred_element_type=cd)
+    held = rhs.shape[0]
+    if held == sizes.shape[0]:
+        return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                  preferred_element_type=cd)
+    # On a TPU XLA's grouped product leaves the rows of no group UNWRITTEN,
+    # in the result and, transposed, in the rows' cotangent: zero both (the
+    # mask on `lhs` is, transposed, the mask on its cotangent).
+    grouped = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes[:held]))[:, None]
+    out = jax.lax.ragged_dot(jnp.where(grouped, lhs, 0), rhs, sizes[:held],
+                             preferred_element_type=cd)
+    return jnp.where(grouped, out, 0)
+
+
+def _relu2(u):
+    """relu(u)², in float32."""
+    return jnp.square(jax.nn.relu(u.astype(jnp.float32)))
 
 
 def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
-                   first, cd, platform: str):
+                   first, cd, platform: str, activation: str = "gelu"):
     """One device's part of the routed layer: x [b, s, D] its tokens, gates
     [b, s, K] their chosen experts and weights, `experts` the leaves of the
     E_local experts it holds, `first` the id of the first of them,
@@ -533,18 +703,25 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
         taken = _take_assignments(x2.astype(cd), order, inverse)
     with jax.named_scope("experts"):
         wide = experts["w_gate" if "w_gate" in experts else "w1"]
-        product = functools.partial(
-            _grouped_matmul, sizes=sizes, cd=cd,
-            kernel=_use_kernel(platform, rows, *wide.shape[1:], cd))
+        width = _kernel_width(platform, rows, *wide.shape[1:], cd)
+        pad = 0 if width is None else width - wide.shape[2]
+
+        def product(lhs, name):
+            return _grouped_matmul(lhs, experts[name], sizes, cd,
+                                   width is not None, (_WIDTH_AXIS[name], pad))
+
         if "w_gate" in experts:
-            gate = product(taken, experts["w_gate"])
-            up = product(taken, experts["w_up"])
+            gate = product(taken, "w_gate")
+            up = product(taken, "w_up")
             hidden = (jax.nn.silu(gate.astype(jnp.float32))
                       * up.astype(jnp.float32)).astype(cd)
-            y = product(hidden, experts["w_down"])
+            y = product(hidden, "w_down")
+        elif activation == "gelu":
+            hidden = jax.nn.gelu(product(taken, "w1"))
+            y = product(hidden, "w2")
         else:
-            hidden = jax.nn.gelu(product(taken, experts["w1"]))
-            y = product(hidden, experts["w2"])
+            hidden = _relu2(product(taken, "w1")).astype(cd)
+            y = product(hidden, "w2")
     with jax.named_scope("combine"):
         y = _permute_rows(y, inverse, order).reshape(
             tokens, top_k, d_model)
@@ -554,16 +731,51 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
         return jnp.sum(weighted, axis=1).reshape(x.shape)
 
 
+def _route(logits, bias, cfg: MoEConfig):
+    """Router logits [B, S, E] float32 -> (gates [B, S, K], experts [B, S,
+    K], the scores the statistics are taken of [B, S, E])."""
+    if cfg.score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)
+        floor = 1e-9
+    else:
+        # independent scores; chosen on score + bias, weighted by the score
+        probs = jax.nn.sigmoid(logits)
+        _, gate_idx = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            cfg.top_k)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        floor = 1e-20
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), floor)
+    if cfg.scale != 1.0:
+        gate_vals = gate_vals * cfg.scale
+    return gate_vals, gate_idx, probs
+
+
+_NOT_ROUTED = ("wg",) + tuple(MOE_EXTRA_LOGICAL)
+
+
 def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
-              mesh=None):
+              mesh=None, three_pass: bool = False):
     """Top-k routed experts, dropless: x [B, S, D] -> (y [B, S, D], stats).
 
-    Router and softmax in float32; `lax.top_k`; the T·K assignments sorted
+    Router and its scores in float32 (`_route`: softmax, or sigmoid with a
+    selection bias); `lax.top_k`; the T·K assignments sorted
     by expert (stable), their rows gathered in that order, the experts'
     matrices applied to the ragged groups by grouped matmuls, and each
     token's K outputs weighted by its gates and summed. No capacity: every
     assignment is computed whatever the routing, and all shapes are static
-    (`moe_plan` gives them).
+    (`moe_plan` gives them). With `cfg.d_shared` a shared expert's output
+    is added for every token (`three_pass`: its forward values to float32
+    accuracy, as in `apply_attention`; set by the one model with a shared
+    expert, off is the single-pass control).
+
+    `cfg.held` / `cfg.first`: the leaves hold a share of the `n_experts` the
+    router scores. The result is then the PART of the layer's output that
+    these experts (and the shared one) give; the assignments to the others
+    are sorted behind the held ones' and come out of the products zero.
 
     mesh: as in `apply_attention` — the grouped matmul is a Mosaic kernel on
     the TPU (`ops.grouped_matmul`, wherever the mesh's devices — without a
@@ -583,17 +795,13 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
     cd = compute_dtype
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
-    experts = {k: v for k, v in params.items() if k != "wg"}
+    experts = {k: v for k, v in params.items() if k not in _NOT_ROUTED}
 
     with jax.named_scope("router"):
         logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
                             params["wg"].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, K)      # [B,S,K]
-        if cfg.norm_topk_prob:
-            gate_vals = gate_vals / jnp.maximum(
-                jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+        gate_vals, gate_idx, probs = _route(logits, params.get("bias"), cfg)
         # [B, E]: a sequence's assignments by expert
         counts = jnp.sum(jax.nn.one_hot(gate_idx, E, dtype=jnp.int32),
                          axis=(1, 2))
@@ -609,15 +817,16 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
     platform = (jax.default_backend() if mesh is None
                 else mesh.devices.flat[0].platform)
     local = functools.partial(_local_experts, n_experts=E, cd=cd,
-                              platform=platform)
+                              platform=platform, activation=cfg.activation)
     if mesh is None:
-        out = local(x, gate_vals, gate_idx, experts, first=0)
+        out = local(x, gate_vals, gate_idx, experts, first=cfg.first)
     else:
         def per_device(x, gate_vals, gate_idx, experts):
             held = next(iter(experts.values())).shape[0]
             return jax.lax.psum(
                 local(x, gate_vals, gate_idx, experts,
-                      first=jax.lax.axis_index("ep") * held), ("ep", "tp"))
+                      first=cfg.first + jax.lax.axis_index("ep") * held),
+                ("ep", "tp"))
 
         logical = GATED_MOE_LOGICAL if "w_gate" in experts else MOE_LOGICAL
         tok = sh.spec("batch", "seq", None)
@@ -626,4 +835,12 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
             in_specs=(tok, tok, tok,
                       {k: sh.spec(*logical[k]) for k in experts}),
             out_specs=tok, check_vma=False)(x, gate_vals, gate_idx, experts)
+    if cfg.d_shared:
+        with jax.named_scope("shared_expert"):
+            project = functools.partial(mxu.einsum, cd=cd,
+                                        three_pass=three_pass)
+            hidden = _relu2(project("bsd,df->bsf", x, params["shared_w1"],
+                                    jnp.float32))
+            out = out + project("bsf,fd->bsd", hidden, params["shared_w2"],
+                                jnp.float32)
     return out.astype(x.dtype), stats

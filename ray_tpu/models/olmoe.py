@@ -12,9 +12,12 @@ Written from the public `olmoe` implementation's equations:
   rotated (`layers.rope`); causal softmax attention; ``·Wo``;
 * routed layer (`layers.apply_moe`): softmax over all experts in float32,
   the top-k with their probabilities as they are (not renormalised), SiLU-
-  gated experts, dropless; its nine grouped products a step run on JAX's
-  Pallas `gmm` / `tgmm` on a TPU (`ops/grouped_matmul.py`; XLA's grouped
-  product elsewhere);
+  gated experts, dropless — `MoEConfig` at its defaults but for
+  `norm_topk_prob`: softmax scores and no selection bias, scale 1, no
+  shared expert, every expert the router scores held here (`held` None;
+  the other settings are `models/nemotron_h.py`'s); its nine grouped
+  products a step run on JAX's Pallas `gmm` / `tgmm` on a TPU
+  (`ops/grouped_matmul.py`; XLA's grouped product elsewhere);
 * loss: mean next-token cross-entropy + ``aux_loss_weight`` × load balance
   + ``z_loss_weight`` × router z-loss, both averaged over layers (the
   paper's 0.01 and 0.001).

@@ -339,17 +339,27 @@ def _row_spec(plan: TilePlan, index_map):
                         index_map)
 
 
-def _kv_index(causal: bool):
+def _kv_head(group: int):
+    """Row of K and V, [B·KV, S, D], that row b of q, [B·H, S, D], reads:
+    query head i its KV head i // group, so row b // group (H = KV · group).
+    With one KV head a query head, b itself."""
+    if group == 1:
+        return lambda b: b
+    return lambda b: b // group
+
+
+def _kv_index(causal: bool, group: int = 1):
     """Index map of the forward's and dq's K/V blocks. Causal: kv-major
     blocks past the q block's diagonal are never needed; clamping their
     index keeps the pipeline from fetching them."""
+    head = _kv_head(group)
     if causal:
-        return lambda b, i, j: (b, jnp.minimum(j, i), 0)
-    return lambda b, i, j: (b, j, 0)
+        return lambda b, i, j: (head(b), jnp.minimum(j, i), 0)
+    return lambda b, i, j: (head(b), j, 0)
 
 
 def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
-    """q,k,v: [BH, S, D] -> (o [BH,S,D], lse [BH,S]).
+    """q [BH, S, D], k, v [B·KV, S, D] -> (o [BH,S,D], lse [BH,S]).
 
     Sequence lengths that don't divide the tiles are zero-padded up to the
     next multiple; padded KV columns are masked inside the kernel and padded
@@ -363,7 +373,8 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
         q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
     n_major = S_pad // major
     qspec = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, major, D), _kv_index(causal))
+    kspec = pl.BlockSpec((1, major, D),
+                         _kv_index(causal, BH // k.shape[0]))
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, plan=plan,
                           seq_len=S),
@@ -394,7 +405,7 @@ def _to_bh(x):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash(q, k, v, heads, scale, causal, block_q, block_k, interpret):
-    """q, k, v [B·H, S, D] → o [B, S, H, D]."""
+    """q [B·H, S, D], k, v [B·KV, S, D] → o [B, S, H, D]."""
     return _flash_vjp_fwd(q, k, v, heads, scale, causal, block_q, block_k,
                           interpret)[0]
 
@@ -523,9 +534,19 @@ def _tile_rows(x, plan: TilePlan):
 
 def _flash_bwd(q, k, v, lse, delta, do, *, scale, causal, block_q, block_k,
                interpret):
-    """Pallas backward: returns (dq, dk, dv), each [BH, S, D]. `delta`
-    [BH, S]: the row sums of do · o."""
+    """Pallas backward: returns (dq [BH, S, D], dk, dv [B·KV, S, D]).
+    `delta` [BH, S]: the row sums of do · o.
+
+    Grouped KV heads: the dq kernel reads each query head's KV head through
+    its index map, as the forward does. The dk/dv kernel runs a QUERY head
+    a grid row and writes that head's dk and dv, [BH, S, D] in the operands'
+    dtype; the group's are summed in float32 outside the kernel. (An
+    accumulation over the group inside it would make the group a grid axis
+    and its blocks' index maps functions of two program ids: the same
+    traffic but for the [BH, S, D] partials, 2 · 2 bytes an element of q.)"""
     BH, S, D = q.shape
+    group = BH // k.shape[0]
+    head = _kv_head(group)
     plans = tile_plan(S, D, q.dtype, block_q, block_k)
     S_pad, major = plans.dq.s_pad, plans.dq.major
     if S_pad != S:
@@ -539,7 +560,7 @@ def _flash_bwd(q, k, v, lse, delta, do, *, scale, causal, block_q, block_k,
 
     # dq: q-major blocks parallel, kv-major sequential, as the forward
     qspec = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, major, D), _kv_index(causal))
+    kspec = pl.BlockSpec((1, major, D), _kv_index(causal, group))
     row_q = _row_spec(plans.dq, lambda b, i, j: (b, i, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, plan=plans.dq, **kw),
@@ -556,19 +577,24 @@ def _flash_bwd(q, k, v, lse, delta, do, *, scale, causal, block_q, block_k,
     # causal: q-major blocks before the kv block's diagonal are never needed
     q_index = (lambda i, j: jnp.maximum(j, i)) if causal else (lambda i, j: j)
     qspec2 = pl.BlockSpec((1, major, D), lambda b, i, j: (b, q_index(i, j), 0))
-    kspec2 = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
+    kspec2 = pl.BlockSpec((1, major, D), lambda b, i, j: (head(b), i, 0))
+    dkspec = pl.BlockSpec((1, major, D), lambda b, i, j: (b, i, 0))
     row_q2 = _row_spec(plans.dkv, lambda b, i, j: (b, q_index(i, j), 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, plan=plans.dkv, **kw),
         grid=(BH, n_major, n_major),
         in_specs=[qspec2, kspec2, kspec2, qspec2, row_q2, row_q2],
-        out_specs=[kspec2, kspec2],
+        out_specs=[dkspec, dkspec],
         out_shape=[jax.ShapeDtypeStruct((BH, S_pad, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, S_pad, D), v.dtype)],
         scratch_shapes=[scratch, scratch],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(q, k, v, do, _tile_rows(lse, plans.dkv), _tile_rows(delta, plans.dkv))
+    if group > 1:
+        dk, dv = (jnp.sum(t.reshape(BH // group, group, S_pad, D)
+                          .astype(jnp.float32), axis=1).astype(t.dtype)
+                  for t in (dk, dv))
     return dq[:, :S], dk[:, :S], dv[:, :S]
 
 
@@ -598,8 +624,9 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Flash attention over [B, S, H, D] (heads layout matching
-    models/layers.apply_attention). Differentiable via custom VJP.
+    """Flash attention over q [B, S, H, D], k, v [B, S, KV, D] (heads layout
+    matching models/layers.apply_attention), KV dividing H: query head i
+    reads KV head i // (H // KV). Differentiable via custom VJP.
 
     The schedule (score-tile shape, major block, padding) is derived from
     ``(S, D, dtype)`` by :func:`tile_plan`; ``block_q``/``block_k`` override

@@ -1,0 +1,137 @@
+"""The Nemotron-H tower (models/nemotron_h.py) on the CPU, at the tiny
+preset: against the plain float32 reference the benchmark holds it to
+(chipbench/references/nemotron_h.py: the recurrence itself, full-softmax
+attention, every held expert on every token), and the flash kernels with
+grouped KV heads in interpret mode. The chip's share of the experts is
+test_nemotron_h_share.py's, the virtual `dp` and `ep` meshes
+test_nemotron_h_mesh.py's: three files, each inside the conftest's
+per-file budget when the whole suite loads the machine."""
+import dataclasses
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import catalog, compare
+from chipbench.accounting import nemotron_h as accounting
+from chipbench.references import nemotron_h as reference
+from ray_tpu.models import nemotron_h
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.parallel.ring_attention import reference_attention
+
+TINY = dataclasses.replace(nemotron_h.nemotron_h_tiny(),
+                           attention="reference")
+with open(os.path.join(catalog.ROOT, "tests", "chipbench_tests", "configs",
+                       "nemotronh-tiny.json")) as f:
+    FILED = json.load(f)
+
+
+def _params(cfg, seed=0):
+    """Fresh parameters with every norm's scale and the selection bias
+    moved off their initial 1 and 0, so that one applied in the wrong place
+    shows."""
+    params = nemotron_h.init(jax.random.PRNGKey(seed), cfg)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+
+    def moved(path, a):
+        name = jax.tree_util.keystr(path)
+        if name.endswith(("['ln']", "['ln_f']", "['norm']", "['bias']")):
+            return a + 0.1 * jax.random.normal(next(keys), a.shape)
+        return a
+    return jax.tree_util.tree_map_with_path(moved, params)
+
+
+def _tokens(cfg, batch=2, seq=40, seed=1):
+    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
+                              cfg.vocab_size)
+
+
+def _rel(got, want):
+    return jax.tree_util.tree_map(compare.rel_l2, got, want)
+
+
+def test_presets_count_the_published_parameters():
+    cut = nemotron_h.nemotron_twotower_30b_a3b_9l()
+    assert cut.n_params == 666_963_456
+    assert (cut.pattern, cut.moe.stacked, cut.vocab_size) == (
+        "MEMEM*EME", 8, 16384)
+    whole = nemotron_h.nemotron_twotower_30b_a3b()
+    assert whole.n_params == 31_577_940_288          # the row's "30B"
+    assert [whole.pattern.count(k) for k in "ME*"] == [23, 23, 6]
+    assert whole.pattern.startswith(cut.pattern)
+    leaves = jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda: nemotron_h.init(jax.random.PRNGKey(0), TINY)))
+    assert sum(math.prod(a.shape) for a in leaves) == TINY.n_params
+    assert accounting.ran_sizes(TINY) == accounting.filed_sizes(FILED)
+
+
+def test_loss_and_every_gradient_match_the_reference_in_float32():
+    """Same arithmetic, two programs — the chunked scan against the
+    recurrence, sorted grouped products against every expert on every
+    token: float32 rounding alone separates them (measured 1e-6), so 1e-5.
+    The selection bias gets no gradient from either."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.float32)
+    params, tokens = _params(cfg), _tokens(cfg)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0]))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: reference.loss(p, tokens, FILED)))(params)
+    assert abs(float(loss) - float(want)) <= 2e-6 * abs(float(want))
+    for stack in (grads, want_grads):
+        assert not np.asarray(stack["moe"].pop("bias")).any()
+    for path, err in jax.tree_util.tree_leaves_with_path(
+            _rel(grads, want_grads)):
+        assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+
+
+def test_bf16_with_remat_is_within_the_benchmarks_bounds():
+    """As the cell runs it: bf16 operands, every layer under the remat
+    policy, against the float32 reference, on the leaves `accounting.pick`
+    names, inside `compare`'s bounds (loss 3e-4, gradients 8e-2)."""
+    cfg = dataclasses.replace(TINY, remat=True)
+    params, tokens = _params(cfg), _tokens(cfg)
+    check = compare.compare(
+        lambda p, t: nemotron_h.loss_fn(p, {"tokens": t}, cfg)[0],
+        lambda p, t: reference.loss(p, t, FILED), params, tokens,
+        jax.devices()[0], pick=accounting.pick, put=accounting.put)
+    assert set(check["errors"]) == {"loss"} | {"grad_" + k for k in (
+        "head", "wq", "wv", "w_in", "A_log", "dt_bias", "w_out", "wg", "w1",
+        "w2", "shared_w1")}
+    assert check["within"], check["errors"]
+
+
+@pytest.mark.parametrize("heads, kv_heads", [(4, 4), (4, 1), (16, 1),
+                                             (8, 2)])
+def test_flash_with_grouped_kv_heads_against_plain_attention(heads,
+                                                             kv_heads):
+    """Interpret mode, float32: o and all three gradients, K and V handed
+    to the kernels as [B·KV, S, D] — query head i reads KV head i // group
+    through the index maps, dk and dv are summed over the group. S 200 is
+    padded to the tiles; groups of 1, 4, 16 and two KV heads of 4."""
+    ks = jax.random.split(jax.random.PRNGKey(heads), 4)
+    batch, seq, dim = 2, 200, 32
+    q = jax.random.normal(ks[0], (batch, seq, heads, dim))
+    k, v = (jax.random.normal(key, (batch, seq, kv_heads, dim))
+            for key in ks[1:3])
+    weight = jax.random.normal(ks[3], q.shape)
+    group = heads // kv_heads
+
+    def plain(q, k, v):
+        return jnp.sum(weight * reference_attention(
+            q, jnp.repeat(k, group, 2), jnp.repeat(v, group, 2),
+            causal=True))
+
+    def flash(q, k, v):
+        return jnp.sum(weight * flash_attention(q, k, v, causal=True,
+                                                interpret=True))
+
+    want, want_grads = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+    got, grads = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, b in zip(grads, want_grads):
+        assert a.shape == b.shape
+        assert compare.rel_l2(a, b) <= 1e-5
