@@ -225,7 +225,7 @@ def ragged():
 
     def system(x, gate_vals, experts):
         return L._local_experts(x, gate_vals, gate_idx, experts, n_experts=E, first=first, cd=jnp.bfloat16,
-                                platform=platform, activation="relu2")
+                                platform=platform, activation="relu2")[0]
 
     def dense(x, gate_vals, experts):
         with jax.default_matmul_precision("highest"):

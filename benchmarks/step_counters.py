@@ -13,7 +13,9 @@ step, the median step of the second half beside the counters' means over it
 — and appends it to chiprun_out/step_counters/<cell>.jsonl. For
 `smallthinker4l-b1s16k` the counter is `moe_held`, the assignments that
 reached an expert held here: what the cell's throughput follows (PERF.md §6,
-PR 36) and what `mfu` credits at its expectation instead."""
+PR 36) and what `mfu` credits at its expectation instead; beside it
+`moe_compact`, the routed layers that ran on the share's bounded prefix of
+the assignments that step (its mean ÷ the routed layers is the hit share)."""
 import dataclasses
 import importlib
 import json

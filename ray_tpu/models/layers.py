@@ -8,7 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -572,6 +572,36 @@ MOE_EXTRA_LOGICAL = {"bias": (None,), "shared_w1": ("embed", "mlp"),
 GROUP_ROW_TILE = grouped_matmul.ROW_TILE
 
 
+# A share's bounds over the held experts' expected rows: ONE, at twice the
+# expectation. It holds the two cells with a share while their routing is
+# even or turns away from this chip. Rungs at 4 and 8 times were tried
+# (`nemotronh9l-b1s8k`: a deeper layer gives one, two or nearly three of
+# every token's six choices to held experts for 3 to 15 steps in four seeds
+# of seven — 17, 33, 45 % of the rows on an expectation of 6.25 % — and runs
+# whole through them, 462 ms a step for 432): each rung is one more program
+# with kernels of its own shapes, ~3 s of that cell's 64 s from start to
+# first step, whose bound is a tenth of it (PERF.md §6, PR 37). A bound
+# that is not under the rows is none.
+_BOUND_FACTORS = (2,)
+
+
+def assignment_bounds(rows: int, local: int, n_experts: int) -> Tuple[int, ...]:
+    """How many of `rows` sorted assignments a device that holds `local` of
+    the `n_experts` scored experts works on while its experts' rows fit
+    them: their expectation under even routing times each of
+    `_BOUND_FACTORS`, rounded up to the grouped kernel's row tile, those
+    under `rows`, ascending. From the shapes alone. Empty where every
+    scored expert is held or no bound is under `rows`: the layer then has
+    the whole path only."""
+    if local >= n_experts:
+        return ()
+    tile = grouped_matmul.row_tile(rows) or GROUP_ROW_TILE
+    expected = -(-rows * local // n_experts)
+    bounds = {-(-factor * expected // tile) * tile
+              for factor in _BOUND_FACTORS}
+    return tuple(sorted(b for b in bounds if b < rows))
+
+
 def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
              gated: bool, itemsize: int = 2, ep: int = 1) -> dict:
     """What one forward pass of `apply_moe` does on one device, from shapes
@@ -582,7 +612,12 @@ def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
     tiled kernel issues under ANY routing (each local expert's group may
     end inside a row tile, which is then visited twice), and the bytes that
     dispatch and combine move. The backward pass is twice the FLOPs (one
-    product for the rows, one for the weights) and the same bytes again."""
+    product for the rows, one for the weights) and the same bytes again.
+    `bounds`: where the leaves hold fewer experts than the router scores,
+    the sorted assignments the layer dispatches, multiplies and combines
+    while the held experts' rows fit them — the least that does
+    (`assignment_bounds`; empty: all `rows` always); the bytes are those of
+    the whole path."""
     rows = tokens * cfg.top_k
     per_row = (3 if gated else 2) * 2 * d_model * d_ff
     tile = grouped_matmul.row_tile(rows) or GROUP_ROW_TILE
@@ -590,6 +625,7 @@ def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
     visits = min(tiles + cfg.stacked // ep - 1, 2 * tiles)
     return {
         "rows": rows,
+        "bounds": assignment_bounds(rows, cfg.stacked // ep, cfg.n_experts),
         "flops_needed": rows * per_row * cfg.stacked // (cfg.n_experts * ep),
         "flops_issued_max": visits * tile * per_row,
         # each row read from its token and written in expert order
@@ -631,6 +667,117 @@ def _permute_rows(y, perm, inverse):
 
 _permute_rows.defvjp(lambda y, perm, inverse: (y[perm], (inverse,)),
                      lambda res, d: (d[res[0]], None, None))
+
+
+def _sum_prefix(y, inverse, top_k: int, weights=None, mask=None):
+    """y [C, D], the first C rows of the sorted order -> [T, D] float32:
+    each token's K assignments' rows (row `inverse[i]` of y; those behind
+    the prefix, and those `mask` [T, K] leaves out, count zero), times
+    `weights` [T, K], summed. One gather of T rows a slot, accumulated:
+    nothing of `[T, K, D]` is laid out, and on the TPU that is what the
+    whole path's combine spends most of its time on (PERF.md §6, PR 37)."""
+    place = inverse.reshape(-1, top_k)
+    out = 0.0
+    for k in range(top_k):
+        rows = y[jnp.minimum(place[:, k], y.shape[0] - 1)].astype(jnp.float32)
+        if weights is not None:
+            rows = rows * weights[:, k, None]
+        keep = place[:, k] < y.shape[0]
+        if mask is not None:
+            keep = keep & mask[:, k]
+        out = out + jnp.where(keep[:, None], rows, 0.0)
+    return out
+
+
+@jax.custom_vjp
+def _take_prefix(x2, order, inverse):
+    """`_take_assignments` for a prefix of the sorted assignments: x2 [T, D]
+    -> [C, D], row j the token of `order[j]` (`order` [C] the first C of the
+    sorted positions, `inverse` [T·K] the whole inverse permutation). The
+    transpose gathers too: each token's rows out of the C, summed in
+    float32 (`_sum_prefix`)."""
+    return x2[order // (inverse.shape[0] // x2.shape[0])]
+
+
+def _take_prefix_fwd(x2, order, inverse):
+    return _take_prefix(x2, order, inverse), (inverse, x2.shape[0])
+
+
+def _take_prefix_bwd(res, d):
+    inverse, tokens = res
+    with jax.named_scope("dispatch"):   # a backward rule inherits no scope
+        dx = _sum_prefix(d, inverse, inverse.shape[0] // tokens)
+        return dx.astype(d.dtype), None, None
+
+
+_take_prefix.defvjp(_take_prefix_fwd, _take_prefix_bwd)
+
+
+@jax.custom_vjp
+def _combine_prefix(y, gate_vals, here, order, inverse):
+    """y [C, D], the experts' outputs for the first C of the sorted
+    assignments -> [T, D] float32: each token's rows times its gates [T, K]
+    in float32, those of experts not `here` [T, K] left out, summed
+    (`_sum_prefix`). The transpose works on the C rows alone: each row's
+    token's cotangent gathered once, for the row and for its gate."""
+    return _sum_prefix(y, inverse, gate_vals.shape[1], gate_vals, here)
+
+
+def _combine_prefix_fwd(y, gate_vals, here, order, inverse):
+    return (_combine_prefix(y, gate_vals, here, order, inverse),
+            (y, gate_vals, here, order, inverse))
+
+
+def _combine_prefix_bwd(res, d):
+    y, gate_vals, here, order, inverse = res
+    top_k = gate_vals.shape[1]
+    with jax.named_scope("combine"):    # a backward rule inherits no scope
+        d_rows = d[order // top_k]
+        gates = jnp.where(here.reshape(-1)[order],
+                          gate_vals.reshape(-1)[order], 0.0)
+        # a gate's cotangent, by row; then by token and slot
+        d_gates = jnp.sum(y.astype(jnp.float32) * d_rows, axis=-1)
+        place = inverse.reshape(-1, top_k)
+        d_gates = jnp.where(here & (place < y.shape[0]),
+                            d_gates[jnp.minimum(place, y.shape[0] - 1)], 0.0)
+        return ((d_rows * gates[:, None]).astype(y.dtype), d_gates, None,
+                None, None)
+
+
+_combine_prefix.defvjp(_combine_prefix_fwd, _combine_prefix_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _one_of(programs, which, args, aux):
+    """`programs[which](*args, *aux)`: several programs of one result, one
+    chosen on the device. Differentiable in `args`. The backward branches
+    on `which` again and differentiates the program that ran from its
+    inputs (its forward once more, as under `remat`), so nothing of the
+    others is kept, zero-filled or run:
+    `lax.switch` under differentiation returns the residuals of EVERY
+    branch, the whole path's `[T·K, ·]` arrays among them."""
+    return jax.lax.switch(which, programs, *args, *aux)
+
+
+def _one_of_fwd(programs, which, args, aux):
+    return _one_of(programs, which, args, aux), (which, args, aux)
+
+
+def _one_of_bwd(programs, res, d):
+    which, args, aux = res
+
+    def gradient(fn):
+        return lambda args, d: jax.vjp(lambda *a: fn(*a, *aux), *args)[1](d)
+
+    # the barrier holds the compiler's conditional code motion off: it
+    # sinks what reads a cotangent into every branch, and where that is the
+    # zero-padded copy of a whole stack of layers (a slice's transpose) each
+    # conditional then returns the stack (4.9 GB more in `nemotronh9l-b1s8k`)
+    return (None, jax.lax.optimization_barrier(jax.lax.switch(
+        which, [gradient(fn) for fn in programs], args, d)), None)
+
+
+_one_of.defvjp(_one_of_fwd, _one_of_bwd)
 
 
 def _use_kernel(platform: str, rows: int, d_model: int, d_ff: int,
@@ -701,13 +848,24 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
     E_local experts it holds, `first` the id of the first of them,
     `platform` what the device is (`_use_kernel`). Returns
     [b, s, D] float32: for each token the weighted outputs of those of its
-    experts that live here (all of them where nothing splits the experts)."""
+    experts that live here (all of them where nothing splits the experts).
+
+    Where E_local < n_experts the local experts' rows are a PREFIX of the
+    sorted assignments, and the shapes give bounds on it
+    (`assignment_bounds`): while this step's routing keeps the prefix under
+    a bound, only that many rows (the least bound that holds them) are
+    gathered, multiplied and combined; otherwise all of them are, as where
+    every expert is local — the same result either way, no assignment
+    dropped. Second result: whether a bounded program ran (int32; None
+    where every scored expert is local)."""
     d_model, top_k = x.shape[-1], gate_idx.shape[-1]
     x2 = x.reshape(-1, d_model)
     gate_vals, gate_idx = (g.reshape(-1, top_k) for g in (gate_vals, gate_idx))
-    tokens = x2.shape[0]
-    rows = tokens * top_k
+    rows = x2.shape[0] * top_k
     local = next(iter(experts.values())).shape[0]
+    through = functools.partial(
+        _through_experts, n_experts=n_experts, cd=cd, platform=platform,
+        activation=activation, gate=gate)
     with jax.named_scope("dispatch"):
         # this device's experts first, in order; the others' rows behind them
         key = (gate_idx.reshape(rows) - first) % n_experts
@@ -717,7 +875,46 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
         # every row's group, the local experts' first: what lies behind
         # them belongs to no matrix here and comes out of a product zero
         sizes = jnp.bincount(key, length=n_experts).astype(jnp.int32)
-        taken = _take_assignments(x2.astype(cd), order, inverse)
+    bounds = assignment_bounds(rows, local, n_experts)
+    if not bounds:
+        # a share too small for a bound under its rows never runs bounded
+        return through(x2, gate_vals, experts, gate_idx, first, order,
+                       inverse, sizes).reshape(x.shape), (
+                           None if local == n_experts else jnp.int32(0))
+    # the least bound that holds the local experts' rows; behind the last,
+    # the whole path. Each program is a `jit`, so a model's layers (and the
+    # forward, its recomputation and the backward of each) trace it once
+    over = jnp.sum(jnp.sum(sizes[:local]) > jnp.asarray(bounds, jnp.int32))
+    programs = tuple(
+        functools.partial(_through_experts_jit, bound=b, **through.keywords)
+        for b in bounds + (None,))
+    out = _one_of(programs, over, (x2, gate_vals, experts),
+                  (gate_idx, jnp.asarray(first, jnp.int32), order, inverse,
+                   sizes))
+    return out.reshape(x.shape), (over < len(bounds)).astype(jnp.int32)
+
+
+def _through_experts(x2, gate_vals, experts, gate_idx, first, order, inverse,
+                     sizes, *, n_experts: int, cd, platform: str,
+                     activation: str, gate: str, bound: Optional[int] = None):
+    """`_local_experts` behind the sort: x2 [T, D], gates [T, K], the
+    sorted positions `order`, their inverse and every group's `sizes`
+    -> [T, D] float32. `bound`: the local experts' rows lie within the first
+    `bound` of the sorted order, and only those are taken, multiplied and
+    combined; None: all T·K."""
+    (tokens, d_model), top_k = x2.shape, gate_idx.shape[-1]
+    local = next(iter(experts.values())).shape[0]
+    with jax.named_scope("dispatch"):
+        if bound is None:
+            rows = tokens * top_k
+            taken = _take_assignments(x2.astype(cd), order, inverse)
+        else:
+            rows, order = bound, order[:bound]
+            # what of the prefix lies behind the local experts' rows is one
+            # more group of no matrix
+            sizes = jnp.append(sizes[:local],
+                               bound - jnp.sum(sizes[:local]))
+            taken = _take_prefix(x2.astype(cd), order, inverse)
     with jax.named_scope("experts"):
         wide = experts["w_gate" if "w_gate" in experts else "w1"]
         width = _kernel_width(platform, rows, *wide.shape[1:], cd)
@@ -741,12 +938,19 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
             hidden = _relu2(product(taken, "w1")).astype(cd)
             y = product(hidden, "w2")
     with jax.named_scope("combine"):
-        y = _permute_rows(y, inverse, order).reshape(
-            tokens, top_k, d_model)
+        if bound is None:
+            y = _permute_rows(y, inverse, order).reshape(
+                tokens, top_k, d_model)
         here = ((gate_idx - first) % n_experts) < local
+        if bound is not None:
+            return _combine_prefix(y, gate_vals, here, order, inverse)
         weighted = jnp.where(here[..., None], y.astype(jnp.float32)
                              * gate_vals[..., None], 0.0)
-        return jnp.sum(weighted, axis=1).reshape(x.shape)
+        return jnp.sum(weighted, axis=1)
+
+
+_through_experts_jit = jax.jit(_through_experts, static_argnames=(
+    "n_experts", "cd", "platform", "activation", "gate", "bound"))
 
 
 def _route(logits, bias, cfg: MoEConfig):
@@ -822,7 +1026,9 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
     `cfg.held` / `cfg.first`: the leaves hold a share of the `n_experts` the
     router scores. The result is then the PART of the layer's output that
     these experts (and the shared one) give; the assignments to the others
-    are sorted behind the held ones' and come out of the products zero.
+    are sorted behind the held ones' and come out of the products zero —
+    or, while the held ones' rows stay under a bound the shapes give
+    (`moe_plan`'s `bounds`), are not touched at all (`_local_experts`).
 
     mesh: as in `apply_attention` — the grouped matmul is a Mosaic kernel on
     the TPU (`ops.grouped_matmul`, wherever the mesh's devices — without a
@@ -837,7 +1043,12 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
     e and P_e its mean router probability, taken a sequence at a time and
     averaged — so that, like the cross-entropy, a batch's value is the mean
     of its sequences' whatever `dp` does with them; `z` = mean
-    logsumexp(logits)²; `counts` [E] the batch's assignments by expert.
+    logsumexp(logits)²; `counts` [E] the batch's assignments by expert;
+    and, where a device holds fewer experts than are scored (a share, or
+    `ep`), `compact`: the share of the devices on which this step's routing
+    kept the held experts' rows under the bound, so that only a bounded
+    prefix of the sorted assignments was worked on (`_local_experts`; 1.0
+    or 0.0 on one device).
     """
     cd = compute_dtype
     E = cfg.n_experts
@@ -853,22 +1064,33 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
                               platform=platform, activation=cfg.activation,
                               gate=cfg.gate)
     if mesh is None:
-        out = local(x, gate_vals, gate_idx, experts, first=cfg.first)
+        out, compact = local(x, gate_vals, gate_idx, experts, first=cfg.first)
     else:
         def per_device(x, gate_vals, gate_idx, experts):
             held = next(iter(experts.values())).shape[0]
-            return jax.lax.psum(
-                local(x, gate_vals, gate_idx, experts,
-                      first=cfg.first + jax.lax.axis_index("ep") * held),
-                ("ep", "tp"))
+            out, compact = local(
+                x, gate_vals, gate_idx, experts,
+                first=cfg.first + jax.lax.axis_index("ep") * held)
+            out = jax.lax.psum(out, ("ep", "tp"))
+            if compact is None:
+                return out
+            return out, jax.lax.pmean(compact.astype(jnp.float32),
+                                      mesh.axis_names)
 
         logical = GATED_MOE_LOGICAL if "w_gate" in experts else MOE_LOGICAL
         tok = sh.spec("batch", "seq", None)
+        # a device has a bound where it holds fewer experts than are scored
+        stacked = next(iter(experts.values())).shape[0]
+        bounded = stacked // dict(mesh.shape).get("ep", 1) < E
         out = jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(tok, tok, tok,
                       {k: sh.spec(*logical[k]) for k in experts}),
-            out_specs=tok, check_vma=False)(x, gate_vals, gate_idx, experts)
+            out_specs=(tok, sh.spec()) if bounded else tok,
+            check_vma=False)(x, gate_vals, gate_idx, experts)
+        out, compact = out if bounded else (out, None)
+    if compact is not None:
+        stats = dict(stats, compact=compact.astype(jnp.float32))
     if cfg.d_shared:
         with jax.named_scope("shared_expert"):
             project = functools.partial(mxu.einsum, cd=cd,

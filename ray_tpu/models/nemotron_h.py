@@ -214,10 +214,11 @@ def partition_specs(cfg: NemotronHConfig, rules=None):
 def _layer_apply(x, layer, *, kind: str, cfg: NemotronHConfig, impl: str,
                  mesh=None):
     """One layer: x [B, S, d] float32 -> (x, the routed layer's assignments
-    by expert [E], None for the other kinds)."""
+    by expert [E] and whether its share ran bounded; None for the other
+    kinds)."""
     h = L.rms_norm(x, layer["ln"], cfg.rms_norm_eps)
     mixer = {k: v for k, v in layer.items() if k != "ln"}
-    counts = None
+    routed = None
     with jax.named_scope(KINDS[kind]):
         if kind == "M":
             out = L.apply_mamba(mixer, h, cfg.mamba, compute_dtype=cfg.dtype,
@@ -231,17 +232,24 @@ def _layer_apply(x, layer, *, kind: str, cfg: NemotronHConfig, impl: str,
             out, stats = L.apply_moe(mixer, h, cfg.moe,
                                      compute_dtype=cfg.dtype, mesh=mesh,
                                      three_pass=True)
-            counts = stats["counts"]
+            routed = stats["counts"], stats.get("compact", jnp.float32(0))
     x = x + out
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    return x, counts
+    return x, routed
 
 
 def forward(params, tokens, cfg: NemotronHConfig,
             mesh: Optional[Mesh] = None):
     """tokens [B, S] -> (logits [B, S, V] f32 over this chip's slice of the
     vocabulary, the routed layers' assignments by expert [n_E, E])."""
+    return _forward(params, tokens, cfg, mesh)[:2]
+
+
+def _forward(params, tokens, cfg: NemotronHConfig, mesh):
+    """`forward`, and by routed layer [n_E] whether its share of the
+    experts ran on a bounded prefix of the assignments (`apply_moe`'s
+    `compact`)."""
     if mesh is not None and dict(mesh.shape).get("tp", 1) > 1:
         raise ValueError(
             "nemotron_h: the Mamba mixers' and the KV heads' leaves are "
@@ -251,7 +259,7 @@ def forward(params, tokens, cfg: NemotronHConfig,
     x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    counts = []
+    by_layer = []
     for depth, kind in enumerate(cfg.pattern):
         nth = cfg.pattern[:depth].count(kind)     # of its kind's stack
         layer = jax.tree_util.tree_map(lambda a: a[nth], params[KINDS[kind]])
@@ -261,7 +269,7 @@ def forward(params, tokens, cfg: NemotronHConfig,
             body = L.remat(body)
         x, routed = body(x, layer)
         if routed is not None:
-            counts.append(routed)
+            by_layer.append(routed)
     with jax.named_scope("loss_tail"):
         # nothing behind the last layer is discontinuous: the head reads
         # the stream in the compute dtype, as `olmoe.forward` does
@@ -271,18 +279,20 @@ def forward(params, tokens, cfg: NemotronHConfig,
             (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if mesh is not None:
         logits = sh.constrain(logits, mesh, "batch", "seq", "vocab")
-    return logits, jnp.stack(counts)
+    return (logits, *(jnp.stack(s) for s in zip(*by_layer)))
 
 
 def loss_fn(params, batch, cfg: NemotronHConfig,
             mesh: Optional[Mesh] = None) -> Tuple[jnp.ndarray, dict]:
     """batch: {"tokens" [B, S+1] int32}, ids of this chip's vocabulary
     slice. Mean next-token cross-entropy over the slice, and how the
-    routing went: `moe_assignments` (tokens × top_k × routed layers) and
+    routing went: `moe_assignments` (tokens × top_k × routed layers),
     `moe_held` (those of them that chose an expert held here; the others'
-    outputs are the absent chips')."""
+    outputs are the absent chips') and `moe_compact` (the routed layers
+    whose held rows stayed under the share's bound, so that only that many
+    were moved)."""
     tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    logits, counts = forward(params, tokens, cfg, mesh)
+    logits, counts, compact = _forward(params, tokens, cfg, mesh)
     with jax.named_scope("loss_tail"):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
@@ -293,4 +303,5 @@ def loss_fn(params, batch, cfg: NemotronHConfig,
         "moe_assignments": jnp.int32(tokens.size * cfg.top_k
                                      * counts.shape[0]),
         "moe_held": jnp.sum(counts[:, cfg.first:cfg.first + held]),
+        "moe_compact": jnp.sum(compact),
     }

@@ -248,7 +248,7 @@ _layers_of.defvjp(
 def _block_apply(x, block, *, kind: Tuple[int, int], cfg: SmallThinkerConfig,
                  impl: str, mesh=None):
     """One layer of `kind` (windowed, rotated): x [B, S, d] float32 -> (x,
-    its assignments by expert [E])."""
+    (its assignments by expert [E], whether its share ran bounded))."""
     windowed, rotated = kind
     cd = cfg.dtype
     # the router reads the layer's input as it is: ahead of the norm, ahead
@@ -269,13 +269,19 @@ def _block_apply(x, block, *, kind: Tuple[int, int], cfg: SmallThinkerConfig,
     x = h + m
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    return x, stats["counts"]
+    return x, (stats["counts"], stats.get("compact", jnp.float32(0)))
 
 
 def forward(params, tokens, cfg: SmallThinkerConfig,
             mesh: Optional[Mesh] = None):
     """tokens [B, S] -> (logits [B, S, V] f32 over this chip's slice of the
     vocabulary, every layer's assignments by expert [L, E])."""
+    return _forward(params, tokens, cfg, mesh)[:2]
+
+
+def _forward(params, tokens, cfg: SmallThinkerConfig, mesh):
+    """`forward`, and by layer [L] whether its share of the experts ran on
+    a bounded prefix of the assignments (`apply_moe`'s `compact`)."""
     if mesh is not None and dict(mesh.shape).get("tp", 1) > 1:
         raise ValueError(
             "smallthinker: the KV heads' leaves are whole on every `tp` "
@@ -287,18 +293,18 @@ def forward(params, tokens, cfg: SmallThinkerConfig,
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
 
     def period(x, stacked):
-        counts = []
+        routed = []
         for kind, block in zip(cfg.kinds[:cfg.period], _layers_of(stacked)):
             layer = functools.partial(_block_apply, kind=kind, cfg=cfg,
                                       impl=impl, mesh=mesh)
-            x, routed = (L.remat(layer) if cfg.remat else layer)(x, block)
-            counts.append(routed)
-        return x, jnp.stack(counts)
+            x, stats = (L.remat(layer) if cfg.remat else layer)(x, block)
+            routed.append(stats)
+        return x, tuple(jnp.stack(s) for s in zip(*routed))
 
     periods = jax.tree_util.tree_map(
         lambda a: a.reshape((-1, cfg.period) + a.shape[1:]),
         params["blocks"])
-    x, counts = jax.lax.scan(period, x, periods)
+    x, (counts, compact) = jax.lax.scan(period, x, periods)
     with jax.named_scope("loss_tail"):
         # nothing behind the last layer is discontinuous: the head reads
         # the stream in the compute dtype, as `olmoe.forward` does
@@ -308,18 +314,20 @@ def forward(params, tokens, cfg: SmallThinkerConfig,
             (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if mesh is not None:
         logits = sh.constrain(logits, mesh, "batch", "seq", "vocab")
-    return logits, counts.reshape(cfg.n_layer, cfg.n_experts)
+    return (logits, counts.reshape(cfg.n_layer, cfg.n_experts),
+            compact.reshape(cfg.n_layer))
 
 
 def loss_fn(params, batch, cfg: SmallThinkerConfig,
             mesh: Optional[Mesh] = None) -> Tuple[jnp.ndarray, dict]:
     """batch: {"tokens" [B, S+1] int32}, ids of this chip's vocabulary
     slice. Mean next-token cross-entropy over the slice, and how the
-    routing went: `moe_assignments` (tokens × top_k × layers) and `moe_held`
+    routing went: `moe_assignments` (tokens × top_k × layers), `moe_held`
     (those of them that chose an expert held here; the others' outputs are
-    the absent chips')."""
+    the absent chips') and `moe_compact` (the layers whose held rows stayed
+    under the share's bound, so that only that many were moved)."""
     tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    logits, counts = forward(params, tokens, cfg, mesh)
+    logits, counts, compact = _forward(params, tokens, cfg, mesh)
     with jax.named_scope("loss_tail"):
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
@@ -329,4 +337,5 @@ def loss_fn(params, batch, cfg: SmallThinkerConfig,
         "loss": loss,
         "moe_assignments": jnp.int32(tokens.size * cfg.top_k * cfg.n_layer),
         "moe_held": jnp.sum(counts[:, cfg.first:cfg.first + held]),
+        "moe_compact": jnp.sum(compact),
     }

@@ -8,6 +8,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from chipbench import compare
 from chipbench.references import nemotron_h as reference
@@ -95,7 +96,7 @@ def test_a_share_on_the_pallas_kernels_at_a_padded_width(monkeypatch):
         def fn(x, gate_vals, experts):
             return L._local_experts(
                 x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, platform=platform, activation="relu2")
+                cd=jnp.float32, platform=platform, activation="relu2")[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 
@@ -151,7 +152,7 @@ def test_a_share_on_xlas_grouped_product_masks_the_rows_of_no_group(
         def fn(x, gate_vals, experts):
             return L._local_experts(
                 x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, platform="tpu", activation="relu2")
+                cd=jnp.float32, platform="tpu", activation="relu2")[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 
@@ -164,3 +165,26 @@ def test_a_share_on_xlas_grouped_product_masks_the_rows_of_no_group(
                     jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("routing,compact", [("even", 2), ("onto_the_held", 0)])
+def test_moe_compact_counts_the_layers_under_the_bound(routing, compact):
+    """128 tokens choose 3 of 16, 4 held: 384 rows, 96 expected here, a
+    bound of 256. An even routing keeps both routed layers under it; a
+    selection bias that sends every token to held experts puts all 384 rows
+    on them, over the bound, and both layers take the whole path — the loss
+    is the plain reference's either way."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.float32)
+    assert L.moe_plan(128, cfg.d_model, cfg.d_expert, cfg.moe,
+                      gated=False)["bounds"] == (256,)
+    params = nemotron_h.init(jax.random.PRNGKey(0), cfg)
+    if routing == "onto_the_held":
+        params["moe"]["bias"] = params["moe"]["bias"].at[:, :cfg.held].set(9.)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0,
+                                cfg.vocab_size)
+    loss, metrics = nemotron_h.loss_fn(params, {"tokens": tokens}, cfg)
+    assert float(metrics["moe_compact"]) == compact
+    held = int(metrics["moe_held"])
+    assert held == 2 * 384 if compact == 0 else 0 < held <= 2 * 256
+    assert float(loss) == pytest.approx(
+        float(reference.loss(params, tokens, FILED)), rel=2e-6)

@@ -308,7 +308,7 @@ def test_local_experts_on_the_pallas_kernels_against_the_oracle(
         L._local_experts(
             x, gate_vals, gate_idx,
             {k: v[first:first + 4] for k, v in experts.items()},
-            n_experts=8, first=first, cd=jnp.float32, platform="tpu")
+            n_experts=8, first=first, cd=jnp.float32, platform="tpu")[0]
         for first in (0, 4)]
     # every product of both devices went through the kernel, with all
     # eight groups' sizes and four matrices

@@ -38,7 +38,7 @@ def test_the_four_shares_parts_add_up_to_the_uncut_layer(kind):
         config=filed))(x)
 
     def layer(moe, held, first):
-        out, counts = smallthinker._block_apply(
+        out, (counts, _) = smallthinker._block_apply(
             x, dict(block, moe=moe), kind=kind, impl="reference",
             cfg=dataclasses.replace(cfg, held=held, first=first))
         assert int(jnp.sum(counts)) == 2 * 48 * cfg.top_k
@@ -112,7 +112,7 @@ def test_a_share_of_gated_experts_on_the_pallas_kernels(gate, monkeypatch):
         def fn(x, gate_vals, experts):
             return L._local_experts(
                 x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, platform=platform, gate=gate)
+                cd=jnp.float32, platform=platform, gate=gate)[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 
@@ -135,3 +135,29 @@ def test_a_share_of_gated_experts_on_the_pallas_kernels(gate, monkeypatch):
         * jnp.sum(jnp.where(gate_idx == 2 + e, gate_vals, 0.0),
                   -1)[..., None] for e in range(2))
     np.testing.assert_allclose(want, dense, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("routing,compact", [("even", 4), ("onto_the_held", 0)])
+def test_moe_compact_counts_the_layers_under_the_bound(routing, compact):
+    """128 tokens choose 3 of 16, 4 held: 384 rows, 96 expected here, a
+    bound of 256. An even routing keeps all four layers under it; a stream
+    and routers that send every token to held experts put all 384 rows on
+    them, over the bound, and every layer takes the whole path — the loss is
+    the plain reference's either way."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.float32)
+    assert L.moe_plan(128, cfg.d_model, cfg.d_expert, cfg.moe,
+                      gated=True)["bounds"] == (256,)
+    params = smallthinker.init(jax.random.PRNGKey(0), cfg)
+    if routing == "onto_the_held":
+        # every token's first coordinate large, the held experts' logits it
+        params["wte"] = params["wte"].at[:, 0].set(1e3)
+        wg = params["blocks"]["moe"]["wg"]
+        params["blocks"]["moe"]["wg"] = wg.at[:, 0, :cfg.held].set(1.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0,
+                                cfg.vocab_size)
+    loss, metrics = smallthinker.loss_fn(params, {"tokens": tokens}, cfg)
+    assert float(metrics["moe_compact"]) == compact
+    held = int(metrics["moe_held"])
+    assert held == 4 * 384 if compact == 0 else 0 < held <= 4 * 256
+    assert float(loss) == pytest.approx(
+        float(reference.loss(params, tokens, FILED)), rel=2e-6)
