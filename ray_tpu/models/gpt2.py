@@ -177,18 +177,20 @@ def _block_apply(block, x, cfg: GPT2Config, impl: str, mesh=None,
     and x its share of the batch; None wherever the partitioner (or nobody)
     splits the weights."""
     cd = cfg.dtype
-    h = L.layer_norm(x, block["ln1"]["scale"], block["ln1"]["bias"])
-    x = x + L.apply_attention(block["attn"], h, causal=True, impl=impl,
-                              compute_dtype=cd, mesh=mesh, reduce=reduce)
-    h = L.layer_norm(x, block["ln2"]["scale"], block["ln2"]["bias"])
-    if cfg.moe:
-        m, stats = L.apply_moe(block["moe"], h, cfg.moe, compute_dtype=cd,
-                               mesh=mesh)
-        aux = stats["load_balance"]
-    else:
-        m = L.apply_mlp(block["mlp"], h, compute_dtype=cd, reduce=reduce)
-        aux = jnp.float32(0)
-    return x + m, aux
+    with jax.named_scope("attention"):
+        h = L.layer_norm(x, block["ln1"]["scale"], block["ln1"]["bias"])
+        x = x + L.apply_attention(block["attn"], h, causal=True, impl=impl,
+                                  compute_dtype=cd, mesh=mesh, reduce=reduce)
+    with jax.named_scope("mlp"):
+        h = L.layer_norm(x, block["ln2"]["scale"], block["ln2"]["bias"])
+        if cfg.moe:
+            m, stats = L.apply_moe(block["moe"], h, cfg.moe,
+                                   compute_dtype=cd, mesh=mesh)
+            aux = stats["load_balance"]
+        else:
+            m = L.apply_mlp(block["mlp"], h, compute_dtype=cd, reduce=reduce)
+            aux = jnp.float32(0)
+        return x + m, aux
 
 
 def _tp_size(cfg: GPT2Config, mesh: Optional[Mesh]) -> int:
@@ -286,15 +288,18 @@ def _tp_blocks(blocks, x, cfg: GPT2Config, impl: str, mesh: Mesh):
         return jnp.concatenate(chains)
 
     x_spec = sh.spec("batch", "seq", "embed")
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(partition_specs(cfg)["blocks"], x_spec),
-        out_specs=x_spec, check_vma=False)(blocks, x)
+    with jax.named_scope("blocks"):
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(partition_specs(cfg)["blocks"], x_spec),
+            out_specs=x_spec, check_vma=False)(blocks, x)
 
 
 def embed(params, tokens, cfg: GPT2Config):
     S = tokens.shape[1]
-    x = jnp.take(params["wte"], tokens, axis=0) + params["wpe"][:S]
-    return x.astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0) + params["wpe"][:S]
+        return x.astype(cfg.dtype)
 
 
 def unembed(params, x, cfg: GPT2Config):
@@ -302,11 +307,12 @@ def unembed(params, x, cfg: GPT2Config):
     einsum + log_softmax loss tail cost ~100ms/step at batch 16 on v5e (vs
     34ms this way, measured) — the f32 [B,S,V] matmul runs far off MXU peak
     and log_softmax materializes a second 3.3 GB tensor."""
-    x = L.layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    return jax.lax.dot_general(
-        x.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
-        (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("loss_tail"):
+        x = L.layer_norm(x, params["ln_f"]["scale"], params["ln_f"]["bias"])
+        return jax.lax.dot_general(
+            x.astype(cfg.dtype), params["wte"].astype(cfg.dtype),
+            (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )
 
 
 def forward(params, tokens, cfg: GPT2Config, mesh: Optional[Mesh] = None):
@@ -328,8 +334,9 @@ def forward(params, tokens, cfg: GPT2Config, mesh: Optional[Mesh] = None):
 
         if cfg.remat:
             body = L.remat(body)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0)),
-                                   params["blocks"])
+        with jax.named_scope("blocks"):
+            (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0)),
+                                       params["blocks"])
     logits = unembed(params, x, cfg)
     if mesh is not None:
         logits = sh.constrain(logits, mesh, "batch", "seq", "vocab")
@@ -395,8 +402,9 @@ def forward_pipelined(
     x = embed(params, tokens, cfg)
     x = sh.constrain(x, mesh, "batch", "seq", "embed")
     mb = microbatch(x, n_microbatches)
-    y = gpipe(stage_fn, staged, mb, mesh, manual_axes=manual_axes,
-              mb_spec=mb_spec)
+    with jax.named_scope("blocks"):
+        y = gpipe(stage_fn, staged, mb, mesh, manual_axes=manual_axes,
+                  mb_spec=mb_spec)
     x = unmicrobatch(y)
     logits = unembed(params, x, cfg)
     return sh.constrain(logits, mesh, "batch", "seq", "vocab"), jnp.float32(0)
@@ -422,8 +430,10 @@ def loss_fn(
         logits, aux = forward(params, tokens, cfg, mesh)
     # -log p(target) = logsumexp(logits) - logits[target]; computed without
     # materializing log_softmax's full [B,S,V] output (HBM-bandwidth win).
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tl = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    loss = jnp.mean(lse - tl)
+    with jax.named_scope("loss_tail"):
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tl = jnp.take_along_axis(logits, targets[..., None],
+                                 axis=-1)[..., 0]
+        loss = jnp.mean(lse - tl)
     total = loss + cfg.aux_loss_weight * aux
     return total, {"loss": loss, "aux_loss": aux, "total_loss": total}

@@ -256,20 +256,23 @@ def _forward(params, tokens, cfg: NemotronHConfig, mesh):
             "whole on every `tp` rank; a mesh with tp > 1 is not supported "
             "(dp and ep meshes are)")
     impl = L.resolve_attention(cfg.attention, mesh)
-    x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
     by_layer = []
-    for depth, kind in enumerate(cfg.pattern):
-        nth = cfg.pattern[:depth].count(kind)     # of its kind's stack
-        layer = jax.tree_util.tree_map(lambda a: a[nth], params[KINDS[kind]])
-        body = functools.partial(_layer_apply, kind=kind, cfg=cfg, impl=impl,
-                                 mesh=mesh)
-        if cfg.remat:
-            body = L.remat(body)
-        x, routed = body(x, layer)
-        if routed is not None:
-            by_layer.append(routed)
+    with jax.named_scope("blocks"):
+        for depth, kind in enumerate(cfg.pattern):
+            nth = cfg.pattern[:depth].count(kind)     # of its kind's stack
+            layer = jax.tree_util.tree_map(lambda a: a[nth],
+                                           params[KINDS[kind]])
+            body = functools.partial(_layer_apply, kind=kind, cfg=cfg,
+                                     impl=impl, mesh=mesh)
+            if cfg.remat:
+                body = L.remat(body)
+            x, routed = body(x, layer)
+            if routed is not None:
+                by_layer.append(routed)
     with jax.named_scope("loss_tail"):
         # nothing behind the last layer is discontinuous: the head reads
         # the stream in the compute dtype, as `olmoe.forward` does

@@ -191,7 +191,8 @@ def forward(params, tokens, cfg: OlmoeConfig, mesh: Optional[Mesh] = None):
     """tokens [B, S] -> (logits [B, S, V] f32, router stats: `load_balance`
     and `z` averaged over layers, `counts` [L, E])."""
     impl = L.resolve_attention(cfg.attention, mesh)
-    x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
 
@@ -203,7 +204,8 @@ def forward(params, tokens, cfg: OlmoeConfig, mesh: Optional[Mesh] = None):
 
     if cfg.remat:
         body = L.remat(body)
-    x, stats = jax.lax.scan(body, x, params["blocks"])
+    with jax.named_scope("blocks"):
+        x, stats = jax.lax.scan(body, x, params["blocks"])
     with jax.named_scope("loss_tail"):
         # nothing behind the last block is discontinuous: the head reads
         # the stream in the compute dtype, as `gpt2.unembed` does

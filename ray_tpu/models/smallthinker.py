@@ -288,7 +288,8 @@ def _forward(params, tokens, cfg: SmallThinkerConfig, mesh):
             "rank; a mesh with tp > 1 is not supported (dp and ep meshes "
             "are)")
     impl = L.resolve_attention(cfg.attention, mesh)
-    x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
     if mesh is not None:
         x = sh.constrain(x, mesh, "batch", "seq", "embed")
 
@@ -301,10 +302,11 @@ def _forward(params, tokens, cfg: SmallThinkerConfig, mesh):
             routed.append(stats)
         return x, tuple(jnp.stack(s) for s in zip(*routed))
 
-    periods = jax.tree_util.tree_map(
-        lambda a: a.reshape((-1, cfg.period) + a.shape[1:]),
-        params["blocks"])
-    x, (counts, compact) = jax.lax.scan(period, x, periods)
+    with jax.named_scope("blocks"):
+        periods = jax.tree_util.tree_map(
+            lambda a: a.reshape((-1, cfg.period) + a.shape[1:]),
+            params["blocks"])
+        x, (counts, compact) = jax.lax.scan(period, x, periods)
     with jax.named_scope("loss_tail"):
         # nothing behind the last layer is discontinuous: the head reads
         # the stream in the compute dtype, as `olmoe.forward` does

@@ -35,9 +35,10 @@ schedule, derived from ``(S, D, dtype)`` by :func:`tile_plan`:
   the sequentially walked grid axis shrinks to the major blocks a window
   can reach (:func:`_seq_blocks`), so the others are neither visited nor
   fetched. :func:`window_plan` counts what that keeps, issues and skips.
-  These calls are named (``flash_window_fwd`` / ``_dq`` / ``_dkv``) so that
+  These calls are named ``flash_window_fwd`` / ``_dq`` / ``_dkv`` so that
   a trace tells them from the full-causal calls of the same operand shapes,
-  which carry no name. ``window=None`` traces to the program it was before.
+  which are ``flash_fwd`` / ``flash_dq`` / ``flash_dkv``. ``window=None``
+  traces to the program it was before, those names aside.
 
 Precision is unchanged: operands in the input dtype, f32 scores, f32
 softmax statistics and accumulators, ``p``/``dS`` cast to the input dtype
@@ -512,10 +513,12 @@ def _kv_index(causal: bool, group: int, n_major: int, n_seq: int):
     return lambda b, i, j: (head(b), j, 0)
 
 
-def _call_name(kernel: str, window: Optional[int]) -> Optional[str]:
-    """`pallas_call`'s `name` (the HLO instruction's and the trace event's):
-    the windowed calls have one, the full-causal calls keep none."""
-    return None if window is None else f"flash_window_{kernel}"
+def _call_name(kernel: str, window: Optional[int]) -> str:
+    """`pallas_call`'s `name`, which is the HLO instruction's and so the
+    trace event's: `flash_fwd` / `flash_dq` / `flash_dkv`, and
+    `flash_window_…` where the call has a window. Without one the
+    instruction is named after whatever function encloses the call."""
+    return f"flash_{kernel}" if window is None else f"flash_window_{kernel}"
 
 
 def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret,

@@ -29,16 +29,29 @@ Mesh construction gets the same treatment through ``mesh_build_timer``
 (``ray_tpu_mesh_build_seconds{kind}``): on a multi-slice pod,
 ``mesh_utils.create_device_mesh`` does real topology work worth seeing.
 
+Device time by the program's own names: the profiler's events carry
+the HLO instruction and no ``jax.named_scope``; the compiled text does
+(``metadata={op_name="…"}``) and its instruction names are the trace's.
+``CompiledFunction.scope_table()`` is that text reduced to ``{instruction:
+(scopes, phase)}`` (rules at ``parse_op_name``), built on request from the
+abstract arguments the newest compile MISS left behind; ``compiled(name)``
+finds the wrapper of a name in this process, so a reader of a device trace
+can ask for ``"train_step"``'s table.
+
 Everything is behind the ``RAY_TPU_INTERNAL_TELEMETRY=0`` kill switch;
-disabled, a wrapped call costs one attribute read and one bool check.
+disabled, a wrapped call costs one attribute read and one bool check, no
+arguments are kept and there is no table.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import os
+import re
 import threading
 import time
+import weakref
 
 from ray_tpu._private import events as _events
 from ray_tpu._private import profiling as _prof
@@ -90,6 +103,164 @@ def _abstract_key(args, kwargs):
     return (treedef, tuple(sig))
 
 
+def _abstract(args, kwargs):
+    """The call's arguments as ``jit.lower`` takes them with no array in
+    hand: shape, dtype and (where the leaf is committed to one, as jit
+    itself asks) sharding of every array leaf, all of them readable on a
+    donated array; any other leaf as it is. Lowering them gives the
+    program the call ran, under the compile cache's key for it."""
+    import jax
+
+    def leaf(x):
+        shape, dtype = getattr(x, "shape", None), getattr(x, "dtype", None)
+        if shape is None or dtype is None:
+            return x
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return jax.tree_util.tree_map(leaf, (args, kwargs))
+
+
+# the scope a train step puts around its optimizer pass
+# (`train_step.make_train_step`); a table without it is no table
+OPTIMIZER_SCOPE = "optimizer"
+# what JAX itself puts on the name stack between the user's scopes
+_STRUCTURE = frozenset((
+    "checkpoint", "rematted_computation", "while", "body", "body_pred",
+    "cond", "closed_call", "shard_map"))
+_BRANCH = re.compile(r"branch_\d+_fun\Z")
+_SCOPE = re.compile(r"[A-Za-z_]\w*\Z")
+_WRAPPED = re.compile(r"(\w+)\((.*)\)\Z", re.S)
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = ")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(")
+_FUSED = re.compile(r" fusion\(.*\), kind=\w+, calls=%?([\w.\-]+)")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+
+
+def parse_op_name(op_name: str):
+    """An HLO instruction's ``op_name`` -> ``(scopes, phase)``.
+
+    ``op_name`` is JAX's name stack when the operation was traced,
+    ``/``-joined, a transform written around the element that follows it.
+    From this JAX (0.9.0), the benchmark's Nemotron-H step (remat, a Python
+    loop over the layers) and GPT-2 step (a scan) compiled for a v5e:
+
+    ``jit(step)/jvp(blocks)/mamba/conv/jit(silu)/add``
+        forward: ``("blocks", "mamba", "conv")``
+    ``jit(step)/transpose(jvp(loss_tail))/add_any``
+        backward: ``("loss_tail",)``
+    ``jit(step)/transpose(jvp(blocks))/jvp(blocks)/checkpoint/rematted_computation/moe/router/bsd,de->bse/dot_general``
+        recompute: ``("blocks", "blocks", "moe", "router")`` (a checkpoint
+        called inside a scope names it twice; a scan's body does not)
+    ``jit(step)/transpose(jvp(blocks))/jvp(blocks)/checkpoint/attn/flash_dq/pallas_call``
+        backward: ``("blocks", "blocks", "attn", "flash_dq")``: a
+        ``custom_vjp``'s backward rule inherits its caller's scopes, and a
+        ``pallas_call``'s ``name`` is one more
+    ``jit(step)/transpose(jvp(blocks))/jvp(blocks)/checkpoint/moe/cond/branch_0_fun/transpose(jvp(jit(_through_experts)))/combine/combine/add``
+        backward: ``("blocks", "blocks", "moe", "combine", "combine")``
+    ``jit(step)/jvp(blocks)/while/body/closed_call/attention/bsd,dhk->bshk/dot_general``
+        forward: ``("blocks", "attention")``
+    ``jit(step)/optimizer/convert_element_type``
+        optimizer: ``("optimizer",)``
+
+    ``scopes``: the ``jax.named_scope`` names, outermost first. Dropped on
+    the way: the trailing primitive; ``jit(…)`` (a function's name, not a
+    scope) whole; the transforms ``jvp(…)`` / ``transpose(…)`` / ``vmap(…)``
+    around a name, the name kept; what JAX's own control flow and
+    checkpointing push (``checkpoint``, ``rematted_computation``,
+    ``while``, ``body``, ``body_pred``, ``cond``, ``branch_N_fun``,
+    ``closed_call``, ``shard_map``); and what is no identifier (the
+    scope ``jnp.einsum`` opens under its own specification,
+    ``bsd,dhk->bshk``).
+
+    ``phase``: ``optimizer`` under the step's ``optimizer`` scope; else
+    ``recompute`` under ``rematted_computation`` (the checkpointed forward
+    run again inside the backward pass); else ``backward`` under a
+    ``transpose(`` wrapper anywhere in the name (so a ``custom_vjp``'s
+    backward rule, which JAX calls while it transposes, and the forward
+    that rule differentiates once more, as `layers._one_of`'s does); else
+    ``forward``.
+
+    A fusion carries ONE ``op_name``, its root's: the table gives a fusion
+    whole to the scope and phase of its root and does not split it
+    (`scope_table_of` says what it does where the root has none)."""
+    parts, depth, start = [], 0, 0
+    for i, c in enumerate(op_name):
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+        elif c == "/" and depth == 0:
+            parts.append(op_name[start:i])
+            start = i + 1
+    scopes, transposed = [], False
+    for part in parts:              # the trailing primitive stays out
+        function = False
+        while (m := _WRAPPED.match(part)):
+            function = m.group(1) == "jit"
+            transposed = transposed or m.group(1) == "transpose"
+            part = m.group(2)
+        if not function:
+            scopes.extend(
+                name for name in part.split("/")
+                if _SCOPE.match(name) and name not in _STRUCTURE
+                and not _BRANCH.match(name))
+    if OPTIMIZER_SCOPE in scopes:
+        phase = "optimizer"
+    elif "/rematted_computation/" in op_name:
+        phase = "recompute"
+    else:
+        phase = "backward" if transposed else "forward"
+    return tuple(scopes), phase
+
+
+def scope_table_of(hlo_text: str):
+    """``{instruction name: (scopes, phase)}`` of a compiled program's text
+    (``compiled.as_text()``): every instruction that carries an
+    ``op_name``, and every fusion that carries none under the last
+    ``op_name`` inside its fused computation (the compiler wraps a fused
+    root in a ``bitcast`` of its own making, which has no metadata, and
+    then the fusion has none either: the instruction the root views is the
+    nearest that has). None where no instruction lies under the
+    ``optimizer`` scope."""
+    table, last_named, unnamed_fusions, computation = {}, {}, {}, None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        name = _INSTRUCTION.match(line)
+        if not name:
+            continue
+        op_name = _OP_NAME.search(line)
+        if op_name:
+            table[name.group(1)] = last_named[computation] = \
+                parse_op_name(op_name.group(1))
+        else:
+            fused = _FUSED.search(line)
+            if fused:
+                unnamed_fusions[name.group(1)] = fused.group(1)
+    for name, fused in unnamed_fusions.items():
+        if fused in last_named:
+            table[name] = last_named[fused]
+    if not any(phase == "optimizer" for _, phase in table.values()):
+        return None
+    return table
+
+
+# every live wrapper by the order it was made in, for `compiled`
+_LIVE: "weakref.WeakValueDictionary[int, CompiledFunction]" = \
+    weakref.WeakValueDictionary()
+_SERIAL = itertools.count()
+
+
+def compiled(name: str):
+    """The newest live ``CompiledFunction`` of this process that was made
+    under ``name`` (``"train_step"``), or None."""
+    named = [item for item in list(_LIVE.items()) if item[1]._name == name]
+    return max(named, key=lambda item: item[0])[1] if named else None
+
+
 class CompiledFunction:
     """Wraps a jitted callable with compile-cache observability.
     Transparent otherwise: unknown attributes (``lower``,
@@ -100,7 +271,12 @@ class CompiledFunction:
         self._name = name
         self._seen: set = set()
         self._seen_lock = threading.Lock()
+        # the newest compile miss's abstract arguments and, once asked
+        # for, the table built from them (False: not built yet)
+        self._abstract = None
+        self._table = False
         functools.update_wrapper(self, fn, updated=())
+        _LIVE[next(_SERIAL)] = self
 
     def __getattr__(self, item):
         if item == "_fn":
@@ -153,8 +329,32 @@ class CompiledFunction:
         # failure
         with self._seen_lock:
             self._seen.add(_abstract_key(args, kwargs))
+            self._abstract, self._table = _abstract(args, kwargs), False
         self._record_miss(start, time.perf_counter() - t0, tags)
         return out
+
+    def scope_table(self):
+        """``{HLO instruction name: (scopes, phase)}`` of the program the
+        newest compile miss built (`parse_op_name` has the rules), the
+        names being the device trace's. Built on the first request, from
+        the compiled text (the program is in the compile cache; the text
+        is dropped once parsed), and kept.
+
+        None where there is nothing to read or nothing to trust: no call
+        has compiled yet, telemetry is off, the callable is not jitted, or
+        no instruction lies under the step's ``optimizer`` scope. The last
+        is a program that has no such scope, or a STALE one: the
+        persistent compile cache's key leaves metadata out
+        (``jax_compilation_cache_include_metadata_in_key`` is False), so
+        an executable loaded from it carries the ``op_name``s of whoever
+        compiled it first, which may predate every scope."""
+        if self._table is False:
+            args = self._abstract
+            if args is None or not hasattr(self._fn, "lower"):
+                return None
+            self._table = scope_table_of(
+                self._fn.lower(*args[0], **args[1]).compile().as_text())
+        return self._table
 
     def _record_failed_call(self, args, kwargs, start, dur, tags):
         """Error-path classification (cost is irrelevant here): the

@@ -22,6 +22,7 @@ from ray_tpu._private import step_anatomy as _sa
 from ray_tpu._private import telemetry as _tm
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.compile_watch import (
+    OPTIMIZER_SCOPE,
     CompiledFunction,
     configure_compile_cache,
 )
@@ -134,7 +135,10 @@ def make_train_step(
     """loss_fn(params, batch) -> (scalar_loss, metrics_dict).
 
     Returns jitted step(state, batch) -> (state, metrics): one program,
-    gradients reduced over the mesh by the partitioner. The steps whose
+    gradients reduced over the mesh by the partitioner, the optimizer's
+    pass (and the gradient's norm) under the `jax.named_scope`
+    ``optimizer``, by which `CompiledFunction.scope_table` tells the step's
+    fourth phase and a table worth trusting. The steps whose
     gradients cross hosts over the collective plane are built on this
     module by `ray_tpu.train`.
     """
@@ -143,11 +147,12 @@ def make_train_step(
         batch = _constrain_batch(batch, mesh, batch_spec)
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, batch)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
         metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+            metrics["grad_norm"] = optax.global_norm(grads)
         return (
             TrainState(step=state.step + 1, params=params,
                        opt_state=opt_state),

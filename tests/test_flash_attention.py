@@ -8,6 +8,7 @@ import pytest
 
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.parallel.ring_attention import reference_attention
+from tests.test_flash_window import _calls
 
 
 def _out_and_grads(attend, q, k, v, w):
@@ -202,3 +203,21 @@ def test_tile_ranges_cover_exactly_the_unmasked_tiles():
             if qi >= first:
                 issued_by_cols.add((qi, t))
     assert issued_by_rows == issued_by_cols
+
+
+@pytest.mark.parametrize("window,names", [
+    (None, ["flash_dkv", "flash_dq", "flash_fwd"]),
+    (1024, ["flash_dkv", "flash_dq", "flash_fwd"]),   # covers the sequence
+    (200, ["flash_window_dkv", "flash_window_dq", "flash_window_fwd"]),
+])
+def test_the_three_calls_carry_their_names_in_the_jaxpr(window, names):
+    """`pallas_call`'s `name` is the HLO instruction's and the trace
+    event's: the full-causal calls have theirs, the windowed ones keep
+    theirs, and a reader tells the kernels by it."""
+    q = jnp.zeros((1, 1024, 2, 64), jnp.bfloat16)
+
+    def f(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, window=window)
+                       .astype(jnp.float32))
+    jaxpr = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(q, q, q).jaxpr
+    assert sorted(name for name, _ in _calls(jaxpr, [])) == names
