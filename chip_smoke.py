@@ -65,34 +65,55 @@ def _rel_err(got, ref) -> float:
     return float(np.max(np.abs(got - ref)) / max(1.0, np.max(np.abs(ref))))
 
 
+# (B, S, H, KV, D), window: GPT-2's shape (one major block), and heads of
+# 128 over four major blocks on grouped KV heads, causal and with a window
+# that reaches three of them (the D 128 cells' form, PR 39)
+KERNEL_CASES = {
+    "b2s1024h12d64": ((2, 1024, 12, 12, 64), None),
+    "b1s8192h8kv2d128": ((1, 8192, 8, 2, 128), None),
+    "b1s8192h8kv2d128w4096": ((1, 8192, 8, 2, 128), 4096),
+}
+
+
 def check_flash_kernels() -> dict:
     """Compiled flash forward + both backward kernels against
-    reference_attention at B=2, S=1024, H=12, D=64 in bf16: the output
-    and all three gradients. Returns the four relative errors."""
+    reference_attention in bf16 at each of `KERNEL_CASES`: the output and
+    all three gradients. Returns the four relative errors of each case."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.parallel.ring_attention import reference_attention
 
-    shape = (2, 1024, 12, 64)
-    q, k, v, w = (jax.random.normal(key, shape, jnp.bfloat16)
-                  for key in jax.random.split(jax.random.PRNGKey(7), 4))
+    def reference(q, k, v, window):
+        group = q.shape[2] // k.shape[2]
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        return reference_attention(q, k, v, window=window)
 
-    def out_and_grads(attend):
-        def run(q, k, v):
-            o, vjp = jax.vjp(attend, q, k, v)
-            return (o, *vjp(w))      # w: a non-symmetric cotangent
-        return jax.jit(run)(q, k, v)
+    errs = {}
+    for case, ((B, S, H, KV, D), window) in KERNEL_CASES.items():
+        q, k, v, w = (jax.random.normal(key, (B, S, h, D), jnp.bfloat16)
+                      for key, h in zip(
+                          jax.random.split(jax.random.PRNGKey(7), 4),
+                          (H, KV, KV, H)))
 
-    got = out_and_grads(flash_attention)
-    # the TPU's default float32 matmul is a single bf16 pass; the
-    # reference must not carry the error it is there to expose
-    with jax.default_matmul_precision("highest"):
-        ref = out_and_grads(reference_attention)
-    errs = {name: _rel_err(g, r)
-            for name, g, r in zip(("o", "dq", "dk", "dv"), got, ref)}
-    bad = {n: e for n, e in errs.items() if not e <= KERNEL_TOL}
+        def out_and_grads(attend):
+            def run(q, k, v):
+                o, vjp = jax.vjp(attend, q, k, v)
+                return (o, *vjp(w))      # w: a non-symmetric cotangent
+            return jax.jit(run)(q, k, v)
+
+        got = out_and_grads(functools.partial(flash_attention, window=window))
+        # the TPU's default float32 matmul is a single bf16 pass; the
+        # reference must not carry the error it is there to expose
+        with jax.default_matmul_precision("highest"):
+            ref = out_and_grads(functools.partial(reference, window=window))
+        errs[case] = {name: _rel_err(g, r) for name, g, r in
+                      zip(("o", "dq", "dk", "dv"), got, ref)}
+    bad = {f"{case}.{n}": e for case, by_name in errs.items()
+           for n, e in by_name.items() if not e <= KERNEL_TOL}
     if bad:
         raise AssertionError(
             f"compiled flash kernels disagree with reference_attention "

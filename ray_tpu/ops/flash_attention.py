@@ -7,18 +7,30 @@ schedule, derived from ``(S, D, dtype)`` by :func:`tile_plan`:
 
 * **Grid** ``(B·H, q-major blocks, kv-major blocks)`` (dk/dv: kv-major
   parallel, q-major sequential). A major block is as much of the sequence
-  as a stated VMEM budget holds — the whole head at S = 1,024 — so K/V (or
-  Q/dO) are fetched once per head and no grid point is spent on a block the
-  causal mask empties; with several major blocks the index maps clamp to
-  the last needed block, so skipped ones are not fetched either.
+  as a stated VMEM budget holds, and at most ``MAJOR_ROWS`` rows — the whole
+  head at S = 1,024 — so K/V (or Q/dO) are fetched once per head there and
+  no grid point is spent on a block the causal mask empties; with several
+  major blocks the index maps clamp to the last needed block, so skipped
+  ones are not fetched either.
 * **Inner loops** over ``tile_q × tile_k`` score tiles inside the kernel
   (each kernel has its own tile, ``_TILES``). The forward and dq walk each
   q tile's kv tiles, dk/dv walks each kv tile's q tiles (on the TRANSPOSED
   score tile ``k·qᵀ``, so that ``pᵀ·dO`` and ``dSᵀ·q`` are plain
   contractions and lse/delta are used as the rows they are stored as). Trip
   counts come from the diagonal (:func:`_kv_tiles`, :func:`_q_tiles`):
-  tiles wholly above it are never issued. With one major block the counts
-  are static and the loops unrolled; with several they are traced.
+  tiles wholly above it are never issued.
+* **Every trip count is a Python int, whatever the number of major blocks**
+  (PR 39), and every loop over tiles unrolled. What a grid step walks
+  depends on where its q-major block lies FROM its kv-major block, not on
+  where either lies, and that offset takes a handful of values known when
+  the kernel is traced: the diagonal block, a whole block, with a window
+  the block its lower edge crosses; non-causal, the last kv block with its
+  padded columns or any other. :func:`_grid_cases` lists them, each kernel
+  holds one schedule a case under ``pl.when`` on the program ids
+  (:func:`_walk`, every row of tiles written out with its own trip
+  counts), and one major block is the one case with no condition.
+  :func:`static_tile_share` counts, from the same cases, the share of the
+  issued tiles such a loop walks (1.0).
 * **Masks only where the diagonal is.** Each loop is split: tiles wholly
   below the diagonal run with no iota/compare/select; only the tiles that
   cross it (or, non-causal, the ones holding padded columns) build a mask.
@@ -73,6 +85,16 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 # What one grid step may hold in VMEM (double-buffered blocks + scratch), of
 # the 16 MiB a Mosaic kernel gets by default; the rest is the compiler's.
 VMEM_BUDGET_BYTES = 10 * 1024 * 1024
+# The most rows of a major block. A kernel holds the code of every schedule
+# a grid step can have (`_grid_cases`), and the diagonal block's, every tile
+# written out, grows with the square of the block: 20 / 10 / 36 tile bodies
+# (forward / dq / dk/dv) at 1,024 rows, 72 / 36 / 136 at the 2,048 the
+# budget would hold at D = 128 — and a windowed call holds a second
+# triangle. Pallas traces and lowers that code for every call site of every
+# process: at 2,048 rows it took 2–3 × the parent's seconds for 9 % less
+# kernel time (PR 39: 115.0 ms a step for 126.5 in smallthinker4l-b1s16k).
+# At 1,024 rows a K/V block still feeds 1,024 FLOP a byte fetched.
+MAJOR_ROWS = 1024
 # Score-tile (rows, columns) of each kernel, swept on the v5e at
 # [192, 1024, 64] bf16 (PR 26): the forward likes a wide tile (fewer softmax
 # row statistics per score element), dq a square one, dk/dv — three live
@@ -119,8 +141,11 @@ def tile_plan(seq_len: int, head_dim: int, dtype,
     than the sequence rounded up to 128; ``block_q``/``block_k`` override
     the rows/columns of all three (tests). The kernels share the padded
     length — the next multiple of the largest tile — and the major block:
-    the largest multiple of the tiles that divides the padded length and
-    fits ``VMEM_BUDGET_BYTES``.
+    the largest multiple of the tiles that divides the padded length, fits
+    ``VMEM_BUDGET_BYTES`` and has at most ``MAJOR_ROWS`` rows. ``MAJOR_ROWS``
+    is the limit that binds for bf16 up to head_dim 256 and float32 up to
+    128 (1,024 rows take 4.6–8.1 MiB there); the budget binds beyond
+    (float32 at 256 and bf16 at 512: 512 rows; float32 at 512: 256).
     """
     cap = _round_up(seq_len, _LANES)
     tiles = {name: (min(_round_up(block_q or tq, _LANES), cap),
@@ -132,7 +157,7 @@ def tile_plan(seq_len: int, head_dim: int, dtype,
         raise ValueError(f"tiles {tiles} do not nest")
     s_pad = _round_up(seq_len, step)
     itemsize = jnp.dtype(dtype).itemsize
-    major = max((m for m in range(step, s_pad + 1, step)
+    major = max((m for m in range(step, max(MAJOR_ROWS, step) + 1, step)
                  if s_pad % m == 0
                  and vmem_bytes(m, head_dim, itemsize) <= VMEM_BUDGET_BYTES),
                 default=step)
@@ -242,24 +267,134 @@ def _seq_blocks(plan: TilePlan, window: Optional[int]) -> int:
 
 def _first_block(i, n_major: int, n_seq: int, *, trailing: bool):
     """The first of the `n_seq` consecutive major blocks that grid row `i`
-    walks. `trailing` (forward, dq): the kv blocks that END at the diagonal
-    block, ``i − n_seq + 1 … i``, shifted up where that would start before
-    block 0 (the surplus then lies past the diagonal, where the causal bound
-    issues nothing). Otherwise (dk/dv): the q blocks that START at it, ``i …
-    i + n_seq − 1``, shifted down at the sequence's end likewise."""
+    (a program id or a Python int) walks. `trailing` (forward, dq): the kv
+    blocks that END at the diagonal block, ``i − n_seq + 1 … i``, shifted up
+    where that would start before block 0 (the surplus then lies past the
+    diagonal, where the causal bound issues nothing). Otherwise (dk/dv): the
+    q blocks that START at it, ``i … i + n_seq − 1``, shifted down at the
+    sequence's end likewise."""
     if trailing:
-        return jnp.maximum(i - (n_seq - 1), 0)
-    return jnp.minimum(i, n_major - n_seq)
+        lo = i - (n_seq - 1)
+        return max(lo, 0) if isinstance(i, int) else jnp.maximum(lo, 0)
+    hi = n_major - n_seq
+    return min(i, hi) if isinstance(i, int) else jnp.minimum(i, hi)
 
 
-def _seq_block_start(plan: TilePlan, n_seq: int, *, trailing: bool):
-    """First row of the sequentially walked block of this grid step (axis
-    2), as `_block_start` gives it when every block is walked."""
+def _step_blocks(plan: TilePlan, window: Optional[int], *, transposed: bool,
+                 ids=None):
+    """``(q-major block, kv-major block)`` of a grid step — of this one, by
+    its program ids, or of step ``ids = (i, j)`` given as Python ints (the
+    counters). The forward and dq walk a q block's kv blocks, dk/dv
+    (`transposed`) a kv block's q blocks. One major block is (0, 0) and
+    reads no program id."""
     n_major = plan.s_pad // plan.major
-    if n_seq == n_major:
-        return _block_start(2, n_major, plan.major)
-    first = _first_block(pl.program_id(1), n_major, n_seq, trailing=trailing)
-    return (first + pl.program_id(2)) * plan.major
+    if n_major == 1:
+        return 0, 0
+    i, j = ids or (pl.program_id(1), pl.program_id(2))
+    n_seq = _seq_blocks(plan, window)
+    if n_seq < n_major:
+        j = _first_block(i, n_major, n_seq, trailing=not transposed) + j
+    return (j, i) if transposed else (i, j)
+
+
+def _row_bounds(plan: TilePlan, row0_major: int, col0_major: int, *,
+                transposed: bool, causal: bool, seq_len: int,
+                window: Optional[int]):
+    """What a grid step walks, row of tiles by row of tiles, for a q-major
+    block that starts at row `row0_major` and a kv-major block at column
+    `col0_major`: a q tile's kv tiles (`transposed`, dk/dv: a kv tile's q
+    tiles) as ``(bounds, masked_first)`` for `_phases`."""
+    tq, tk, major = plan.tile_q, plan.tile_k, plan.major
+    kw = dict(plan=plan, causal=causal, seq_len=seq_len, window=window)
+    if transposed:
+        return [(_q_bounds(col0_major + ki * tk, row0_major, major // tq,
+                           **kw), True) for ki in range(major // tk)]
+    return [_kv_bounds(row0_major + qi * tq, col0_major, major // tk, **kw)
+            for qi in range(major // tq)]
+
+
+def _issued(rows) -> int:
+    return sum(bounds[-1] - bounds[0] for bounds, _ in rows)
+
+
+def _masked(rows) -> int:
+    """The tiles among them that build a mask (`_phases`' masked ranges)."""
+    return sum(hi - lo for bounds, masked_first in rows
+               for lo, hi in list(zip(bounds, bounds[1:]))[not masked_first::2])
+
+
+class _Case(NamedTuple):
+    when: object     # True, False, or a traced bool: does this step run it
+    row0: int        # the q-major block's first row and the kv-major
+    col0: int        # block's first column, as the bounds and masks see them
+    rows: list       # `_row_bounds` there
+
+
+def _grid_cases(plan: TilePlan, q_block, kv_block, *, transposed: bool,
+                causal: bool, seq_len: int, window: Optional[int]):
+    """The schedules a grid step can have, each with Python-int trip counts,
+    and the condition under which a step runs it (`_step_blocks` gives the
+    blocks: traced in a kernel of several major blocks, else ints).
+
+    The trip counts depend on where the q-major block lies FROM the kv-major
+    block, ``d = q_block − kv_block``, not on where either lies: causal,
+    ``d < 0`` issues nothing, ``d = 0`` is the diagonal block, and ``d ≥ 1``
+    is a whole block, or with a window whatever its lower edge leaves of
+    one (``d < `_seq_blocks```), so a case sees the q block at row
+    ``d · major`` and the kv block at column 0. Non-causal only the last kv
+    block can hold padded columns. Consecutive offsets that walk the same
+    tiles and build no mask (whole blocks) are one case."""
+    kw = dict(transposed=transposed, causal=causal, seq_len=seq_len,
+              window=window)
+    major = plan.major
+
+    def case(when, row0, col0):
+        return _Case(when, row0, col0, _row_bounds(plan, row0, col0, **kw))
+
+    if not causal:
+        last = plan.s_pad // major - 1
+        if plan.s_pad == seq_len or last == 0:
+            return [case(True, 0, 0)]
+        return [case(kv_block < last, 0, 0),
+                case(kv_block == last, 0, last * major)]
+    d = q_block - kv_block
+    cases, spans = [], []          # spans: the [lo, hi] of d of each case
+    for off in range(_seq_blocks(plan, window)):
+        new = case(None, off * major, 0)
+        if cases and new.rows == cases[-1].rows and not _masked(new.rows):
+            spans[-1][1] = off
+        elif _issued(new.rows):
+            cases.append(new)
+            spans.append([off, off])
+    return [c._replace(when=(d == lo) if lo == hi else (d >= lo) & (d <= hi))
+            for c, (lo, hi) in zip(cases, spans)]
+
+
+def static_tile_share(plan: TilePlan, seq_len: int,
+                      window: Optional[int] = None, *, causal: bool = True,
+                      transposed: bool = False) -> float:
+    """Of the tiles a kernel issues over a head's grid steps (by the masks'
+    own bounds at each step's blocks), the share a loop with a Python-int
+    trip count walks: the tiles of the case `_grid_cases` gives the step.
+    The sibling of `issued_area_ratio`: 1.0 says every step of the grid
+    meets a case and the case issues exactly the step's tiles (0.0 for
+    several major blocks before PR 39, whose bounds came from program ids).
+    `transposed`: dk/dv's walk."""
+    n_major, major = plan.s_pad // plan.major, plan.major
+    kw = dict(transposed=transposed, causal=causal, seq_len=seq_len,
+              window=window)
+    issued = static = 0
+    for i in range(n_major):
+        for j in range(_seq_blocks(plan, window)):
+            q_block, kv_block = _step_blocks(plan, window,
+                                             transposed=transposed,
+                                             ids=(i, j))
+            issued += _issued(_row_bounds(plan, q_block * major,
+                                          kv_block * major, **kw))
+            static += sum(_issued(case.rows) for case in
+                          _grid_cases(plan, q_block, kv_block, **kw)
+                          if case.when)
+    return static / issued
 
 
 def issued_area_ratio(plan: TilePlan, seq_len: int,
@@ -358,12 +493,6 @@ def _cols_to_rows(cols):
     return jnp.transpose(cols)[:8]
 
 
-def _block_start(axis: int, n_blocks: int, size: int):
-    """First row of this grid step's block: a Python int when there is one
-    block, so the loops it bounds have static trip counts."""
-    return 0 if n_blocks == 1 else pl.program_id(axis) * size
-
-
 def _keep(shape, row0, col0, *, causal, seq_len, transposed=False,
           window=None):
     """The mask of a tile that crosses the diagonal (causal) or holds padded
@@ -389,13 +518,12 @@ def _phases(bounds, body, carry, *, masked_first: bool):
     return carry
 
 
-def _loop(lo, hi, body, carry):
-    """fori_loop; unrolled when the bounds are static (one major block: at
-    most major / tile trips), so that the scheduler overlaps one tile's
-    softmax with the next tile's matmul — 1.8× on the v5e (PR 26)."""
-    if all(isinstance(b, int) for b in (lo, hi)):
-        return jax.lax.fori_loop(lo, hi, body, carry, unroll=True)
-    return jax.lax.fori_loop(lo, hi, body, carry)
+def _loop(lo: int, hi: int, body, carry):
+    """fori_loop over tiles, unrolled: every trip count of the kernels is a
+    Python int (`_grid_cases`), so that the scheduler overlaps one tile's
+    softmax with the next tile's matmul — 1.8× on the v5e at D 64 (PR 26),
+    2.2× a call at D 128 (PR 39)."""
+    return jax.lax.fori_loop(lo, hi, body, carry, unroll=True)
 
 
 def _ds(i, size: int):
@@ -422,16 +550,35 @@ def _q_bounds(col0, row0, n_tiles, *, plan, causal, seq_len, window):
     return _window_q_tiles(col0, row0, n_tiles, plan=plan, window=window)
 
 
+def _walk(row, plan: TilePlan, *, transposed: bool, causal: bool,
+          seq_len: int, window: Optional[int]):
+    """Run ``row(i, case, bounds, masked_first)`` for every row of tiles of
+    the case this grid step meets (`_grid_cases`), under `pl.when` where
+    the grid has more than one: every row written out with its own trip
+    counts. (A whole block's rows are all alike, and ONE loop around one
+    row's code holds less of it — and ran the forward 1.9 × slower at
+    [28, 16384, 128] on the v5e, for no `setup_s` that showed: PR 39,
+    `PERF.md` §6.)"""
+    blocks = _step_blocks(plan, window, transposed=transposed)
+    for case in _grid_cases(plan, *blocks, transposed=transposed,
+                            causal=causal, seq_len=seq_len, window=window):
+        def run(case=case):
+            for i, (bounds, masked_first) in enumerate(case.rows):
+                if bounds[-1] > bounds[0]:
+                    row(i, case, bounds, masked_first)
+        if case.when is True:
+            run()
+        elif case.when is not False:
+            pl.when(case.when)(run)
+
+
 # -------------------------------------------------------------------- forward
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale, causal, plan, seq_len, window=None):
     tq, tk, major = plan.tile_q, plan.tile_k, plan.major
-    n_major = plan.s_pad // major
     n_seq = _seq_blocks(plan, window)
     head_dim = q_ref.shape[-1]
     j = pl.program_id(2)
-    row0_major = _block_start(1, n_major, major)
-    col0_major = _seq_block_start(plan, n_seq, trailing=True)
 
     @pl.when(j == 0)
     def _init():
@@ -439,22 +586,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    for qi in range(major // tq):
+    def row(qi, case, bounds, masked_first):
         rows = slice(qi * tq, (qi + 1) * tq)
-        row0 = row0_major + qi * tq
         q = _scaled(q_ref[0, rows, :], scale)
-        bounds, masked_first = _kv_bounds(
-            row0, col0_major, major // tk, plan=plan, causal=causal,
-            seq_len=seq_len, window=window)
 
         def body(t, carry, masked):
             m, l, acc = carry               # [tq,128], [tq,128], [tq,D]
             cols = _ds(t, tk)
             s = _dot(q, k_ref[0, cols, :], _NT)              # [tq, tk]
             if masked:
-                s = jnp.where(_keep(s.shape, row0, col0_major + t * tk,
-                                    causal=causal, seq_len=seq_len,
-                                    window=window),
+                s = jnp.where(_keep(s.shape, case.row0 + qi * tq,
+                                    case.col0 + t * tk, causal=causal,
+                                    seq_len=seq_len, window=window),
                               s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
@@ -471,6 +614,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[rows, :] = m
         l_scr[rows, :] = l
         acc_scr[rows, :] = acc
+
+    _walk(row, plan, transposed=False, causal=causal, seq_len=seq_len,
+          window=window)
 
     @pl.when(j == n_seq - 1)
     def _finish():
@@ -597,36 +743,29 @@ def _flash_vjp_fwd(q, k, v, heads, scale, causal, block_q, block_k,
 # ------------------------------------------------------------------- backward
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_scr, *, scale, causal, plan, seq_len, window=None):
-    tq, tk, major = plan.tile_q, plan.tile_k, plan.major
-    n_major = plan.s_pad // major
+    tq, tk = plan.tile_q, plan.tile_k
     n_seq = _seq_blocks(plan, window)
     j = pl.program_id(2)
-    row0_major = _block_start(1, n_major, major)
-    col0_major = _seq_block_start(plan, n_seq, trailing=True)
 
     @pl.when(j == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    for qi in range(major // tq):
+    def row(qi, case, bounds, masked_first):
         rows = slice(qi * tq, (qi + 1) * tq)
-        row0 = row0_major + qi * tq
         q = _scaled(q_ref[0, rows, :], scale)
         do = do_ref[0, rows, :]
         lse = _lanes(_rows_to_cols(lse_ref[0, qi]), tk)       # [tq, tk]
         delta = _lanes(_rows_to_cols(delta_ref[0, qi]), tk)
-        bounds, masked_first = _kv_bounds(
-            row0, col0_major, major // tk, plan=plan, causal=causal,
-            seq_len=seq_len, window=window)
 
         def body(t, dq, masked):
             cols = _ds(t, tk)
             k = k_ref[0, cols, :]
             s = _dot(q, k, _NT)                                # [tq, tk]
             if masked:
-                s = jnp.where(_keep(s.shape, row0, col0_major + t * tk,
-                                    causal=causal, seq_len=seq_len,
-                                    window=window),
+                s = jnp.where(_keep(s.shape, case.row0 + qi * tq,
+                                    case.col0 + t * tk, causal=causal,
+                                    seq_len=seq_len, window=window),
                               s, NEG_INF)
             p = jnp.exp(s - lse)
             dp = _dot(do, v_ref[0, cols, :], _NT)              # do · vᵀ
@@ -636,6 +775,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_scr[rows, :] = _phases(bounds, body, dq_scr[rows, :],
                                   masked_first=masked_first)
 
+    _walk(row, plan, transposed=False, causal=causal, seq_len=seq_len,
+          window=window)
+
     @pl.when(j == n_seq - 1)
     def _finish():
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
@@ -644,26 +786,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                     dv_ref, dk_scr, dv_scr, *, scale, causal, plan, seq_len,
                     window=None):
-    tq, tk, major = plan.tile_q, plan.tile_k, plan.major
-    n_major = plan.s_pad // major
+    tq, tk = plan.tile_q, plan.tile_k
     n_seq = _seq_blocks(plan, window)
-    j = pl.program_id(2)
-    col0_major = _block_start(1, n_major, major)   # kv-major: parallel
-    # q-major: accumulated
-    row0_major = _seq_block_start(plan, n_seq, trailing=False)
+    j = pl.program_id(2)   # kv-major blocks parallel, q-major accumulated
 
     @pl.when(j == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    for ki in range(major // tk):
+    def row(ki, case, bounds, masked_first):
         cols = slice(ki * tk, (ki + 1) * tk)
-        col0 = col0_major + ki * tk
         k = _scaled(k_ref[0, cols, :], scale)
         v = v_ref[0, cols, :]
-        bounds = _q_bounds(col0, row0_major, major // tq, plan=plan,
-                           causal=causal, seq_len=seq_len, window=window)
 
         def body(t, carry, masked):
             dk, dv = carry
@@ -672,9 +807,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
             do = do_ref[0, rows, :]
             st = _dot(k, q, _NT)                               # [tk, tq]
             if masked:
-                st = jnp.where(_keep(st.shape, row0_major + t * tq, col0,
-                                     causal=causal, seq_len=seq_len,
-                                     transposed=True, window=window),
+                st = jnp.where(_keep(st.shape, case.row0 + t * tq,
+                                     case.col0 + ki * tk, causal=causal,
+                                     seq_len=seq_len, transposed=True,
+                                     window=window),
                                st, NEG_INF)
             pt = jnp.exp(st - lse_ref[0, t, 0:1, :])           # row lse
             dv = dv + _dot(pt.astype(do.dtype), do, _NN)       # pᵀ · do
@@ -684,9 +820,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
             return dk, dv
 
         dk, dv = _phases(bounds, body, (dk_scr[cols, :], dv_scr[cols, :]),
-                         masked_first=True)
+                         masked_first=masked_first)
         dk_scr[cols, :] = dk
         dv_scr[cols, :] = dv
+
+    _walk(row, plan, transposed=True, causal=causal, seq_len=seq_len,
+          window=window)
 
     @pl.when(j == n_seq - 1)
     def _finish():

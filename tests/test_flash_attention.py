@@ -8,7 +8,7 @@ import pytest
 
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.parallel.ring_attention import reference_attention
-from tests.test_flash_window import _calls
+from tests.test_flash_window import _calls, _eqns
 
 
 def _out_and_grads(attend, q, k, v, w):
@@ -86,8 +86,10 @@ def test_flash_bf16_within_the_chip_smoke_tolerance():
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 def test_flash_several_major_blocks(monkeypatch, causal):
     """A VMEM budget that holds a quarter of the sequence: four q-major and
-    four kv-major grid blocks, traced loop bounds, clamped index maps; the
-    padded tail (S = 450 of 512) sits in the last one."""
+    four kv-major grid blocks, one schedule of Python-int trip counts for
+    each offset between them (`_grid_cases`; traced loop bounds before
+    PR 39), clamped index maps; the padded tail (S = 450 of 512) sits in the
+    last one."""
     monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 1_100_000)
     S, D = 450, 64
     assert fa.tile_plan(S, D, jnp.float32, 128, 128).fwd == \
@@ -109,8 +111,10 @@ def test_flash_several_major_blocks(monkeypatch, causal):
 def test_flash_head_dim_128_several_major_blocks(monkeypatch, causal):
     """The OLMoE cell's form at a size the interpreter can run: heads of
     128 and more than one major block (three of 128 rows under a budget
-    that holds a third of S = 384), so every kernel's loops are traced and
-    its index maps clamped, as at [32, 4096, 128] on the chip."""
+    that holds a third of S = 384), so every kernel holds the diagonal
+    block's schedule and the whole block's under `pl.when` (traced loops
+    before PR 39) and its index maps are clamped, as at [32, 4096, 128] on
+    the chip."""
     monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 1_100_000)
     S, D = 384, 128
     assert fa.tile_plan(S, D, jnp.float32, 128, 128).dkv == \
@@ -129,14 +133,17 @@ def test_flash_head_dim_128_several_major_blocks(monkeypatch, causal):
 
 
 def test_tile_plan_at_the_olmoe_cells_shape():
-    """[32, 4096, 128] bf16 (olmoe1l-b2s4k): the budget holds half the
-    sequence, so each kernel has two major blocks a side and traced loops;
-    the causal schedule issues 6 % (3 % in dk/dv's smaller tile) more score
-    elements than the mask keeps."""
+    """[32, 4096, 128] bf16 (olmoe1l-b2s4k): the budget would hold half the
+    sequence and `MAJOR_ROWS` holds a quarter (PR 39: the code a kernel
+    writes out grows with the square of the block), so each kernel has four
+    major blocks a side; the causal schedule issues 6 % (3 % in dk/dv's
+    smaller tile) more score elements than the mask keeps, whatever the
+    block."""
     plans = fa.tile_plan(4096, 128, jnp.bfloat16)
-    assert plans == fa.TilePlans(fwd=fa.TilePlan(128, 256, 2048, 4096),
-                                 dq=fa.TilePlan(256, 256, 2048, 4096),
-                                 dkv=fa.TilePlan(128, 128, 2048, 4096))
+    assert plans == fa.TilePlans(fwd=fa.TilePlan(128, 256, 1024, 4096),
+                                 dq=fa.TilePlan(256, 256, 1024, 4096),
+                                 dkv=fa.TilePlan(128, 128, 1024, 4096))
+    assert fa.MAJOR_ROWS == 1024
     assert fa.vmem_bytes(2048, 128, 2) <= fa.VMEM_BUDGET_BYTES \
         < fa.vmem_bytes(4096, 128, 2)
     assert fa.issued_area_ratio(plans.fwd, 4096) == pytest.approx(1.0622,
@@ -161,7 +168,8 @@ def test_tile_plan_shapes(S, D):
         assert plan.major % plan.tile_q == 0 and plan.major % plan.tile_k == 0
         assert plan.s_pad % plan.major == 0
         assert fa.vmem_bytes(plan.major, D, 2) <= fa.VMEM_BUDGET_BYTES
-    if S <= 2048:
+        assert plan.major <= fa.MAJOR_ROWS
+    if S <= 1024:
         assert plans.fwd.major == plans.fwd.s_pad   # one block: K/V once a head
 
 
@@ -221,3 +229,61 @@ def test_the_three_calls_carry_their_names_in_the_jaxpr(window, names):
                        .astype(jnp.float32))
     jaxpr = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(q, q, q).jaxpr
     assert sorted(name for name, _ in _calls(jaxpr, [])) == names
+
+
+def _loops(jaxpr):
+    """Every loop of a jaxpr, kernels' bodies included: a `scan` (a
+    Python-int trip count: its length) or a `while` (a traced bound)."""
+    return [(eqn.primitive.name, eqn.params.get("length"))
+            for eqn in _eqns(jaxpr) if eqn.primitive.name in ("scan", "while")]
+
+
+@pytest.mark.parametrize("S,H,KV,D,window", [
+    (1024, 2, 2, 64, None),       # the GPT-2 cells: one major block
+    (4096, 2, 2, 128, None),      # olmoe1l-b2s4k: 4 x 4 major blocks
+    (8192, 4, 1, 128, None),      # nemotronh9l-b1s8k: 8 x 8, grouped KV heads
+    (16384, 2, 1, 128, None),     # smallthinker4l-b1s16k, the global layer
+    (16384, 2, 1, 128, 4096),     # and its window layers: 16 x 5 grid steps
+])
+def test_every_loop_of_the_kernels_has_a_static_trip_count(S, H, KV, D,
+                                                           window):
+    """The three kernels as the D 128 cells trace them (PR 39): no loop in
+    a `pallas_call`'s body has a traced bound, whatever the number of major
+    blocks — a traced one is a `while`, which the TPU scheduler does not
+    overlap across trips (1.8 x a tile, PR 26; 2.7 x a call at
+    [28, 16384, 128]) — and `static_tile_share`, computed from the same
+    cases, reads 1.0."""
+    q = jnp.zeros((1, S, H, D), jnp.bfloat16)
+    k = jnp.zeros((1, S, KV, D), jnp.bfloat16)
+
+    def f(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, window=window)
+                       .astype(jnp.float32))
+    jaxpr = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(q, k, k).jaxpr
+    assert len(_calls(jaxpr, [])) == 3
+    loops = _loops(jaxpr)
+    assert loops and all(kind == "scan" for kind, _ in loops), \
+        [loop for loop in loops if loop[0] != "scan"][:5]
+    plans = fa.tile_plan(S, D, jnp.bfloat16)
+    assert plans.fwd.s_pad // plans.fwd.major == S // 1024
+    for plan, transposed in ((plans.fwd, False), (plans.dq, False),
+                             (plans.dkv, True)):
+        assert fa.static_tile_share(plan, S, window,
+                                    transposed=transposed) == 1.0
+        # a walk's trip counts are at most a major block's tiles
+        assert max(n for _, n in loops) <= plan.major // 128
+
+
+def test_static_tile_share_counts_the_cases_not_the_claim(monkeypatch):
+    """`static_tile_share` sums what `_grid_cases` gives each grid step
+    against what the masks ask of that step: a case that a step does not
+    meet, or one with another step's trip counts, shows as a share off 1.0."""
+    plan = fa.TilePlan(128, 128, 128, 512)
+    assert fa.static_tile_share(plan, 512) == 1.0
+    assert fa.static_tile_share(plan, 450, causal=False) == 1.0
+    assert fa.static_tile_share(plan, 512, 257, transposed=True) == 1.0
+    cases = fa._grid_cases
+    monkeypatch.setattr(fa, "_grid_cases", lambda *args, **kw: [
+        case for case in cases(*args, **kw) if fa._masked(case.rows)])
+    # 4 diagonal tiles of the 10 the causal grid issues
+    assert fa.static_tile_share(plan, 512) == pytest.approx(0.4)

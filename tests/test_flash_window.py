@@ -56,9 +56,11 @@ def test_windowed_kernels_against_plain_masked_attention(S, W, H, KV):
 
 @pytest.mark.parametrize("W", [100, 257, 600])
 def test_windowed_kernels_over_several_major_blocks(monkeypatch, W):
-    """The traced-loop form the cell runs: four major blocks a side, of
-    which a window reaches 2, 3 or all 4, so the sequential grid axis is
-    shorter than the parallel one and its blocks are `_first_block`'s."""
+    """The form the cell runs: four major blocks a side, of which a window
+    reaches 2, 3 or all 4, so the sequential grid axis is shorter than the
+    parallel one, its blocks are `_first_block`'s, and a kernel holds one
+    schedule of Python-int trip counts for each block a window reaches
+    (`_grid_cases`; traced loop bounds before PR 39)."""
     monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", 300 * 1024)
     plan = fa.tile_plan(1024, 32, jnp.float32).fwd
     assert plan.major == 256 and plan.s_pad == 1024
@@ -126,13 +128,14 @@ def test_window_tile_ranges_cover_exactly_the_kept_tiles(W):
 
 
 def test_window_plan_at_the_smallthinker_cells_shape():
-    """[28, 16384, 128] bf16, window 4,096 (smallthinker4l-b1s16k): eight
-    major blocks a side of which a window reaches three; what the windowed
-    forward keeps, issues and skips, by the kernel's own trip counts."""
+    """[28, 16384, 128] bf16, window 4,096 (smallthinker4l-b1s16k): sixteen
+    major blocks a side (`MAJOR_ROWS`, PR 39; eight before) of which a
+    window reaches five; what the windowed forward keeps, issues and skips,
+    by the kernel's own trip counts."""
     S, W = 16384, 4096
     plans = fa.tile_plan(S, 128, jnp.bfloat16)
-    assert plans.fwd == fa.TilePlan(128, 256, 2048, S)
-    assert fa._seq_blocks(plans.fwd, W) == 3 == fa._seq_blocks(plans.dkv, W)
+    assert plans.fwd == fa.TilePlan(128, 256, 1024, S)
+    assert fa._seq_blocks(plans.fwd, W) == 5 == fa._seq_blocks(plans.dkv, W)
     plan = fa.window_plan(S, W, plans.fwd)
     kept = W * S - W * (W - 1) // 2
     assert plan["kept_area"] == kept == 58_722_304
@@ -152,26 +155,73 @@ def test_window_plan_at_the_smallthinker_cells_shape():
     assert plan["tiles_issued"] + plan["tiles_skipped"] == causal_tiles
     assert plan["tiles_skipped"] / causal_tiles == pytest.approx(0.545,
                                                                  abs=5e-3)
-    # 8 x 3 grid steps a head where the causal call walks 8 x 8, and 21 K/V
-    # major blocks fetched where it fetches 36
-    assert plan["grid_steps"] == 24 and plan["blocks_fetched"] == 21
+    # 16 x 5 grid steps a head where the causal call walks 16 x 16 (ten of
+    # the 80 are the first four rows' surplus), and 70 K/V major blocks
+    # fetched where it fetches 136
+    assert plan["grid_steps"] == 80 and plan["blocks_fetched"] == 70
     # a window that covers everything is the causal schedule
     whole = fa.window_plan(S, S, plans.fwd)
-    assert whole["tiles_skipped"] == 0 and whole["grid_steps"] == 64
+    assert whole["tiles_skipped"] == 0 and whole["grid_steps"] == 256
+    assert whole["blocks_fetched"] == 16 * 17 // 2
     assert whole["issued_area"] / whole["kept_area"] == \
         fa.issued_area_ratio(plans.fwd, S)
 
 
-def _calls(jaxpr, found):
+@pytest.mark.parametrize("S, H, KV, causal, W, major", [
+    (512, 2, 2, True, None, 128),   # diagonal and whole blocks
+    (450, 2, 2, False, None, 128),  # padded columns in the last kv block only
+    (512, 4, 1, True, None, 128),   # a group of four query heads a KV head
+    (512, 4, 1, True, 100, 128),    # a window that reaches two major blocks
+    (512, 2, 1, True, 257, 128),    # three; row 0's, 1's surplus steps empty
+    (512, 2, 2, True, 128, 128),    # its lower edge on a block's boundary
+    (512, 2, 1, True, None, 256),   # two rows of tiles a block, two blocks
+    (512, 2, 1, True, 300, 256),    # and a window's edge inside a block
+], ids=["causal", "full-padded", "group4", "window2", "window3", "edge",
+        "rows2", "rows2-window"])
+def test_several_major_blocks_are_one_major_block_bitwise(monkeypatch, S, H,
+                                                          KV, causal, W,
+                                                          major):
+    """The schedule of a grid step is a function of where its q-major block
+    lies from its kv-major block (`_grid_cases`): the same tiles in the
+    same order with the same masks as one major block walks them, the
+    carries through float32 scratch in between — so four major blocks a
+    side (two of two rows of tiles each) give `o`, `dq`, `dk` and `dv` of
+    one bit for bit."""
+    q, k, v, w = _inputs(S, H, KV, 32)
+
+    def run():
+        return _out_and_grads(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=causal, window=W, block_q=128, block_k=128,
+                interpret=True), q, k, v, w)
+    plan = fa.tile_plan(S, 32, jnp.float32, 128, 128).dkv
+    assert plan.major == plan.s_pad == 512
+    one = run()
+    monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES", fa.vmem_bytes(major, 32, 4))
+    plan = fa.tile_plan(S, 32, jnp.float32, 128, 128).dkv
+    assert plan.major == major and plan.s_pad == 512
+    if W is not None:
+        assert fa._seq_blocks(plan, W) == {100: 2, 257: 3, 128: 2, 300: 2}[W]
+    for name, a, b in zip(("o", "dq", "dk", "dv"), run(), one):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters
+    (kernels' bodies, loops' bodies, branches)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"], eqn.params["grid_mapping"].grid))
+        yield eqn
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (list, tuple)) else [value]):
                 if hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
-                    _calls(sub.jaxpr, found)
+                    yield from _eqns(sub.jaxpr)
                 elif hasattr(sub, "eqns"):
-                    _calls(sub, found)
+                    yield from _eqns(sub)
+
+
+def _calls(jaxpr, found):
+    found += [(eqn.params["name"], eqn.params["grid_mapping"].grid)
+              for eqn in _eqns(jaxpr) if eqn.primitive.name == "pallas_call"]
     return found
 
 
@@ -199,8 +249,14 @@ def test_windowed_calls_are_named_and_walk_a_shorter_grid(monkeypatch):
 # branches, and without a window none of them adds or moves an operation.
 # Since PR 38 the three calls carry a name where that trace had none: the
 # one difference, taken out before hashing.
+# (4096, 4, 2, 128) was "f0c38224bea791bd" until PR 39 moved it BY DESIGN:
+# with several major blocks the kernels now hold one schedule of Python-int
+# trip counts for each offset between a step's blocks, under `pl.when`, where
+# they held loops bounded by program ids, and a major block has at most
+# `MAJOR_ROWS` rows (four a side here, two before). One major block, the
+# first case, is the trace it was.
 PARENT_JAXPR = {(1024, 4, 4, 64): "c40b43bd2c945677",
-                (4096, 4, 2, 128): "f0c38224bea791bd"}
+                (4096, 4, 2, 128): "dc2f8ba272ecf275"}
 
 
 @pytest.mark.parametrize("shape", sorted(PARENT_JAXPR))
