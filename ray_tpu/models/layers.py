@@ -181,6 +181,22 @@ def resolve_attention(attention: str, mesh=None) -> str:
 # `checkpoint_name` of the attention sub-layer's output, [B, S, d_model] as
 # it is added to the residual stream (after `reduce`).
 ATTENTION_OUT = "attention_out"
+# `checkpoint_name` of a product whose forward value took three bf16 passes
+# (`_project`): three passes to rebuild, so `remat` keeps it.
+THREE_PASS_OUT = "three_pass_out"
+
+
+def _project(cd, three_pass: bool):
+    """A layer's products on the MXU: ``(eq, x, w, out_dtype) -> result``,
+    `mxu.einsum` with operands rounded to `cd`. With `three_pass` the result
+    carries the name `THREE_PASS_OUT`, which says nothing without a
+    checkpoint and under `remat` keeps the result where the backward pass
+    reads it (the layer's other results — an out-projection's, which only
+    the residual add reads — are no residual and cost nothing)."""
+    product = functools.partial(mxu.einsum, cd=cd, three_pass=three_pass)
+    if not three_pass:
+        return product
+    return lambda *args: checkpoint_name(product(*args), THREE_PASS_OUT)
 
 
 def apply_attention(
@@ -234,7 +250,7 @@ def apply_attention(
         raise ValueError("ring attention has no window: a layer with one "
                          "cannot run on a mesh that splits the sequence")
     cd = compute_dtype
-    project = functools.partial(mxu.einsum, cd=cd, three_pass=three_pass)
+    project = _project(cd, three_pass)
     # float32 out of the MXU's accumulator where something is still to be
     # done to q and k; the compute dtype's own result type otherwise
     qk_dtype = None if qk_fn is None else jnp.float32
@@ -277,20 +293,33 @@ def apply_attention(
 
 
 def remat(body):
-    """`jax.checkpoint` for a layer loop's scan body that keeps, besides the
-    block's input, what is dear to recompute: the flash forward kernel's `o`
-    and `lse` (the backward kernels' residuals: without them the kernel
-    runs twice a step) and the attention sub-layer's output (without it the
-    recompute needs the `wo` product and, under `tp`, its exchange, only to
-    rebuild the second norm's input). Everything else in the block — norms,
-    q/k/v products, the MLP's first product — is recomputed. A block that
-    names none of these (ring or reference attention has no `o`/`lse`)
-    keeps what it does name."""
+    """`jax.checkpoint` for a layer loop's body that keeps, besides the
+    block's input, what is dear to recompute — and of that only what the
+    backward pass reads:
+
+    * the flash forward kernel's `o` and `lse` (the backward kernels'
+      residuals: without them the kernel runs twice a step);
+    * the attention sub-layer's output, where the block goes on from it
+      (without it the recompute needs the `wo` product and, under `tp`, its
+      exchange, only to rebuild the second norm's input);
+    * the result of a product whose forward value was brought to float32
+      accuracy by three bf16 passes (`_project` with `three_pass`: a mixer's
+      in-projection, a feed-forward's first products, q, k and v as they
+      are rounded for the kernel): rebuilding it costs three passes where
+      the backward's own products cost one, so a kept byte saves three
+      times what it saves behind a single-pass product.
+
+    Everything else in the block — norms, single-pass q/k/v products, the
+    MLP's first product, convs and gates, the scan, the routed experts — is
+    recomputed. A block that names none of these (ring or reference
+    attention has no `o`/`lse`; a model in one pass names no product) keeps
+    what it does name. What it costs a layer, from shapes alone:
+    `gpt2.remat_saved_plan`, `nemotron_h.remat_saved_plan`."""
     from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 
     return jax.checkpoint(
         body, policy=jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES, ATTENTION_OUT))
+            *RESIDUAL_NAMES, ATTENTION_OUT, THREE_PASS_OUT))
 
 
 # ------------------------------------------------- depthwise causal conv
@@ -336,8 +365,7 @@ def apply_short_conv(params: Params, x, *, compute_dtype=jnp.bfloat16,
     products on the MXU in `compute_dtype` (`three_pass` as in
     `apply_attention`), gates and taps in float32 from the in-projection's
     accumulator, under the scope `gate_conv`: plain JAX, no kernel."""
-    project = functools.partial(mxu.einsum, cd=compute_dtype,
-                                three_pass=three_pass)
+    project = _project(compute_dtype, three_pass)
     bcu = project("btd,de->bte", x, params["w_in"], jnp.float32)
     with jax.named_scope("gate_conv"):
         b, c, u = jnp.split(bcu, 3, axis=-1)
@@ -423,8 +451,7 @@ def apply_mamba(params: Params, u, cfg: MambaConfig, *,
     (`ssd._use_kernel`), elsewhere plain JAX."""
     B, T, _ = u.shape
     H, P, G, N = cfg.n_heads, cfg.head_dim, cfg.n_groups, cfg.d_state
-    project = functools.partial(mxu.einsum, cd=compute_dtype,
-                                three_pass=three_pass)
+    project = _project(compute_dtype, three_pass)
     zxbcdt = project("btd,de->bte", u, params["w_in"], jnp.float32)
     z, xbc, dt = jnp.split(zxbcdt, [cfg.inner, cfg.inner + cfg.conv_dim],
                            axis=-1)
@@ -561,8 +588,7 @@ def apply_gated_mlp(params: Params, x, *, compute_dtype=jnp.bfloat16,
     holds an expert's; with `three_pass` (as in `apply_attention`) all three
     stay float32, so that the extra passes have something to add to and the
     last product sees the hidden row's own low part."""
-    project = functools.partial(mxu.einsum, cd=compute_dtype,
-                                three_pass=three_pass)
+    project = _project(compute_dtype, three_pass)
     wide = jnp.float32 if three_pass else compute_dtype
     gate = project("bsd,df->bsf", x, params["w_gate"], wide)
     up = project("bsd,df->bsf", x, params["w_up"], wide)
@@ -1173,8 +1199,7 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
         stats = dict(stats, compact=compact.astype(jnp.float32))
     if cfg.d_shared:
         with jax.named_scope("shared_expert"):
-            project = functools.partial(mxu.einsum, cd=cd,
-                                        three_pass=three_pass)
+            project = _project(cd, three_pass)
             hidden = _relu2(project("bsd,df->bsf", x, params["shared_w1"],
                                     jnp.float32))
             out = out + project("bsf,fd->bsd", hidden, params["shared_w2"],

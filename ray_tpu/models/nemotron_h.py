@@ -50,9 +50,12 @@ whose value reaches a later router is brought to float32 accuracy in the
 FORWARD pass by two more bf16 passes (`ops.mxu.einsum`'s `three_pass`: the
 projections of all three kinds, the shared expert, the scan's four
 products); the flash kernels and the held experts' grouped products stay
-single bf16 passes, and the backward pass is single-pass throughout. Not
-extended to it: `tp` > 1 (the mixers' and the KV heads' leaves are whole on
-every rank; `forward` refuses such a mesh), `sp`, the pipelined forward.
+single bf16 passes, and the backward pass is single-pass throughout. A
+three-pass result is what `L.remat` keeps of a layer (`remat_saved_plan`
+has the bytes): the passes run once a step, not again ahead of the
+backward pass. Not extended to it: `tp` > 1 (the mixers' and the KV heads'
+leaves are whole on every rank; `forward` refuses such a mesh), `sp`, the
+pipelined forward.
 """
 from __future__ import annotations
 
@@ -206,6 +209,34 @@ def logical_axes(cfg: NemotronHConfig):
 
 def partition_specs(cfg: NemotronHConfig, rules=None):
     return L.partition_specs(logical_axes(cfg), rules)
+
+
+def remat_saved_plan(cfg: NemotronHConfig, local_batch: int, seq: int, *,
+                     flash: bool = True):
+    """{kind: {name: bytes}} of what ONE layer of each kind keeps on one
+    device under `L.remat` besides its input, from shapes alone (as
+    `gpt2.remat_saved_plan`): the results of the three-pass products the
+    backward pass reads — the mixer's in-projection and the shared
+    expert's first product in float32, q, k and v in the compute dtype, as
+    they are rounded for the kernel — and, where the flash kernels run
+    (`flash`), their `o` and float32 `lse`. Not in it, because no backward
+    reads them: the out-projections' results, the shared expert's second
+    product, `wo`'s (a layer is one mixer: nothing in it goes on from the
+    attention output). The router's `[tokens, n_experts]` product is
+    float32 at `highest` precision outside `_project`: not named, rebuilt.
+    The step's total is each kind's sum times the pattern's count of it."""
+    rows = local_batch * seq
+    item = jnp.dtype(cfg.dtype).itemsize
+    q, kv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
+    plan = {"M": {L.THREE_PASS_OUT: rows * cfg.mamba.in_proj * 4},
+            "E": {L.THREE_PASS_OUT: rows * cfg.d_shared * 4},
+            "*": {L.THREE_PASS_OUT: rows * (q + 2 * kv) * item}}
+    if flash:
+        from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+        o, lse = RESIDUAL_NAMES
+        plan["*"].update({o: rows * q * item, lse: rows * cfg.n_head * 4})
+    return plan
 
 
 # ----------------------------------------------------------------- forward

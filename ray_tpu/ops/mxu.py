@@ -11,7 +11,10 @@ def einsum(eq, x, w, out_dtype, *, cd, three_pass):
     to the FORWARD value only, the two products with the operands' rounding
     errors (x_hi·w_lo + x_lo·w_hi, the `lo` parts themselves in `cd`): the
     result is the float32 product to ~2^-16. The backward pass is the
-    single product's, as without it."""
+    single product's, as without it — unless a checkpoint has to rebuild
+    the forward value first, which costs the three passes again: a layer's
+    products go through `models.layers._project`, which names the result
+    so that `layers.remat` keeps it; a caller here keeps nothing."""
     hi_x, hi_w = x.astype(cd), w.astype(cd)
     out = jnp.einsum(eq, hi_x, hi_w, preferred_element_type=out_dtype)
     if not three_pass:

@@ -184,6 +184,31 @@ def test_the_scan_engages_its_kernels_under_remat(monkeypatch):
     assert calls(dataclasses.replace(TINY, remat=True)) == []
 
 
+@pytest.mark.parametrize("checkpoint", ["policy", "keeps_nothing"])
+def test_remat_rebuilds_the_scan_and_not_the_in_projection(monkeypatch,
+                                                           checkpoint):
+    """What `layers.remat` keeps of a mixer: the in-projection's three-pass
+    result, not the scan's y. So the gradient under remat holds the forward
+    kernel twice a mixer (the benchmark's `kernels.ssd_roofline` counts
+    three executions a layer) and the in-projection's three passes ONCE; a
+    checkpoint that keeps nothing holds them twice."""
+    from ray_tpu.models import layers
+    from tests.test_zz_remat_policy import _products_with
+    if checkpoint == "keeps_nothing":
+        monkeypatch.setattr(layers, "remat", jax.checkpoint)
+    _as_on_a_tpu(monkeypatch)
+    cfg = dataclasses.replace(KERNEL_TINY, remat=True)
+    params = jax.eval_shape(
+        lambda: nemotron_h.init(jax.random.PRNGKey(0), cfg))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, t: nemotron_h.loss_fn(p, {"tokens": t}, cfg)[0]))(
+            params, jax.ShapeDtypeStruct((1, 131), jnp.int32)).jaxpr
+    passes, _ = _products_with(jaxpr, (cfg.d_model, cfg.mamba.in_proj))
+    mixers = cfg.pattern.count("M")
+    assert _ssd_calls(jaxpr).count("ssd_fwd") == 2 * mixers
+    assert passes == (3 if checkpoint == "policy" else 6) * mixers
+
+
 def test_the_model_on_the_kernels_is_the_model_on_the_plain_form(
         monkeypatch):
     """Float32, the kernels in the interpreter, under the remat policy:

@@ -1,10 +1,12 @@
 """What the layer loops' checkpoint keeps (`models/layers.py:remat`): the
-flash forward kernel's `o` and `lse` and the attention sub-layer's output,
+flash forward kernel's `o` and `lse`, the attention sub-layer's output and
+the results of the products whose forward value took three bf16 passes,
 besides the block's input. So a training step runs the forward kernel once
-a layer, not twice, and recomputes neither the `wo` product nor, under
-`tp`, its exchange (`tests/test_zz_tp_overlap.py` counts those); the bytes
-kept are `gpt2.remat_saved_plan`'s; no number changes; and where remat is
-off the names lower to nothing.
+a layer, not twice, recomputes neither the `wo` product nor, under `tp`,
+its exchange (`tests/test_zz_tp_overlap.py` counts those), and runs a
+three-pass product's passes once; the bytes kept are
+`gpt2.remat_saved_plan`'s and `nemotron_h.remat_saved_plan`'s; no number
+changes; and where remat is off the names lower to nothing.
 
 CPU virtual devices, the Pallas kernels through the interpreter
 (`interpret=True`: `force_tpu_interpret_mode` has effects a checkpoint
@@ -13,6 +15,7 @@ refuses). What the kept values are worth on the chip is in PERF.md §6.
 import contextlib
 import dataclasses
 import functools
+import math
 import re
 
 import jax
@@ -21,7 +24,7 @@ import pytest
 from jax._src.ad_checkpoint import saved_residuals
 from jax.sharding import NamedSharding
 
-from ray_tpu.models import gpt2, olmoe
+from ray_tpu.models import gpt2, lfm2, nemotron_h, olmoe
 from ray_tpu.models import layers as L
 from ray_tpu.ops import flash_attention as fa
 from tests.test_zz_tp_overlap import _mesh as _mesh_of, _walk
@@ -85,13 +88,17 @@ def test_three_kernel_calls_a_layer_and_chain_under_remat(interpreted, case):
 
 
 # ------------------- in which loop the kernel and the `wo` product run
-def _olmoe(remat=True, **overrides):
-    cfg = dataclasses.replace(olmoe.olmoe_tiny(), dtype=jnp.float32,
-                              remat=remat, **overrides)
-    params = olmoe.init(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, SEQ + 1), 0,
+def _tiny(module, preset, batch, **fields):
+    cfg = dataclasses.replace(preset(), **fields)
+    params = module.init(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, SEQ + 1), 0,
                                 cfg.vocab_size)
-    return olmoe, cfg, params, tokens
+    return module, cfg, params, tokens
+
+
+def _olmoe(remat=True, **overrides):
+    return _tiny(olmoe, olmoe.olmoe_tiny, 4, dtype=jnp.float32, remat=remat,
+                 **overrides)
 
 
 # one head of 64: the width the kernels' tile plan starts at
@@ -216,6 +223,150 @@ def test_remat_saved_plan_at_the_remat_cells_shapes():
                                  flash=False) == {L.ATTENTION_OUT: 20_971_520}
 
 
+# ------------------------- what a three-pass product's layer keeps
+# grouped KV heads of 64, the width the kernels' tile plan starts at
+NEMOTRON_FLASH = {"attention": "flash", "n_head": 2, "n_kv_head": 1,
+                  "head_dim": 64}
+
+
+def _nemotron(remat=True, **overrides):
+    return _tiny(nemotron_h, nemotron_h.nemotron_h_tiny, 2, remat=remat,
+                 **{"attention": "reference", **overrides})
+
+
+def _lfm2(remat=True, **overrides):
+    return _tiny(lfm2, lfm2.lfm2_tiny, 2, remat=remat,
+                 **{"attention": "reference", "three_pass": True,
+                    **overrides})
+
+
+def _kept(body, x, layer):
+    """(shape, dtype) of what a checkpointed layer saves besides its
+    arguments and constants (a share's bound: one int32), sorted."""
+    return sorted((a.shape, str(a.dtype))
+                  for a, what in saved_residuals(body, x, layer)
+                  if not what.startswith(("from the argument",
+                                          "from a constant")))
+
+
+@pytest.mark.parametrize("kind", ["M", "E", "*", "*-flash"])
+def test_a_nemotron_layer_keeps_its_input_and_the_planned_values(
+        interpreted, kind):
+    kind, _, flash = kind.partition("-")
+    _, cfg, params, _ = _nemotron(**(NEMOTRON_FLASH if flash else {}))
+    batch, impl = 2, cfg.attention
+    layer = jax.tree_util.tree_map(lambda a: a[0],
+                                   params[nemotron_h.KINDS[kind]])
+    x = jnp.zeros((batch, SEQ, cfg.d_model), jnp.float32)
+    kept = _kept(L.remat(functools.partial(
+        nemotron_h._layer_apply, kind=kind, cfg=cfg, impl=impl)), x, layer)
+    q, kv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
+    want = {
+        "M": [((batch, SEQ, cfg.mamba.in_proj), "float32")],
+        "E": [((batch, SEQ, cfg.d_shared), "float32")],
+        # q, k, v as they are rounded for the kernel
+        "*": [((batch, SEQ, cfg.n_head, cfg.head_dim), "bfloat16"),
+              ((batch, SEQ, cfg.n_kv_head, cfg.head_dim), "bfloat16"),
+              ((batch, SEQ, cfg.n_kv_head, cfg.head_dim), "bfloat16")],
+    }[kind]
+    if flash:       # o as the model reads it, lse as the kernels do
+        want += [((batch, SEQ, cfg.n_head, cfg.head_dim), "bfloat16"),
+                 ((batch * cfg.n_head, SEQ), "float32")]
+    assert kept == sorted(want)
+    # no out-projection's result, no second product's: [B, S, d_model]
+    assert all(shape != x.shape for shape, _ in kept)
+    plan = nemotron_h.remat_saved_plan(cfg, batch, SEQ, flash=bool(flash))
+    assert set(plan) == set(nemotron_h.KINDS)
+    assert set(plan[kind]) == {L.THREE_PASS_OUT, *(
+        fa.RESIDUAL_NAMES if flash else ())}
+    assert sum(plan[kind].values()) == sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in kept)
+    if kind == "*":
+        assert plan[kind][L.THREE_PASS_OUT] == batch * SEQ * (q + 2 * kv) * 2
+
+
+def test_an_lfm2_layer_in_three_passes_keeps_by_the_same_rule():
+    """A conv operator and a dense feed-forward in ONE checkpointed layer:
+    `bcu`, the operator's output (the feed-forward goes on from it, as a
+    GPT-2 block from its attention output), gate and up; not `w_down`'s
+    result. In one pass, the cell's program: the input alone."""
+    _, cfg, params, _ = _lfm2()
+    depth = next(i for i, kind in enumerate(cfg.layer_types)
+                 if kind == lfm2.CONV and i < cfg.n_dense)
+    x = jnp.zeros((2, SEQ, cfg.d_model), jnp.float32)
+
+    def kept(cfg):
+        return _kept(L.remat(functools.partial(
+            lfm2._layer_apply, kind=lfm2.CONV, dense=True, cfg=cfg,
+            impl="reference")), x, params["layers"][depth])
+
+    rows = (2, SEQ)
+    assert kept(cfg) == sorted([
+        ((*rows, 3 * cfg.d_model), "float32"), ((*rows, cfg.d_model), "float32"),
+        ((*rows, cfg.d_ff), "float32"), ((*rows, cfg.d_ff), "float32")])
+    assert kept(dataclasses.replace(cfg, three_pass=False)) == []
+
+
+def _products_with(jaxpr, weight):
+    """(products of the form x·W with W of this shape, every product with
+    an operand or a result as wide as W's second axis) in a jaxpr, nested
+    ones too."""
+    forward = every = 0
+    for eqn, times, _ in _walk(jaxpr):
+        if eqn.primitive.name != "dot_general":
+            continue
+        shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+        every += times * any(weight[1] in shape for shape in shapes)
+        forward += times * (shapes[1] == weight
+                            and shapes[2][-1] == weight[1])
+    return forward, every
+
+
+def test_the_backward_holds_no_three_pass_product_again(monkeypatch):
+    """The mixer's in-projection and the shared expert's two products (no
+    other array is as wide as either): three passes forward and the
+    backward's own two products each, with the policy as without remat; a
+    checkpoint that keeps nothing runs the in-projection's and the shared
+    expert's first three passes again (its second product nobody reads)."""
+    def products(remat):
+        module, cfg, params, tokens = _nemotron(remat)
+        jaxpr = jax.make_jaxpr(_loss_and_grads(module, cfg, None))(
+            params, tokens).jaxpr
+        return {name: _products_with(jaxpr, (cfg.d_model, width))
+                for name, width in (("w_in", cfg.mamba.in_proj),
+                                    ("shared_w1", cfg.d_shared))}, cfg
+
+    plain, cfg = products(remat=False)
+    mixers, routed = cfg.pattern.count("M"), cfg.pattern.count("E")
+    assert plain == {"w_in": (3 * mixers, 5 * mixers),
+                     "shared_w1": (3 * routed, 10 * routed)}
+    assert products(remat=True)[0] == plain
+    monkeypatch.setattr(L, "remat", jax.checkpoint)
+    assert products(remat=True)[0] == {
+        "w_in": (6 * mixers, 8 * mixers),
+        "shared_w1": (6 * routed, 13 * routed)}
+
+
+def test_three_pass_plan_at_the_nemotron_cells_shapes():
+    """nemotronh9l-b1s8k: 1 x 8,192, four mixers, four routed layers, one
+    attention layer of 32 query heads on 2 KV heads of 128."""
+    o, lse = fa.RESIDUAL_NAMES
+    cfg = nemotron_h.nemotron_twotower_30b_a3b_9l()
+    plan = nemotron_h.remat_saved_plan(cfg, 1, 8192)
+    assert plan == {
+        "M": {L.THREE_PASS_OUT: 337_641_472},
+        "E": {L.THREE_PASS_OUT: 121_634_816},
+        "*": {L.THREE_PASS_OUT: 75_497_472, o: 67_108_864, lse: 1_048_576}}
+    by_name = {}
+    for kind in cfg.pattern:
+        for name, size in plan[kind].items():
+            by_name[name] = by_name.get(name, 0) + size
+    assert by_name == {
+        L.THREE_PASS_OUT: 4 * 337_641_472 + 4 * 121_634_816 + 75_497_472,
+        o: 67_108_864, lse: 1_048_576}
+    assert by_name[L.THREE_PASS_OUT] == 1_912_602_624
+
+
 # ------------------------------------------------- the same numbers
 SAME_NUMBERS = {
     "gpt2_no_mesh": ({}, lambda: (gpt2, *_gpt2(4, attention="flash"), {})),
@@ -226,6 +377,9 @@ SAME_NUMBERS = {
         gpt2, *_gpt2(8), {"pipelined": True, "n_microbatches": 2})),
     "olmoe_tiny": ({}, lambda: (*_olmoe(attention="reference"), {})),
     "olmoe_tiny_flash": ({}, lambda: (*_olmoe(**OLMOE_FLASH), {})),
+    "nemotron_tiny": ({}, lambda: (*_nemotron(), {})),
+    "nemotron_tiny_flash": ({}, lambda: (*_nemotron(**NEMOTRON_FLASH), {})),
+    "lfm2_tiny_three_pass": ({}, lambda: (*_lfm2(), {})),
 }
 
 
@@ -254,29 +408,42 @@ def test_remat_changes_no_number(interpreted, monkeypatch, case, against):
         want, want_grads = run(remat=against == "bare_checkpoint")
     assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
     errors = jax.tree_util.tree_map(
-        lambda g, w: float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)),
+        lambda g, w: float(jnp.linalg.norm(g - w)
+                           / jnp.maximum(jnp.linalg.norm(w), 1e-30)),
         got_grads, want_grads)
     for path, err in jax.tree_util.tree_leaves_with_path(errors):
         assert err <= 1e-6, (jax.tree_util.keystr(path), err)
 
 
 # ------------------------------------- nothing where remat is off
-def test_names_lower_to_nothing_without_remat(interpreted, monkeypatch):
-    cfg, params, tokens = _gpt2(4, attention="flash", remat=False)
+NAMED = {
+    "gpt2": (lambda: (gpt2, *_gpt2(4, attention="flash", remat=False)),
+             (*fa.RESIDUAL_NAMES, L.ATTENTION_OUT)),
+    # attention in three passes with remat off: the cell olmoe1l-b2s4k
+    "olmoe_three_pass": (lambda: _olmoe(False, **OLMOE_FLASH),
+                         (*fa.RESIDUAL_NAMES, L.ATTENTION_OUT,
+                          L.THREE_PASS_OUT)),
+}
+
+
+@pytest.mark.parametrize("case", list(NAMED))
+def test_names_lower_to_nothing_without_remat(interpreted, monkeypatch, case):
+    make, names = NAMED[case]
+    module, cfg, params, tokens = make()
 
     def lowered():
         # a fresh function each time: nothing traced before is reused
-        f = _loss_and_grads(gpt2, cfg, None)
+        f = _loss_and_grads(module, cfg, None)
         text = jax.jit(f).lower(params, tokens).as_text()
         # private functions are numbered by a counter of the process
         return (str(jax.make_jaxpr(f)(params, tokens)),
                 re.sub(r"@(\w+?)_\d+\b", r"@\1", text))
 
     named_jaxpr, named = lowered()
-    for module in (L, fa):
-        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    for holder in (L, fa):
+        monkeypatch.setattr(holder, "checkpoint_name", lambda x, name: x)
     bare_jaxpr, bare = lowered()
-    for name in (*fa.RESIDUAL_NAMES, L.ATTENTION_OUT):
+    for name in names:
         assert f"name={name}" in named_jaxpr
         assert f"name={name}" not in bare_jaxpr
     assert named == bare
