@@ -3,7 +3,8 @@
 The hot op of the flagship models. Three kernels — forward, dq, dk/dv (the
 standard flash-attention split; each recomputes the probability tile from
 the saved logsumexp, so no O(S²) tensor ever reaches HBM) — share one tile
-schedule, derived from ``(S, D, dtype)`` by :func:`tile_plan`:
+schedule, derived from ``(S, D, dtype)`` — and, where v is another width than
+q and k, that width — by :func:`tile_plan`:
 
 * **Grid** ``(B·H, q-major blocks, kv-major blocks)`` (dk/dv: kv-major
   parallel, q-major sequential). A major block is as much of the sequence
@@ -12,10 +13,14 @@ schedule, derived from ``(S, D, dtype)`` by :func:`tile_plan`:
   no grid point is spent on a block the causal mask empties; with several
   major blocks the index maps clamp to the last needed block, so skipped
   ones are not fetched either.
-* **Inner loops** over ``tile_q × tile_k`` score tiles inside the kernel
-  (each kernel has its own tile, ``_TILES``). The forward and dq walk each
-  q tile's kv tiles, dk/dv walks each kv tile's q tiles (on the TRANSPOSED
-  score tile ``k·qᵀ``, so that ``pᵀ·dO`` and ``dSᵀ·q`` are plain
+* **Inner loops** over ``tile_q × tile_k`` score tiles inside the kernel.
+  Each kernel has its own tile, looked up in ``_TILES`` under the widths the
+  call arrives with (q/k's, v's): a call whose widths are equal — every
+  call without ``k_shared`` — gets the default entry, forward 128 × 256, dq
+  256 × 256, dk/dv 128 × 128; the latent call at 192 / 128 gets a 256 × 512
+  forward tile and a 256 × 256 dk/dv tile (below). The forward and dq walk
+  each q tile's kv tiles, dk/dv walks each kv tile's q tiles (on the
+  TRANSPOSED score tile ``k·qᵀ``, so that ``pᵀ·dO`` and ``dSᵀ·q`` are plain
   contractions and lse/delta are used as the rows they are stored as). Trip
   counts come from the diagonal (:func:`_kv_tiles`, :func:`_q_tiles`):
   tiles wholly above it are never issued.
@@ -65,7 +70,17 @@ schedule, derived from ``(S, D, dtype)`` by :func:`tile_plan`:
   query head's part of the shared columns' gradient and the heads are
   summed outside, as a KV group's are. These calls are named
   ``flash_latent_fwd`` / ``_dq`` / ``_dkv``. Without ``k_shared`` every
-  kernel traces to the program it was.
+  kernel traces to the program it was. **Their tiles are their own**
+  (``_TILES[(192, 128)]``, PR 44): the MXU contracts 128 at a time, so a
+  product with the 64 shared columns costs a whole pass for half a pass's
+  work, and what a score tile pays around it — q's ``[tile_q, 64]`` slice,
+  the turned ``[tile_k, 64]`` key tile — it pays once whatever its size, so
+  it is amortised over 256 rows and not 128. The two kernels that walked
+  128-row tiles (the forward; dk/dv) fell furthest from their rooflines and
+  dq at 256 rows did not, so the forward walks 256 × 512 tiles and dk/dv
+  256 × 256, dq 256 × 256 as before (the sweep's figures: ``_TILES``'
+  comment). The widths are read from the operands' shapes; nothing else
+  selects a tile.
 
 Precision is unchanged: operands in the input dtype, f32 scores, f32
 softmax statistics and accumulators, ``p``/``dS`` cast to the input dtype
@@ -85,6 +100,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu._private.telemetry import counter_inc
 
 NEG_INF = -1e30
 _LANES = 128
@@ -110,11 +127,36 @@ VMEM_BUDGET_BYTES = 10 * 1024 * 1024
 # kernel time (PR 39: 115.0 ms a step for 126.5 in smallthinker4l-b1s16k).
 # At 1,024 rows a K/V block still feeds 1,024 FLOP a byte fetched.
 MAJOR_ROWS = 1024
-# Score-tile (rows, columns) of each kernel, swept on the v5e at
-# [192, 1024, 64] bf16 (PR 26): the forward likes a wide tile (fewer softmax
-# row statistics per score element), dq a square one, dk/dv — three live
-# tiles and two accumulators — the smallest.
-_TILES = {"fwd": (128, 256), "dq": (256, 256), "dkv": (128, 128)}
+# Score-tile (rows, columns) of each kernel BY THE CALL'S HEAD WIDTHS, (q/k's,
+# v's) as `flash_attention` finds them in its operands' shapes; a pair that
+# has no entry — every call whose widths are equal — gets the default.
+# Default: swept on the v5e at [192, 1024, 64] bf16 (PR 26), and what the
+# D 64 and D 128 cells' kernels have run at since: the forward likes a wide
+# tile (fewer softmax row statistics per score element), dq a square one,
+# dk/dv — three live tiles and two accumulators — the smallest.
+# (192, 128), latent attention (128 columns of a head's own + 64 shared, v
+# 128), swept at [2, 8192, 32, 192 / 128] bf16 (PR 44: thirteen forms, each
+# kernel by name from a device profile,
+# benchmarks/results/pr44_tiles_by_width/kernels/): a product with the 64
+# shared columns costs the MXU a whole 128-deep pass, and what a score tile
+# pays around it — q's [rows, 64] slice, the turned [columns, 64] key tile —
+# it pays once whatever its size. The two kernels that walked 128-row tiles
+# fell furthest from their rooflines: forward 19.86 ms a call at 128 × 256,
+# 13.73 at 256 × 256, 10.99 at 256 × 512 (512 rows or 1,024 columns: no
+# better); dk/dv 26.07 at 128 × 128, 19.91 at 128 × 256, 18.55 at 256 × 256;
+# dq 17.0–17.2 at every form with 256 rows or more. The equal-width kernels
+# move by under 6 % over the same forms (the forward 8.18 → 7.74 at 256 ×
+# 512), which is why they keep the default. A grid step holds the same
+# blocks in VMEM as before — they are a major block's, not a tile's:
+# `vmem_bytes(1024, 192, 2)`, 8.1 MiB of the 10 — and live float32 score
+# tiles of 512 KiB for 128 in the forward (s and p), 256 KiB for 64 in dk/dv
+# (sᵀ, pᵀ and dSᵀ). The diagonal block writes out FEWER tile bodies:
+# forward 20 → 6, dk/dv 36 → 10 (dq 10 as before), so a call site traces
+# and lowers no slower. The padded length is a multiple of 512 here.
+_TILES = {
+    None: {"fwd": (128, 256), "dq": (256, 256), "dkv": (128, 128)},
+    (192, 128): {"fwd": (256, 512), "dq": (256, 256), "dkv": (256, 256)},
+}
 
 
 # ------------------------------------------------------------------ tile plan
@@ -150,13 +192,17 @@ def vmem_bytes(major: int, head_dim: int, itemsize: int) -> int:
 
 def tile_plan(seq_len: int, head_dim: int, dtype,
               block_q: Optional[int] = None,
-              block_k: Optional[int] = None) -> TilePlans:
-    """The schedule of the three kernels for ``[B·H, seq_len, head_dim]``.
+              block_k: Optional[int] = None, *,
+              v_dim: Optional[int] = None) -> TilePlans:
+    """The schedule of the three kernels for ``[B·H, seq_len, head_dim]``
+    (``head_dim``: q's and the whole key's; ``v_dim``: v's, where it is
+    another).
 
-    Tiles are ``_TILES`` (128 · 2^n: a score tile's lane dimension and the
-    lane dimension of an lse row block need multiples of 128), never larger
-    than the sequence rounded up to 128; ``block_q``/``block_k`` override
-    the rows/columns of all three (tests). The kernels share the padded
+    Tiles are ``_TILES``' entry for the two widths, else its default (128 ·
+    2^n: a score tile's lane dimension and the lane dimension of an lse row
+    block need multiples of 128), never larger than the sequence rounded up
+    to 128; ``block_q``/``block_k`` override the rows/columns of all three
+    (tests). The kernels share the padded
     length — the next multiple of the largest tile — and the major block:
     the largest multiple of the tiles that divides the padded length, fits
     ``VMEM_BUDGET_BYTES`` and has at most ``MAJOR_ROWS`` rows. ``MAJOR_ROWS``
@@ -167,7 +213,8 @@ def tile_plan(seq_len: int, head_dim: int, dtype,
     cap = _round_up(seq_len, _LANES)
     tiles = {name: (min(_round_up(block_q or tq, _LANES), cap),
                     min(_round_up(block_k or tk, _LANES), cap))
-             for name, (tq, tk) in _TILES.items()}
+             for name, (tq, tk) in _TILES.get(
+                 (head_dim, v_dim or head_dim), _TILES[None]).items()}
     sides = {side for pair in tiles.values() for side in pair}
     step = max(sides)
     if any(step % side for side in sides):
@@ -180,6 +227,17 @@ def tile_plan(seq_len: int, head_dim: int, dtype,
                 default=step)
     return TilePlans(**{name: TilePlan(tq, tk, major, s_pad)
                         for name, (tq, tk) in tiles.items()})
+
+
+def _count_plans(head_dim: int, v_dim: int, **plans: TilePlan):
+    """Which `_TILES` entry each call took: one count a kernel whose call is
+    being built — in Python, when a call site is traced, never on the
+    device — tagged with the kernel, the widths ("192/128") and the tile
+    ("256x512")."""
+    for kernel, plan in plans.items():
+        counter_inc("ray_tpu_flash_tile_plans_total", tags={
+            "kernel": kernel, "widths": f"{head_dim}/{v_dim}",
+            "tile": f"{plan.tile_q}x{plan.tile_k}"})
 
 
 def _clamp(x, lo, hi):
@@ -733,7 +791,8 @@ def _flash_fwd(q, k, v, shared=(), *, scale, causal, block_q, block_k,
     """
     BH, S, D = q.shape
     Dv = v.shape[-1]
-    plan = tile_plan(S, D, q.dtype, block_q, block_k).fwd
+    plan = tile_plan(S, D, q.dtype, block_q, block_k, v_dim=Dv).fwd
+    _count_plans(D, Dv, fwd=plan)
     S_pad, major = plan.s_pad, plan.major
     if S_pad != S:
         q, k, v, *shared = _pad_rows((q, k, v, *shared), S_pad)
@@ -933,7 +992,8 @@ def _flash_bwd(q, k, v, shared, lse, delta, do, *, scale, causal, block_q,
     kernel writes each head's part of their gradient, [BH, S, Dr], and the
     heads are summed the same way."""
     BH, S, D = q.shape
-    plans = tile_plan(S, D, q.dtype, block_q, block_k)
+    plans = tile_plan(S, D, q.dtype, block_q, block_k, v_dim=v.shape[-1])
+    _count_plans(D, v.shape[-1], dq=plans.dq, dkv=plans.dkv)
     S_pad, major = plans.dq.s_pad, plans.dq.major
     if S_pad != S:
         q, k, v, do, *shared = _pad_rows((q, k, v, do, *shared), S_pad)
@@ -1062,8 +1122,9 @@ def flash_attention(
     causal call itself.
 
     The schedule (score-tile shape, major block, padding) is derived from
-    ``(S, D, dtype)`` by :func:`tile_plan`; ``block_q``/``block_k`` override
-    the score tile's rows/columns (multiples of 128) and exist for tests.
+    ``(S, D, dtype)`` and v's width by :func:`tile_plan`; ``block_q`` /
+    ``block_k`` override the score tile's rows/columns (multiples of 128)
+    and exist for tests.
     """
     H, D = q.shape[2:]
     if scale is None:

@@ -154,6 +154,28 @@ def test_tile_plan_at_the_olmoe_cells_shape():
                                                                   abs=1e-4)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("D", [32, 64, 128, 256])
+def test_equal_widths_keep_the_plans_they_had(D, dtype):
+    """A call whose q/k and v widths are equal takes `_TILES`' default, with
+    or without `v_dim=`: the plans PR 43's `tile_plan` gave, spelled out —
+    forward 128 × 256, dq 256 × 256, dk/dv 128 × 128 (clamped to S rounded
+    up to 128), padded to the largest tile, a major block of at most 1,024
+    rows (512 for float32 at 256: the budget)."""
+    most = 512 if (D, dtype) == (256, jnp.float32) else 1024
+    for S, major, s_pad in [(200, 256, 256), (512, 512, 512),
+                            (1000, most, 1024), (4096, most, 4096),
+                            (8192, most, 8192), (16384, most, 16384)]:
+        want = fa.TilePlans(fwd=fa.TilePlan(128, 256, major, s_pad),
+                            dq=fa.TilePlan(256, 256, major, s_pad),
+                            dkv=fa.TilePlan(128, 128, major, s_pad))
+        assert fa.tile_plan(S, D, dtype) == want
+        assert fa.tile_plan(S, D, dtype, v_dim=D) == want
+    assert fa.tile_plan(100, D, dtype) == fa.TilePlans(
+        *[fa.TilePlan(128, 128, 128, 128)] * 3)
+
+
 @pytest.mark.parametrize("S,D", [(1024, 64), (2048, 64), (4096, 128),
                                  (192, 32)])
 def test_tile_plan_shapes(S, D):

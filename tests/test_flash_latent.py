@@ -85,6 +85,114 @@ def test_latent_kernels_over_several_major_blocks(monkeypatch):
         assert np.array_equal(np.asarray(g), np.asarray(o)), name
 
 
+# ---- tiles by the call's head widths (PR 44): q/k 192, v 128 has its own
+LATENT = dict(nope=128, rope=64, v_dim=128)      # the cell's widths
+
+
+@pytest.mark.parametrize("S, major, s_pad", [
+    (512, 512, 512), (1000, 1024, 1024), (1024, 1024, 1024),
+    (1100, 512, 1536), (4096, 1024, 4096), (8192, 1024, 8192),
+    (16384, 1024, 16384)])
+def test_the_latent_widths_have_their_own_tiles(S, major, s_pad):
+    """`tile_plan(v_dim=)`: (192, 128) takes `_TILES`' entry of that pair —
+    a 256 × 512 forward tile, a 256 × 256 dk/dv tile, dq as it was — for
+    bf16 and float32 alike; the sides nest, the kernels share the padded
+    length and a major block within `MAJOR_ROWS` and the budget (which
+    holds 512 rows of float32 at 192). Widths with no entry (the tiny
+    presets' 48 / 24, v as wide as q) get the default's."""
+    for dtype, major in ((jnp.bfloat16, major),
+                         (jnp.float32, min(major, 512))):
+        plans = fa.tile_plan(S, 192, dtype, v_dim=128)
+        assert plans == fa.TilePlans(fwd=fa.TilePlan(256, 512, major, s_pad),
+                                     dq=fa.TilePlan(256, 256, major, s_pad),
+                                     dkv=fa.TilePlan(256, 256, major, s_pad))
+        assert major <= fa.MAJOR_ROWS and s_pad % major == 0
+        assert fa.vmem_bytes(major, 192, jnp.dtype(dtype).itemsize) \
+            <= fa.VMEM_BUDGET_BYTES
+        for plan in plans:
+            assert major % plan.tile_q == 0 and major % plan.tile_k == 0
+        default = fa.tile_plan(S, 192, dtype)
+        assert default == fa.tile_plan(S, 192, dtype, v_dim=192) \
+            == fa.tile_plan(S, 192, dtype, v_dim=64)
+        assert [p[:2] for p in default] == [(128, 256), (256, 256), (128, 128)]
+        assert fa.tile_plan(S, 48, dtype, v_dim=24) == fa.tile_plan(S, 48, dtype)
+    # the override is still every kernel's, whatever the widths
+    assert {p[:2] for p in fa.tile_plan(S, 192, jnp.bfloat16, 128, 128,
+                                        v_dim=128)} == {(128, 128)}
+
+
+def test_the_new_tiles_write_out_fewer_tile_bodies():
+    """What the diagonal block of 1,024 rows holds, every tile written out
+    (`_walk`): forward 20 → 6, dk/dv 36 → 10, dq 10 as before — so a call
+    site traces and lowers no more code than it did."""
+    def bodies(plans):
+        return [fa._issued(fa._row_bounds(
+            plan, 0, 0, transposed=name == "dkv", causal=True,
+            seq_len=plan.s_pad, window=None))
+            for name, plan in plans._asdict().items()]
+    assert bodies(fa.tile_plan(8192, 192, jnp.bfloat16)) == [20, 10, 36]
+    assert bodies(fa.tile_plan(8192, 192, jnp.bfloat16, v_dim=128)) == \
+        [6, 10, 10]
+    for plan in fa.tile_plan(8192, 192, jnp.bfloat16, v_dim=128):
+        assert fa.static_tile_share(plan, 8192) == 1.0
+
+
+@pytest.mark.parametrize("S, H, whole, blocks", [
+    (1024, 2, True, 1),      # one major block, as bf16 has at 1,024 rows
+    (1024, 1, False, 2),     # float32's own: the budget holds 512 rows
+    (1100, 1, False, 3),     # S no multiple of 256: padded to 1,536
+], ids=["one_block", "two_blocks", "padded"])
+def test_latent_kernels_at_the_new_tiles(monkeypatch, S, H, whole, blocks):
+    """The three kernels at the (192, 128) entry's tiles — none overridden,
+    none clamped to the sequence — against plain attention: o, dq, dk, the
+    shared columns' gradient and dv."""
+    if whole:
+        monkeypatch.setattr(fa, "VMEM_BUDGET_BYTES",
+                            fa.vmem_bytes(1024, 192, 4))
+    plans = fa.tile_plan(S, 192, jnp.float32, v_dim=128)
+    assert [p[:2] for p in plans] == [(256, 512), (256, 256), (256, 256)]
+    assert plans.fwd.s_pad // plans.fwd.major == blocks
+    got, want = _both(1, S, H, **LATENT)
+    for name, g, r in zip(NAMES, got, want):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-4,
+                                   err_msg=name)
+
+
+def test_the_counter_says_which_call_took_which_entry():
+    """`ray_tpu_flash_tile_plans_total`: tracing a latent and an equal-width
+    call leaves one tag set a kernel — the kernel, the widths as the
+    operands have them, the tile its plan took."""
+    from ray_tpu.util.metrics import registry_snapshot
+
+    def counts():
+        family = next((m for m in registry_snapshot()
+                       if m["name"] == "ray_tpu_flash_tile_plans_total"),
+                      {"values": []})
+        return {(v["tags"]["kernel"], v["tags"]["widths"], v["tags"]["tile"]):
+                v["value"] for v in family["values"]}
+    before = counts()
+    q, k, shared, v, _ = _inputs(1, 1024, 1, **LATENT)
+
+    def latent(q, k, shared, v):
+        return jnp.sum(fa.flash_attention(q, k, v, k_shared=shared))
+
+    def equal(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v))
+    jax.make_jaxpr(jax.grad(latent, (0, 1, 2, 3)))(q, k, shared, v)
+    for width in (128, 64):
+        x = v[..., :width]
+        jax.make_jaxpr(jax.grad(equal, (0, 1, 2)))(x, x, x)
+    after = counts()
+    assert {key for key in after if after[key] > before.get(key, 0)} == {
+        ("fwd", "192/128", "256x512"), ("dq", "192/128", "256x256"),
+        ("dkv", "192/128", "256x256"),
+        ("fwd", "128/128", "128x256"), ("dq", "128/128", "256x256"),
+        ("dkv", "128/128", "128x128"),
+        ("fwd", "64/64", "128x256"), ("dq", "64/64", "256x256"),
+        ("dkv", "64/64", "128x128")}
+
+
 def test_the_calls_carry_their_names_and_their_own_operand_lists():
     """`flash_latent_fwd` / `_dq` / `_dkv`, with four and seven operands:
     the benchmark's generic reader (`flops.flash_call_cost`: three and six,
