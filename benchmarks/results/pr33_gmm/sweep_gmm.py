@@ -6,7 +6,7 @@ Copy it and a variants file into the git-ignored .bench_tree/ (the chip tool
 copies that too), then from the repo root, through the chip tool:
   python3 .bench_tree/sweep_gmm.py <variants-file.json> [out-name]
 SWEEP_TINY=1 rehearses the control flow on the CPU at the tiny preset."""
-import dataclasses, glob, importlib, json, math, os, re, shutil, sys, time
+import dataclasses, glob, importlib, json, math, os, re, shutil, sys, time, types
 ROOT = os.getcwd(); sys.path.insert(0, ROOT)
 import jax, jax.numpy as jnp, numpy as np
 from chipbench import catalog, flops, generate, trace_reduce
@@ -35,8 +35,10 @@ def _patched():
         return tgmm(lhsT, d, sizes, tiling=tuple(t) if t else tiling, **kw)
     return g, t_
 gm._kernels = _patched
-_orig_use = L._use_kernel
-L._use_kernel = lambda *a, **kw: (not VARIANT.get("ragged")) and _orig_use(*a, **kw)
+# the "ragged" variant: XLA's product on the chip, the op told it runs elsewhere
+_where = gm.target.where
+gm.target = types.SimpleNamespace(
+    where=lambda *a, **kw: ("cpu", 1) if VARIANT.get("ragged") else _where(*a, **kw))
 
 manifest = catalog.load_manifest()
 cell = catalog.resolve_cell(manifest, CELL, "end_to_end")

@@ -211,7 +211,7 @@ def ragged():
     gate_idx = jax.random.randint(ks[3], (1, T, K), 0, E)
     gate_vals = jax.random.uniform(ks[4], (1, T, K))
     platform = jax.default_backend()
-    assert L._kernel_width(platform, T * K, D, F, jnp.bfloat16) is None
+    assert L.grouped_matmul.kernel_width(T * K, D, F, jnp.bfloat16) is None
     # the bare product: rows sorted by group, the first `held` groups have a matrix
     sizes = jnp.bincount((gate_idx.reshape(-1) - first) % E, length=E).astype(jnp.int32)
     rows = jax.random.normal(ks[0], (T * K, D), jnp.bfloat16)

@@ -1,11 +1,14 @@
 """A cell's whole train step compiled for a DESCRIBED v5e (no chip), its
 optimized HLO dumped for `benchmarks/step_hlo_compare.py`:
-    JAX_PLATFORMS=cpu python3 step_program.py <tree> <cell> <dump dir>
+    JAX_PLATFORMS=cpu python3 step_program.py <tree> <cell> <dump dir> [flash|auto]
+(`attention` forced to "flash" unless "auto" leaves the traffic's own: since
+PR 47 the two are one program, on an older tree "auto" holds no flash call).
 `<tree>` is a checkout (the program and the benchmark's files are read from
 it, so two trees give two dumps). A compile is not a chip run."""
 import dataclasses, importlib, math, os, sys, time
 
 tree, cell, dump = sys.argv[1:4]
+forced = {} if sys.argv[4:] == ["auto"] else {"attention": "flash"}
 sys.path.insert(0, os.path.abspath(tree))
 os.chdir(tree)
 import jax, jax.numpy as jnp, numpy as np
@@ -21,8 +24,8 @@ resolved = catalog.resolve_cell(manifest, cell, "end_to_end")
 traffic = resolved["traffic"]
 module_name, preset = resolved["model"]["entry"].split(":")
 module = importlib.import_module(module_name)
-cfg = dataclasses.replace(getattr(module, preset)(), attention="flash",
-                          remat=traffic["remat"])
+cfg = dataclasses.replace(getattr(module, preset)(), remat=traffic["remat"],
+                          **forced)
 axes = {"dp": 1, "tp": 1, **traffic["mesh"]}
 n = math.prod(axes.values())
 topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")

@@ -18,8 +18,8 @@ def left():
 
 def probe(overrides, seeds):
     args = [f"{k}={v}" for k, v in overrides.items()]
-    p = subprocess.run([sys.executable, "benchmarks/results/pr43_joyai/held_by_layer.py",
-                        str(STEPS), *args, *map(str, seeds)], capture_output=True, text=True)
+    p = subprocess.run([sys.executable, "benchmarks/held_by_layer.py",
+                        "joyaiflash5l-b2s8k", str(STEPS), *args, *map(str, seeds)], capture_output=True, text=True)
     rows = []
     for line in p.stdout.splitlines():
         if line.startswith("{"):

@@ -2,10 +2,10 @@
 profile folded over the program's own scope table
 (`compile_watch.compiled("train_step").scope_table()`):
 
-    python3 benchmarks/results/pr38_scope/step_by_scope.py <cell> <steps before> <seed>
+    python3 benchmarks/step_by_scope.py <cell> <steps before> <seed>
 
 One process (it holds the cell's chips). Prints one JSON line and appends it
-to chiprun_out/pr38_scope/step_by_scope.jsonl: milliseconds a step by the
+to chiprun_out/step_by_scope/step_by_scope.jsonl: milliseconds a step by the
 innermost scope of a fixed list (`PARTS`) with each part's eight longest
 instructions, by phase, by flash / ssd / gmm call name, the seconds `scope_table()` took (its re-lowering: a
 compile-cache hit), and every instruction that stays unscoped with its time,
@@ -38,7 +38,7 @@ from ray_tpu.parallel.train_step import (  # noqa: E402
 )
 
 TINY = os.environ.get("PROBE_TINY") == "1"
-OUT = os.path.join(ROOT, "chiprun_out", "pr38_scope")
+OUT = os.path.join(ROOT, "chiprun_out", "step_by_scope")
 # the part of the model an instruction is booked under: the first of these
 # found in its scopes, read from the innermost outwards
 PARTS = ("optimizer", "loss_tail", "embed", "router", "dispatch", "experts",

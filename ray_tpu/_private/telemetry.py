@@ -395,17 +395,6 @@ CATALOG: dict[str, dict] = {
                        "mid-training means shape churn is recompiling "
                        "the step",
     },
-    # --- flash kernels' tile schedule (ops/flash_attention.py) ---
-    # bounded: three kernels x the head-width pairs a model has x the
-    # tiles `_TILES` holds
-    "ray_tpu_flash_tile_plans_total": {
-        "kind": "Counter", "tags": ("kernel", "widths", "tile"),
-        "description": "Flash-attention kernel calls TRACED (once a call "
-                       "site and trace, nothing on the device), by kernel "
-                       "(fwd|dq|dkv), the call's head widths (q and k's / "
-                       "v's, e.g. 192/128) and the score tile its plan "
-                       "took (rows x columns, e.g. 256x256)",
-    },
     "ray_tpu_mesh_build_seconds": {
         "kind": "Histogram", "tags": ("kind",),
         "boundaries": [0.001, 0.01, 0.1, 0.5, 1.0, 5.0, 30.0],

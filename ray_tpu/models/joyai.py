@@ -272,9 +272,7 @@ def _layer_apply(x, layer, *, dense: bool, cfg: JoyaiConfig, impl: str,
                                      compute_dtype=cd, mesh=mesh)
             routed = stats["counts"], stats.get("compact", jnp.float32(0))
         x = h + out
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    return x, routed
+    return sh.constrain(x, mesh, "batch", "seq", "embed"), routed
 
 
 def _run_layer(x, layer, *, dense: bool, cfg: JoyaiConfig, impl, mesh):
@@ -283,24 +281,14 @@ def _run_layer(x, layer, *, dense: bool, cfg: JoyaiConfig, impl, mesh):
     return (L.remat(body) if cfg.remat else body)(x, layer)
 
 
-def _embed(params, tokens, mesh):
-    x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    return x
-
-
 def _trunk(params, tokens, cfg: JoyaiConfig, mesh):
     """tokens [B, S] -> (the stream behind the last layer [B, S, d], BEFORE
     the last norm; the routed layers' (assignments by expert, ran bounded))."""
-    if mesh is not None and dict(mesh.shape).get("tp", 1) > 1:
-        raise ValueError(
-            "joyai: latent attention's low-rank matrices and shared key "
-            "columns are whole on every `tp` rank; a mesh with tp > 1 is "
-            "not supported (dp and ep meshes are)")
+    L.refuse_tp(mesh, "joyai", "latent attention's low-rank matrices and "
+                "shared key columns")
     impl = L.resolve_attention(cfg.attention, mesh)
     with jax.named_scope("embed"):
-        x = _embed(params, tokens, mesh)
+        x = L.embed(params["wte"], tokens, mesh)
     by_layer = []
     with jax.named_scope("blocks"):
         for depth, layer in enumerate(params["layers"]):
@@ -346,7 +334,8 @@ def loss_fn(params, batch, cfg: JoyaiConfig,
             mtp, eps = params["mtp"], cfg.norm_eps
             # row i: t_{i+1}'s embedding beside h_i, to predict t_{i+2}
             joined = jnp.concatenate(
-                [L.rms_norm(_embed(params, targets, mesh), mtp["enorm"], eps),
+                [L.rms_norm(L.embed(params["wte"], targets, mesh),
+                            mtp["enorm"], eps),
                  L.rms_norm(x, mtp["hnorm"], eps)], axis=-1)
             y = L._project(cfg.dtype, False)(
                 "bse,ed->bsd", joined, mtp["eh_proj"], jnp.float32)

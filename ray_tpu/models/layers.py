@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import grouped_matmul, mamba_stages, mxu, ssd
+from ray_tpu.ops import grouped_matmul, mamba_stages, mxu, ssd, target
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention_local
 
@@ -176,15 +176,14 @@ GROUPED_ATTENTION_LOGICAL = dict(ATTENTION_LOGICAL,
 
 def resolve_attention(attention: str, mesh=None) -> str:
     """A config's `attention` as `apply_attention`'s `impl`: "auto" is ring
-    attention where the mesh splits the sequence, the Pallas kernel on a
-    TPU, the plain reference elsewhere."""
+    attention where the mesh splits the sequence, the Pallas kernel where
+    the call runs on a TPU (`target.where`), the plain reference
+    elsewhere."""
     if attention != "auto":
         return attention
     if mesh is not None and dict(mesh.shape).get("sp", 1) > 1:
         return "ring"
-    if jax.default_backend() == "tpu":
-        return "flash"
-    return "reference"
+    return "flash" if target.where(mesh)[0] == "tpu" else "reference"
 
 
 # `checkpoint_name` of the attention sub-layer's output, [B, S, d_model] as
@@ -804,14 +803,6 @@ MOE_EXTRA_LOGICAL = {"bias": (None,), "shared_w1": ("embed", "mlp"),
 # a shared expert beside GATED experts: the dense form's three leaves
 GATED_SHARED_LOGICAL = {"shared": GATED_MLP_LOGICAL}
 
-# Rows of a row tile of the grouped-matmul kernel (`ops.grouped_matmul`'s
-# `row_tile`, wherever a tile that size divides the rows). A tile that holds
-# the end of one expert's rows and the start of the next's is visited once
-# for each, so the FLOPs issued exceed the needed by at most E − 1 tiles of
-# rows; `grouped_matmul.issued_ratio` gives the ratio of a given routing.
-GROUP_ROW_TILE = grouped_matmul.ROW_TILE
-
-
 # A share's bounds over the held experts' expected rows: ONE, at twice the
 # expectation. It holds the two cells with a share while their routing is
 # even or turns away from this chip. Rungs at 4 and 8 times were tried
@@ -835,7 +826,7 @@ def assignment_bounds(rows: int, local: int, n_experts: int) -> Tuple[int, ...]:
     the whole path only."""
     if local >= n_experts:
         return ()
-    tile = grouped_matmul.row_tile(rows) or GROUP_ROW_TILE
+    tile = grouped_matmul.row_tile(rows) or grouped_matmul.ROW_TILE
     expected = -(-rows * local // n_experts)
     bounds = {-(-factor * expected // tile) * tile
               for factor in _BOUND_FACTORS}
@@ -860,7 +851,7 @@ def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
     the whole path."""
     rows = tokens * cfg.top_k
     per_row = (3 if gated else 2) * 2 * d_model * d_ff
-    tile = grouped_matmul.row_tile(rows) or GROUP_ROW_TILE
+    tile = grouped_matmul.row_tile(rows) or grouped_matmul.ROW_TILE
     tiles = -(-rows // tile)
     visits = min(tiles + cfg.stacked // ep - 1, 2 * tiles)
     return {
@@ -1020,73 +1011,18 @@ def _one_of_bwd(programs, res, d):
 _one_of.defvjp(_one_of_fwd, _one_of_bwd)
 
 
-def _use_kernel(platform: str, rows: int, d_model: int, d_ff: int,
-                cd) -> bool:
-    """Whether the routed layer's products go through the Pallas kernels
-    (`ops.grouped_matmul`): on a TPU, where tiles divide the layer's shapes
-    — all of its products or none, decided once from what can be observed,
-    as `resolve_attention` decides for flash. Elsewhere (the CPU, shapes no
-    tile divides) they are XLA's grouped product (`_grouped_matmul`)."""
-    return (platform == "tpu"
-            and grouped_matmul.tile_plan(rows, d_model, d_ff, cd) is not None)
-
-
-def _kernel_width(platform: str, rows: int, d_model: int, d_ff: int, cd):
-    """The expert width at which the routed layer's products go through the
-    Pallas kernels: `d_ff` where `_use_kernel` says so; its next multiple of
-    128 where tiles divide THAT (the experts' compute-dtype copies are then
-    zero-padded to it: every activation here maps 0 to 0, so the padding
-    computes zeros, adds nothing, and takes no gradient); None where the
-    products are XLA's."""
-    for width in (d_ff, -(-d_ff // 128) * 128):
-        if _use_kernel(platform, rows, d_model, width, cd):
-            return width
-    return None
-
-
-# the axis of each expert leaf that is the expert's width
-_WIDTH_AXIS = {"w_gate": 2, "w_up": 2, "w1": 2, "w_down": 1, "w2": 1}
-
-
-def _grouped_matmul(lhs, rhs, sizes, cd, kernel: bool, widen=(0, 0)):
-    """lhs [M, k] rows in group order, rhs [G, k, n], sizes [E ≥ G] every
-    row's group: each of the first G groups' rows times its own matrix, the
-    other groups' rows zero. `kernel`: JAX's Pallas `gmm` / `tgmm`
-    (`ops.grouped_matmul`: the weight cast once and read as it lies by the
-    forward and by the product to the rows); otherwise `lax.ragged_dot`,
-    plain XLA off the TPU. `widen` (axis, by): zeros appended to the cast
-    weight (`_kernel_width`)."""
-    rhs = rhs.astype(cd)
-    if widen[1]:
-        rhs = jnp.pad(rhs, [(0, widen[1] if axis == widen[0] else 0)
-                            for axis in range(rhs.ndim)])
-    if kernel:
-        return grouped_matmul.grouped_matmul(lhs, rhs, sizes)
-    held = rhs.shape[0]
-    if held == sizes.shape[0]:
-        return jax.lax.ragged_dot(lhs, rhs, sizes,
-                                  preferred_element_type=cd)
-    # On a TPU XLA's grouped product leaves the rows of no group UNWRITTEN,
-    # in the result and, transposed, in the rows' cotangent: zero both (the
-    # mask on `lhs` is, transposed, the mask on its cotangent).
-    grouped = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes[:held]))[:, None]
-    out = jax.lax.ragged_dot(jnp.where(grouped, lhs, 0), rhs, sizes[:held],
-                             preferred_element_type=cd)
-    return jnp.where(grouped, out, 0)
-
-
 def _relu2(u):
     """relu(u)², in float32."""
     return jnp.square(jax.nn.relu(u.astype(jnp.float32)))
 
 
 def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
-                   first, cd, platform: str, activation: str = "gelu",
+                   first, cd, mesh=None, activation: str = "gelu",
                    gate: str = "silu"):
     """One device's part of the routed layer: x [b, s, D] its tokens, gates
     [b, s, K] their chosen experts and weights, `experts` the leaves of the
-    E_local experts it holds, `first` the id of the first of them,
-    `platform` what the device is (`_use_kernel`). Returns
+    E_local experts it holds, `first` the id of the first of them, `mesh`
+    where this runs (`grouped_matmul.grouped_matmul`'s). Returns
     [b, s, D] float32: for each token the weighted outputs of those of its
     experts that live here (all of them where nothing splits the experts).
 
@@ -1103,9 +1039,17 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
     gate_vals, gate_idx = (g.reshape(-1, top_k) for g in (gate_vals, gate_idx))
     rows = x2.shape[0] * top_k
     local = next(iter(experts.values())).shape[0]
-    through = functools.partial(
-        _through_experts, n_experts=n_experts, cd=cd, platform=platform,
-        activation=activation, gate=gate)
+    wide = experts["w_gate" if "w_gate" in experts else "w1"]
+
+    def through(fn, bound=None):
+        # decided ONCE for all of a program's products: the width at which
+        # they are the op's kernels, None where they are XLA's
+        width = grouped_matmul.kernel_width(bound or rows, *wide.shape[1:],
+                                            cd, mesh)
+        return functools.partial(
+            fn, n_experts=n_experts, cd=cd, mesh=mesh, width=width,
+            activation=activation, gate=gate, bound=bound)
+
     with jax.named_scope("dispatch"):
         # this device's experts first, in order; the others' rows behind them
         key = (gate_idx.reshape(rows) - first) % n_experts
@@ -1118,16 +1062,16 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
     bounds = assignment_bounds(rows, local, n_experts)
     if not bounds:
         # a share too small for a bound under its rows never runs bounded
-        return through(x2, gate_vals, experts, gate_idx, first, order,
-                       inverse, sizes).reshape(x.shape), (
+        return through(_through_experts)(
+            x2, gate_vals, experts, gate_idx, first, order, inverse,
+            sizes).reshape(x.shape), (
                            None if local == n_experts else jnp.int32(0))
     # the least bound that holds the local experts' rows; behind the last,
     # the whole path. Each program is a `jit`, so a model's layers (and the
     # forward, its recomputation and the backward of each) trace it once
     over = jnp.sum(jnp.sum(sizes[:local]) > jnp.asarray(bounds, jnp.int32))
-    programs = tuple(
-        functools.partial(_through_experts_jit, bound=b, **through.keywords)
-        for b in bounds + (None,))
+    programs = tuple(through(_through_experts_jit, b)
+                     for b in bounds + (None,))
     out = _one_of(programs, over, (x2, gate_vals, experts),
                   (gate_idx, jnp.asarray(first, jnp.int32), order, inverse,
                    sizes))
@@ -1135,7 +1079,7 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
 
 
 def _through_experts(x2, gate_vals, experts, gate_idx, first, order, inverse,
-                     sizes, *, n_experts: int, cd, platform: str,
+                     sizes, *, n_experts: int, cd, mesh, width: Optional[int],
                      activation: str, gate: str, bound: Optional[int] = None):
     """`_local_experts` behind the sort: x2 [T, D], gates [T, K], the
     sorted positions `order`, their inverse and every group's `sizes`
@@ -1146,37 +1090,39 @@ def _through_experts(x2, gate_vals, experts, gate_idx, first, order, inverse,
     local = next(iter(experts.values())).shape[0]
     with jax.named_scope("dispatch"):
         if bound is None:
-            rows = tokens * top_k
             taken = _take_assignments(x2.astype(cd), order, inverse)
         else:
-            rows, order = bound, order[:bound]
+            order = order[:bound]
             # what of the prefix lies behind the local experts' rows is one
             # more group of no matrix
             sizes = jnp.append(sizes[:local],
                                bound - jnp.sum(sizes[:local]))
             taken = _take_prefix(x2.astype(cd), order, inverse)
     with jax.named_scope("experts"):
-        wide = experts["w_gate" if "w_gate" in experts else "w1"]
-        width = _kernel_width(platform, rows, *wide.shape[1:], cd)
-        pad = 0 if width is None else width - wide.shape[2]
-
-        def product(lhs, name):
-            return _grouped_matmul(lhs, experts[name], sizes, cd,
-                                   width is not None, (_WIDTH_AXIS[name], pad))
+        def product(lhs, name, axis):
+            """`axis`: the one of the leaf's that is the experts' width,
+            the cast leaf zero-padded there to `width` (every activation
+            here maps 0 to 0)."""
+            rhs = experts[name].astype(cd)
+            pad = width and width - rhs.shape[axis]
+            if pad:
+                rhs = jnp.pad(rhs, [(0, pad if a == axis else 0)
+                                    for a in range(rhs.ndim)])
+            return grouped_matmul.grouped_matmul(lhs, rhs, sizes, mesh=mesh)
 
         if "w_gate" in experts:
             gate_fn = {"silu": jax.nn.silu, "relu": jax.nn.relu}[gate]
-            gated = product(taken, "w_gate")
-            up = product(taken, "w_up")
+            gated = product(taken, "w_gate", 2)
+            up = product(taken, "w_up", 2)
             hidden = (gate_fn(gated.astype(jnp.float32))
                       * up.astype(jnp.float32)).astype(cd)
-            y = product(hidden, "w_down")
+            y = product(hidden, "w_down", 1)
         elif activation == "gelu":
-            hidden = jax.nn.gelu(product(taken, "w1"))
-            y = product(hidden, "w2")
+            hidden = jax.nn.gelu(product(taken, "w1", 2))
+            y = product(hidden, "w2", 1)
         else:
-            hidden = _relu2(product(taken, "w1")).astype(cd)
-            y = product(hidden, "w2")
+            hidden = _relu2(product(taken, "w1", 2)).astype(cd)
+            y = product(hidden, "w2", 1)
     with jax.named_scope("combine"):
         if bound is None:
             y = _permute_rows(y, inverse, order).reshape(
@@ -1190,7 +1136,7 @@ def _through_experts(x2, gate_vals, experts, gate_idx, first, order, inverse,
 
 
 _through_experts_jit = jax.jit(_through_experts, static_argnames=(
-    "n_experts", "cd", "platform", "activation", "gate", "bound"))
+    "n_experts", "cd", "mesh", "width", "activation", "gate", "bound"))
 
 
 def _route(logits, bias, cfg: MoEConfig):
@@ -1271,9 +1217,8 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
     (`moe_plan`'s `bounds`), are not touched at all (`_local_experts`).
 
     mesh: as in `apply_attention` — the grouped matmul is a Mosaic kernel on
-    the TPU (`ops.grouped_matmul`, wherever the mesh's devices — without a
-    mesh the default backend's — are TPUs and its tiles divide the shapes:
-    `_use_kernel`), so dispatch, experts and combine run as per-device code.
+    the TPU (`ops.grouped_matmul` decides, from `target.where(mesh)` and its
+    tiles), so dispatch, experts and combine run as per-device code.
     Each device takes its share of the batch and the experts `ep` gives it
     (their `expert_mlp` slice under `tp`), computes its experts' part of
     its tokens' outputs, and the parts are summed over `ep` and `tp`.
@@ -1298,11 +1243,8 @@ def apply_moe(params: Params, x, cfg: MoEConfig, compute_dtype=jnp.bfloat16,
         routing = moe_route(params, x, cfg)
     gate_vals, gate_idx, stats = routing
 
-    platform = (jax.default_backend() if mesh is None
-                else mesh.devices.flat[0].platform)
-    local = functools.partial(_local_experts, n_experts=E, cd=cd,
-                              platform=platform, activation=cfg.activation,
-                              gate=cfg.gate)
+    local = functools.partial(_local_experts, n_experts=E, cd=cd, mesh=mesh,
+                              activation=cfg.activation, gate=cfg.gate)
     if mesh is None:
         out, compact = local(x, gate_vals, gate_idx, experts, first=cfg.first)
     else:
@@ -1354,6 +1296,22 @@ def partition_specs(logical, rules=None):
         is_leaf=lambda x: isinstance(x, tuple))
 
 
+def refuse_tp(mesh, model: str, whole: str):
+    """A model whose leaves (`whole`: which) every `tp` rank holds whole
+    has no program for a mesh that splits them."""
+    if mesh is not None and dict(mesh.shape).get("tp", 1) > 1:
+        raise ValueError(
+            f"{model}: {whole} are whole on every `tp` rank; a mesh with "
+            f"tp > 1 is not supported (dp and ep meshes are)")
+
+
+def embed(table, tokens, mesh=None):
+    """tokens [B, S] -> their rows of `table` [V, d] as the float32 stream
+    [B, S, d], split over the mesh as the layers keep it."""
+    return sh.constrain(jnp.take(table, tokens, axis=0).astype(jnp.float32),
+                        mesh, "batch", "seq", "embed")
+
+
 def head_logits(x, scale, table, *, eps: float, compute_dtype, mesh=None):
     """The stream behind the last layer -> logits [B, S, V] float32: an
     RMSNorm and the product with `table` [V, d] (an untied head, or the
@@ -1363,9 +1321,7 @@ def head_logits(x, scale, table, *, eps: float, compute_dtype, mesh=None):
     logits = jax.lax.dot_general(
         x, table.astype(compute_dtype), (((2,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-    if mesh is not None:
-        logits = sh.constrain(logits, mesh, "batch", "seq", "vocab")
-    return logits
+    return sh.constrain(logits, mesh, "batch", "seq", "vocab")
 
 
 def next_token_loss(logits, targets, mask=None):
@@ -1377,6 +1333,21 @@ def next_token_loss(logits, targets, mask=None):
     if mask is None:
         return jnp.mean(lse - tl)
     return jnp.sum(jnp.where(mask, lse - tl, 0.0)) / jnp.sum(mask)
+
+
+def share_loss(forward, params, batch, cfg: MoEConfig):
+    """The `loss_fn` of a model that holds a share of its experts: batch
+    {"tokens" [B, S+1] int32}, ids of this chip's vocabulary slice;
+    `forward(params, tokens)` -> (logits [B, S, V], the routed layers'
+    assignments by expert, whether each ran bounded). Mean next-token
+    cross-entropy over the slice, and how the routing went
+    (`share_metrics`)."""
+    tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
+    logits, counts, compact = forward(params, tokens)
+    with jax.named_scope("loss_tail"):
+        loss = next_token_loss(logits, targets)
+    return loss, share_metrics(loss, counts, compact, tokens=tokens.size,
+                               cfg=cfg)
 
 
 def share_metrics(loss, counts, compact, *, tokens: int, cfg: MoEConfig):

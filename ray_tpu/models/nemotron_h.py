@@ -263,9 +263,7 @@ def _layer_apply(x, layer, *, kind: str, cfg: NemotronHConfig, impl: str,
                                      three_pass=True)
             routed = stats["counts"], stats.get("compact", jnp.float32(0))
     x = x + out
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    return x, routed
+    return sh.constrain(x, mesh, "batch", "seq", "embed"), routed
 
 
 def forward(params, tokens, cfg: NemotronHConfig,
@@ -279,16 +277,11 @@ def _forward(params, tokens, cfg: NemotronHConfig, mesh):
     """`forward`, and by routed layer [n_E] whether its share of the
     experts ran on a bounded prefix of the assignments (`apply_moe`'s
     `compact`)."""
-    if mesh is not None and dict(mesh.shape).get("tp", 1) > 1:
-        raise ValueError(
-            "nemotron_h: the Mamba mixers' and the KV heads' leaves are "
-            "whole on every `tp` rank; a mesh with tp > 1 is not supported "
-            "(dp and ep meshes are)")
+    L.refuse_tp(mesh, "nemotron_h",
+                "the Mamba mixers' and the KV heads' leaves")
     impl = L.resolve_attention(cfg.attention, mesh)
     with jax.named_scope("embed"):
-        x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
+        x = L.embed(params["wte"], tokens, mesh)
     by_layer = []
     with jax.named_scope("blocks"):
         for depth, kind in enumerate(cfg.pattern):
@@ -311,16 +304,7 @@ def _forward(params, tokens, cfg: NemotronHConfig, mesh):
 
 def loss_fn(params, batch, cfg: NemotronHConfig,
             mesh: Optional[Mesh] = None) -> Tuple[jnp.ndarray, dict]:
-    """batch: {"tokens" [B, S+1] int32}, ids of this chip's vocabulary
-    slice. Mean next-token cross-entropy over the slice, and how the
-    routing went: `moe_assignments` (tokens × top_k × routed layers),
-    `moe_held` (those of them that chose an expert held here; the others'
-    outputs are the absent chips') and `moe_compact` (the routed layers
-    whose held rows stayed under the share's bound, so that only that many
-    were moved)."""
-    tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    logits, counts, compact = _forward(params, tokens, cfg, mesh)
-    with jax.named_scope("loss_tail"):
-        loss = L.next_token_loss(logits, targets)
-    return loss, L.share_metrics(loss, counts, compact, tokens=tokens.size,
-                                 cfg=cfg.moe)
+    """`layers.share_loss` of this model: the cross-entropy, and how the
+    routed layers' routing went."""
+    return L.share_loss(functools.partial(_forward, cfg=cfg, mesh=mesh),
+                        params, batch, cfg.moe)

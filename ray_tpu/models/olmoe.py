@@ -190,15 +190,11 @@ def forward(params, tokens, cfg: OlmoeConfig, mesh: Optional[Mesh] = None):
     and `z` averaged over layers, `counts` [L, E])."""
     impl = L.resolve_attention(cfg.attention, mesh)
     with jax.named_scope("embed"):
-        x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
+        x = L.embed(params["wte"], tokens, mesh)
 
     def body(x, block):
         x, stats = _block_apply(block, x, cfg, impl, mesh)
-        if mesh is not None:
-            x = sh.constrain(x, mesh, "batch", "seq", "embed")
-        return x, stats
+        return sh.constrain(x, mesh, "batch", "seq", "embed"), stats
 
     if cfg.remat:
         body = L.remat(body)

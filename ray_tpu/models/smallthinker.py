@@ -265,9 +265,8 @@ def _block_apply(x, block, *, kind: Tuple[int, int], cfg: SmallThinkerConfig,
         block["moe"], L.rms_norm(h, block["ln2"], cfg.rms_norm_eps), cfg.moe,
         compute_dtype=cd, mesh=mesh, routing=routing)
     x = h + m
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
-    return x, (stats["counts"], stats.get("compact", jnp.float32(0)))
+    return sh.constrain(x, mesh, "batch", "seq", "embed"), (
+        stats["counts"], stats.get("compact", jnp.float32(0)))
 
 
 def forward(params, tokens, cfg: SmallThinkerConfig,
@@ -280,16 +279,10 @@ def forward(params, tokens, cfg: SmallThinkerConfig,
 def _forward(params, tokens, cfg: SmallThinkerConfig, mesh):
     """`forward`, and by layer [L] whether its share of the experts ran on
     a bounded prefix of the assignments (`apply_moe`'s `compact`)."""
-    if mesh is not None and dict(mesh.shape).get("tp", 1) > 1:
-        raise ValueError(
-            "smallthinker: the KV heads' leaves are whole on every `tp` "
-            "rank; a mesh with tp > 1 is not supported (dp and ep meshes "
-            "are)")
+    L.refuse_tp(mesh, "smallthinker", "the KV heads' leaves")
     impl = L.resolve_attention(cfg.attention, mesh)
     with jax.named_scope("embed"):
-        x = jnp.take(params["wte"], tokens, axis=0).astype(jnp.float32)
-    if mesh is not None:
-        x = sh.constrain(x, mesh, "batch", "seq", "embed")
+        x = L.embed(params["wte"], tokens, mesh)
 
     def period(x, stacked):
         routed = []
@@ -315,15 +308,7 @@ def _forward(params, tokens, cfg: SmallThinkerConfig, mesh):
 
 def loss_fn(params, batch, cfg: SmallThinkerConfig,
             mesh: Optional[Mesh] = None) -> Tuple[jnp.ndarray, dict]:
-    """batch: {"tokens" [B, S+1] int32}, ids of this chip's vocabulary
-    slice. Mean next-token cross-entropy over the slice, and how the
-    routing went: `moe_assignments` (tokens × top_k × layers), `moe_held`
-    (those of them that chose an expert held here; the others' outputs are
-    the absent chips') and `moe_compact` (the layers whose held rows stayed
-    under the share's bound, so that only that many were moved)."""
-    tokens, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    logits, counts, compact = _forward(params, tokens, cfg, mesh)
-    with jax.named_scope("loss_tail"):
-        loss = L.next_token_loss(logits, targets)
-    return loss, L.share_metrics(loss, counts, compact, tokens=tokens.size,
-                                 cfg=cfg.moe)
+    """`layers.share_loss` of this model: the cross-entropy, and how every
+    layer's routing went."""
+    return L.share_loss(functools.partial(_forward, cfg=cfg, mesh=mesh),
+                        params, batch, cfg.moe)

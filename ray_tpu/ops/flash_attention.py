@@ -101,8 +101,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu._private.telemetry import counter_inc
-
 NEG_INF = -1e30
 _LANES = 128
 # `checkpoint_name`s of the forward kernel's two outputs as the backward
@@ -227,17 +225,6 @@ def tile_plan(seq_len: int, head_dim: int, dtype,
                 default=step)
     return TilePlans(**{name: TilePlan(tq, tk, major, s_pad)
                         for name, (tq, tk) in tiles.items()})
-
-
-def _count_plans(head_dim: int, v_dim: int, **plans: TilePlan):
-    """Which `_TILES` entry each call took: one count a kernel whose call is
-    being built — in Python, when a call site is traced, never on the
-    device — tagged with the kernel, the widths ("192/128") and the tile
-    ("256x512")."""
-    for kernel, plan in plans.items():
-        counter_inc("ray_tpu_flash_tile_plans_total", tags={
-            "kernel": kernel, "widths": f"{head_dim}/{v_dim}",
-            "tile": f"{plan.tile_q}x{plan.tile_k}"})
 
 
 def _clamp(x, lo, hi):
@@ -792,7 +779,6 @@ def _flash_fwd(q, k, v, shared=(), *, scale, causal, block_q, block_k,
     BH, S, D = q.shape
     Dv = v.shape[-1]
     plan = tile_plan(S, D, q.dtype, block_q, block_k, v_dim=Dv).fwd
-    _count_plans(D, Dv, fwd=plan)
     S_pad, major = plan.s_pad, plan.major
     if S_pad != S:
         q, k, v, *shared = _pad_rows((q, k, v, *shared), S_pad)
@@ -993,7 +979,6 @@ def _flash_bwd(q, k, v, shared, lse, delta, do, *, scale, causal, block_q,
     heads are summed the same way."""
     BH, S, D = q.shape
     plans = tile_plan(S, D, q.dtype, block_q, block_k, v_dim=v.shape[-1])
-    _count_plans(D, v.shape[-1], dq=plans.dq, dkv=plans.dkv)
     S_pad, major = plans.dq.s_pad, plans.dq.major
     if S_pad != S:
         q, k, v, do, *shared = _pad_rows((q, k, v, do, *shared), S_pad)

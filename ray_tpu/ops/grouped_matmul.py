@@ -29,9 +29,11 @@ it does not visit is UNWRITTEN. So `sizes` names every row's group —
 — and the kernel's own epilogue zeroes the rows of the other E − G; with
 E = G there is nothing to zero and no pass is spent on it.
 
-The kernels compile for the TPU or raise. ``interpret=True`` (the Pallas
-interpreter) is for tests that ask for it; off-TPU product code uses XLA's
-grouped product (`models.layers._grouped_matmul` chooses).
+The kernels compile for the TPU or raise, so `grouped_matmul` takes them
+where the call runs on a TPU (`target.where`) and `tile_plan` has tiles for
+its shapes, and XLA's own grouped product (`lax.ragged_dot`: the CPU's path
+and the tests' reference) everywhere else. ``interpret=True`` (the Pallas
+interpreter) is for tests that ask for it.
 """
 from __future__ import annotations
 
@@ -41,6 +43,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ray_tpu.ops import target
 
 _LANES = 128
 # What one grid step may hold in VMEM (double-buffered blocks, the float32
@@ -135,22 +139,13 @@ def _kernels():
     return gmm, tgmm
 
 
-def _tiling(m: int, k: int, n: int, dtype,
-            to_weights: bool = False) -> Tuple[int, int, int]:
-    plan = tile_plan(m, k, n, dtype, to_weights=to_weights)
-    if plan is None:
-        raise ValueError(
-            f"grouped_matmul: no tiles divide [{m}, {k}] × [G, {k}, {n}]")
-    return plan
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _grouped(lhs, rhs, sizes, interpret):
     m, k = lhs.shape
     gmm, _ = _kernels()
     return gmm(
         lhs, rhs, sizes, preferred_element_type=lhs.dtype,
-        tiling=_tiling(m, k, rhs.shape[2], lhs.dtype), interpret=interpret)
+        tiling=tile_plan(m, k, rhs.shape[2], lhs.dtype), interpret=interpret)
 
 
 def _grouped_fwd(lhs, rhs, sizes, interpret):
@@ -164,13 +159,13 @@ def _grouped_bwd(interpret, res, d):
     gmm, tgmm = _kernels()
     d_lhs = gmm(
         d, rhs, sizes, preferred_element_type=lhs.dtype,
-        tiling=_tiling(m, n, k, lhs.dtype), transpose_rhs=True,
+        tiling=tile_plan(m, n, k, lhs.dtype), transpose_rhs=True,
         interpret=interpret)
     # `tgmm` takes the rows as [k, M] and turns them back itself: no
     # transpose is left in the program
     d_rhs = tgmm(
         lhs.T, d, sizes, preferred_element_type=rhs.dtype,
-        tiling=_tiling(m, k, n, lhs.dtype, to_weights=True),
+        tiling=tile_plan(m, k, n, lhs.dtype, to_weights=True),
         num_actual_groups=rhs.shape[0], interpret=interpret)
     return d_lhs, d_rhs, None
 
@@ -178,13 +173,59 @@ def _grouped_bwd(interpret, res, d):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(lhs, rhs, sizes, *, interpret: bool = False):
+def _ragged(lhs, rhs, sizes):
+    """The plain form: `lax.ragged_dot`, the rows of no group zero."""
+    held = rhs.shape[0]
+    if held == sizes.shape[0]:
+        return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                  preferred_element_type=lhs.dtype)
+    # On a TPU XLA's grouped product leaves the rows of no group UNWRITTEN,
+    # in the result and, transposed, in the rows' cotangent: zero both (the
+    # mask on `lhs` is, transposed, the mask on its cotangent).
+    grouped = (jnp.arange(lhs.shape[0]) < jnp.sum(sizes[:held]))[:, None]
+    out = jax.lax.ragged_dot(jnp.where(grouped, lhs, 0), rhs, sizes[:held],
+                             preferred_element_type=lhs.dtype)
+    return jnp.where(grouped, out, 0)
+
+
+def kernel_width(rows: int, d_model: int, d_ff: int, dtype,
+                 mesh=None) -> Optional[int]:
+    """The width at which a layer of experts ``[G, d_model, d_ff]`` (and
+    back) over `rows` rows goes through the kernels, for a caller that
+    decides once for all of a layer's products: `d_ff` where the call runs
+    on a TPU and tiles divide the shapes; its next multiple of 128 where
+    tiles divide THAT (the caller zero-pads the experts' compute-dtype
+    copies to it: an activation that maps 0 to 0 makes the padding compute
+    zeros, add nothing and take no gradient); None where `grouped_matmul`
+    is XLA's product whatever the padding. mesh: as there."""
+    if target.where(mesh)[0] != "tpu":
+        return None
+    return next((width for width in (d_ff, -(-d_ff // _LANES) * _LANES)
+                 if tile_plan(rows, d_model, width, dtype) is not None), None)
+
+
+def grouped_matmul(lhs, rhs, sizes, *, mesh=None, interpret: bool = False):
     """lhs [M, k] rows in group order, rhs [G, k, n], sizes [E ≥ G] int32
     with Σ sizes = M -> [M, n] in ``lhs.dtype``: each of the first G groups'
     rows times its own matrix, the rows of the other groups zero.
     Differentiable in `lhs` and `rhs` (rows of the other groups get a zero
-    gradient, and give none to a weight)."""
+    gradient, and give none to a weight).
+
+    mesh: where the call runs (`target.where`; the call itself is
+    per-device code, under the caller's `shard_map` where there are several
+    devices). On a TPU, where `tile_plan` has tiles for the shapes, JAX's
+    Pallas `gmm` / `tgmm` (the weight read as it lies by the forward and by
+    the product to the rows); elsewhere `lax.ragged_dot`. `interpret` runs
+    the kernels in Pallas's interpreter wherever the process is, and exists
+    for tests."""
     if lhs.dtype != rhs.dtype:
         raise ValueError(f"grouped_matmul: lhs is {lhs.dtype}, rhs "
                          f"{rhs.dtype}; cast them to one compute dtype")
-    return _grouped(lhs, rhs, sizes.astype(jnp.int32), interpret)
+    (m, k), n = lhs.shape, rhs.shape[2]
+    if (target.where(mesh, interpret=interpret)[0] == "tpu"
+            and tile_plan(m, k, n, lhs.dtype) is not None):
+        return _grouped(lhs, rhs, sizes.astype(jnp.int32), interpret)
+    if interpret:
+        raise ValueError(
+            f"grouped_matmul: no tiles divide [{m}, {k}] × [G, {k}, {n}]")
+    return _ragged(lhs, rhs, sizes)

@@ -65,6 +65,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import target
+
 _LANES = 128
 # A grid step's blocks [rows, tokens]: the conv's (both directions) and the
 # gate-norm's token width forward and backward (its rows are one group's).
@@ -130,8 +132,8 @@ def _use_conv_kernel(platform: str, devices: int, tokens: int, start: int,
     that splits the batch would need the calls under a `shard_map`, which
     is not written) where `CONV_BLOCK` divides the tokens, the channels and
     the column the channels start at, the history fits the halo, and a grid
-    step fits the VMEM budget. Decided from what can be observed, as
-    `ssd._use_kernel` decides for the scan."""
+    step fits the VMEM budget. `platform` and `devices` are
+    `target.where`'s answer."""
     rows, block = CONV_BLOCK
     return (platform == "tpu" and devices == 1
             and block % _LANES == 0 and rows % 8 == 0
@@ -469,27 +471,19 @@ _gate_stage.defvjp(_gate_stage_fwd, _gate_stage_bwd)
 
 
 # ------------------------------------------------------------------ entries
-def _where(mesh, interpret: bool):
-    if interpret:
-        return "tpu", 1
-    if mesh is None:
-        return jax.default_backend(), 1
-    return mesh.devices.flat[0].platform, mesh.devices.size
-
-
 def conv_silu(src, w, b, *, start: int = 0, mesh=None,
               interpret: bool = False):
     """src [B, T, ·] float32, taps w [K, C], bias b [C] -> ``SiLU(causal
     depthwise conv_K(x) + b)`` [B, T, C] float32 for x = src's columns
     [start, start + C).
 
-    mesh: as in `ssd.ssd` — its devices (without one, the default
-    backend's) and the shapes decide between the kernels and the plain
-    form (`_use_conv_kernel`). `interpret` runs the kernels in Pallas's
-    interpreter wherever the process is, and exists for tests."""
+    mesh: where the stage runs (`target.where`); that and the shapes
+    decide between the kernels and the plain form (`_use_conv_kernel`).
+    `interpret` runs the kernels in Pallas's interpreter wherever the
+    process is, and exists for tests."""
     (taps, channels), tokens = w.shape, src.shape[1]
-    if _use_conv_kernel(*_where(mesh, interpret), tokens, start, channels,
-                        taps):
+    if _use_conv_kernel(*target.where(mesh, interpret=interpret), tokens,
+                        start, channels, taps):
         return _conv_stage(src, w, b, start, interpret)
     if interpret:
         raise ValueError(f"conv_silu: no kernel tiling for {tokens} tokens, "
@@ -505,7 +499,8 @@ def gate_norm(y, src, scale, *, groups: int, eps: float, mesh=None,
     float32, the norm over each of `groups` equal shares of the width.
     mesh, interpret: as in `conv_silu` (`_use_gate_kernel`)."""
     tokens, inner = y.shape[1:]
-    if _use_gate_kernel(*_where(mesh, interpret), tokens, inner, groups):
+    if _use_gate_kernel(*target.where(mesh, interpret=interpret), tokens,
+                        inner, groups):
         return _gate_stage(y, src, scale, groups, float(eps), interpret)
     if interpret:
         raise ValueError(f"gate_norm: no kernel tiling for {tokens} tokens, "

@@ -68,7 +68,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops import mxu
+from ray_tpu.ops import mxu, target
 
 _LANES = 128
 # What one grid step may hold in VMEM (double-buffered blocks + scratch), of
@@ -185,8 +185,8 @@ def _use_kernel(platform: str, devices: int, chunk: int, per_group: int,
     not written) where (8, 128) tiles divide every block — the chunk and
     the state multiples of 128, a head a whole number of sublane tiles, a
     group's heads one or a multiple of 8 that divides 128 — and a grid
-    step fits the VMEM budget. Decided once from what can be observed, as
-    `layers._use_kernel` decides for the grouped products."""
+    step fits the VMEM budget. `platform` and `devices` are
+    `target.where`'s answer."""
     return (platform == "tpu" and devices == 1
             and chunk % _LANES == 0 and state % _LANES == 0
             and head_dim % 8 == 0
@@ -513,17 +513,14 @@ def ssd(x, dt, A, B, C, D, *, chunk: int, compute_dtype=jnp.bfloat16,
     """x [b, T, H, P], dt [b, T, H] (Δ > 0), A [H] (< 0), B, C [b, T, G, N],
     D [H] -> y [b, T, H, P] float32.
 
-    mesh: as in `layers.apply_attention` — where the scan runs; its devices
-    (without one, the default backend's) and the shapes decide between the
-    kernels and the plain form (`_use_kernel`). `interpret` runs the kernels
-    in Pallas's interpreter wherever the process is, and exists for tests."""
+    mesh: where the scan runs (`target.where`); that and the shapes decide
+    between the kernels and the plain form (`_use_kernel`). `interpret` runs
+    the kernels in Pallas's interpreter wherever the process is, and exists
+    for tests."""
     H, P = x.shape[2:]
     G, N = B.shape[2:]
-    platform, devices = ((jax.default_backend(), 1) if mesh is None else
-                         (mesh.devices.flat[0].platform, mesh.devices.size))
-    if interpret:
-        platform, devices = "tpu", 1
-    if _use_kernel(platform, devices, chunk, H // G, P, N):
+    if _use_kernel(*target.where(mesh, interpret=interpret), chunk, H // G,
+                   P, N):
         return _ssd_kernels(x, dt, A, B, C, D, chunk=chunk,
                             compute_dtype=compute_dtype,
                             three_pass=three_pass, interpret=interpret)

@@ -66,8 +66,11 @@ def tree_shard(tree, mesh: Mesh, spec_tree):
     )
 
 
-def constrain(x, mesh: Mesh, *logical_axes: Optional[str], rules=None):
-    """In-jit sharding constraint by logical names."""
+def constrain(x, mesh: Optional[Mesh], *logical_axes: Optional[str],
+              rules=None):
+    """In-jit sharding constraint by logical names; without a mesh, x."""
+    if mesh is None:
+        return x
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, spec(*logical_axes, rules=rules))
     )

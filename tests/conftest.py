@@ -260,3 +260,18 @@ def ray_start_cluster():
     cluster = Cluster()
     yield cluster
     cluster.shutdown()
+
+
+@pytest.fixture
+def runs_on(monkeypatch):
+    """``runs_on("tpu")``: until the test ends `ops.target.where` answers
+    ``(platform, devices)`` whatever the mesh — what the ops see in a
+    compile for a described chip. Every op's kernel-or-plain choice hangs
+    on that one function, so this is the tests' one seam for it."""
+    def answer(platform: str, devices: int = 1):
+        from ray_tpu.ops import target
+
+        monkeypatch.setattr(
+            target, "where",
+            lambda mesh=None, *, interpret=False: (platform, devices))
+    return answer

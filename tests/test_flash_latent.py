@@ -159,19 +159,17 @@ def test_latent_kernels_at_the_new_tiles(monkeypatch, S, H, whole, blocks):
                                    err_msg=name)
 
 
-def test_the_counter_says_which_call_took_which_entry():
-    """`ray_tpu_flash_tile_plans_total`: tracing a latent and an equal-width
-    call leaves one tag set a kernel — the kernel, the widths as the
-    operands have them, the tile its plan took."""
-    from ray_tpu.util.metrics import registry_snapshot
+def test_a_traced_call_takes_the_plan_of_its_own_widths(monkeypatch):
+    """Tracing a latent and two equal-width calls, forward and backward:
+    each kernel's call asks `tile_plan` for the widths its operands have (q
+    and k's, v's), and what it is given is that entry's tiles."""
+    asked, plan = {}, fa.tile_plan
 
-    def counts():
-        family = next((m for m in registry_snapshot()
-                       if m["name"] == "ray_tpu_flash_tile_plans_total"),
-                      {"values": []})
-        return {(v["tags"]["kernel"], v["tags"]["widths"], v["tags"]["tile"]):
-                v["value"] for v in family["values"]}
-    before = counts()
+    def recording(seq_len, head_dim, dtype, *blocks, v_dim=None):
+        plans = plan(seq_len, head_dim, dtype, *blocks, v_dim=v_dim)
+        asked[head_dim, v_dim] = tuple(p[:2] for p in plans)
+        return plans
+    monkeypatch.setattr(fa, "tile_plan", recording)
     q, k, shared, v, _ = _inputs(1, 1024, 1, **LATENT)
 
     def latent(q, k, shared, v):
@@ -183,14 +181,9 @@ def test_the_counter_says_which_call_took_which_entry():
     for width in (128, 64):
         x = v[..., :width]
         jax.make_jaxpr(jax.grad(equal, (0, 1, 2)))(x, x, x)
-    after = counts()
-    assert {key for key in after if after[key] > before.get(key, 0)} == {
-        ("fwd", "192/128", "256x512"), ("dq", "192/128", "256x256"),
-        ("dkv", "192/128", "256x256"),
-        ("fwd", "128/128", "128x256"), ("dq", "128/128", "256x256"),
-        ("dkv", "128/128", "128x128"),
-        ("fwd", "64/64", "128x256"), ("dq", "64/64", "256x256"),
-        ("dkv", "64/64", "128x128")}
+    default = ((128, 256), (256, 256), (128, 128))
+    assert asked == {(192, 128): ((256, 512), (256, 256), (256, 256)),
+                     (128, 128): default, (64, 64): default}
 
 
 def test_the_calls_carry_their_names_and_their_own_operand_lists():

@@ -13,6 +13,7 @@ import pytest
 from chipbench import compare
 from chipbench.references import lfm2_moe as reference
 from ray_tpu.models import layers as L
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.models import lfm2
 from tests.test_lfm2 import FILED, TINY
 
@@ -73,7 +74,7 @@ def test_the_eight_shares_parts_add_up_to_the_uncut_layer(depth):
         assert compare.rel_l2(part, routed) > 0.5
 
 
-def test_the_cells_routed_layer_at_the_published_widths():
+def test_the_cells_routed_layer_at_the_published_widths(runs_on):
     """16,384 tokens choose 4 of 64: 65,536 rows a layer, of which the 8
     held experts see 8,192 at their expectation (1,024 each, an eighth of
     their deployment's 8,192), worked on within a bound of 16,384; 1,536 is
@@ -88,8 +89,11 @@ def test_the_cells_routed_layer_at_the_published_widths():
     assert plan["bounds"] == (16_384,)
     assert plan["flops_needed"] == 8_192 * 3 * 2 * 2048 * 1536
     assert 8_192 // 8 == 1024 == 8 * 16384 * 4 // 64 // 8
-    assert L._kernel_width("tpu", 16_384, 2048, 1536, jnp.bfloat16) == 1536
-    assert L._kernel_width("cpu", 16_384, 2048, 1536, jnp.bfloat16) is None
+    assert grouped_matmul.kernel_width(16_384, 2048, 1536,
+                                       jnp.bfloat16) is None
+    runs_on("tpu")
+    assert grouped_matmul.kernel_width(16_384, 2048, 1536,
+                                       jnp.bfloat16) == 1536
 
 
 @pytest.mark.parametrize("routing,compact", [("even", 4), ("onto_the_held", 0)])

@@ -8,6 +8,7 @@ how often a kernel's body is traced for a step (the start-up budget); what
 benchmark's readers that go by shape."""
 import dataclasses
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from chipbench import catalog, compare, flops
 from chipbench.readers import trace_held, trace_ssm
 from ray_tpu.models import layers, nemotron_h
 from ray_tpu.ops import mamba_stages as stages
+from ray_tpu.ops import target
 from tests.test_ssd_kernels import _event_text
 from tests.test_zz_tp_overlap import _walk
 
@@ -221,9 +223,9 @@ def test_entries_fall_back_to_the_plain_form(blocks):
         with pytest.raises(ValueError, match="no kernel tiling"):
             entry(inputs, arg, interpret=True)
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("dp",))
-    assert stages._where(mesh, False) == ("cpu", 2)
-    assert stages._where(None, False) == ("cpu", 1)
-    assert stages._where(mesh, True) == ("tpu", 1)
+    assert target.where(mesh) == ("cpu", 2)
+    assert target.where() == ("cpu", 1)
+    assert target.where(mesh, interpret=True) == ("tpu", 1)
 
 
 def _apply_mamba_parent(params, u, cfg, *, compute_dtype, eps, three_pass):
@@ -441,7 +443,9 @@ def test_the_stages_call_sites_keep_their_scopes_in_every_phase(monkeypatch,
     from ray_tpu.parallel.compile_watch import parse_op_name
 
     blocks((128, 128), (128, 128))
-    monkeypatch.setattr(stages, "_where", lambda mesh, interpret: ("tpu", 1))
+    # the stages alone: the scan and the routed products keep this host's
+    monkeypatch.setattr(stages, "target", types.SimpleNamespace(
+        where=lambda mesh=None, *, interpret=False: ("tpu", 1)))
     cfg = dataclasses.replace(KERNEL_TINY, pattern="ME", remat=True)
     params = jax.eval_shape(
         lambda: nemotron_h.init(jax.random.PRNGKey(0), cfg))

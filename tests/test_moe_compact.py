@@ -217,8 +217,12 @@ def test_the_plans_bounds_are_the_layers_and_multiples_of_the_row_tile(
 # with every expert held and no mesh, as THE PARENT OF PR 37 traced it
 # (commit 6d357e1, JAX 0.9.0; addresses and source line numbers stripped):
 # the bound is a Python-level branch on shapes, and where the leaves hold
-# every scored expert it adds or moves no operation.
-PARENT_JAXPR = {"relu2": "93530557b970d28d", "silu_gated": "2d7a99b318aff008"}
+# every scored expert it adds or moves no operation. The digests are of that
+# text with a dtype's two spellings made one (`<class 'jax.numpy.bfloat16'>`
+# as `bfloat16`: since PR 47 the plain grouped product lives in
+# `ops.grouped_matmul` and hands `ragged_dot` its operand's dtype where the
+# layer handed the scalar type — the same operation, printed otherwise).
+PARENT_JAXPR = {"relu2": "4a9c779829774eec", "silu_gated": "99fd950b997c3eed"}
 
 
 def _digest(form):
@@ -230,6 +234,7 @@ def _digest(form):
         return jnp.sum(L.apply_moe(params, x, cfg)[0])
     text = str(jax.make_jaxpr(jax.grad(f, (0, 1)))(params, x))
     text = re.sub(r"\.py:\d+", ".py", re.sub(r"0x[0-9a-f]+", "0x", text))
+    text = re.sub(r"<class 'jax\.numpy\.(\w+)'>", r"\1", text)
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 

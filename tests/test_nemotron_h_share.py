@@ -4,6 +4,7 @@ reference; the cell's routed layer at the published widths; a share on the
 Pallas kernels at a padded width (interpreter) and on XLA's grouped product,
 whose unwritten rows are masked."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ import pytest
 from chipbench import compare
 from chipbench.references import nemotron_h as reference
 from ray_tpu.models import layers as L
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.models import nemotron_h
 from tests.test_nemotron_h import FILED, TINY
 
@@ -54,7 +56,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         48 * 3 * 4 * 64 * 32
 
 
-def test_the_cells_routed_layer_at_the_published_widths():
+def test_the_cells_routed_layer_at_the_published_widths(runs_on):
     """8,192 tokens choose 6 of 128: 49,152 rows, of which the 8 held
     experts see 3,072 at their expectation; 1,856 is no multiple of 128, so
     the Pallas kernels run at 1,920 on zero-padded copies of the weights."""
@@ -62,45 +64,50 @@ def test_the_cells_routed_layer_at_the_published_widths():
     plan = L.moe_plan(8192, 2688, 1856, moe, gated=False)
     assert plan["rows"] == 49_152
     assert plan["flops_needed"] == 3_072 * 2 * 2 * 2688 * 1856
-    assert not L._use_kernel("tpu", 49_152, 2688, 1856, jnp.bfloat16)
-    assert L._kernel_width("tpu", 49_152, 2688, 1856, jnp.bfloat16) == 1920
-    assert L._kernel_width("tpu", 65_536, 2048, 1024, jnp.bfloat16) == 1024
-    assert L._kernel_width("cpu", 49_152, 2688, 1856, jnp.bfloat16) is None
-    assert L._kernel_width("tpu", 100, 2688, 1856, jnp.bfloat16) is None
+    width = functools.partial(grouped_matmul.kernel_width,
+                              dtype=jnp.bfloat16)
+    assert width(49_152, 2688, 1856) is None
+    runs_on("tpu")
+    assert grouped_matmul.tile_plan(49_152, 2688, 1856, jnp.bfloat16) is None
+    assert width(49_152, 2688, 1856) == 1920
+    assert width(65_536, 2048, 1024) == 1024
+    assert width(100, 2688, 1856) is None
 
 
-def test_a_share_on_the_pallas_kernels_at_a_padded_width(monkeypatch):
+def test_a_share_on_the_pallas_kernels_at_a_padded_width(monkeypatch,
+                                                         runs_on):
     """What the cell's routed layer runs on a TPU, here in the Pallas
     interpreter: 2 of 8 experts held (`first` 2), relu², an expert width
     (160) that no tile divides, so the kernels run at 256 on zero-padded
     weights. Output and every gradient are the XLA path's; the tokens'
     rows for the absent experts come out zero and pass no gradient."""
-    from ray_tpu.ops import grouped_matmul
     calls = []
 
-    def interpreted(lhs, rhs, sizes):
+    def interpreted(lhs, rhs, sizes, mesh=None):
         calls.append((lhs.shape, rhs.shape, sizes.shape))
         return kernel(lhs, rhs, sizes, interpret=True)
 
     kernel = grouped_matmul.grouped_matmul
-    monkeypatch.setattr(grouped_matmul, "grouped_matmul", interpreted)
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     x = jax.random.normal(ks[0], (2, 64, 128))
     experts = {"w1": 0.1 * jax.random.normal(ks[1], (2, 128, 160)),
                "w2": 0.1 * jax.random.normal(ks[2], (2, 160, 128))}
     gate_idx = jax.random.randint(ks[3], (2, 64, 2), 0, 8)
     gate_vals = jax.random.uniform(ks[4], (2, 64, 2))
-    assert L._kernel_width("tpu", 256, 128, 160, jnp.float32) == 256
-
     def part(platform):
+        runs_on(platform)
+        monkeypatch.setattr(grouped_matmul, "grouped_matmul",
+                            interpreted if platform == "tpu" else kernel)
+
         def fn(x, gate_vals, experts):
             return L._local_experts(
                 x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, platform=platform, activation="relu2")[0]
+                cd=jnp.float32, activation="relu2")[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 
     got, got_grads = part("tpu")
+    assert grouped_matmul.kernel_width(256, 128, 160, jnp.float32) == 256
     assert calls and {c[1] for c in calls} == {(2, 128, 256), (2, 256, 128)}
     want, want_grads = part("cpu")
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
@@ -113,7 +120,7 @@ def test_a_share_on_the_pallas_kernels_at_a_padded_width(monkeypatch):
 
 
 def test_a_share_on_xlas_grouped_product_masks_the_rows_of_no_group(
-        monkeypatch):
+        monkeypatch, runs_on):
     """On a TPU `lax.ragged_dot` given fewer matrices than groups leaves
     the rows of no group UNWRITTEN, in its result and in the cotangent of
     its rows (PR 34's chip probe: a NaN loss). Here a stand-in that writes
@@ -146,13 +153,14 @@ def test_a_share_on_xlas_grouped_product_masks_the_rows_of_no_group(
     gate_idx = jax.random.randint(ks[3], (2, 50, 2), 0, 8)
     gate_vals = jax.random.uniform(ks[4], (2, 50, 2))
     # no tile divides 100 rows: XLA's product, on a TPU too
-    assert L._kernel_width("tpu", 100, 128, 160, jnp.float32) is None
+    runs_on("tpu")
+    assert grouped_matmul.kernel_width(100, 128, 160, jnp.float32) is None
 
     def part():
         def fn(x, gate_vals, experts):
             return L._local_experts(
                 x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, platform="tpu", activation="relu2")[0]
+                cd=jnp.float32, activation="relu2")[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 

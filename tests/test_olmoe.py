@@ -224,7 +224,7 @@ def test_swaps_of_the_last_chosen_expert_under_bf16_and_what_they_cost():
     assert rounding_alone <= 0.01 < with_swaps <= compare.GRAD_RTOL
 
 
-def test_moe_plan_counts_what_a_real_call_does():
+def test_moe_plan_counts_what_a_real_call_does(runs_on):
     cfg = L.MoEConfig(n_experts=8, top_k=2, norm_topk_prob=False)
     params = L.init_moe(jax.random.PRNGKey(8), 64, 32, cfg, gated=True)
     x = jnp.zeros((2, 128, 64))
@@ -255,10 +255,12 @@ def test_moe_plan_counts_what_a_real_call_does():
     # the kernel's row tile is `tile_plan`'s: 65,536 / 512 = 128 row tiles
     # and at most 63 more that two experts share
     tile = grouped_matmul.tile_plan(65_536, 2048, 1024, jnp.bfloat16)[0]
-    assert tile == grouped_matmul.row_tile(65_536) == L.GROUP_ROW_TILE
+    assert tile == grouped_matmul.row_tile(65_536) == grouped_matmul.ROW_TILE
     # and on a TPU the cell's layer takes the kernels, the layer above not
-    assert L._use_kernel("tpu", 65_536, 2048, 1024, jnp.bfloat16)
-    assert not L._use_kernel("tpu", 512, 64, 32, jnp.bfloat16)
+    runs_on("tpu")
+    assert grouped_matmul.kernel_width(65_536, 2048, 1024,
+                                       jnp.bfloat16) == 1024
+    assert grouped_matmul.kernel_width(512, 64, 32, jnp.bfloat16) is None
     assert 65_536 % tile == 0
     tiles = 65_536 // tile
     assert plan["flops_issued_max"] / plan["flops_needed"] == \
@@ -280,15 +282,15 @@ def test_moe_plan_counts_what_a_real_call_does():
 @pytest.mark.parametrize("gated, norm", [(True, False), (False, True)],
                          ids=["olmoe_form", "gpt2_form"])
 def test_local_experts_on_the_pallas_kernels_against_the_oracle(
-        gated, norm, monkeypatch):
+        gated, norm, monkeypatch, runs_on):
     """What a TPU runs, here in the Pallas interpreter: `_local_experts`
-    told its device is a TPU, at shapes the tiles divide, as two devices
+    where `target.where` says TPU, at shapes the tiles divide, as two devices
     that hold four of the eight experts each (`first` 0 and 4). Each
     device's rows for the other's experts lie behind its own and come out
     zero; the two parts sum to every token's full output."""
     calls = []
 
-    def interpreted(lhs, rhs, sizes):
+    def interpreted(lhs, rhs, sizes, mesh=None):
         calls.append((lhs.shape, rhs.shape, sizes.shape))
         return kernel(lhs, rhs, sizes, interpret=True)
 
@@ -302,13 +304,14 @@ def test_local_experts_on_the_pallas_kernels_against_the_oracle(
     if norm:
         gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
     experts = {k: v for k, v in params.items() if k != "wg"}
-    assert L._use_kernel("tpu", 256, 128, 128, jnp.float32)
-    assert not L._use_kernel("cpu", 256, 128, 128, jnp.float32)
+    assert grouped_matmul.kernel_width(256, 128, 128, jnp.float32) is None
+    runs_on("tpu")
+    assert grouped_matmul.kernel_width(256, 128, 128, jnp.float32) == 128
     parts = [
         L._local_experts(
             x, gate_vals, gate_idx,
             {k: v[first:first + 4] for k, v in experts.items()},
-            n_experts=8, first=first, cd=jnp.float32, platform="tpu")[0]
+            n_experts=8, first=first, cd=jnp.float32)[0]
         for first in (0, 4)]
     # every product of both devices went through the kernel, with all
     # eight groups' sizes and four matrices

@@ -13,6 +13,7 @@ import pytest
 from chipbench import compare
 from chipbench.references import smallthinker as reference
 from ray_tpu.models import layers as L
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.models import smallthinker
 from tests.test_smallthinker import FILED, TINY
 
@@ -68,7 +69,7 @@ def test_the_four_shares_parts_add_up_to_the_uncut_layer(kind):
         assert compare.rel_l2(part, routed) > 0.3
 
 
-def test_the_cells_routed_layer_at_the_published_widths():
+def test_the_cells_routed_layer_at_the_published_widths(runs_on):
     """16,384 tokens choose 6 of 64: 98,304 rows a layer, of which the 16
     held experts see 24,576 at their expectation (1,536 each, a quarter of
     their deployment's 6,144); 768 is a multiple of 128, so the Pallas
@@ -80,26 +81,26 @@ def test_the_cells_routed_layer_at_the_published_widths():
     assert plan["rows"] == 98_304
     assert plan["flops_needed"] == 24_576 * 3 * 2 * 2560 * 768
     assert 24_576 // 16 == 1536 == 4 * 16384 * 6 // 64 // 4
-    assert L._kernel_width("tpu", 98_304, 2560, 768, jnp.bfloat16) == 768
-    assert L._kernel_width("cpu", 98_304, 2560, 768, jnp.bfloat16) is None
+    assert grouped_matmul.kernel_width(98_304, 2560, 768, jnp.bfloat16) is None
+    runs_on("tpu")
+    assert grouped_matmul.kernel_width(98_304, 2560, 768, jnp.bfloat16) == 768
 
 
 @pytest.mark.parametrize("gate", ["relu", "silu"])
-def test_a_share_of_gated_experts_on_the_pallas_kernels(gate, monkeypatch):
+def test_a_share_of_gated_experts_on_the_pallas_kernels(gate, monkeypatch,
+                                                        runs_on):
     """What the cell's routed layer runs on a TPU, here in the Pallas
     interpreter: 2 of 8 gated experts held (`first` 2). Output and every
     gradient are the XLA path's; the tokens' rows for the absent experts
     come out zero and pass no gradient; and the ReLU gate is not the SiLU
     one."""
-    from ray_tpu.ops import grouped_matmul
     calls = []
 
-    def interpreted(lhs, rhs, sizes):
+    def interpreted(lhs, rhs, sizes, mesh=None):
         calls.append((lhs.shape, rhs.shape, sizes.shape))
         return kernel(lhs, rhs, sizes, interpret=True)
 
     kernel = grouped_matmul.grouped_matmul
-    monkeypatch.setattr(grouped_matmul, "grouped_matmul", interpreted)
     ks = jax.random.split(jax.random.PRNGKey(7), 6)
     x = jax.random.normal(ks[0], (2, 64, 128))
     experts = {"w_gate": 0.1 * jax.random.normal(ks[1], (2, 128, 128)),
@@ -109,10 +110,14 @@ def test_a_share_of_gated_experts_on_the_pallas_kernels(gate, monkeypatch):
     gate_vals = jax.random.uniform(ks[4], (2, 64, 2))
 
     def part(platform, gate=gate):
+        runs_on(platform)
+        monkeypatch.setattr(grouped_matmul, "grouped_matmul",
+                            interpreted if platform == "tpu" else kernel)
+
         def fn(x, gate_vals, experts):
             return L._local_experts(
                 x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, platform=platform, gate=gate)[0]
+                cd=jnp.float32, gate=gate)[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 

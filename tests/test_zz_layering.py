@@ -71,11 +71,8 @@ def _edges(box, denied=(), allowed=None):
 
 
 BOXES = {
-    # the kernels know nothing of the package but the runtime's metric
-    # catalog, into which they count what they chose at trace time (PR 44:
-    # `ray_tpu_flash_tile_plans_total`); the catalog imports nothing of the
-    # package at module level, and the runtime box below is denied `ops`
-    "ops": dict(box=["ops"], allowed=["_private.telemetry"]),
+    # the kernels import nothing of the package outside `ops`
+    "ops": dict(box=["ops"], allowed=[]),
     "parallel": dict(box=["parallel"],
                      denied=["models", "data", "air", "train", "serve",
                              "util.collective"]),
@@ -105,6 +102,21 @@ def test_a_model_imports_no_other_model():
                 found.append(f"{path.relative_to(ROOT)}:{line} imports "
                              f"{name}")
     assert found == []
+
+
+def test_one_function_asks_where_a_call_runs():
+    """Kernel or plain form hangs on `ops.target.where` alone: nothing else
+    under `ops/` or `models/` asks JAX for its backend or a device for its
+    platform (`parallel/` asks for other reasons)."""
+    found = []
+    for box in ("ops", "models"):
+        for path in sorted((ROOT / PKG / box).glob("*.py")):
+            for node in ast.walk(ast.parse(path.read_text(), str(path))):
+                if isinstance(node, ast.Attribute) and node.attr in (
+                        "default_backend", "platform"):
+                    found.append(f"{path.relative_to(ROOT)}:{node.lineno}")
+    assert {f.rpartition(":")[0] for f in found} == {
+        f"{PKG}/ops/target.py"}, found
 
 
 def test_the_walk_sees_function_level_and_relative_imports(tmp_path):
