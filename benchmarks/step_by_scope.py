@@ -49,7 +49,7 @@ PARTS = ("optimizer", "loss_tail", "embed", "router", "dispatch", "experts",
 KERNEL = re.compile(r"(flash_(?:window|latent)_(?:fwd|dq|dkv)|"
                     r"flash_(?:fwd|dq|dkv)|"
                     r"ssd_(?:fwd|bwd)|mamba_(?:conv|gate)_(?:fwd|bwd)|"
-                    r"t?gmm)(?:\.\d+)?$")
+                    r"delta_(?:fwd|bwd)|t?gmm)(?:\.\d+)?$")
 
 
 def part_of(scopes) -> str:

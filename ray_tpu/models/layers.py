@@ -709,8 +709,8 @@ def apply_gated_delta(params: Params, u, cfg: DeltaConfig, *,
     than `mamba_stages.gate_norm`'s; ``·W_out``. The three projections and
     the rule's products on the MXU in `compute_dtype`; conv, norms, decays
     and gates in float32. Scopes: `delta_proj`, `delta_conv`, `delta_rule`,
-    `delta_gate_norm`. mesh: as in `apply_mamba` (the conv stage's kernels on
-    one TPU whose tiles divide the shapes)."""
+    `delta_gate_norm`. mesh: as in `apply_mamba` (the conv stage's and the
+    rule's kernels on one TPU whose tiles divide the shapes)."""
     B, T, _ = u.shape
     G, H, K, V = cfg.n_k_heads, cfg.n_v_heads, cfg.k_dim, cfg.v_dim
     project = _project(compute_dtype, False)
@@ -728,16 +728,16 @@ def apply_gated_delta(params: Params, u, cfg: DeltaConfig, *,
             qkv, params["conv_w"], jnp.zeros((cfg.conv_dim,), f32),
             start=0, mesh=mesh)
     with jax.named_scope("delta_rule"):
-        q, k, v = jnp.split(qkv, [cfg.key_dim, 2 * cfg.key_dim], axis=-1)
         b, a = jnp.split(ba, 2, axis=-1)
         g = -jnp.exp(params["A_log"].astype(f32)) * jax.nn.softplus(
             a + params["dt_bias"].astype(f32))
         # q and k L2-normed, q scaled, inside the rule: its backward keeps
-        # the chunked conv outputs and no normed copy beside them
-        o = gated_delta.gated_delta(
-            q.reshape(B, T, G, K), k.reshape(B, T, G, K),
-            v.reshape(B, T, H, V), g, jax.nn.sigmoid(b), chunk=cfg.chunk,
-            compute_dtype=compute_dtype, normalize=eps)
+        # the conv's output and no normed copy beside it; its kernels read
+        # each head's columns out of `qkv` in place
+        o = gated_delta.gated_delta_packed(
+            qkv, g, jax.nn.sigmoid(b), key_heads=G, k_dim=K,
+            chunk=cfg.chunk, compute_dtype=compute_dtype, normalize=eps,
+            mesh=mesh)
     with jax.named_scope("delta_gate_norm"):
         y = rms_norm(o, params["norm"], eps) * jax.nn.silu(
             z.reshape(B, T, H, V))

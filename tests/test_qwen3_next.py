@@ -245,6 +245,62 @@ def test_each_half_of_a_layer_is_a_checkpoint_of_its_own():
         assert all("from the argument" in why for _, why in kept), kept
 
 
+def test_the_rules_kernels_in_the_step_lowered_for_a_tpu(monkeypatch):
+    """The step of three delta layers and an attention layer under remat,
+    lowered FOR A TPU (no compile, nothing run) at head widths the rule's
+    tiles divide: `delta_fwd` at six sites (forward and recompute) and
+    `delta_bwd` at three, every one under the scope `delta_rule` (a
+    `custom_vjp`'s backward rule inherits its caller's scopes), the kernels
+    named for the trace, and no loop left under `delta_rule` (the plain
+    form's carry is a `while`)."""
+    import re
+    import types
+
+    from ray_tpu.ops import gated_delta as gd
+    from ray_tpu.parallel.compile_watch import parse_op_name
+
+    # the rule alone: the conv stage and the routed products keep this host's
+    monkeypatch.setattr(gd, "target", types.SimpleNamespace(
+        where=lambda mesh=None, *, interpret=False: ("tpu", 1)))
+
+    def lowered(**fields):
+        cfg = dataclasses.replace(qwen3_next.qwen3_next_tiny(), remat=True,
+                                  layer_types=qwen3_next.layer_types(4),
+                                  **fields)
+        params = jax.eval_shape(
+            lambda: qwen3_next.init(jax.random.PRNGKey(0), cfg))
+        text = jax.jit(jax.grad(
+            lambda p, t: qwen3_next.loss_fn(p, {"tokens": t}, cfg)[0])).trace(
+                params, jax.ShapeDtypeStruct((1, 129), jnp.int32)).lower(
+                    lowering_platforms=("tpu",)).as_text(debug_info=True)
+        return text, dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text,
+                                     re.M))
+
+    text, names = lowered(n_k_heads=1, n_v_heads=2, k_dim=128, v_dim=128,
+                          chunk=64)
+    sites = {}
+    for callee, loc in re.findall(
+            r"call @(_delta_(?:fwd|bwd))(?:_\d+)?\(.*loc\((#loc\d+)\)", text):
+        scopes, phase = parse_op_name(names[loc] + "/call")
+        assert "delta_rule" in scopes and "gdn" in scopes, names[loc]
+        sites.setdefault(callee, []).append(phase)
+    assert {k: sorted(v) for k, v in sites.items()} == {
+        "_delta_fwd": ["forward"] * 3 + ["recompute"] * 3,
+        "_delta_bwd": ["backward"] * 3}
+    kernels = re.findall(r'custom_call @tpu_custom_call.*loc\((#loc\d+)\)',
+                         text)
+    assert sorted({names[loc] for loc in kernels}) == [
+        "delta_bwd/pallas_call", "delta_fwd/pallas_call"]
+
+    def loops(names):
+        return [n for n in names.values()
+                if "delta_rule" in n and "while" in n]
+    assert not loops(names)
+    # the tiny preset's own widths take the plain form, whose carry loops
+    text, names = lowered()
+    assert "tpu_custom_call" not in text and loops(names)
+
+
 def test_the_presets_and_what_the_model_refuses():
     cut, whole = (qwen3_next.qwen3_next_80b_a3b_4l(),
                   qwen3_next.qwen3_next_80b_a3b())

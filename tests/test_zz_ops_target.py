@@ -1,6 +1,7 @@
 """The one rule for "kernel or plain form" (`ops/target.py`): what `where`
 answers, and that each op with a kernel — flash through `resolve_attention`,
-the grouped product, the scan, the mixer's two stages — takes the kernel
+the grouped product, the scan, the mixer's two stages, the gated delta
+rule — takes the kernel
 where `where` says TPU and its plain form where it says CPU, at shapes its
 tiles divide. Tracing only: nothing runs, nothing compiles."""
 import jax
@@ -9,7 +10,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import layers as L
-from ray_tpu.ops import grouped_matmul, mamba_stages, ssd, target
+from ray_tpu.ops import gated_delta, grouped_matmul, mamba_stages, ssd, target
 from tests.test_zz_tp_overlap import _walk
 
 F32 = jnp.float32
@@ -46,6 +47,11 @@ OPS = {
         lambda y, src, scale: mamba_stages.gate_norm(y, src, scale, groups=8,
                                                      eps=1e-5),
         _shapes((1, 1024, 64), (1, 1024, 96), (64,)), "mamba_gate_"),
+    # two value heads on a key head of 128, the conv's [q | k | v] whole
+    "gated_delta": (
+        lambda qkv, g, beta: gated_delta.gated_delta_packed(
+            qkv, g, beta, key_heads=1, k_dim=128, normalize=1e-6),
+        _shapes((1, 512, 512), (1, 512, 2), (1, 512, 2)), "delta_"),
 }
 
 
