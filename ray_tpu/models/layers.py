@@ -207,6 +207,12 @@ ATTENTION_OUT = "attention_out"
 # `checkpoint_name` of a product whose forward value took three bf16 passes
 # (`_project`): three passes to rebuild, so `remat` keeps it.
 THREE_PASS_OUT = "three_pass_out"
+# `checkpoint_name` of what a routed layer decides once a step: the router's
+# float32 logits (six bf16 passes to rebuild), the top-k's choices, and the
+# sort of the assignments (`order`, `inverse`, `sizes`): all of it integers
+# and one `[tokens, n_experts]` table, and sorts and scatters to rebuild
+# (`moe_route`, `_local_experts`; `routing_plan` gives the bytes).
+ROUTING = "routing"
 
 
 def _project(cd, three_pass: bool):
@@ -453,19 +459,26 @@ def remat(body):
       in-projection, a feed-forward's first products, q, k and v as they
       are rounded for the kernel): rebuilding it costs three passes where
       the backward's own products cost one, so a kept byte saves three
-      times what it saves behind a single-pass product.
+      times what it saves behind a single-pass product;
+    * a routed layer's routing (`ROUTING`): the router's float32 logits,
+      a product of six passes, the top-k's chosen experts, and the sorted
+      positions of the assignments, their inverse and the groups' sizes —
+      a `[tokens, n_experts]` table and integers, whose rebuilding is a
+      full sort of every token's scores, an argsort and two scatters
+      (`routing_plan`: 4–36 MB a layer where the cells' layers weigh GBs).
 
     Everything else in the block — norms, single-pass q/k/v products, the
-    MLP's first product, convs and gates, the scan, the routed experts — is
-    recomputed. A block that names none of these (ring or reference
-    attention has no `o`/`lse`; a model in one pass names no product) keeps
-    what it does name. What it costs a layer, from shapes alone:
-    `gpt2.remat_saved_plan`, `nemotron_h.remat_saved_plan`."""
+    MLP's first product, convs and gates, the scan, the routed experts, the
+    router's softmax and statistics — is recomputed. A block that names
+    none of these (ring or reference attention has no `o`/`lse`; a model in
+    one pass names no product; a dense block routes nothing) keeps what it
+    does name. What it costs a layer, from shapes alone:
+    `gpt2.remat_saved_plan`, `nemotron_h.remat_saved_plan`, `routing_plan`."""
     from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 
     return jax.checkpoint(
         body, policy=jax.checkpoint_policies.save_only_these_names(
-            *RESIDUAL_NAMES, ATTENTION_OUT, THREE_PASS_OUT))
+            *RESIDUAL_NAMES, ATTENTION_OUT, THREE_PASS_OUT, ROUTING))
 
 
 # ------------------------------------------------- depthwise causal conv
@@ -1023,6 +1036,20 @@ def moe_plan(tokens: int, d_model: int, d_ff: int, cfg: MoEConfig, *,
     }
 
 
+def routing_plan(tokens: int, cfg: MoEConfig) -> dict:
+    """Bytes of what one routed layer keeps under `remat` by the name
+    `ROUTING`, on one device with `tokens` there, from shapes alone: the
+    router's float32 `logits`, the `top_k`'s chosen experts (and, of a
+    softmax router, their probabilities as the sort gave them; a sigmoid
+    router reads its gates at the choice, which is rebuilt), and the
+    assignments' sort: `order`, `inverse`, `sizes`."""
+    rows = tokens * cfg.top_k
+    return {"logits": tokens * cfg.n_experts * 4,
+            "top_k": rows * (8 if cfg.score == "softmax" else 4),
+            "order": rows * 4, "inverse": rows * 4,
+            "sizes": cfg.n_experts * 4}
+
+
 @jax.custom_vjp
 def _take_assignments(x2, order, inverse):
     """x2 [T, D] -> [T·K, D], row j the token of the j-th assignment in
@@ -1208,14 +1235,18 @@ def _local_experts(x, gate_vals, gate_idx, experts, *, n_experts: int,
             activation=activation, gate=gate, bound=bound)
 
     with jax.named_scope("dispatch"):
-        # this device's experts first, in order; the others' rows behind them
+        # this device's experts first, in order; the others' rows behind
+        # them. Sort, inverse and sizes carry the name `remat` keeps: the
+        # argsort and the two scatters run once a step
         key = (gate_idx.reshape(rows) - first) % n_experts
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros((rows,), jnp.int32).at[order].set(
-            jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+        order = checkpoint_name(
+            jnp.argsort(key, stable=True).astype(jnp.int32), ROUTING)
+        inverse = checkpoint_name(jnp.zeros((rows,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32), unique_indices=True), ROUTING)
         # every row's group, the local experts' first: what lies behind
         # them belongs to no matrix here and comes out of a product zero
-        sizes = jnp.bincount(key, length=n_experts).astype(jnp.int32)
+        sizes = checkpoint_name(
+            jnp.bincount(key, length=n_experts).astype(jnp.int32), ROUTING)
     bounds = assignment_bounds(rows, local, n_experts)
     if not bounds:
         # a share too small for a bound under its rows never runs bounded
@@ -1296,12 +1327,38 @@ _through_experts_jit = jax.jit(_through_experts, static_argnames=(
     "n_experts", "cd", "mesh", "width", "activation", "gate", "bound"))
 
 
+@jax.custom_jvp
+def _chosen(values, scores, indices):
+    """`values`, the top-k of `scores` [..., E] at `indices` [..., K], as a
+    function of the scores: the tangent is the scores' at the indices THE
+    CALLER HOLDS. `lax.top_k`'s own rule gathers by the indices as the sort
+    gave them, which no `checkpoint_name` reaches: a checkpoint that keeps
+    the named choice would still sort again for its backward pass."""
+    return values
+
+
+@_chosen.defjvp
+def _chosen_jvp(primals, tangents):
+    values, _, indices = primals
+    # the gather of `lax.top_k`'s own rule, so that a step without a
+    # checkpoint stays the program it was
+    batch = tuple(range(indices.ndim - 1))
+    return values, jax.lax.gather(
+        tangents[1], indices[..., None], jax.lax.GatherDimensionNumbers(
+            offset_dims=(), collapsed_slice_dims=(len(batch),),
+            start_index_map=(len(batch),), operand_batching_dims=batch,
+            start_indices_batching_dims=batch), (1,) * indices.ndim)
+
+
 def _route(logits, bias, cfg: MoEConfig):
     """Router logits [B, S, E] float32 -> (gates [B, S, K], experts [B, S,
     K], the scores the statistics are taken of [B, S, E])."""
     if cfg.score == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)
+        gate_vals, gate_idx = (checkpoint_name(a, ROUTING) for a in
+                               jax.lax.top_k(jax.lax.stop_gradient(probs),
+                                             cfg.top_k))
+        gate_vals = _chosen(gate_vals, probs, gate_idx)
         floor = 1e-9
     else:
         # independent scores; chosen on score + bias, weighted by the score
@@ -1309,6 +1366,7 @@ def _route(logits, bias, cfg: MoEConfig):
         _, gate_idx = jax.lax.top_k(
             probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
             cfg.top_k)
+        gate_idx = checkpoint_name(gate_idx, ROUTING)
         gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
         floor = 1e-20
     if cfg.norm_topk_prob:
@@ -1331,9 +1389,10 @@ def moe_route(params: Params, x, cfg: MoEConfig):
     E, K = cfg.n_experts, cfg.top_k
     S = x.shape[1]
     with jax.named_scope("router"):
-        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                            params["wg"].astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST)
+        logits = checkpoint_name(jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32),
+            params["wg"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), ROUTING)
         gate_vals, gate_idx, probs = _route(logits, params.get("bias"), cfg)
         # [B, E]: a sequence's assignments by expert
         counts = jnp.sum(jax.nn.one_hot(gate_idx, E, dtype=jnp.int32),

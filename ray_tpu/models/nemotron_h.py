@@ -222,14 +222,17 @@ def remat_saved_plan(cfg: NemotronHConfig, local_batch: int, seq: int, *,
     (`flash`), their `o` and float32 `lse`. Not in it, because no backward
     reads them: the out-projections' results, the shared expert's second
     product, `wo`'s (a layer is one mixer: nothing in it goes on from the
-    attention output). The router's `[tokens, n_experts]` product is
-    float32 at `highest` precision outside `_project`: not named, rebuilt.
+    attention output). A routed layer keeps its routing besides
+    (`L.ROUTING`, `L.routing_plan`: the router's `[tokens, n_experts]`
+    product, float32 at `highest` precision outside `_project`, the chosen
+    experts and the assignments' sort: decided once a step).
     The step's total is each kind's sum times the pattern's count of it."""
     rows = local_batch * seq
     item = jnp.dtype(cfg.dtype).itemsize
     q, kv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
     plan = {"M": {L.THREE_PASS_OUT: rows * cfg.mamba.in_proj * 4},
-            "E": {L.THREE_PASS_OUT: rows * cfg.d_shared * 4},
+            "E": {L.THREE_PASS_OUT: rows * cfg.d_shared * 4,
+                  L.ROUTING: sum(L.routing_plan(rows, cfg.moe).values())},
             "*": {L.THREE_PASS_OUT: rows * (q + 2 * kv) * item}}
     if flash:
         from ray_tpu.ops.flash_attention import RESIDUAL_NAMES

@@ -225,7 +225,8 @@ def test_the_kernel_path_equals_the_reference_path(interpreted):
 def test_each_half_of_a_layer_is_a_checkpoint_of_its_own():
     """Under remat a delta layer's half keeps its arguments and nothing of
     the rule (whose three stages run again for the backward: three scans a
-    delta layer with the checkpoint, two without)."""
+    delta layer with the checkpoint, two without); the routed half its
+    arguments and its routing (`L.ROUTING`: `L.routing_plan`'s bytes)."""
     from jax._src.ad_checkpoint import saved_residuals
 
     cfg, params, tokens = _setup()
@@ -237,12 +238,17 @@ def test_each_half_of_a_layer_is_a_checkpoint_of_its_own():
             p, {"tokens": tokens}, c)[0]))(params)).count("= scan[")
     assert (scans(False), scans(True)) == (2 * delta_layers, 3 * delta_layers)
     x = jnp.zeros((2, 40, cfg.d_model), jnp.float32)
-    for body in (functools.partial(qwen3_next._mixer_apply,
-                                   kind="linear_attention", cfg=cfg,
-                                   impl="reference"),
-                 functools.partial(qwen3_next._moe_apply, cfg=cfg)):
-        kept = saved_residuals(L.remat(body), x, params["layers"][0])
-        assert all("from the argument" in why for _, why in kept), kept
+    for body, routing in (
+            (functools.partial(qwen3_next._mixer_apply,
+                               kind="linear_attention", cfg=cfg,
+                               impl="reference"), {}),
+            (functools.partial(qwen3_next._moe_apply, cfg=cfg),
+             L.routing_plan(2 * 40, cfg.moe))):
+        kept = [a for a, why in saved_residuals(
+            L.remat(body), x, params["layers"][0])
+            if not why.startswith(("from the argument", "from a constant"))]
+        assert sum(a.size * a.dtype.itemsize for a in kept) \
+            == sum(routing.values()), kept
 
 
 def test_the_rules_kernels_in_the_step_lowered_for_a_tpu(monkeypatch):

@@ -1,12 +1,15 @@
 """What the layer loops' checkpoint keeps (`models/layers.py:remat`): the
-flash forward kernel's `o` and `lse`, the attention sub-layer's output and
-the results of the products whose forward value took three bf16 passes,
-besides the block's input. So a training step runs the forward kernel once
-a layer, not twice, recomputes neither the `wo` product nor, under `tp`,
-its exchange (`tests/test_zz_tp_overlap.py` counts those), and runs a
-three-pass product's passes once; the bytes kept are
-`gpt2.remat_saved_plan`'s and `nemotron_h.remat_saved_plan`'s; no number
-changes; and where remat is off the names lower to nothing.
+flash forward kernel's `o` and `lse`, the attention sub-layer's output, the
+results of the products whose forward value took three bf16 passes and a
+routed layer's routing (the router's logits, the chosen experts, the
+assignments' sort), besides the block's input. So a training step runs the
+forward kernel once a layer, not twice, recomputes neither the `wo` product
+nor, under `tp`, its exchange (`tests/test_zz_tp_overlap.py` counts
+those), runs a three-pass product's passes once, and sorts and scatters a
+routed layer's assignments once; the bytes kept are
+`gpt2.remat_saved_plan`'s, `nemotron_h.remat_saved_plan`'s and
+`layers.routing_plan`'s; no number changes; and where remat is off the
+names lower to nothing.
 
 CPU virtual devices, the Pallas kernels through the interpreter
 (`interpret=True`: `force_tpu_interpret_mode` has effects a checkpoint
@@ -24,7 +27,8 @@ import pytest
 from jax._src.ad_checkpoint import saved_residuals
 from jax.sharding import NamedSharding
 
-from ray_tpu.models import gpt2, lfm2, nemotron_h, olmoe
+from ray_tpu.models import (gpt2, joyai, lfm2, nemotron_h, olmoe, qwen3_next,
+                            smallthinker)
 from ray_tpu.models import layers as L
 from ray_tpu.ops import flash_attention as fa
 from tests.test_zz_tp_overlap import _mesh as _mesh_of, _walk
@@ -240,6 +244,11 @@ def _lfm2(remat=True, **overrides):
                     **overrides})
 
 
+def _smallthinker(remat=True, **overrides):
+    return _tiny(smallthinker, smallthinker.smallthinker_tiny, 2, remat=remat,
+                 **{"attention": "reference", **overrides})
+
+
 def _kept(body, x, layer):
     """(shape, dtype) of what a checkpointed layer saves besides its
     arguments and constants (a share's bound: one int32), sorted."""
@@ -247,6 +256,32 @@ def _kept(body, x, layer):
                   for a, what in saved_residuals(body, x, layer)
                   if not what.startswith(("from the argument",
                                           "from a constant")))
+
+
+def _bytes(kept):
+    return sum(math.prod(shape) * jnp.dtype(dtype).itemsize
+               for shape, dtype in kept)
+
+
+def _routing_kept(batch, seq, moe):
+    """(shape, dtype) of what a routed layer keeps by the name `L.ROUTING`
+    with `batch` x `seq` tokens on the device: the router's logits, the
+    top-k's choice — with a softmax router's probabilities as the sort gave
+    them; a sigmoid router's choice counts twice, because
+    `take_along_axis`'s jit hands its indices through to its tangent's
+    gather as a result of its own (one buffer to the compiler) — and the
+    assignments' sorted positions, their inverse and the groups' sizes."""
+    chosen = (batch, seq, moe.top_k)
+    rows = batch * seq * moe.top_k
+    return [((batch, seq, moe.n_experts), "float32"), (chosen, "int32"),
+            (chosen, "float32" if moe.score == "softmax" else "int32"),
+            ((rows,), "int32"), ((rows,), "int32"),
+            ((moe.n_experts,), "int32")]
+
+
+def _handed_through(batch, seq, moe):
+    """Bytes `_routing_kept` lists twice (a sigmoid router's choice)."""
+    return 0 if moe.score == "softmax" else batch * seq * moe.top_k * 4
 
 
 @pytest.mark.parametrize("kind", ["M", "E", "*", "*-flash"])
@@ -263,7 +298,8 @@ def test_a_nemotron_layer_keeps_its_input_and_the_planned_values(
     q, kv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
     want = {
         "M": [((batch, SEQ, cfg.mamba.in_proj), "float32")],
-        "E": [((batch, SEQ, cfg.d_shared), "float32")],
+        "E": [((batch, SEQ, cfg.d_shared), "float32"),
+              *_routing_kept(batch, SEQ, cfg.moe)],
         # q, k, v as they are rounded for the kernel
         "*": [((batch, SEQ, cfg.n_head, cfg.head_dim), "bfloat16"),
               ((batch, SEQ, cfg.n_kv_head, cfg.head_dim), "bfloat16"),
@@ -278,9 +314,10 @@ def test_a_nemotron_layer_keeps_its_input_and_the_planned_values(
     plan = nemotron_h.remat_saved_plan(cfg, batch, SEQ, flash=bool(flash))
     assert set(plan) == set(nemotron_h.KINDS)
     assert set(plan[kind]) == {L.THREE_PASS_OUT, *(
-        fa.RESIDUAL_NAMES if flash else ())}
-    assert sum(plan[kind].values()) == sum(
-        math.prod(shape) * jnp.dtype(dtype).itemsize for shape, dtype in kept)
+        fa.RESIDUAL_NAMES if flash else ()), *(
+        [L.ROUTING] if kind == "E" else [])}
+    assert sum(plan[kind].values()) == _bytes(kept) - (
+        _handed_through(batch, SEQ, cfg.moe) if kind == "E" else 0)
     if kind == "*":
         assert plan[kind][L.THREE_PASS_OUT] == batch * SEQ * (q + 2 * kv) * 2
 
@@ -355,7 +392,10 @@ def test_three_pass_plan_at_the_nemotron_cells_shapes():
     plan = nemotron_h.remat_saved_plan(cfg, 1, 8192)
     assert plan == {
         "M": {L.THREE_PASS_OUT: 337_641_472},
-        "E": {L.THREE_PASS_OUT: 121_634_816},
+        # logits [8192, 128] float32; 49,152 choices, sorted positions and
+        # their inverse, int32; 128 sizes
+        "E": {L.THREE_PASS_OUT: 121_634_816,
+              L.ROUTING: 4_194_304 + 3 * 196_608 + 512},
         "*": {L.THREE_PASS_OUT: 75_497_472, o: 67_108_864, lse: 1_048_576}}
     by_name = {}
     for kind in cfg.pattern:
@@ -363,8 +403,124 @@ def test_three_pass_plan_at_the_nemotron_cells_shapes():
             by_name[name] = by_name.get(name, 0) + size
     assert by_name == {
         L.THREE_PASS_OUT: 4 * 337_641_472 + 4 * 121_634_816 + 75_497_472,
-        o: 67_108_864, lse: 1_048_576}
+        L.ROUTING: 4 * 4_784_640, o: 67_108_864, lse: 1_048_576}
     assert by_name[L.THREE_PASS_OUT] == 1_912_602_624
+
+
+# ------------------------------------- what a routed layer keeps: its routing
+D_MODEL, D_EXPERT = 64, 32
+ROUTERS = {
+    # softmax, the probabilities as they are (OLMoE)
+    "softmax": L.MoEConfig(n_experts=8, top_k=2, norm_topk_prob=False),
+    # softmax renormalised beside a gated shared expert (Qwen3-Next)
+    "softmax_renormalised_shared": L.MoEConfig(
+        n_experts=8, top_k=2, gate="silu", d_shared=48, shared_gate=True),
+    # sigmoid scores chosen on score + bias, rescaled (Nemotron, LFM2, JoyAI)
+    "sigmoid_bias": L.MoEConfig(n_experts=8, top_k=2, score="sigmoid",
+                                scale=2.5, gate="silu"),
+}
+
+
+def _routed(router, held, three_pass=False):
+    """(layer body, x, leaves, cfg): `apply_moe` alone, gated experts, the
+    leaves holding `held` of the eight scored experts (None: all)."""
+    cfg = dataclasses.replace(ROUTERS[router], held=held)
+    params = L.init_moe(jax.random.PRNGKey(0), D_MODEL, D_EXPERT, cfg,
+                        gated=True)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, SEQ, D_MODEL))
+
+    def body(x, params):
+        return L.apply_moe(params, x, cfg, three_pass=three_pass)[0]
+
+    return body, x, params, cfg
+
+
+@pytest.mark.parametrize("held", [None, 2], ids=["every_expert", "a_share"])
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_a_routed_layer_keeps_its_input_the_old_names_and_its_routing(
+        router, held):
+    """Logits, choice and sort by the name `L.ROUTING`, with the bytes
+    `L.routing_plan` gives; a shared expert in three passes keeps its
+    products as it did (gate and up, and the last one's result, which its
+    scalar gate's gradient reads); nothing else (no gathered row, no
+    expert's product, no gate)."""
+    three_pass = bool(ROUTERS[router].d_shared)
+    body, x, params, cfg = _routed(router, held, three_pass)
+    kept = _kept(L.remat(body), x, params)
+    wide = [((2, SEQ, cfg.d_shared), "float32")] * 2 \
+        + [(x.shape, "float32")] if three_pass else []
+    assert kept == sorted(_routing_kept(2, SEQ, cfg) + wide)
+    plan = L.routing_plan(2 * SEQ, cfg)
+    assert list(plan) == ["logits", "top_k", "order", "inverse", "sizes"]
+    assert sum(plan.values()) == _bytes(kept) - _bytes(wide) \
+        - _handed_through(2, SEQ, cfg)
+    # a bare checkpoint keeps the input alone
+    assert _kept(jax.checkpoint(body), x, params) == []
+
+
+def _routing_work(jaxpr, cfg, tokens):
+    """How often a step sorts (the top-k's sort of every token's scores,
+    the argsort of the assignments), scatter-adds into `[E]` (the
+    bincount), scatters into `[T·K]` (the inverse permutation) and
+    multiplies by the router's `wg` [D, E]."""
+    found = dict.fromkeys(("sorts", "into_E", "into_TK", "by_wg"), 0)
+    for eqn, times, _ in _walk(jaxpr):
+        name, out = eqn.primitive.name, eqn.outvars[0].aval.shape
+        if name in ("sort", "top_k"):
+            found["sorts"] += times
+        elif name == "scatter-add" and out == (cfg.n_experts,):
+            found["into_E"] += times
+        elif name == "scatter" and out == (tokens * cfg.top_k,):
+            found["into_TK"] += times
+        elif name == "dot_general" \
+                and eqn.invars[1].aval.shape == (D_MODEL, cfg.n_experts):
+            found["by_wg"] += times
+    return found
+
+
+@pytest.mark.parametrize("held", [None, 2], ids=["every_expert", "a_share"])
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_the_backward_sorts_and_scatters_no_assignment_again(router, held):
+    """Loss and gradients of a routed layer: the top-k and the argsort, the
+    bincount, the inverse's scatter once; the router's product forward and
+    for `wg`'s gradient (x's reads the transpose) — with the policy as
+    without remat. A checkpoint that keeps nothing does each once more."""
+    body, x, params, cfg = _routed(router, held)
+
+    def work(wrap):
+        return _routing_work(jax.make_jaxpr(jax.grad(
+            lambda x, p: jnp.sum(wrap(body)(x, p)), argnums=(0, 1)))(
+                x, params).jaxpr, cfg, 2 * SEQ)
+
+    plain = work(lambda body: body)
+    assert plain == {"sorts": 2, "into_E": 1, "into_TK": 1, "by_wg": 2}
+    assert work(L.remat) == plain
+    assert work(jax.checkpoint) == {"sorts": 4, "into_E": 2, "into_TK": 2,
+                                    "by_wg": 3}
+
+
+def test_routing_plan_at_the_routed_remat_cells_shapes():
+    """A routed layer's kept routing in the five cells that checkpoint
+    one, bytes a layer: E 64–512, 49,152–163,840 assignments."""
+    cells = {
+        "qwen3next4l-b2s8k": (qwen3_next.qwen3_next_80b_a3b_4l, 16_384),
+        "joyaiflash5l-b2s8k": (joyai.joyai_llm_flash_5l, 16_384),
+        "nemotronh9l-b1s8k": (nemotron_h.nemotron_twotower_30b_a3b_9l, 8_192),
+        "lfm2moe5l-b2s8k": (lfm2.lfm2_24b_a2b_5l, 16_384),
+        "smallthinker4l-b1s16k": (smallthinker.smallthinker_21b_a3b_4l,
+                                  16_384),
+    }
+    plans = {cell: L.routing_plan(tokens, preset().moe)
+             for cell, (preset, tokens) in cells.items()}
+    assert plans["qwen3next4l-b2s8k"] == {      # softmax, 10 of 512
+        "logits": 33_554_432, "top_k": 1_310_720, "order": 655_360,
+        "inverse": 655_360, "sizes": 2_048}
+    assert {cell: sum(plan.values()) for cell, plan in plans.items()} == {
+        "qwen3next4l-b2s8k": 36_177_920,
+        "joyaiflash5l-b2s8k": 18_351_104,       # sigmoid, 8 of 256
+        "nemotronh9l-b1s8k": 4_784_640,         # sigmoid, 6 of 128
+        "lfm2moe5l-b2s8k": 4_980_992,           # sigmoid, 4 of 64
+        "smallthinker4l-b1s16k": 5_767_424}     # softmax, 6 of 64
 
 
 # ------------------------------------------------- the same numbers
@@ -380,6 +536,9 @@ SAME_NUMBERS = {
     "nemotron_tiny": ({}, lambda: (*_nemotron(), {})),
     "nemotron_tiny_flash": ({}, lambda: (*_nemotron(**NEMOTRON_FLASH), {})),
     "lfm2_tiny_three_pass": ({}, lambda: (*_lfm2(), {})),
+    # a softmax router on a share, routed on the layer's input: the gates'
+    # gradient by the kept choice
+    "smallthinker_tiny_routed_share": ({}, lambda: (*_smallthinker(), {})),
 }
 
 
@@ -422,7 +581,7 @@ NAMED = {
     # attention in three passes with remat off: the cell olmoe1l-b2s4k
     "olmoe_three_pass": (lambda: _olmoe(False, **OLMOE_FLASH),
                          (*fa.RESIDUAL_NAMES, L.ATTENTION_OUT,
-                          L.THREE_PASS_OUT)),
+                          L.THREE_PASS_OUT, L.ROUTING)),
 }
 
 
