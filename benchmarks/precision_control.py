@@ -14,12 +14,13 @@ that the comparison catches prints `"correct": false`, with the errors
 beside their limits in the `notes:` line (`check.errors`).
 
 Controls (for `ray_tpu.models.smallthinker`, PR 36, `ray_tpu.models.lfm2`,
-PR 40, `ray_tpu.models.joyai`, PR 43, and `ray_tpu.models.qwen3_next`, PR
-48: `_PROGRAMS`; a preset's module is the one whose name its own starts
-with):
-  e4m3         every matmul weight (attention's four projections or latent
-               attention's five, a conv operator's or a delta layer's in- and
-               out-projections, a dense, a shared or
+PR 40, `ray_tpu.models.joyai`, PR 43, `ray_tpu.models.qwen3_next`, PR 48,
+and `ray_tpu.models.phi4_flash`, PR 51: `_PROGRAMS`; a preset's module is
+the one whose name, or whose entry in `_PRESETS`, its own starts with):
+  e4m3         every matmul weight (attention's four projections, latent
+               attention's five or differential attention's fused two, a
+               conv operator's, a delta layer's or a Mamba-1 mixer's in-, x-,
+               dt- and out-projections, a gated memory unit's two, a dense, a shared or
                an expert's three matrices, a prediction module's `eh_proj`,
                the head — the table where it is tied) rounded to an 8-bit float
                forward, the gradient straight through: `chipbench/compare.py`'s
@@ -48,10 +49,13 @@ _PROGRAMS = {
     "ray_tpu.models.joyai": ("JoyaiConfig", "_layer_apply"),
     # a layer is two checkpoints there; its first half reads the stream
     "ray_tpu.models.qwen3_next": ("Qwen3NextConfig", "_mixer_apply"),
+    "ray_tpu.models.phi4_flash": ("Phi4FlashConfig", "_layer_apply"),
 }
+# presets that are not named after their module: prefix -> module
+_PRESETS = {"phi_4_mini_flash": "ray_tpu.models.phi4_flash"}
 _MATMUL_LEAVES = {"wq", "wk", "wv", "wo", "w_in", "w_out", "w_gate", "w_up",
                   "w_down", "head", "wq_a", "wq_b", "wkv_a", "wkv_b",
-                  "eh_proj", "w_ba"}
+                  "eh_proj", "w_ba", "w_qkv", "w_q", "w_o", "w_x", "w_dt"}
 
 
 # ---------------------------------------------------------------- worker side
@@ -59,6 +63,9 @@ def _module_of(preset: str) -> str:
     """`smallthinker_tiny` -> `ray_tpu.models.smallthinker`."""
     for module in _PROGRAMS:
         if preset.startswith(module.rpartition(".")[2] + "_"):
+            return module
+    for prefix, module in _PRESETS.items():
+        if preset.startswith(prefix):
             return module
     raise AttributeError(preset)
 
@@ -135,8 +142,8 @@ def _rounded_stream(program, layer_fn: str):
 
     apply = getattr(program, layer_fn)
 
-    def rounded(x, layer, **kw):
-        return apply(jax.lax.reduce_precision(x, 8, 7), layer, **kw)
+    def rounded(x, layer, *side, **kw):
+        return apply(jax.lax.reduce_precision(x, 8, 7), layer, *side, **kw)
     setattr(program, layer_fn, rounded)
     try:
         yield

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -19,6 +20,7 @@ from ray_tpu.ops import (
     grouped_matmul,
     mamba_stages,
     mxu,
+    selective_scan,
     ssd,
     target,
 )
@@ -228,6 +230,26 @@ def _project(cd, three_pass: bool):
     return lambda *args: checkpoint_name(product(*args), THREE_PASS_OUT)
 
 
+def _flash_on(mesh, grouped: bool, **kw):
+    """``(q, k, v) -> o``: the Pallas flash kernels (`kw`:
+    `flash_attention`'s) as a layer calls them. A Mosaic kernel cannot be
+    partitioned automatically (lowering it on sharded operands raises):
+    under a mesh each device runs the kernel on its own batch and head
+    shard. `grouped`: fewer KV heads than query heads, which are not split
+    with the query heads (a model with them refuses a `tp` that would split
+    either)."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    attend = functools.partial(flash_attention, **kw)
+    if mesh is None:
+        return attend
+    io_spec = sh.spec("batch", None, "heads", None)
+    kv_spec = sh.spec("batch", None, "kv", None) if grouped else io_spec
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=(io_spec, kv_spec, kv_spec),
+        out_specs=io_spec, check_vma=False)
+
+
 def apply_attention(
     params: Params,
     x: jnp.ndarray,
@@ -305,20 +327,7 @@ def apply_attention(
     elif impl == "ring_local":
         o = ring_attention_local(q, k, v, axis_name=sp_axis, causal=causal)
     elif impl == "flash":
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        attend = functools.partial(flash_attention, causal=causal,
-                                   window=window)
-        if mesh is not None:
-            io_spec = sh.spec("batch", None, "heads", None)
-            # grouped KV heads are not split with the query heads (a model
-            # with them refuses a `tp` that would split either)
-            kv_spec = io_spec if group == 1 else sh.spec(
-                "batch", None, "kv", None)
-            attend = jax.shard_map(
-                attend, mesh=mesh, in_specs=(io_spec, kv_spec, kv_spec),
-                out_specs=io_spec, check_vma=False)
-        o = attend(q, k, v)
+        o = _flash_on(mesh, group > 1, causal=causal, window=window)(q, k, v)
     else:
         o = reference_attention(q, k, v, causal=causal, window=window)
     if out_gate:
@@ -444,6 +453,131 @@ def apply_latent_attention(params: Params, x, cfg: LatentConfig, *,
     return checkpoint_name(out, ATTENTION_OUT)
 
 
+# --------------------------------------------------- differential attention
+@dataclasses.dataclass(frozen=True)
+class DiffAttnConfig:
+    """Differential attention's heads (arXiv:2410.05258, as the SambaY
+    family wires it): `n_head` query heads and `n_kv_head` K and V heads of
+    `head_dim`, read two by two — pair j's ``q¹, q²`` are query heads 2j and
+    2j + 1, KV pair m's ``k¹, k²`` K heads 2m and 2m + 1, and its ``V`` the V
+    heads 2m and 2m + 1 side by side, `2·head_dim` wide; query pair j reads
+    KV pair ``j // (pairs // kv_pairs)``."""
+    n_head: int = 40
+    n_kv_head: int = 20
+    head_dim: int = 64
+
+    @property
+    def pairs(self) -> int:
+        return self.n_head // 2
+
+    @property
+    def kv_pairs(self) -> int:
+        return self.n_kv_head // 2
+
+    @property
+    def q_dim(self) -> int:
+        return self.n_head * self.head_dim
+
+    @property
+    def kv_dim(self) -> int:
+        return self.n_kv_head * self.head_dim
+
+
+def lambda_init(depth: int) -> float:
+    """λ's constant part at the layer of PUBLISHED index `depth`."""
+    return 0.8 - 0.6 * math.exp(-0.3 * depth)
+
+
+def init_diff_attention(key, d_model, cfg: DiffAttnConfig, dtype=jnp.float32,
+                        *, own_kv: bool = True):
+    """The layer's leaves: `w_qkv` [d, q | k | v] with `b_qkv` — or, for a
+    layer that reads ANOTHER layer's K and V (`own_kv` false), `w_q` [d, q]
+    with `b_q` alone — `w_o` [q, d] with `b_o`, the four λ vectors of
+    `head_dim` (normal at 0.1) and the sub-norm's scale `subln`
+    [2·head_dim], one for all pairs."""
+    k_in, k_out, *k_lam = jax.random.split(key, 6)
+    width = cfg.q_dim + (2 * cfg.kv_dim if own_kv else 0)
+    name = "qkv" if own_kv else "q"
+    lam = {f"lambda_{n}": _init_dense(k, (cfg.head_dim,), 0.1, dtype)
+           for n, k in zip(("q1", "k1", "q2", "k2"), k_lam)}
+    return {
+        f"w_{name}": _init_dense(k_in, (d_model, width), dtype=dtype),
+        f"b_{name}": jnp.zeros((width,), dtype),
+        "w_o": _init_dense(k_out, (cfg.q_dim, d_model), dtype=dtype),
+        "b_o": jnp.zeros((d_model,), dtype),
+        **lam, "subln": jnp.ones((2 * cfg.head_dim,), dtype),
+    }
+
+
+# every leaf whole on every `tp` rank (10 KV pairs divide by neither 4 nor
+# 8): a model with the layer refuses `tp` > 1
+_DIFF_REST = {"w_o": (None, "embed"), "b_o": ("embed",), "subln": (None,),
+              **{f"lambda_{n}": (None,) for n in ("q1", "k1", "q2", "k2")}}
+DIFF_ATTENTION_LOGICAL = dict(_DIFF_REST, w_qkv=("embed", None),
+                              b_qkv=(None,))
+DIFF_CROSS_LOGICAL = dict(_DIFF_REST, w_q=("embed", None), b_q=(None,))
+
+
+def apply_diff_attention(params: Params, x, cfg: DiffAttnConfig, *,
+                         depth: int, window: Optional[int] = None, kv=None,
+                         impl: str = "reference", compute_dtype=jnp.bfloat16,
+                         eps: float = 1e-5, mesh=None):
+    """x [B, S, d] -> (out [B, S, d], (k [B, S, KV, K], v [B, S, KV/2, 2K])
+    as the kernels read them, for a later layer's `kv`).
+
+    ``a¹_j = softmax(q¹_j k¹ᵀ / √K)·V``, ``a²_j`` likewise from ``q², k²`` —
+    TWO attention calls at q/k `head_dim`, v `2·head_dim` (the published
+    code's four at `head_dim` take each score twice), under the scope
+    `diff_flash`; then, under `diff_combine`, ``λ = exp(λ_q1·λ_k1) −
+    exp(λ_q2·λ_k2) + λ_init(depth)``, ``o_j = RMSNorm(a¹_j − λ·a²_j)·subln ·
+    (1 − λ_init)`` over a pair's `2·head_dim` columns; ``o·W_o + b_o``.
+
+    kv: another layer's ``(k, v)`` — the layer then projects q alone (`w_q`)
+    and hands the same pair on. window: as `apply_attention`'s. impl:
+    "flash" or "reference"; the ring paths know one head width and no
+    window and are refused. Causal. Projections on the MXU in
+    `compute_dtype`, biases added to their float32 accumulators; the
+    softmaxes' statistics, λ and the sub-norm float32."""
+    if impl not in ("flash", "reference"):
+        raise ValueError(f"differential attention has no {impl!r} path")
+    B, S, _ = x.shape
+    cd, K, f32 = compute_dtype, cfg.head_dim, jnp.float32
+    project = _project(cd, False)
+    name = "qkv" if kv is None else "q"
+    q = (project("bsd,de->bse", x, params["w_" + name], f32)
+         + params["b_" + name].astype(f32)).astype(cd)
+    if kv is None:
+        q, k, v = jnp.split(q, [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
+        kv = (k.reshape(B, S, cfg.n_kv_head, K),
+              v.reshape(B, S, cfg.kv_pairs, 2 * K))
+    k, v = kv
+    q = q.reshape(B, S, cfg.pairs, 2, K)
+    k = k.reshape(B, S, cfg.kv_pairs, 2, K)
+    group = cfg.pairs // cfg.kv_pairs
+    with jax.named_scope("diff_flash"):
+        if impl == "flash":
+            attend = _flash_on(mesh, group > 1, causal=True, window=window)
+        else:
+            def attend(q_, k_, v_):
+                k_, v_ = (jnp.repeat(t, group, axis=2) for t in (k_, v_))
+                return reference_attention(q_, k_, v_, causal=True,
+                                           window=window)
+        a1, a2 = (attend(q[:, :, :, i], k[:, :, :, i], v) for i in (0, 1))
+    with jax.named_scope("diff_combine"):
+        lam0 = lambda_init(depth)
+
+        def dot(a, b):
+            return jnp.sum(params[a].astype(f32) * params[b].astype(f32))
+
+        lam = (jnp.exp(dot("lambda_q1", "lambda_k1"))
+               - jnp.exp(dot("lambda_q2", "lambda_k2")) + lam0)
+        o = rms_norm(a1.astype(f32) - lam * a2.astype(f32),
+                     params["subln"].astype(f32), eps) * (1.0 - lam0)
+    out = project("bse,ed->bsd", o.reshape(B, S, cfg.q_dim).astype(cd),
+                  params["w_o"], f32) + params["b_o"].astype(f32)
+    return checkpoint_name(out.astype(x.dtype), ATTENTION_OUT), kv
+
+
 def remat(body):
     """`jax.checkpoint` for a layer loop's body that keeps, besides the
     block's input, what is dear to recompute — and of that only what the
@@ -559,15 +693,20 @@ class MambaConfig:
         return self.inner + self.conv_dim + self.n_heads
 
 
-def _init_decays(k_dt, k_a, heads: int, cfg, dtype):
-    """A recurrent mixer's per-head decay leaves: `dt_bias` =
-    softplus⁻¹(Δ₀) with Δ₀ log-uniform in [`cfg.dt_min`, `cfg.dt_max`],
-    floored, and `A_log` = log U[1, 16]."""
+def _init_dt_bias(key, n: int, cfg, dtype):
+    """`dt_bias` [n] = softplus⁻¹(Δ₀) with Δ₀ log-uniform in [`cfg.dt_min`,
+    `cfg.dt_max`], floored."""
     dt0 = jnp.maximum(jnp.exp(
-        jax.random.uniform(k_dt, (heads,))
+        jax.random.uniform(key, (n,))
         * (jnp.log(cfg.dt_max) - jnp.log(cfg.dt_min)) + jnp.log(cfg.dt_min)),
         cfg.dt_floor)
-    return {"dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
+    return (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
+
+
+def _init_decays(k_dt, k_a, heads: int, cfg, dtype):
+    """A recurrent mixer's per-head decay leaves: `dt_bias`
+    (`_init_dt_bias`) and `A_log` = log U[1, 16]."""
+    return {"dt_bias": _init_dt_bias(k_dt, heads, cfg, dtype),
             "A_log": jnp.log(jax.random.uniform(
                 k_a, (heads,), minval=1.0, maxval=16.0)).astype(dtype)}
 
@@ -641,6 +780,90 @@ def apply_mamba(params: Params, u, cfg: MambaConfig, *,
                                    params["norm"], groups=G, eps=eps,
                                    mesh=mesh)
     return project("bte,ed->btd", y, params["w_out"], u.dtype)
+
+
+# ------------------------------------------------------------ Mamba-1 mixer
+@dataclasses.dataclass(frozen=True)
+class Mamba1Config:
+    """Mamba-1's widths: `inner` channels (``expand · d_model``), a state of
+    `d_state` a channel, Δ from a projection of rank `dt_rank`; `chunk` and
+    `block` are the scan's walk (`ops.selective_scan`)."""
+    inner: int = 5120
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 160
+    chunk: int = 32
+    block: int = 512
+    # Δ at initialisation, as `MambaConfig`'s
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+
+def init_mamba1(key, d_model, cfg: Mamba1Config, dtype=jnp.float32):
+    """Mamba-1's leaves and customary draws: `w_in` [d, s | z]; the
+    depthwise conv (`conv_w` [d_conv, inner] uniform ±d_conv^-½, `conv_b`);
+    `w_x` [inner, dt_rank | B | C]; `w_dt` [dt_rank, inner] uniform
+    ±dt_rank^-½ with a channel's `dt_bias` (`_init_dt_bias`, as
+    `init_mamba`'s); `A_log` = log(1 … d_state) a channel; `D` = 1;
+    `w_out`."""
+    k_in, k_x, k_dt, k_out, k_conv, k_b = jax.random.split(key, 6)
+    bound = cfg.d_conv ** -0.5
+    rank = cfg.dt_rank ** -0.5
+    return {
+        "w_in": _init_dense(k_in, (d_model, 2 * cfg.inner), dtype=dtype),
+        "conv_w": jax.random.uniform(
+            k_conv, (cfg.d_conv, cfg.inner), minval=-bound,
+            maxval=bound).astype(dtype),
+        "conv_b": jnp.zeros((cfg.inner,), dtype),
+        "w_x": _init_dense(k_x, (cfg.inner, cfg.dt_rank + 2 * cfg.d_state),
+                           dtype=dtype),
+        "w_dt": jax.random.uniform(
+            k_dt, (cfg.dt_rank, cfg.inner), minval=-rank,
+            maxval=rank).astype(dtype),
+        "dt_bias": _init_dt_bias(k_b, cfg.inner, cfg, dtype),
+        "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+            1, cfg.d_state + 1, dtype=jnp.float32)),
+            (cfg.inner, cfg.d_state)).astype(dtype),
+        "D": jnp.ones((cfg.inner,), dtype),
+        "w_out": _init_dense(k_out, (cfg.inner, d_model), dtype=dtype),
+    }
+
+
+# every leaf whole on every `tp` rank: a model with a mixer refuses `tp` > 1
+MAMBA1_LOGICAL = {
+    "w_in": ("embed", None), "conv_w": (None, None), "conv_b": (None,),
+    "w_x": (None, None), "w_dt": (None, None), "dt_bias": (None,),
+    "A_log": (None, None), "D": (None,), "w_out": (None, "embed"),
+}
+
+
+def apply_mamba1(params: Params, u, cfg: Mamba1Config, *,
+                 compute_dtype=jnp.bfloat16, mesh=None):
+    """u [B, T, d] -> (out [B, T, d], y [B, T, inner] float32): ``[s | z] =
+    u·W_in``; ``s ← SiLU(causal depthwise conv(s) + b)`` (`ops.mamba_stages.
+    conv_silu`, the Mamba-2 mixer's stage, scope `conv`); ``[r | B | C] =
+    s·W_x``; ``Δ = softplus(r·W_dt + dt_bias)``, ``A = −exp(A_log)``; the
+    selective scan (`ops.selective_scan`, scope `selective_scan`); out ``=
+    (y ⊙ SiLU(z))·W_out``. `y`, the scan's result BEFORE the gate, is what a
+    gated memory unit of a later layer reads (`apply_gmu`). The four
+    projections on the MXU in `compute_dtype`; conv, Δ, decays, state,
+    readout and gate float32. mesh: where the conv stage runs (its kernel on
+    one TPU, as in `apply_mamba`); the scan has one form."""
+    project = _project(compute_dtype, False)
+    f32, N = jnp.float32, cfg.d_state
+    sz = project("btd,de->bte", u, params["w_in"], f32)
+    with jax.named_scope("conv"):
+        s = mamba_stages.conv_silu(sz, params["conv_w"], params["conv_b"],
+                                   start=0, mesh=mesh)
+    rbc = project("bte,ef->btf", s, params["w_x"], f32)
+    r, b_in, c_out = jnp.split(rbc, [cfg.dt_rank, cfg.dt_rank + N], axis=-1)
+    dt = project("btr,re->bte", r, params["w_dt"], f32)
+    y = selective_scan.selective_scan(
+        s, dt, -jnp.exp(params["A_log"].astype(f32)), b_in, c_out,
+        params["D"], params["dt_bias"], chunk=cfg.chunk, block=cfg.block)
+    gated = y * jax.nn.silu(sz[..., cfg.inner:])
+    return project("bte,ed->btd", gated, params["w_out"], u.dtype), y
 
 
 # ------------------------------------------------------ Gated DeltaNet
@@ -880,6 +1103,29 @@ def apply_gated_mlp(params: Params, x, *, compute_dtype=jnp.bfloat16,
     hidden = (jax.nn.silu(gate.astype(jnp.float32))
               * up.astype(jnp.float32)).astype(wide)
     return project("bsf,fd->bsd", hidden, params["w_down"], x.dtype)
+
+
+# ------------------------------------------------------ gated memory unit
+def init_gmu(key, d_model, inner, dtype=jnp.float32):
+    """`w_in` [d, inner], `w_out` [inner, d], no bias."""
+    k_in, k_out = jax.random.split(key)
+    return {"w_in": _init_dense(k_in, (d_model, inner), dtype=dtype),
+            "w_out": _init_dense(k_out, (inner, d_model), dtype=dtype)}
+
+
+GMU_LOGICAL = {"w_in": ("embed", None), "w_out": (None, "embed")}
+
+
+def apply_gmu(params: Params, x, memory, *, compute_dtype=jnp.bfloat16):
+    """``(SiLU(x·W_in) ⊙ memory)·W_out`` (arXiv:2507.06607): `memory` [B, T,
+    inner] an EARLIER layer's scan result (`apply_mamba1`'s second), which
+    this layer gates with its own stream and does not recompute. Products
+    on the MXU in `compute_dtype`, the gate float32."""
+    project = _project(compute_dtype, False)
+    gate = project("btd,de->bte", x, params["w_in"], jnp.float32)
+    return project("bte,ed->btd",
+                   jax.nn.silu(gate) * memory.astype(jnp.float32),
+                   params["w_out"], x.dtype)
 
 
 # ---------------------------------------------------------------- MoE (EP)
@@ -1536,12 +1782,16 @@ def embed(table, tokens, mesh=None):
                         mesh, "batch", "seq", "embed")
 
 
-def head_logits(x, scale, table, *, eps: float, compute_dtype, mesh=None):
+def head_logits(x, scale, table, *, eps: float, compute_dtype, mesh=None,
+                bias=None):
     """The stream behind the last layer -> logits [B, S, V] float32: an
-    RMSNorm and the product with `table` [V, d] (an untied head, or the
-    embedding). Nothing behind the last layer is discontinuous: the head
-    reads the stream in the compute dtype, as `gpt2.unembed` does."""
-    x = rms_norm(x.astype(compute_dtype), scale, eps)
+    RMSNorm — with `bias`, a LayerNorm — and the product with `table` [V,
+    d] (an untied head, or the embedding). Nothing behind the last layer is
+    discontinuous: the head reads the stream in the compute dtype, as
+    `gpt2.unembed` does."""
+    x = x.astype(compute_dtype)
+    x = (rms_norm(x, scale, eps) if bias is None
+         else layer_norm(x, scale, bias, eps))
     logits = jax.lax.dot_general(
         x, table.astype(compute_dtype), (((2,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)

@@ -82,6 +82,17 @@ q and k, that width — by :func:`tile_plan`:
   comment). The widths are read from the operands' shapes; nothing else
   selects a tile.
 
+* **Which (q/k, v) width pairs were swept, and which fall to the default.**
+  Swept on the chip: equal widths at 64 (PR 26; the default entry, which
+  128 and 256 have run at since without a sweep of their own) and (192, 128)
+  with shared key columns (PR 44). Every other pair falls to the default —
+  among them **(64, 128), differential attention's** (``models.layers.
+  apply_diff_attention``, PR 51: q and k 64 wide, v two heads side by side,
+  with or without a window; no ``k_shared``): an unswept pair, whose
+  ``QKᵀ`` fills half the MXU's depth as the D 64 calls' does, so the
+  default's reasoning carries over and its figures do not. It runs the same
+  kernels: the accumulators that follow v are as wide as v whatever q is.
+
 Precision is unchanged: operands in the input dtype, f32 scores, f32
 softmax statistics and accumulators, ``p``/``dS`` cast to the input dtype
 for their matmuls.
@@ -199,7 +210,9 @@ def tile_plan(seq_len: int, head_dim: int, dtype,
     Tiles are ``_TILES``' entry for the two widths, else its default (128 ·
     2^n: a score tile's lane dimension and the lane dimension of an lse row
     block need multiples of 128), never larger than the sequence rounded up
-    to 128; ``block_q``/``block_k`` override the rows/columns of all three
+    to 128. Swept pairs: equal widths at 64 (the default entry) and (192,
+    128); every other pair — equal widths at 128 and 256, and (64, 128),
+    differential attention's — gets the default unswept; ``block_q``/``block_k`` override the rows/columns of all three
     (tests). The kernels share the padded
     length — the next multiple of the largest tile — and the major block:
     the largest multiple of the tiles that divides the padded length, fits

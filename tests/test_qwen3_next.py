@@ -205,8 +205,8 @@ def test_the_kernel_path_equals_the_reference_path(interpreted):
 
     def run(attention, remat):
         c = dataclasses.replace(cfg, attention=attention, remat=remat)
-        return jax.value_and_grad(
-            lambda p: qwen3_next.loss_fn(p, {"tokens": tokens}, c)[0])(params)
+        return jax.jit(jax.value_and_grad(
+            lambda p: qwen3_next.loss_fn(p, {"tokens": tokens}, c)[0]))(params)
     want = run("reference", False)
     for remat in (False, True):
         got = run("flash", remat)
