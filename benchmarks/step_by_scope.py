@@ -43,13 +43,14 @@ OUT = os.path.join(ROOT, "chiprun_out", "step_by_scope")
 # found in its scopes, read from the innermost outwards
 PARTS = ("optimizer", "loss_tail", "embed", "router", "dispatch", "experts",
          "combine", "shared_expert", "conv", "gate_norm", "ssd", "mamba",
+         "selective_scan", "mamba1", "gmu", "diff_flash", "cross_attn",
          "delta_proj", "delta_conv", "delta_rule", "delta_gate_norm", "gdn",
          "latent_proj", "attn_gate", "attention", "attn", "mlp", "moe",
          "mtp", "blocks")
 KERNEL = re.compile(r"(flash_(?:window|latent)_(?:fwd|dq|dkv)|"
                     r"flash_(?:fwd|dq|dkv)|"
                     r"ssd_(?:fwd|bwd)|mamba_(?:conv|gate)_(?:fwd|bwd)|"
-                    r"delta_(?:fwd|bwd)|t?gmm)(?:\.\d+)?$")
+                    r"delta_(?:fwd|bwd)|sscan_(?:fwd|bwd)|t?gmm)(?:\.\d+)?$")
 
 
 def part_of(scopes) -> str:

@@ -848,8 +848,9 @@ def apply_mamba1(params: Params, u, cfg: Mamba1Config, *,
     (y ⊙ SiLU(z))·W_out``. `y`, the scan's result BEFORE the gate, is what a
     gated memory unit of a later layer reads (`apply_gmu`). The four
     projections on the MXU in `compute_dtype`; conv, Δ, decays, state,
-    readout and gate float32. mesh: where the conv stage runs (its kernel on
-    one TPU, as in `apply_mamba`); the scan has one form."""
+    readout and gate float32. mesh: where the conv stage and the scan run
+    (each its kernels on one TPU where its tiles divide the shapes, as in
+    `apply_mamba`; its plain form everywhere else)."""
     project = _project(compute_dtype, False)
     f32, N = jnp.float32, cfg.d_state
     sz = project("btd,de->bte", u, params["w_in"], f32)
@@ -861,7 +862,8 @@ def apply_mamba1(params: Params, u, cfg: Mamba1Config, *,
     dt = project("btr,re->bte", r, params["w_dt"], f32)
     y = selective_scan.selective_scan(
         s, dt, -jnp.exp(params["A_log"].astype(f32)), b_in, c_out,
-        params["D"], params["dt_bias"], chunk=cfg.chunk, block=cfg.block)
+        params["D"], params["dt_bias"], chunk=cfg.chunk, block=cfg.block,
+        mesh=mesh)
     gated = y * jax.nn.silu(sz[..., cfg.inner:])
     return project("bte,ed->btd", gated, params["w_out"], u.dtype), y
 

@@ -180,6 +180,60 @@ def test_a_run_of_layers_holds_what_its_cross_decoder_reads(first, n_layer,
     model.Phi4FlashConfig(first_layer=0, n_layer=16)    # no reader: fine
 
 
+def test_the_scans_kernels_in_the_step_lowered_for_a_tpu(monkeypatch):
+    """The step of the tiny pattern's two Mamba-1 layers under remat, lowered
+    FOR A TPU (no compile, nothing run) at widths the scan's tiles divide
+    (128 channels, 8 states): `sscan_fwd` at four sites (forward and
+    recompute) and `sscan_bwd` at two, every one under the scope
+    `selective_scan` inside `mamba1` (a `custom_vjp`'s backward rule
+    inherits its caller's scopes) in its phase, the kernels named for the
+    trace, and no loop left under `selective_scan` (the plain form's walk is
+    `while`s)."""
+    import re
+    import types
+
+    from ray_tpu.ops import selective_scan as ss
+    from ray_tpu.parallel.compile_watch import parse_op_name
+
+    # the scan alone: the conv stage and flash keep this host's
+    monkeypatch.setattr(ss, "target", types.SimpleNamespace(
+        where=lambda mesh=None, *, interpret=False: ("tpu", 1)))
+
+    def lowered(**fields):
+        cfg = dataclasses.replace(model.phi4_flash_tiny(), remat=True,
+                                  **fields)
+        params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), cfg))
+        text = jax.jit(jax.grad(
+            lambda p, t: model.loss_fn(p, {"tokens": t}, cfg)[0])).trace(
+                params, jax.ShapeDtypeStruct((1, 129), jnp.int32)).lower(
+                    lowering_platforms=("tpu",)).as_text(debug_info=True)
+        return text, dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text,
+                                     re.M))
+
+    text, names = lowered(d_state=8)
+    sites = {}
+    for callee, loc in re.findall(
+            r"call @(_sscan_(?:fwd|bwd))(?:_\d+)?\(.*loc\((#loc\d+)\)", text):
+        scopes, phase = parse_op_name(names[loc] + "/call")
+        assert "selective_scan" in scopes and "mamba1" in scopes, names[loc]
+        sites.setdefault(callee, []).append(phase)
+    assert {k: sorted(v) for k, v in sites.items()} == {
+        "_sscan_fwd": ["forward"] * 2 + ["recompute"] * 2,
+        "_sscan_bwd": ["backward"] * 2}
+    kernels = re.findall(r'custom_call @tpu_custom_call.*loc\((#loc\d+)\)',
+                         text)
+    assert sorted({names[loc] for loc in kernels}) == [
+        "sscan_bwd/pallas_call", "sscan_fwd/pallas_call"]
+
+    def loops(names):
+        return [n for n in names.values()
+                if "selective_scan" in n and "while" in n]
+    assert not loops(names)
+    # the tiny preset's own 4 states take the plain form, whose walk loops
+    text, names = lowered()
+    assert "tpu_custom_call" not in text and loops(names)
+
+
 def test_a_mesh_that_splits_heads_is_refused():
     """Refused from the mesh alone, before anything is computed."""
     cfg = model.phi4_flash_tiny()

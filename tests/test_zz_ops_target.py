@@ -1,7 +1,7 @@
 """The one rule for "kernel or plain form" (`ops/target.py`): what `where`
 answers, and that each op with a kernel — flash through `resolve_attention`,
 the grouped product, the scan, the mixer's two stages, the gated delta
-rule — takes the kernel
+rule, Mamba-1's selective scan — takes the kernel
 where `where` says TPU and its plain form where it says CPU, at shapes its
 tiles divide. Tracing only: nothing runs, nothing compiles."""
 import jax
@@ -10,7 +10,14 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import layers as L
-from ray_tpu.ops import gated_delta, grouped_matmul, mamba_stages, ssd, target
+from ray_tpu.ops import (
+    gated_delta,
+    grouped_matmul,
+    mamba_stages,
+    selective_scan,
+    ssd,
+    target,
+)
 from tests.test_zz_tp_overlap import _walk
 
 F32 = jnp.float32
@@ -52,6 +59,11 @@ OPS = {
         lambda qkv, g, beta: gated_delta.gated_delta_packed(
             qkv, g, beta, key_heads=1, k_dim=128, normalize=1e-6),
         _shapes((1, 512, 512), (1, 512, 2), (1, 512, 2)), "delta_"),
+    # one lane tile of channels, 16 states, a token block
+    "selective_scan": (
+        selective_scan.selective_scan,
+        _shapes((1, 64, 128), (1, 64, 128), (128, 16), (1, 64, 16),
+                (1, 64, 16), (128,), (128,)), "sscan_"),
 }
 
 
