@@ -11,7 +11,13 @@ and .bench_tree/parent_bench (`git archive` of the parent commit with this
 tree's BENCHMARK.json and the directories of its `paths` laid over it, as
 the driver lays them). Plans:
   runs<N>     N runs of the change, --trace 0, seeds first, first+1, ...
+  quiet<N>    the same with the program's rings off (RAY_TPU_TIMELINE=0
+              RAY_TPU_INTERNAL_TELEMETRY=0): what the tracing costs
+  onoff<N>    N pairs: a `runs` run, then a `quiet` run, on seeds of
+              their own
   traced      the change with --trace 1
+  cold        the same with JAX_COMPILATION_CACHE_DIR a fresh directory:
+              every program compiled, none loaded
   parent      parent_bench with --trace 0: must fail cleanly where the
               parent cannot run the cell
   ptraced     parent_bench with --trace 1 (an old cell under the new
@@ -26,6 +32,7 @@ import json
 import os
 import subprocess
 import sys
+import tempfile
 import time
 
 ROOT = os.getcwd()
@@ -48,7 +55,10 @@ def left():
             "defunct": sum("<defunct>" in line for line in ps)}
 
 
-def one(cell, side, seed, trace, control=None):
+QUIET = {"RAY_TPU_TIMELINE": "0", "RAY_TPU_INTERNAL_TELEMETRY": "0"}
+
+
+def one(cell, side, seed, trace, control=None, quiet=False, cold=False):
     tree = os.path.join(ROOT, ".bench_tree", side)
     command = ["-m", "chipbench.run"] if control is None else \
         ["-m", "benchmarks.precision_control", control]
@@ -56,7 +66,10 @@ def one(cell, side, seed, trace, control=None):
     p = subprocess.run(
         [sys.executable, *command, "--workload", cell, "--seed", str(seed),
          "--seconds", "30" if control is None else "5", "--trace", str(trace)],
-        cwd=tree, env=dict(os.environ, BENCH_RUN="builder"),
+        cwd=tree, env=dict(os.environ, BENCH_RUN="builder",
+                           **(QUIET if quiet else {}),
+                           **({"JAX_COMPILATION_CACHE_DIR": tempfile.mkdtemp(
+                               prefix="cold_cache_")} if cold else {})),
         capture_output=True, text=True)
     at_return = left()
     time.sleep(5)
@@ -65,6 +78,10 @@ def one(cell, side, seed, trace, control=None):
            "left_after_run": {"at_return": at_return, "5s_later": left()}}
     if control:
         row["control"] = control
+    if quiet:
+        row["quiet"] = True
+    if cold:
+        row["cold"] = True
     lines = [line for line in p.stdout.splitlines() if line.strip()]
     try:
         row.update(json.loads(lines[-1]))
@@ -79,7 +96,8 @@ def one(cell, side, seed, trace, control=None):
     with open(os.path.join(OUT, cell + ".jsonl"), "a") as f:
         f.write(json.dumps(row) + "\n")
     errors = (row.get("notes", {}).get("check") or {}).get("errors", {})
-    print(cell, side, control or "", seed, "traced" if trace else "e2e",
+    print(cell, side, control or ("quiet" if quiet else ""), seed,
+          "traced" if trace else "e2e",
           "rc", p.returncode, "wall", row["wall_s"],
           "correct", row.get("correct"),
           {k: round(v["value"], 4) for k, v in row.get("metrics", {}).items()},
@@ -96,8 +114,17 @@ for spec in sys.argv[3:]:
     if plan.startswith("runs"):
         for i in range(int(plan[4:])):
             one(cell, "change", seed + i, 0)
+    elif plan.startswith("quiet"):
+        for i in range(int(plan[5:])):
+            one(cell, "change", seed + i, 0, quiet=True)
+    elif plan.startswith("onoff"):
+        for i in range(int(plan[5:])):
+            one(cell, "change", seed + 2 * i, 0)
+            one(cell, "change", seed + 2 * i + 1, 0, quiet=True)
     elif plan == "traced":
         one(cell, "change", seed, 1)
+    elif plan == "cold":
+        one(cell, "change", seed, 1, cold=True)
     elif plan == "parent":
         one(cell, "parent_bench", seed, 0)
     elif plan == "ptraced":

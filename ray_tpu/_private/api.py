@@ -38,21 +38,27 @@ class _LocalNode:
 
     def __init__(self, num_cpus=None, num_tpus=None, resources=None,
                  object_store_memory=None, session_dir=None):
+        from ray_tpu._private import profiling
         from ray_tpu._private.gcs import GcsServer
         from ray_tpu._private.raylet import Raylet, detect_resources
 
         self.session_dir = session_dir or os.path.join(
             "/tmp/ray_tpu", f"session_{os.getpid()}_{int(time.time())}")
         os.makedirs(self.session_dir, exist_ok=True)
-        self.gcs = GcsServer(
-            snapshot_path=os.path.join(self.session_dir, "gcs_snapshot")
-        ).start()
-        self.raylet = Raylet(
-            self.gcs.addr,
-            resources=detect_resources(num_cpus, num_tpus, resources=resources),
-            store_size=object_store_memory or 256 * 1024 * 1024,
-            session_dir=self.session_dir,
-        )
+        with profiling.record_span("startup", "gcs_start"):
+            self.gcs = GcsServer(
+                snapshot_path=os.path.join(self.session_dir, "gcs_snapshot")
+            ).start()
+        # counting the node's resources is the raylet's start too: the
+        # chip probe (`chip_probe`, its child) runs there
+        with profiling.record_span("startup", "raylet_start"):
+            self.raylet = Raylet(
+                self.gcs.addr,
+                resources=detect_resources(num_cpus, num_tpus,
+                                           resources=resources),
+                store_size=object_store_memory or 256 * 1024 * 1024,
+                session_dir=self.session_dir,
+            )
 
     def stop(self):
         self.raylet.stop()
@@ -69,6 +75,16 @@ def init(address=None, *, num_cpus=None, num_tpus=None, num_gpus=None,
     `num_gpus` is accepted for reference-API compatibility and maps to TPU
     chips.
     """
+    from ray_tpu._private import profiling
+
+    with profiling.record_span("startup", "init"):
+        return _init(address, num_cpus, num_tpus, num_gpus, resources,
+                     namespace, object_store_memory, ignore_reinit_error,
+                     kwargs)
+
+
+def _init(address, num_cpus, num_tpus, num_gpus, resources, namespace,
+          object_store_memory, ignore_reinit_error, kwargs):
     global _global_node, _namespace
     with _global_lock:
         if current_worker() is not None:
@@ -311,9 +327,12 @@ def timeline(filename=None):
     else:
         # drop markers ride along (ph "M" metadata rows): a ring that
         # evicted spans must say so in the merged timeline
-        events = profiling.snapshot(with_drop_marker=True)  # driver
+        events = profiling.snapshot(with_drop_marker=True)  # this process
+        # each raylet answers with its own process's ring and its
+        # workers'; a raylet inside this process, and this process's own
+        # worker, bring the rows above again
         events.extend(_each_raylet(worker.gcs.call, "profile_events"))
-        trace = profiling.to_chrome_trace(events)
+        trace = profiling.to_chrome_trace(profiling.merge(events))
     if filename:
         import json
 

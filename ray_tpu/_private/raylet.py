@@ -26,6 +26,7 @@ import threading
 import time
 import uuid
 
+from ray_tpu._private import profiling as _prof
 from ray_tpu._private.protocol import ConnectionLost, RpcClient, RpcServer
 from ray_tpu._private.store_client import StoreClient
 
@@ -50,10 +51,19 @@ def _probe_local_chips() -> dict | None:
     ``ray_tpu.init()`` returns and a worker can ask for them."""
     if not _chip_detection_enabled():
         return None
+    from ray_tpu._private import tpu_probe
     from ray_tpu._private.config import get_config
-    from ray_tpu._private.tpu_probe import probe_chips
 
-    return probe_chips(timeout_s=float(get_config("chip_probe_timeout_s")))
+    timeout_s = float(get_config("chip_probe_timeout_s"))
+    if tpu_probe.probed():
+        return tpu_probe.probe_chips(timeout_s=timeout_s)
+    # the span is the subprocess: a JAX start that takes the chips, counts
+    # them and lets them go, all before `init()` returns
+    args = {"timeout_s": timeout_s}
+    with _prof.record_span("startup", "chip_probe", args):
+        found = tpu_probe.probe_chips(timeout_s=timeout_s)
+        args["chips"] = (found or {}).get("chips", 0)
+    return found
 
 
 def detect_tpu_topology() -> dict | None:
@@ -116,6 +126,9 @@ class WorkerHandle:
         self.assigned_lease = None  # lease_id when leased out
         self.is_actor = False
         self.actor_id = None
+        # id of the `worker_spawn` span, taken ahead: the worker learns it
+        # at registration, the span is recorded once it has registered
+        self.spawn_span = _prof.next_id() if proc is not None else None
 
 
 class Lease:
@@ -388,7 +401,12 @@ class Raylet:
 
     # ---- worker pool (reference: raylet/worker_pool.h) ----------------------
 
-    def _spawn_worker(self) -> WorkerHandle:
+    def _spawn_worker(self, cause: dict | None = None) -> WorkerHandle:
+        """``cause``: the ``trace_ctx`` of the spec this worker is spawned
+        for (an actor's creation), None for the pool's own refill. The
+        `worker_spawn` span (Popen → the worker registered) names it as
+        its parent; the worker is told the span's id when it registers
+        and hangs its `worker_boot` under it."""
         if self._stopped:
             raise RuntimeError("raylet is stopped")
         # Bound concurrent process STARTUPS (reference: worker_pool.h
@@ -398,6 +416,7 @@ class Raylet:
         # fork until the worker registers (or 30 s), so at most gate-width
         # workers are mid-startup; callers keep their own registered.wait.
         self._spawn_gate.acquire()
+        started = time.time()
         try:
             handle = self._spawn_worker_inner()
         except BaseException:
@@ -406,9 +425,15 @@ class Raylet:
 
         def _release_when_up():
             try:
-                handle.registered.wait(30.0)
+                up = handle.registered.wait(30.0)
             finally:
                 self._spawn_gate.release()
+            cause_ = cause or {}
+            _prof.record_completed_span(
+                "startup", "worker_spawn", started, time.time() - started,
+                {"worker_id": handle.worker_id, "registered": up},
+                parent=cause_.get("cause"), run=cause_.get("run"),
+                span_id=handle.spawn_span)
 
         threading.Thread(target=_release_when_up, daemon=True).start()
         return handle
@@ -465,7 +490,8 @@ class Raylet:
         self._log_monitor.track(worker_id, proc.pid, out_path, err_path)
         return handle
 
-    def _pop_worker(self, timeout: float | None = None) -> WorkerHandle:
+    def _pop_worker(self, timeout: float | None = None,
+                    cause: dict | None = None) -> WorkerHandle:
         if timeout is None:
             from ray_tpu._private.config import get_config
 
@@ -480,7 +506,7 @@ class Raylet:
         if handle is not None:
             self._maybe_refill()   # keep the next burst warm
             return handle
-        handle = self._spawn_worker()
+        handle = self._spawn_worker(cause)
         self._maybe_refill()
         if not handle.registered.wait(timeout):
             raise TimeoutError(
@@ -500,6 +526,7 @@ class Raylet:
         # it to object OWNERS when announcing copies (owner-based directory)
         return {"node_id": self.node_id, "store_name": self.store_name,
                 "spill_dir": self.spill_dir,
+                "spawn_span": handle.spawn_span,
                 "node": {"NodeID": self.node_id,
                          "NodeManagerAddress": self.addr[0],
                          "NodeManagerPort": self.addr[1],
@@ -1094,7 +1121,7 @@ class Raylet:
         resources = reserved
         worker = None
         try:
-            worker = self._pop_worker()
+            worker = self._pop_worker(cause=spec.get("trace_ctx"))
             worker.is_actor = True
             worker.actor_id = actor_id
             lease_id = uuid.uuid4().hex
@@ -1266,7 +1293,13 @@ class Raylet:
         return out
 
     def rpc_profile_events(self, conn):
-        return self._fanout_workers("profile_events")
+        """This node's timeline: the raylet process's own ring (the chip
+        probe and every `worker_spawn` are recorded HERE) with every
+        registered worker's. Where the raylet shares the driver's
+        process, the driver's worker answers with the same ring:
+        `profiling.merge` keeps one of each."""
+        return _prof.merge(_prof.snapshot(with_drop_marker=True)
+                           + self._fanout_workers("profile_events"))
 
     def rpc_trace_spans(self, conn):
         return self._fanout_workers("trace_spans")

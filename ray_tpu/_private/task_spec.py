@@ -34,7 +34,9 @@ class TaskSpec(TypedDict, total=False):
                                  # args (executor skips the owner round trip)
     dynamic_returns: bool        # num_returns="dynamic"/"streaming": the
                                  # task yields items, each its own object
-    trace_ctx: dict              # {"trace_id", "parent_span_id"}
+    trace_ctx: dict              # {"trace_id", "parent_span_id"} where
+                                 # util.tracing is on; {"cause", "run"}:
+                                 # the submitter's live timeline span
     # actor-call extension (producer: submit_actor_task)
     actor_id: bytes
     method_name: str

@@ -395,6 +395,19 @@ CATALOG: dict[str, dict] = {
                        "mid-training means shape churn is recompiling "
                        "the step",
     },
+    "ray_tpu_compile_cache_hits_total": {
+        "kind": "Counter", "tags": ("fn",),
+        "description": "Executables JAX loaded from its persistent "
+                       "compilation cache, by the instrumented function "
+                       "whose call asked (fn=- outside every such call)",
+    },
+    "ray_tpu_compile_cache_misses_total": {
+        "kind": "Counter", "tags": ("fn",),
+        "description": "Executables JAX compiled and wrote to its "
+                       "persistent compilation cache (a cold start: the "
+                       "next process loads them), by instrumented "
+                       "function (fn=- outside every such call)",
+    },
     "ray_tpu_mesh_build_seconds": {
         "kind": "Histogram", "tags": ("kind",),
         "boundaries": [0.001, 0.01, 0.1, 0.5, 1.0, 5.0, 30.0],

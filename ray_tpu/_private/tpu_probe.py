@@ -57,6 +57,11 @@ print(json.dumps(info))
 _chip_probe_cache: list = []   # [] = never probed; [result] = cached
 
 
+def probed() -> bool:
+    """Whether this process has made its one probe already."""
+    return bool(_chip_probe_cache)
+
+
 def probe_chips(timeout_s: float = 60.0) -> dict | None:
     """Chip count / coords / slice id / per-device HBM limit via a
     SUBPROCESS jax.devices() call (the raylet's process must not hold the

@@ -12,6 +12,8 @@ import signal
 import sys
 import time
 
+_T_PROCESS = time.time()    # as early as this process knows its own start
+
 
 def main():
     import faulthandler
@@ -87,6 +89,13 @@ def main():
     # (pyarrow submodule init) are unreliable on short-lived dispatch
     # threads; the main thread is always safe. Returns when the raylet
     # connection drops — the node is gone.
+    # interpreter start → connected, registered and about to take the first
+    # call, under the raylet's `worker_spawn`
+    from ray_tpu._private import profiling
+
+    profiling.record_completed_span(
+        "startup", "worker_boot", _T_PROCESS, time.time() - _T_PROCESS,
+        {"worker_id": worker.worker_id}, parent=worker.spawn_span)
     if prof is not None:
         # Perf diagnosis aid (RAY_TPU_WORKER_PROFILE=dir): cProfile the
         # main task loop — where normal-task execution happens — and dump

@@ -28,6 +28,7 @@ from ray_tpu import exceptions as exc
 from ray_tpu._private import events as _events
 from ray_tpu._private import fault_injection as _fi
 from ray_tpu._private import memory_anatomy as _ma
+from ray_tpu._private import profiling as _profiling
 from ray_tpu._private import serialization as ser
 from ray_tpu._private.object_ref import ObjectRef, ReferenceCounter
 from ray_tpu._private.protocol import ConnectionLost, RpcClient, RpcServer
@@ -769,6 +770,9 @@ class CoreWorker:
         reg = self.raylet.call("register_worker", worker_id=self.worker_id,
                                addr=self.addr, pid=os.getpid())
         self.node_id = reg["node_id"]
+        # the raylet's `worker_spawn` span, for this process's
+        # `worker_boot` (None: not spawned by the raylet, a driver)
+        self.spawn_span = reg.get("spawn_span")
         # Owner-based object directory (reference:
         # src/ray/object_manager/ownership_based_object_directory.h:1 — the
         # OWNER of an object tracks which nodes hold copies; borrowers and
@@ -2020,6 +2024,7 @@ class CoreWorker:
 
         validate_task_spec(spec)
         _events.task_event(spec["task_id"], "SUBMITTED", desc=task_desc)
+        _carry_cause(spec)
         with tracing.submit_span(spec, task_desc):
             # refs whose bytes ride the spec need no pin: the task no
             # longer depends on the object outliving the submission
@@ -2293,6 +2298,7 @@ class CoreWorker:
             "runtime_env": self._normalize_runtime_env(
                 options.get("runtime_env")),
         }
+        _carry_cause(spec)
         reg = self.gcs.call("register_actor", actor_id=actor_id, spec=spec)
         if reg.get("existing"):
             return bytes.fromhex(reg["existing"]["ActorID"]), True
@@ -2369,6 +2375,7 @@ class CoreWorker:
         from ray_tpu._private.task_spec import validate_task_spec
 
         validate_task_spec(spec, actor=True)
+        _carry_cause(spec)
         with tracing.submit_span(spec, spec["task_desc"]):
             self._pin_args(spec, args, kwargs)
             self._owned.update(return_ids)
@@ -2578,15 +2585,16 @@ class CoreWorker:
             # skip the span generator entirely when no trace context
             # arrived and tracing is off here — two context managers per
             # task are measurable on the sync hot path
-            if spec.get("trace_ctx") is None and not tracing.is_enabled():
+            trace_ctx, cause = _split_trace_ctx(spec)
+            if trace_ctx is None and not tracing.is_enabled():
                 trace_cm = contextlib.nullcontext()
             else:
                 trace_cm = tracing.span(
                     f"execute {spec.get('task_desc', 'task')}",
-                    "CONSUMER", spec.get("trace_ctx"),
+                    "CONSUMER", trace_ctx,
                     {"task_id": task_id.hex()})
             with record_span("task", spec.get("task_desc", "task"),
-                             {"task_id": task_id.hex()}), trace_cm:
+                             {"task_id": task_id.hex()}, **cause), trace_cm:
                 if "runtime_env" in spec or \
                         getattr(self, "_env_applied_key", None) is not None:
                     # the second clause REVERTS a previous task's overlay
@@ -2716,15 +2724,17 @@ class CoreWorker:
 
             from ray_tpu.util import tracing
 
+            trace_ctx, cause = _split_trace_ctx(spec)
             try:
                 with record_span(
                         "actor_task",
                         spec.get("task_desc", f"actor.{method_name}"),
                         {"actor_id": (self.actor_id.hex()
-                                      if self.actor_id else "")}), \
+                                      if self.actor_id else "")},
+                        **cause), \
                      tracing.span(
                          f"execute {spec.get('task_desc', method_name)}",
-                         "CONSUMER", spec.get("trace_ctx"),
+                         "CONSUMER", trace_ctx,
                          {"task_id": spec["task_id"].hex()}):
                     if inspect.iscoroutinefunction(method):
                         fut = asyncio.run_coroutine_threadsafe(
@@ -3508,6 +3518,28 @@ COL_RECV_POOL = _ColBufferPool()
 from ray_tpu._private import protocol as _protocol  # noqa: E402
 
 _protocol.set_oob_buffer_pool(COL_RECV_POOL)
+
+
+def _carry_cause(spec: dict):
+    """Put the submitting thread's live timeline span (and its run) into
+    the spec's ``trace_ctx`` slot, so that the spans of the execution name
+    it as their parent. The slot is util.tracing's; its own keys
+    (``trace_id``, ``parent_span_id``) join these only where that tracing
+    is on, and nothing here switches it on."""
+    cause = _profiling.cause()
+    if cause is not None:
+        spec["trace_ctx"] = cause
+
+
+def _split_trace_ctx(spec: dict) -> tuple:
+    """A spec's ``trace_ctx`` as (util.tracing's context or None, the
+    timeline's ``parent=`` / ``run=``)."""
+    ctx = spec.get("trace_ctx")
+    if not ctx:
+        return None, {}
+    cause = ({"parent": ctx["cause"], "run": ctx.get("run")}
+             if "cause" in ctx else {})
+    return (ctx if "trace_id" in ctx else None), cause
 
 
 def _freeze(obj):

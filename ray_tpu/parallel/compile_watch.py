@@ -11,7 +11,13 @@ classified against a per-signature compile cache:
 - cache miss: ``COMPILE_BEGIN``/``COMPILE_END`` cluster events, a span
   in BOTH the chrome-trace timeline (_private/profiling.py, µs) and
   util/tracing (ns — joins the surrounding task's trace), and the
-  wall time into ``ray_tpu_pjit_compile_seconds``.
+  wall time into ``ray_tpu_pjit_compile_seconds``. The timeline's span
+  ``compile::<fn>`` is split where the compile happens: JAX's own
+  ``jax.monitoring`` durations that fired during the call become its
+  children ``trace``, ``lower``, ``backend_compile`` and ``cache_load``,
+  and it says whether the persistent cache gave the executable
+  (``args.persistent_cache``: ``hit`` | ``miss`` | ``off``) and what was
+  left for the first execution (``args.first_execute_s``).
 
 Classification is O(1) on the hit path: jitted callables expose
 ``_cache_size()`` (~0.1µs), so a call that grew the cache IS a
@@ -49,6 +55,7 @@ import functools
 import itertools
 import os
 import re
+import sys
 import threading
 import time
 import weakref
@@ -69,14 +76,90 @@ def configure_compile_cache() -> str:
     file's location — the path is part of the cache key, so it must not
     depend on a pid, a session directory or the working directory, or
     no later process would ever hit."""
+    import jax
+
+    _listen()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir
-    import jax
 
     cache_dir = os.path.join(_REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     return cache_dir
+
+
+# what JAX reports of a compile (jax.monitoring), and the child span or
+# cache outcome each becomes
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# per thread: `booked`, the CompiledFunction whose call is on the stack;
+# `events`, what JAX reported during it, [(kind, start, seconds)];
+# `unbooked`, the cache outcome awaiting a compile outside every such call
+_calls = threading.local()
+_listening = False
+# this process's cache outcomes so far, whoever asked: what the two
+# counters add up to over `fn`, stamped on every compile span
+_cache_totals = {"hit": 0, "miss": 0}
+
+
+def _booked_events() -> list:
+    """What JAX has reported during the wrapped call on this thread."""
+    return _calls.__dict__.setdefault("events", [])
+
+
+def _on_duration(event, duration, **_):
+    kind = _DURATIONS.get(event)
+    if kind is None:
+        return
+    start = time.time() - duration
+    if getattr(_calls, "booked", None) is not None:
+        _booked_events().append((kind, start, duration))
+    elif kind == "backend_compile":
+        # a compile no wrapper saw (a plain `jax.jit`, an eager
+        # operation): one span, under whatever span is live here
+        _prof.record_completed_span(
+            "compile", kind, start, duration,
+            {"persistent_cache": _calls.__dict__.pop("unbooked", "off"),
+             "cache_misses_total": _cache_totals["miss"],
+             "cache_hits_total": _cache_totals["hit"]})
+
+
+def _on_event(event, **_):
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is None:
+        return
+    booked = getattr(_calls, "booked", None)
+    _cache_totals[outcome] += 1
+    _tm.counter_inc(
+        "ray_tpu_compile_cache_hits_total" if outcome == "hit"
+        else "ray_tpu_compile_cache_misses_total",
+        tags={"fn": booked._name if booked is not None else "-"})
+    if booked is not None:
+        _booked_events().append((outcome, time.time(), 0.0))
+    elif outcome == "miss" or "unbooked" not in _calls.__dict__:
+        _calls.unbooked = outcome
+
+
+def _listen():
+    """Register the listeners above, once a process; nothing under
+    ``RAY_TPU_INTERNAL_TELEMETRY=0``. They run only while JAX traces or
+    compiles: a call that hits jit's cache fires none."""
+    global _listening
+    if _listening or not _tm.ENABLED:
+        return
+    import jax
+
+    _listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    jax.monitoring.register_event_listener(_on_event)
 
 
 def _abstract_key(args, kwargs):
@@ -277,6 +360,8 @@ class CompiledFunction:
         self._table = False
         functools.update_wrapper(self, fn, updated=())
         _LIVE[next(_SERIAL)] = self
+        if "jax" in sys.modules:    # a plain callable's wrapper never
+            _listen()               # makes its process import it
 
     def __getattr__(self, item):
         if item == "_fn":
@@ -310,16 +395,28 @@ class CompiledFunction:
         start = time.time()
         t0 = time.perf_counter()
         tags = {"fn": self._name}
+        # what JAX reports while this call is on the stack is this
+        # call's (`_on_duration`, `_on_event`)
+        outer = getattr(_calls, "booked", None)
+        _calls.booked = self
         try:
             out = self._fn(*args, **kwargs)
         except BaseException:
             # NOT gated on the cache delta: jax grows the pjit cache
             # even when tracing raises, so the delta can't distinguish
             # failure modes — the _seen set can (below)
+            _calls.__dict__.pop("events", None)
             self._record_failed_call(args, kwargs, start,
                                      time.perf_counter() - t0, tags)
             raise
+        finally:
+            _calls.booked = outer
         if cache_size() == before:
+            if "events" in _calls.__dict__:
+                # traced or compiled without growing jit's cache (an
+                # eager operation inside a wrapped plain function): no
+                # miss of this wrapper, nothing to split
+                del _calls.events
             _tm.counter_inc("ray_tpu_pjit_cache_total",
                             tags={**tags, "result": "hit"})
             return out
@@ -402,13 +499,40 @@ class CompiledFunction:
                                 fn=self._name)
         start_ns = int(start * 1e9)
         end_ns = start_ns + int(dur * 1e9)
-        _prof.record_completed_span("compile", f"compile::{self._name}",
-                                    start, dur, {"fn": self._name,
-                                                 "step": step_id})
+        self._record_split(start, dur, step_id,
+                           _calls.__dict__.pop("events", ()))
         tracing.record_completed_span(f"compile {self._name}", "INTERNAL",
                                       start_ns, end_ns,
                                       attributes={"fn": self._name,
                                                   "step": step_id})
+
+    def _record_split(self, start: float, dur: float, step_id, events):
+        """The timeline's `compile::<fn>` and, under it, what JAX
+        reported during the call: `trace` (the longest trace: a nested
+        jit's is inside its caller's), and every `lower`,
+        `backend_compile` (the compile on a persistent-cache miss, the
+        load on a hit) and `cache_load` (the retrieval inside that
+        load), each with its own start and duration."""
+        from ray_tpu._private import step_anatomy as _sa
+
+        traces = [e for e in events if e[0] == "trace"]
+        children = ([max(traces, key=lambda e: e[2])] if traces else []) \
+            + [e for e in events
+               if e[0] in ("lower", "backend_compile", "cache_load")]
+        outcomes = {e[0] for e in events}
+        cache = ("miss" if "miss" in outcomes
+                 else "hit" if "hit" in outcomes else "off")
+        span_id = _prof.record_completed_span(
+            "compile", f"compile::{self._name}", start, dur,
+            {"fn": self._name, "step": step_id, "persistent_cache": cache,
+             "first_execute_s": max(0.0, dur - _sa._total(_sa._merge(
+                 [(s, s + d) for _, s, d in children]))),
+             "cache_misses_total": _cache_totals["miss"],
+             "cache_hits_total": _cache_totals["hit"]})
+        for kind, child_start, seconds in children:
+            _prof.record_completed_span("compile", kind, child_start,
+                                        seconds, {"fn": self._name},
+                                        parent=span_id)
 
     def _call_classified_by_signature(self, args, kwargs):
         """Plain (non-jit) callables have no ``_cache_size``: classify

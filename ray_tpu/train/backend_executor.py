@@ -11,6 +11,7 @@ import time
 
 import ray_tpu
 from ray_tpu import exceptions as exc
+from ray_tpu._private import profiling as _prof
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.train.worker_group import WorkerGroup
 from ray_tpu.util.placement_group import (
@@ -297,6 +298,10 @@ class BackendExecutor:
         self.pg = None
 
     def start(self):
+        with _prof.record_span("startup", "gang_start"):
+            return self._start()
+
+    def _start(self):
         bundles = self.scaling.as_placement_group_bundles()
         self.pg = placement_group(
             bundles, strategy=self.scaling.placement_strategy,
@@ -309,10 +314,11 @@ class BackendExecutor:
         # connection per start.
         self._preempt = _PreemptionMonitor(self.pg.id)
         try:
-            ok = self.pg.wait(
-                120.0,
-                _created_event=(self._preempt.created_event()
-                                if self._preempt.active() else None))
+            with _prof.record_span("startup", "pg_wait"):
+                ok = self.pg.wait(
+                    120.0,
+                    _created_event=(self._preempt.created_event()
+                                    if self._preempt.active() else None))
             if not ok:
                 remove_placement_group(self.pg)
                 self.pg = None
@@ -326,9 +332,13 @@ class BackendExecutor:
                 raise PlacementGroupUnschedulableError(
                     f"could not gang-schedule {len(bundles)} training "
                     f"bundles {bundles}: insufficient cluster resources")
-            self.worker_group = WorkerGroup(
-                self.scaling.num_workers, self.scaling.worker_resources(),
-                placement_group=self.pg)
+            # the actors' creation is asynchronous: the span is their
+            # specs' cause (`worker_spawn` hangs under it), not their wait
+            with _prof.record_span("startup", "worker_group_start"):
+                self.worker_group = WorkerGroup(
+                    self.scaling.num_workers,
+                    self.scaling.worker_resources(),
+                    placement_group=self.pg)
             # checkpoint-then-yield fan-out: the warning reaches every
             # rank's session so the train loop can checkpoint in the
             # grace window (fire-and-forget refs: a rank that can't
@@ -338,7 +348,10 @@ class BackendExecutor:
                 w.notify_preemption.remote(grace_s)
                 for w in self.worker_group.workers])
             self.backend = self.backend_config.backend_cls()
-            self.backend.on_start(self.worker_group, self.scaling)
+            # the first call the gang answers: holds the wait for every
+            # worker to be spawned, booted and made an actor
+            with _prof.record_span("startup", "backend_on_start"):
+                self.backend.on_start(self.worker_group, self.scaling)
         except BaseException:
             # a failure ANYWHERE in startup must release the monitor's
             # dedicated GCS connection + poll thread — a crash-looping
@@ -473,6 +486,16 @@ class BackendExecutor:
         if self.worker_group is not None:
             if getattr(self, "backend", None) is not None:
                 self.backend.on_shutdown(self.worker_group)
+            # the workers' rings die with them: keep their share of the
+            # run's start-up, so that `timeline()` after `fit()` still
+            # resolves every parent (dead ranks answer fast, with an
+            # error; the timeout only bounds a hung one)
+            try:
+                for spans in self.worker_group.execute("run_spans",
+                                                       timeout=5.0):
+                    _prof.adopt(spans)
+            except Exception:
+                pass
             self.worker_group.shutdown()
             self.worker_group = None
         if self.pg is not None:

@@ -96,6 +96,16 @@ class DataParallelTrainer(BaseTrainer):
         (_private/retry.py): full-jitter exponential backoff, and each
         gang retry draws one token from the process-wide retry budget so
         restart storms surface through the budget-exhaustion event."""
+        from ray_tpu._private import profiling
+
+        # the run's root span: every span of this run, in the driver, the
+        # raylet and the workers, carries its `run` (it rides the specs
+        # with the causing span's id)
+        run = f"{self.run_config.name or 'train'}-{os.urandom(4).hex()}"
+        with profiling.record_span("startup", "fit", run=run):
+            return self._fit()
+
+    def _fit(self) -> Result:
         from ray_tpu._private import events as _events
         from ray_tpu._private import telemetry as _tm
         from ray_tpu._private.retry import RetryPolicy, default_budget

@@ -14,6 +14,7 @@ import time
 
 import ray_tpu
 from ray_tpu._private import api as _api
+from ray_tpu._private import profiling as _prof
 
 
 class TrainWorker:
@@ -52,20 +53,40 @@ class TrainWorker:
         (``TpuBackendError``). A process whose JAX_PLATFORMS names other
         platforms only was pinned there on purpose — CPU dry runs with
         injected TPU resources — and is left alone."""
-        pinned = os.environ.get("JAX_PLATFORMS", "")
-        if not self.num_tpus or (pinned and "tpu" not in pinned.split(",")):
-            return
-        import jax
-
         from ray_tpu import exceptions as exc
 
-        backend = jax.default_backend()
+        # the worker's own JAX start: the import, and the backend taking
+        # the chips. A worker left alone leaves the span empty (backend
+        # None): JAX then starts wherever the train function first asks
+        args = {"backend": None, "devices": 0}
+        with _prof.record_span("startup", "backend_up", args):
+            pinned = os.environ.get("JAX_PLATFORMS", "")
+            if not self.num_tpus or (
+                    pinned and "tpu" not in pinned.split(",")):
+                return
+            import jax
+
+            backend = args["backend"] = jax.default_backend()
+            args["devices"] = jax.local_device_count()
         if backend != "tpu":
             raise exc.TpuBackendError(
                 f"train worker rank {self.world_rank} holds "
                 f"{self.num_tpus:g} TPU chip(s) but its JAX backend is "
                 f"{backend!r}: libtpu could not take the chips (another "
                 f"process may hold them)")
+
+    def run_spans(self) -> list:
+        """This process's start-up and compile spans and the spans they
+        name as parents (the call that started the train function), for
+        the driver to keep (`BackendExecutor.shutdown`): the process is
+        about to be killed."""
+        events = [ev for ev in _prof.snapshot() if ev.get("ph") == "X"]
+        kept = [ev for ev in events
+                if ev["cat"] in ("startup", "compile")]
+        parents = {ev["args"].get("parent") for ev in kept}
+        return kept + [ev for ev in events
+                       if ev["cat"] not in ("startup", "compile")
+                       and ev["args"]["id"] in parents]
 
     def setup_collective_group(self, world_size, rank, backend, group_name):
         from ray_tpu.util import collective as col
@@ -121,18 +142,29 @@ class TrainWorker:
             # train.sharded_checkpoint save/restore need no path plumbing
             self.session.checkpoint_dir = config.pop("_checkpoint_dir")
         _session._set_session(self.session)
+        # this call's span (its parent: the driver's span that made the
+        # call) is the train function's cause; the function's own thread
+        # starts with an empty stack
+        above = _prof.current() or (None, None)
 
         def _run():
             from ray_tpu._private import step_anatomy
 
-            # step 1 opens when the train function starts; each
-            # session.report advances it (iteration == step_id), so
-            # every collective/data/compile interval recorded by this
-            # gang member fuses by step, not by wall-clock windows
-            step_anatomy.start(rank=self.world_rank)
             try:
-                self._require_tpu_backend()
-                train_fn(config) if config is not None else train_fn()
+                with _prof.record_span("startup", "train_fn",
+                                       {"rank": self.world_rank},
+                                       parent=above[0], run=above[1]):
+                    self._require_tpu_backend()
+                    # step 1 opens when the train function starts, AFTER
+                    # the worker's JAX start (`backend_up` says where
+                    # that time went: `step::1` and the first sample of
+                    # `ray_tpu_step_seconds` do not hold it); each
+                    # session.report advances it (iteration == step_id),
+                    # so every collective/data/compile interval recorded
+                    # by this gang member fuses by step, not by
+                    # wall-clock windows
+                    step_anatomy.start(rank=self.world_rank)
+                    train_fn(config) if config is not None else train_fn()
             except BaseException as e:  # noqa: BLE001
                 self.session.error = e
             finally:

@@ -198,7 +198,9 @@ def submit_span(spec: dict, name: str):
     def _cm():
         with span(f"submit {name}", "PRODUCER", ctx,
                   {"task_id": spec["task_id"].hex()}) as sp:
-            spec["trace_ctx"] = {"trace_id": sp["trace_id"],
+            # beside the timeline's cause, where the spec carries one
+            spec["trace_ctx"] = {**(spec.get("trace_ctx") or {}),
+                                 "trace_id": sp["trace_id"],
                                  "parent_span_id": sp["span_id"]}
             yield sp
 

@@ -232,6 +232,29 @@ def pytest_sessionfinish(session, exitstatus):
         session.exitstatus = 1
 
 
+@pytest.fixture(scope="session")
+def once_a_run(tmp_path_factory):
+    """``once_a_run(name, make)``: ``make()``'s JSON-able result, made
+    once a test run — the workers of a parallel run share the first one's
+    through a file beside their temp directories (a heavy fixture's
+    cluster, fit or subprocess is then paid once, not once a worker that
+    is handed one of its cases)."""
+    import json
+
+    def once(name, make):
+        if not os.environ.get("PYTEST_XDIST_WORKER"):
+            return make()
+        from filelock import FileLock
+
+        shared = tmp_path_factory.getbasetemp().parent / f"{name}.json"
+        with FileLock(f"{shared}.lock"):
+            if not shared.is_file():
+                shared.write_text(json.dumps(make()))
+            return json.loads(shared.read_text())
+
+    return once
+
+
 @pytest.fixture
 def ray_start_regular():
     """Start a fresh single-node runtime for a test, shut down after.

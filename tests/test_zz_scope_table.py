@@ -64,29 +64,34 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def built():
-    """{case: (the step, its table, its compiled text)}, one real step run
-    each: the table is built from what the compile MISS left behind."""
-    out = {}
-    for case, (module, preset, remat, _) in STEPS.items():
+_BUILT = {}
+
+
+def _built(case):
+    """(the step, its table, its compiled text) of one case, built when a
+    test first asks for it and once a process: one real step run, ONE
+    compile. The table is built from what the compile MISS left behind;
+    the text comes from the arguments themselves, lowered before the call
+    donates the state and compiled after it, when the executable is
+    already in JAX's in-process cache (as the table's own is)."""
+    if case not in _BUILT:
+        module, preset, remat, _ = STEPS[case]
         cfg = dataclasses.replace(preset(), remat=remat)
         opt = default_optimizer()
         state = make_train_state(lambda rng: module.init(rng, cfg),
                                  jax.random.PRNGKey(0), opt)
         step = make_train_step(
-            lambda p, b, module=module, cfg=cfg: module.loss_fn(p, b, cfg),
-            opt)
+            lambda p, b: module.loss_fn(p, b, cfg), opt)
         tokens = jnp.zeros((2, 65), jnp.int32)
-        text = step.lower(state, {"tokens": tokens}).compile().as_text()
+        lowered = step.lower(state, {"tokens": tokens})
         step(state, {"tokens": tokens})
-        out[case] = (step, step.scope_table(), text)
-    return out
+        _BUILT[case] = (step, step.scope_table(),
+                        lowered.compile().as_text())
+    return _BUILT[case]
 
 
-@pytest.mark.parametrize("case", sorted(STEPS))
-def test_every_scope_the_model_declares_is_in_the_table(built, case):
-    _, table, _ = built[case]
+def _every_scope_the_model_declares_is_in_the_table(case, step, table,
+                                                    text):
     found = {name for scopes, _ in table.values() for name in scopes}
     assert STEPS[case][3] <= found, STEPS[case][3] - found
     # and nothing of JAX's own structure got through as a scope
@@ -96,9 +101,8 @@ def test_every_scope_the_model_declares_is_in_the_table(built, case):
     assert not any(re.match(r"branch_\d+", name) for name in found)
 
 
-@pytest.mark.parametrize("case", sorted(STEPS))
-def test_the_four_phases_and_no_recompute_without_remat(built, case):
-    _, table, _ = built[case]
+def _the_four_phases_and_no_recompute_without_remat(case, step, table,
+                                                    text):
     phases = {phase for _, phase in table.values()}
     want = {"forward", "backward", "optimizer"}
     assert phases == (want | {"recompute"} if STEPS[case][2] else want)
@@ -127,9 +131,7 @@ def _computations(text):
     return roots, instructions
 
 
-@pytest.mark.parametrize("case", sorted(STEPS))
-def test_every_matmul_lies_under_a_scope(built, case):
-    _, table, text = built[case]
+def _every_matmul_lies_under_a_scope(case, step, table, text):
     roots, instructions = _computations(text)
     matmul = {"dot", "convolution"}
     seen = 0
@@ -141,13 +143,27 @@ def test_every_matmul_lies_under_a_scope(built, case):
     assert seen >= 10
 
 
-@pytest.mark.parametrize("case", sorted(STEPS))
-def test_the_table_is_of_the_program_that_ran(built, case):
+def _the_table_is_of_the_program_that_ran(case, step, table, text):
     """Lowering the kept abstract arguments (the state was donated by
     then) gives the text that lowering the arguments themselves gave."""
-    step, table, text = built[case]
     assert table == compile_watch.scope_table_of(text)
     assert step.scope_table() is table      # built once
+
+
+CHECKS = {check.__name__.lstrip("_"): check for check in (
+    _every_scope_the_model_declares_is_in_the_table,
+    _the_four_phases_and_no_recompute_without_remat,
+    _every_matmul_lies_under_a_scope,
+    _the_table_is_of_the_program_that_ran)}
+
+
+# the upper decorator varies fastest: a case's four checks are collected
+# side by side, so that the workers of a parallel run, which are handed
+# neighbouring tests, build few cases each
+@pytest.mark.parametrize("check", sorted(CHECKS))
+@pytest.mark.parametrize("case", sorted(STEPS))
+def test_the_steps_table(case, check):
+    CHECKS[check](case, *_built(case))
 
 
 OP_NAMES = {
