@@ -160,9 +160,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "directory to write per-worker cProfile dumps into."),
     _k("TESTING", "", "bool",
        "set by the test harness; relaxes timing-sensitive defaults."),
-    _k("TEST_FILE_BUDGET_S", "120", "float",
-       "tier-1 duration guard: per-file wall-clock budget for "
-       "early-alphabet test files (0 disables; see tests/conftest.py)."),
     _k("SOAK_NODES", "100", "int",
        "default fleet size for the cluster-scale soak harness "
        "(_private/sim_cluster.py / benchmarks/soak_bench.py)."),

@@ -18,6 +18,12 @@ if "xla_force_host_platform_device_count" not in xla_flags:
     ).strip()
 os.environ.setdefault("RAY_TPU_TESTING", "1")
 
+import signal  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
+import time  # noqa: E402
+import traceback  # noqa: E402
+
 import pytest  # noqa: E402
 
 
@@ -170,65 +176,113 @@ def pytest_runtest_makereport(item, call):
 
 
 # ---------------------------------------------------------------------------
-# Tier-1 duration guard. The tier-1 budget is a hard 870 s wall-clock
-# timeout over the alphabetical file order, so one slow EARLY file
-# silently starves every file behind it out of the run (DOTS_PASSED is
-# wall-clock sensitive). This guard turns that silent starvation into an
-# attributable failure: any early-alphabet test file whose summed test
-# durations (the same per-phase numbers --durations reports) exceed the
-# per-file budget fails the session at the end. Late-alphabet files
-# (test_z*) are exempt by design — they are sequenced last precisely so
-# they spill past the timeout, not displace others. Override/disable via
-# RAY_TPU_TEST_FILE_BUDGET_S (0 disables).
-
-_FILE_BUDGET_DEFAULT_S = 120.0
-_file_durations: dict = {}
+# A limit for every case. Nothing the suite guards is worth ten minutes of
+# one worker in a `get()` (ROADMAP D15): a phase of a case (set-up, call,
+# tear-down) that runs past its limit FAILS, by name, with where it hung,
+# and the worker goes on to its next case. CASE_LIMIT_S by default;
+# `@pytest.mark.limit(seconds, reason="...")` where a case needs another.
+# The alarm is SIGALRM's: it reaches the main thread when the interpreter
+# next runs Python there, so a call that never leaves native code is out of
+# its reach.
+CASE_LIMIT_S = 300.0
 
 
-def _file_budget_s() -> float:
+def _other_threads() -> str:
+    names = {t.ident: t.name for t in threading.enumerate()}
+    return "".join(
+        f"--- thread {names.get(ident, ident)}\n"
+        + "".join(traceback.format_stack(frame))
+        for ident, frame in sys._current_frames().items()
+        if ident != threading.get_ident())
+
+
+def _limited(item, phase):
+    marker = item.get_closest_marker("limit")
+    seconds, why = (marker.args[0], marker.kwargs["reason"]) if marker \
+        else (CASE_LIMIT_S, "the default")
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def over(signum, frame):
+        pytest.fail(f"{item.nodeid}: its {phase} ran past the case's limit "
+                    f"of {seconds:g} s ({why}); the other threads:\n"
+                    f"{_other_threads()}")
+
+    previous = signal.signal(signal.SIGALRM, over)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
     try:
-        return float(os.environ.get("RAY_TPU_TEST_FILE_BUDGET_S",
-                                    _FILE_BUDGET_DEFAULT_S))
-    except ValueError:
-        return _FILE_BUDGET_DEFAULT_S
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_setup(item):
+    yield from _limited(item, "set-up")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    yield from _limited(item, "call")
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_teardown(item):
+    yield from _limited(item, "tear-down")
+
+
+# ---------------------------------------------------------------------------
+# The suite's clock. The driver runs the whole of `tests/` under six
+# workers (`-n 6 --dist load`) and cuts the command at CLOCK_S; a cut run
+# counts only as far as it got. The session ends with the facts that
+# command depends on and fails on the one thing that can cut a run by
+# itself: a case that is not `slow` and takes over CASE_MAX_S of a worker.
+# The benchmark's own tests (tests/chipbench_tests/, a `benchmark` PR's to
+# change) are reported and not judged.
+CLOCK_S = 1470.0
+CASE_MAX_S = 120.0
+_cases: dict = {}       # nodeid -> [seconds over its phases, marked slow]
+_started = time.monotonic()
 
 
 def pytest_runtest_logreport(report):
-    fname = report.nodeid.split("::", 1)[0]
-    _file_durations[fname] = \
-        _file_durations.get(fname, 0.0) + report.duration
+    case = _cases.setdefault(report.nodeid, [0.0, False])
+    case[0] += report.duration
+    case[1] = case[1] or "slow" in report.keywords
 
 
-def _early_alphabet(fname: str) -> bool:
-    base = os.path.basename(fname)
-    return base.startswith("test_") and not base.startswith("test_z")
+def clock_report(cases: dict, wall_s: float, workers) -> tuple:
+    """`(lines, over)`: the facts, and the cases that fail the session."""
+    longest = max(cases, key=lambda c: cases[c][0])
+    lines = [f"{sum(s for s, _ in cases.values()):.0f} case-seconds over "
+             f"{len(cases)} cases in {wall_s:.0f} s of wall time",
+             f"longest case: {cases[longest][0]:.1f} s  {longest}"]
+    if workers == 6:
+        lines.append(f"{100 * wall_s / CLOCK_S:.0f} % of the driver's "
+                     f"{CLOCK_S:.0f} s for this command (-n 6)")
+    over = sorted((c for c, (s, slow) in cases.items()
+                   if s > CASE_MAX_S and not slow
+                   and not c.startswith("tests/chipbench_tests/")),
+                  key=lambda c: -cases[c][0])
+    lines += [f"OVER {CASE_MAX_S:.0f} s and not marked slow: "
+              f"{cases[c][0]:.1f} s  {c}" for c in over]
+    return lines, over
 
 
 def pytest_sessionfinish(session, exitstatus):
-    budget = _file_budget_s()
-    if budget <= 0:
-        return
-    if len(_file_durations) < 10:
-        return   # targeted run (one file / a few tests), not the suite:
-                 # a developer iterating on a slow file shouldn't fail
-                 # their own focused run
-    over = sorted(((f, d) for f, d in _file_durations.items()
-                   if _early_alphabet(f) and d > budget),
-                  key=lambda p: -p[1])
-    if not over:
-        return
+    if hasattr(session.config, "workerinput") or not _cases:
+        return          # a worker of a parallel run: its controller reports
+    lines, over = clock_report(
+        _cases, time.monotonic() - _started,
+        getattr(session.config.option, "numprocesses", None))
     tr = session.config.pluginmanager.get_plugin("terminalreporter")
-    lines = [f"  {f}: {d:.1f}s > {budget:.0f}s budget" for f, d in over]
-    msg = ("tier-1 duration guard: early-alphabet test file(s) over the "
-           "per-file wall-clock budget (slow early files starve the "
-           "870s tier-1 run; mark tests `slow`, speed them up, or raise "
-           "RAY_TPU_TEST_FILE_BUDGET_S):\n" + "\n".join(lines))
     if tr is not None:
-        tr.write_sep("=", "tier-1 duration guard", red=True)
-        tr.write_line(msg)
-    if session.exitstatus in (0, 1):
-        # escalate only from ok/tests-failed — an interrupted (2) or
-        # internally-errored (3) session keeps its more-severe code
+        tr.write_sep("=", "the suite's clock", red=bool(over))
+        for line in lines:
+            tr.write_line(line)
+    if over and session.exitstatus == 0:
         session.exitstatus = 1
 
 
@@ -298,3 +352,15 @@ def runs_on(monkeypatch):
             target, "where",
             lambda mesh=None, *, interpret=False: (platform, devices))
     return answer
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """`apply_attention(impl="flash")` through the Pallas interpreter: what
+    a TPU's flash kernels compute, on this CPU."""
+    import functools
+
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=True))

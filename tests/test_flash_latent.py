@@ -263,7 +263,8 @@ def test_apply_latent_attention_flash_equals_reference(monkeypatch):
     def loss(params, impl):
         return jnp.sum(jnp.square(L.apply_latent_attention(
             params, x, cfg, impl=impl, compute_dtype=jnp.float32)))
-    want = jax.value_and_grad(loss)(params, "reference")
+    grads_of = jax.jit(jax.value_and_grad(loss), static_argnums=1)
+    want = grads_of(params, "reference")
     seen = []
     original = fa.flash_attention
 
@@ -271,7 +272,7 @@ def test_apply_latent_attention_flash_equals_reference(monkeypatch):
         seen.append((q.shape, k.shape, v.shape, kw["k_shared"].shape))
         return original(q, k, v, **dict(kw, interpret=True))
     monkeypatch.setattr(fa, "flash_attention", interpreted)
-    got = jax.value_and_grad(loss)(params, "flash")
+    got = grads_of(params, "flash")
     assert seen == [((2, 128, 4, 32), (2, 128, 4, 24), (2, 128, 4, 16),
                      (2, 128, 8))]
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)

@@ -242,13 +242,15 @@ def test_the_norm_inside_the_rule_is_the_callers_norm(form):
                 *a, normalize=1e-6, chunk=chunk,
                 compute_dtype=jnp.float32))(*args), atol=1e-6)
         chunked = gd._to_chunks(*args, 16)
-        _, residuals = gd._rule_fwd(*chunked, jnp.float32, 1e-6)
+        _, residuals = jax.jit(lambda *c: gd._rule_fwd(
+            *c, jnp.float32, 1e-6))(*chunked)
         np.testing.assert_array_equal(residuals[0], chunked[0])
 
 
 def test_bfloat16_products_stay_near_float32s():
     args = _inputs()
-    exact = gd.gated_delta(*args, chunk=64, compute_dtype=jnp.float32)
+    exact = jax.jit(lambda *a: gd.gated_delta(
+        *a, chunk=64, compute_dtype=jnp.float32))(*args)
     rounded = jax.jit(lambda *a: gd.gated_delta(*a, chunk=64))(*args)
     assert rounded.dtype == jnp.float32
     assert 1e-4 < _rel(rounded, exact) < 2e-2

@@ -5,30 +5,26 @@ system's `loss_fn` against the benchmark's plain reference, the second
 loss's weight at zero against the module-less model, the share test of the
 model-configs guide, and the kernel path through the Pallas interpreter."""
 import dataclasses
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import compare
 from chipbench.accounting import joyai_flash as accounting
 from chipbench.references import joyai_flash as reference
 from ray_tpu.models import joyai
 from ray_tpu.models import layers as L
-from ray_tpu.ops import flash_attention as fa
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+from tests import test_model_checks as checks
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "tests", "chipbench_tests", "configs",
-                       "joyai-tiny.json")) as f:
-    FILED = json.load(f)
+FILED = checks.filed("joyai-tiny")
 LEAVES = ("head", "wq_b", "wkv_b", "wkv_a", "wo", "wg", "w_gate", "w_down",
           "shared_w_gate", "eh_proj")
 
 
+@functools.cache
 def _setup(seed=3, **fields):
     cfg = dataclasses.replace(joyai.joyai_tiny(), dtype=jnp.float32, **fields)
     params = joyai.init(jax.random.PRNGKey(seed), cfg)
@@ -36,11 +32,13 @@ def _setup(seed=3, **fields):
     return cfg, params, tokens
 
 
-@pytest.fixture
-def interpreted(monkeypatch):
-    original = fa.flash_attention
-    monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: original(
-        *a, **dict(kw, interpret=True)))
+@functools.cache
+def _reference(weight):
+    """The reference's half of the comparison: remat is not its business."""
+    _, params, tokens = _setup(mtp_weight=weight)
+    filed = dict(FILED, mtp_loss_weight=weight)
+    return checks.reference_side(lambda p, t: reference.loss(p, t, filed),
+                                 accounting, params, tokens)
 
 
 @pytest.mark.parametrize("weight", [0.3, 0.0])
@@ -51,12 +49,11 @@ def test_loss_and_picked_gradients_against_the_reference(weight, remat):
     on the S − 1 rows that have a second target, where the program runs S
     and masks the last)."""
     cfg, params, tokens = _setup(mtp_weight=weight, remat=remat)
-    filed = dict(FILED, mtp_loss_weight=weight)
-    assert accounting.ran_sizes(cfg) == accounting.filed_sizes(filed)
-    out = compare.compare(
-        lambda p, t: joyai.loss_fn(p, {"tokens": t}, cfg)[0],
-        lambda p, t: reference.loss(p, t, filed), params, tokens,
-        jax.devices()[0], pick=accounting.pick, put=accounting.put)
+    assert accounting.ran_sizes(cfg) == accounting.filed_sizes(
+        dict(FILED, mtp_loss_weight=weight))
+    out = checks.compared(
+        lambda p, t: joyai.loss_fn(p, {"tokens": t}, cfg)[0], accounting,
+        params, tokens, _reference(weight))
     assert set(out["errors"]) == {"loss"} | {"grad_" + k for k in LEAVES}
     if weight == 0.0:
         # nothing of the second loss reaches its own matrix
@@ -77,9 +74,9 @@ def test_the_second_loss_at_weight_zero_is_the_module_less_model():
         joyai.init(jax.random.PRNGKey(0), bare_cfg))
 
     def grads(cfg, params):
-        return jax.value_and_grad(
-            lambda p: joyai.loss_fn(p, {"tokens": tokens}, cfg),
-            has_aux=True)(params)
+        return checks.loss_and_grads(
+            lambda p: joyai.loss_fn(p, {"tokens": tokens}, cfg), params,
+            has_aux=True)
     (loss0, metrics0), g0 = grads(cfg, params)
     (loss_bare, _), g_bare = grads(bare_cfg, bare)
     assert float(loss0) == float(loss_bare) == float(metrics0["loss_main"])
@@ -140,8 +137,8 @@ def test_the_kernel_path_equals_the_reference_path(interpreted):
 
     def run(attention, remat):
         c = dataclasses.replace(cfg, attention=attention, remat=remat)
-        return jax.value_and_grad(
-            lambda p: joyai.loss_fn(p, {"tokens": tokens}, c)[0])(params)
+        return checks.loss_and_grads(
+            lambda p: joyai.loss_fn(p, {"tokens": tokens}, c)[0], params)
     want = run("reference", False)
     for remat in (False, True):
         got = run("flash", remat)

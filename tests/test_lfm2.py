@@ -8,24 +8,22 @@ ahead of the rotation; the sigmoid-and-bias top-k; the presets' counts. The
 chip's share of the experts is test_lfm2_share.py's, the operator alone
 test_short_conv.py's."""
 import dataclasses
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import catalog, compare
+from chipbench import compare
 from chipbench.accounting import lfm2_moe as accounting
 from chipbench.references import lfm2_moe as reference
 from ray_tpu.models import layers as L
 from ray_tpu.models import lfm2
+from tests import test_model_checks as checks
 
 TINY = dataclasses.replace(lfm2.lfm2_tiny(), attention="reference")
-with open(os.path.join(catalog.ROOT, "tests", "chipbench_tests", "configs",
-                       "lfm2-tiny.json")) as f:
-    FILED = json.load(f)
+FILED = checks.filed("lfm2-tiny")
 SEQ = 64        # 2 × 64 tokens choose 3 of 16: 384 rows, a bound of 256
 
 
@@ -33,26 +31,16 @@ def _params(cfg, seed=0):
     """Fresh parameters with every norm's scale (the two per-head ones
     too) and the selection bias moved off their initial values, so that a
     norm in the wrong place or a bias that reached a gate shows."""
-    params = lfm2.init(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def moved(path, a):
-        name = path[-1].key
-        if name.startswith("ln_") or name in ("q_norm", "k_norm"):
-            return a + 0.3 * jax.random.normal(next(keys), a.shape)
-        if name == "bias":
-            return a + 0.05 * jax.random.normal(next(keys), a.shape)
-        return a
-    return jax.tree_util.tree_map_with_path(moved, params)
+    def amount(key, _):
+        if key.startswith("ln_") or key in ("q_norm", "k_norm"):
+            return 0.3
+        return 0.05 * (key == "bias")
+    return checks.moved_off(lfm2.init(jax.random.PRNGKey(seed), cfg),
+                            seed + 1, amount)
 
 
-def _tokens(cfg, batch=2, seq=SEQ, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
-
-
-def _rel(got, want):
-    return jax.tree_util.tree_map(compare.rel_l2, got, want)
+def _tokens(cfg, seq=SEQ, **kw):
+    return checks.token_ids(cfg.vocab_size, seq=seq, **kw)
 
 
 def test_presets_count_the_published_parameters():
@@ -118,19 +106,13 @@ def test_loss_and_every_gradient_match_the_reference_in_float32(remat):
     gate and inside both."""
     cfg = dataclasses.replace(TINY, dtype=jnp.float32, remat=remat)
     params, tokens = _params(cfg), _tokens(cfg)
-    (loss, metrics), grads = jax.value_and_grad(
+    # the selection bias is behind a stop_gradient: zero both sides
+    (_, metrics), grads, want_grads = checks.against_reference(
         lambda p: lfm2.loss_fn(p, {"tokens": tokens}, cfg),
-        has_aux=True)(params)
-    want, want_grads = jax.value_and_grad(
-        lambda p: reference.loss(p, tokens, FILED))(params)
-    assert float(loss) == pytest.approx(float(want), rel=2e-6)
-    errors = jax.tree_util.tree_leaves_with_path(_rel(grads, want_grads))
-    assert len(errors) == len(jax.tree_util.tree_leaves(params))
-    for path, err in errors:
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['bias']"):
-            continue                # behind a stop_gradient: zero both sides
-        assert err <= 3e-5, (name, err)
+        lambda p: reference.loss(p, tokens, FILED), params,
+        loss_rtol=2e-6, grad_tol=3e-5, skip=("['bias']",), has_aux=True)
+    assert jax.tree_util.tree_structure(grads) == \
+        jax.tree_util.tree_structure(params)
     for layer, want_layer in zip(grads["layers"], want_grads["layers"]):
         if "bias" in layer["ff"]:
             assert not layer["ff"]["bias"].any()
@@ -170,18 +152,22 @@ def test_through_make_train_step_the_loss_is_the_references_and_falls():
         set(metrics)
 
 
+@functools.cache
+def _reference(seed):
+    params, tokens = _params(TINY, seed), _tokens(TINY, seed=seed + 1)
+    return params, tokens, checks.picked(
+        lambda p, t: reference.loss(p, t, FILED), accounting, params, tokens)
+
+
+@functools.cache
 def _compared(cfg, seed):
-    params, tokens = _params(cfg, seed), _tokens(cfg, seed=seed + 1)
-    run = compare.loss_and_grads(
-        lambda p, t: lfm2.loss_fn(p, {"tokens": t}, cfg)[0],
-        accounting.pick, accounting.put)
-    ref = compare.loss_and_grads(
-        lambda p, t: reference.loss(p, t, FILED), accounting.pick,
-        accounting.put)
-    loss, grads = run(params, tokens)
-    want, want_grads = ref(params, tokens)
-    return abs(float(loss) - float(want)) / float(want), _rel(grads,
-                                                               want_grads)
+    """Once a process: two cases read `(TINY under remat, 0)`."""
+    params, tokens, (want, want_grads) = _reference(seed)
+    loss, grads = checks.picked(
+        lambda p, t: lfm2.loss_fn(p, {"tokens": t}, cfg)[0], accounting,
+        params, tokens)
+    return abs(float(loss) - float(want)) / float(want), checks.rel(
+        grads, want_grads)
 
 
 @pytest.mark.parametrize("seed", [0, 2])

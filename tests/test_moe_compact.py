@@ -74,8 +74,8 @@ def _value_and_grads(params, x, cfg, idx, mesh=None):
                                  mesh=mesh, routing=_routing(params, x, idx))
         weights = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
         return jnp.sum(out * weights.reshape(out.shape)), (out, stats)
-    (_, (out, stats)), grads = jax.value_and_grad(
-        f, argnums=(0, 1), has_aux=True)(params, x)
+    (_, (out, stats)), grads = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1), has_aux=True))(params, x)
     return out, stats, grads
 
 
@@ -143,8 +143,8 @@ def test_the_bounded_path_with_the_layers_own_router(form):
             out, stats = L.apply_moe(params, x, cfg,
                                      compute_dtype=jnp.float32)
             return jnp.sum(jnp.sin(out)), (out, stats)
-        (_, (out, stats)), grads = jax.value_and_grad(
-            f, argnums=(0, 1), has_aux=True)(params, x)
+        (_, (out, stats)), grads = jax.jit(jax.value_and_grad(
+            f, argnums=(0, 1), has_aux=True))(params, x)
         return out, stats, grads
     got = run()
     with mock.patch.object(L, "assignment_bounds", lambda *a: ()):

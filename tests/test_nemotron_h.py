@@ -7,51 +7,37 @@ test_nemotron_h_share.py's, the virtual `dp` and `ep` meshes
 test_nemotron_h_mesh.py's: three files, each inside the conftest's
 per-file budget when the whole suite loads the machine."""
 import dataclasses
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import catalog, compare
+from chipbench import compare
 from chipbench.accounting import nemotron_h as accounting
 from chipbench.references import nemotron_h as reference
 from ray_tpu.models import nemotron_h
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.parallel.ring_attention import reference_attention
+from tests import test_model_checks as checks
 
 TINY = dataclasses.replace(nemotron_h.nemotron_h_tiny(),
                            attention="reference")
-with open(os.path.join(catalog.ROOT, "tests", "chipbench_tests", "configs",
-                       "nemotronh-tiny.json")) as f:
-    FILED = json.load(f)
+FILED = checks.filed("nemotronh-tiny")
 
 
 def _params(cfg, seed=0):
     """Fresh parameters with every norm's scale and the selection bias
     moved off their initial 1 and 0, so that one applied in the wrong place
     shows."""
-    params = nemotron_h.init(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith(("['ln']", "['ln_f']", "['norm']", "['bias']")):
-            return a + 0.1 * jax.random.normal(next(keys), a.shape)
-        return a
-    return jax.tree_util.tree_map_with_path(moved, params)
+    return checks.moved_off(
+        nemotron_h.init(jax.random.PRNGKey(seed), cfg), seed + 1,
+        lambda key, _: 0.1 * (key in ("ln", "ln_f", "norm", "bias")))
 
 
-def _tokens(cfg, batch=2, seq=40, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
-
-
-def _rel(got, want):
-    return jax.tree_util.tree_map(compare.rel_l2, got, want)
+def _tokens(cfg, seq=40, **kw):
+    return checks.token_ids(cfg.vocab_size, seq=seq, **kw)
 
 
 def test_presets_count_the_published_parameters():
@@ -76,16 +62,12 @@ def test_loss_and_every_gradient_match_the_reference_in_float32():
     The selection bias gets no gradient from either."""
     cfg = dataclasses.replace(TINY, dtype=jnp.float32)
     params, tokens = _params(cfg), _tokens(cfg)
-    loss, grads = jax.jit(jax.value_and_grad(
-        lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0]))(params)
-    want, want_grads = jax.jit(jax.value_and_grad(
-        lambda p: reference.loss(p, tokens, FILED)))(params)
-    assert abs(float(loss) - float(want)) <= 2e-6 * abs(float(want))
+    _, grads, want_grads = checks.against_reference(
+        lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0],
+        lambda p: reference.loss(p, tokens, FILED), params,
+        loss_rtol=2e-6, grad_tol=1e-5, skip=("['bias']",))
     for stack in (grads, want_grads):
-        assert not np.asarray(stack["moe"].pop("bias")).any()
-    for path, err in jax.tree_util.tree_leaves_with_path(
-            _rel(grads, want_grads)):
-        assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+        assert not np.asarray(stack["moe"]["bias"]).any()
 
 
 def test_bf16_with_remat_is_within_the_benchmarks_bounds():
@@ -129,8 +111,8 @@ def test_flash_with_grouped_kv_heads_against_plain_attention(heads,
         return jnp.sum(weight * flash_attention(q, k, v, causal=True,
                                                 interpret=True))
 
-    want, want_grads = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
-    got, grads = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    want, want_grads = jax.jit(jax.value_and_grad(plain, (0, 1, 2)))(q, k, v)
+    got, grads = jax.jit(jax.value_and_grad(flash, (0, 1, 2)))(q, k, v)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for a, b in zip(grads, want_grads):
         assert a.shape == b.shape
@@ -222,16 +204,16 @@ def test_the_model_on_the_kernels_is_the_model_on_the_plain_form(
     from ray_tpu.ops import ssd as ssd_ops
     cfg = dataclasses.replace(KERNEL_TINY, dtype=jnp.float32, remat=True)
     params, tokens = _params(cfg), _tokens(cfg, batch=1, seq=130)
-    loss = jax.jit(jax.value_and_grad(
-        lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0]))
-    want, want_grads = loss(params)
+    def run():
+        return checks.loss_and_grads(
+            lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0],
+            params)
+    want, want_grads = run()
     monkeypatch.setattr(layers.ssd, "ssd",
                         functools.partial(ssd_ops.ssd, interpret=True))
-    got, grads = jax.jit(jax.value_and_grad(
-        lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0]))(params)
+    got, grads = run()
     assert abs(float(got) - float(want)) <= 2e-6 * abs(float(want))
-    for path, err in jax.tree_util.tree_leaves_with_path(
-            _rel(grads, want_grads)):
-        name = jax.tree_util.keystr(path)
-        if not name.endswith("['bias']"):       # no gradient, 0 / 0
-            assert err <= (5e-4 if "A_log" in name else 2e-5), (name, err)
+    # the selection bias: no gradient, 0 / 0
+    checks.assert_close(grads, want_grads, 5e-4, skip=("['bias']",))
+    checks.assert_close(grads, want_grads, 2e-5,
+                        skip=("['bias']", "['A_log']"))

@@ -3,6 +3,7 @@ on the CPU, at the tiny preset: against the plain float32 reference the
 benchmark holds it to (chipbench/references/olmoe.py), against per-token
 oracles written here, and on virtual meshes against one device."""
 import dataclasses
+import functools
 import math
 
 import jax
@@ -23,6 +24,7 @@ from ray_tpu.parallel.train_step import (
     make_train_state,
     make_train_step,
 )
+from tests import test_model_checks as checks
 
 TINY = dataclasses.replace(olmoe.olmoe_tiny(), attention="reference")
 # what the reference reads from the configuration file
@@ -32,22 +34,14 @@ FILED = {"num_attention_heads": TINY.n_head, "rms_norm_eps": 1e-5,
 
 
 def _params(cfg, seed=0):
-    """Fresh parameters with every norm's scale moved off 1, so that a
-    scale applied in the wrong place shows."""
-    params = olmoe.init(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-    return jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape)
-        if a.ndim <= 3 and float(a.reshape(-1)[0]) == 1.0 else a, params)
+    """Fresh parameters with every norm's scale moved off 1."""
+    return checks.moved_off(
+        olmoe.init(jax.random.PRNGKey(seed), cfg), seed + 1,
+        lambda _, a: 0.1 * (a.ndim <= 3 and float(a.reshape(-1)[0]) == 1.0))
 
 
-def _tokens(cfg, batch=2, seq=64, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
-
-
-def _rel(got, want):
-    return jax.tree_util.tree_map(compare.rel_l2, got, want)
+def _tokens(cfg, **kw):
+    return checks.token_ids(cfg.vocab_size, **kw)
 
 
 def test_presets_count_the_published_parameters():
@@ -63,14 +57,10 @@ def test_loss_and_every_gradient_match_the_reference_in_float32():
     them (measured 7e-7), so 1e-5."""
     cfg = dataclasses.replace(TINY, dtype=jnp.float32)
     params, tokens = _params(cfg), _tokens(cfg)
-    loss, grads = jax.value_and_grad(
-        lambda p: olmoe.loss_fn(p, {"tokens": tokens}, cfg)[0])(params)
-    want, want_grads = jax.value_and_grad(
-        lambda p: reference.loss(p, tokens, FILED))(params)
-    assert abs(float(loss) - float(want)) <= 1e-6 * abs(float(want))
-    errors = _rel(grads, want_grads)
-    for path, err in jax.tree_util.tree_leaves_with_path(errors):
-        assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+    checks.against_reference(
+        lambda p: olmoe.loss_fn(p, {"tokens": tokens}, cfg)[0],
+        lambda p: reference.loss(p, tokens, FILED), params,
+        loss_rtol=1e-6, grad_tol=1e-5)
 
 
 def test_bf16_is_within_the_benchmarks_bounds():
@@ -205,20 +195,25 @@ def test_swaps_of_the_last_chosen_expert_under_bf16_and_what_they_cost():
     assert 0.005 <= rate <= 0.03, rate
 
     target = jax.random.normal(jax.random.PRNGKey(7), exact.shape)
-
-    def expert_grad(x, keep):
-        def loss(w_gate):
-            out, _ = L.apply_moe(dict(params, w_gate=w_gate), x, cfg,
-                                 compute_dtype=jnp.float32)
-            return jnp.sum(out * target * keep[None, :, None])
-        return jax.grad(loss)(params["w_gate"])
-
-    everyone = jnp.ones(4096)
-    with_swaps = compare.rel_l2(expert_grad(rounded, everyone),
-                                expert_grad(exact, everyone))
     steady = jnp.asarray(~swapped, jnp.float32)
-    rounding_alone = compare.rel_l2(expert_grad(rounded, steady),
-                                    expert_grad(exact, steady))
+
+    def expert_grads(x):
+        """An expert's gate-matrix gradient of Σ out · target over every
+        token, and over the steady ones: one forward, a backward each. Op
+        by op on purpose: at these row counts the CPU's grouped products are
+        dense by group, and one compiled program holds 7–9 GB of them at
+        once where this holds 3.4 (twice as fast alone, slower in the whole
+        suite: PERF.md §6, PR 55)."""
+        out, back = jax.vjp(lambda w_gate: L.apply_moe(
+            dict(params, w_gate=w_gate), x, cfg,
+            compute_dtype=jnp.float32)[0], params["w_gate"])
+        return [back(target * keep[None, :, None])[0]
+                for keep in (jnp.ones(4096), steady)]
+
+    (every_r, steady_r), (every_e, steady_e) = map(expert_grads,
+                                                   (rounded, exact))
+    with_swaps = compare.rel_l2(every_r, every_e)
+    rounding_alone = compare.rel_l2(steady_r, steady_e)
     print(f"swap rate {rate:.4f}, gradient error {with_swaps:.4f} "
           f"(rounding alone {rounding_alone:.4f})")
     assert rounding_alone <= 0.01 < with_swaps <= compare.GRAD_RTOL
@@ -379,6 +374,15 @@ def test_rms_norm_lean_vjp_is_the_plain_ones():
 
 # ------------------------------------------------------------------ meshes
 
+@functools.cache
+def _on_one_device():
+    """What the three meshes are held to, made once a process."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.float32)
+    params, tokens = _params(cfg), _tokens(cfg, batch=4)
+    return cfg, params, tokens, *checks.loss_and_grads(
+        lambda p: olmoe.loss_fn(p, {"tokens": tokens}, cfg)[0], params)
+
+
 @pytest.mark.parametrize("axes", [{"dp": 2}, {"dp": 1, "ep": 2},
                                   {"dp": 2, "ep": 2}],
                          ids=["dp2", "ep2", "dp2_ep2"])
@@ -386,10 +390,7 @@ def test_model_on_a_mesh_agrees_with_one_device(axes):
     """Batch over `dp`, experts over `ep` (each device computes its
     experts' part of its tokens' outputs, the parts are summed): loss and
     every gradient as on one device, to float32 rounding."""
-    cfg = dataclasses.replace(TINY, dtype=jnp.float32)
-    params, tokens = _params(cfg), _tokens(cfg, batch=4)
-    want, want_grads = jax.value_and_grad(
-        lambda p: olmoe.loss_fn(p, {"tokens": tokens}, cfg)[0])(params)
+    cfg, params, tokens, want, want_grads = _on_one_device()
     n = math.prod(axes.values())
     mesh = create_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
     sharded = sh.tree_shard(params, mesh, olmoe.partition_specs(cfg))
@@ -398,9 +399,7 @@ def test_model_on_a_mesh_agrees_with_one_device(axes):
             lambda p: olmoe.loss_fn(p, {"tokens": tokens}, cfg, mesh)[0]))(
                 sharded)
     assert float(loss) == pytest.approx(float(want), rel=1e-6)
-    for path, err in jax.tree_util.tree_leaves_with_path(
-            _rel(grads, want_grads)):
-        assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+    checks.assert_close(grads, want_grads, 1e-5)
 
 
 def test_trains_through_the_normal_path():

@@ -58,8 +58,10 @@ def test_ring_attention_grad():
     B, S, H, D = 2, 32, 2, 8
     q, kk, v = [jax.random.normal(kq, (B, S, H, D)) for kq in jax.random.split(k, 3)]
     with jax.set_mesh(mesh):
-        g = jax.grad(lambda q: jnp.sum(ring_attention(q, kk, v, mesh) ** 2))(q)
-    gref = jax.grad(lambda q: jnp.sum(reference_attention(q, kk, v) ** 2))(q)
+        g = jax.jit(jax.grad(
+            lambda q: jnp.sum(ring_attention(q, kk, v, mesh) ** 2)))(q)
+    gref = jax.jit(jax.grad(
+        lambda q: jnp.sum(reference_attention(q, kk, v) ** 2)))(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gref), atol=5e-5)
 
 
@@ -190,9 +192,8 @@ def test_gpipe_matches_sequential():
         ref = jnp.tanh(jnp.tanh(x @ Ws[0]) @ Ws[1])
         np.testing.assert_allclose(np.asarray(unmicrobatch(y)), np.asarray(ref), atol=1e-5)
         # gradients flow through the schedule
-        g = jax.grad(lambda s: jnp.sum(gpipe(stage_fn, s, microbatch(x, 4), mesh) ** 2))(
-            st
-        )
+        g = jax.jit(jax.grad(lambda s: jnp.sum(
+            gpipe(stage_fn, s, microbatch(x, 4), mesh) ** 2)))(st)
     assert jax.tree_util.tree_map(lambda a: a.shape, g)["w"] == (2, 8, 8)
 
 

@@ -7,31 +7,26 @@ guide, the layer's new pieces each against a hand-written line, the presets
 and what the model refuses."""
 import dataclasses
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import compare
 from chipbench.accounting import qwen3_next as accounting
 from chipbench.references import qwen3_next as reference
 from ray_tpu.models import layers as L
 from ray_tpu.models import qwen3_next
-from ray_tpu.ops import flash_attention as fa
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 from ray_tpu.parallel.ring_attention import reference_attention
+from tests import test_model_checks as checks
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(ROOT, "tests", "chipbench_tests", "configs",
-                       "qwen3-next-tiny.json")) as f:
-    FILED = json.load(f)
+FILED = checks.filed("qwen3-next-tiny")
 LEAVES = ("head", "w_in", "w_out", "A_log", "dt_bias", "conv_w", "wq",
           "k_norm", "wg", "w_gate", "w_down", "shared_w_gate", "w_sg")
 
 
+@functools.cache
 def _setup(seed=3, **fields):
     cfg = dataclasses.replace(qwen3_next.qwen3_next_tiny(),
                               dtype=jnp.float32, **fields)
@@ -45,11 +40,12 @@ def _setup(seed=3, **fields):
     return cfg, params, tokens
 
 
-@pytest.fixture
-def interpreted(monkeypatch):
-    original = fa.flash_attention
-    monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: original(
-        *a, **dict(kw, interpret=True)))
+@functools.cache
+def _reference():
+    """The reference's half of the comparison: remat is not its business."""
+    _, params, tokens = _setup()
+    return checks.reference_side(lambda p, t: reference.loss(p, t, FILED),
+                                 accounting, params, tokens)
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -59,28 +55,31 @@ def test_loss_and_picked_gradients_against_the_reference(remat):
     backward against the recurrence differentiated by JAX."""
     cfg, params, tokens = _setup(remat=remat)
     assert accounting.ran_sizes(cfg) == accounting.filed_sizes(FILED)
-    out = compare.compare(
-        lambda p, t: qwen3_next.loss_fn(p, {"tokens": t}, cfg)[0],
-        lambda p, t: reference.loss(p, t, FILED), params, tokens,
-        jax.devices()[0], pick=accounting.pick, put=accounting.put)
+    out = checks.compared(
+        lambda p, t: qwen3_next.loss_fn(p, {"tokens": t}, cfg)[0], accounting,
+        params, tokens, _reference())
     assert set(out["errors"]) == {"loss"} | {"grad_" + k for k in LEAVES}
     assert max(out["errors"].values()) < 2e-5, out["errors"]
     assert out["reference_loss"] > 5.0
+
+
+@functools.cache
+def _every_leaf_of_the_reference():
+    _, params, tokens = _setup(seed=5)
+    with jax.default_matmul_precision("highest"):
+        return checks.loss_and_grads(
+            lambda p: reference.loss(p, tokens, FILED), params)[1]
 
 
 @pytest.mark.parametrize("remat", [False, True])
 def test_every_leafs_gradient_against_the_reference(remat):
     cfg, params, tokens = _setup(seed=5, remat=remat)
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: qwen3_next.loss_fn(
-            p, {"tokens": tokens}, cfg)[0]))(params)
-        want = jax.jit(jax.grad(
-            lambda p: reference.loss(p, tokens, FILED)))(params)
-    flat = jax.tree_util.tree_leaves_with_path(got)
-    assert len(flat) == 3 + 4 * 17 + 16
-    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(want)):
-        error = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-        assert error < 5e-5, (jax.tree_util.keystr(path), error)
+        _, got = checks.loss_and_grads(lambda p: qwen3_next.loss_fn(
+            p, {"tokens": tokens}, cfg)[0], params)
+        want = _every_leaf_of_the_reference()
+    assert len(jax.tree_util.tree_leaves(got)) == 3 + 4 * 17 + 16
+    checks.assert_close(got, want, 5e-5)
 
 
 def test_the_sixteen_shares_add_up_to_the_uncut_layer():

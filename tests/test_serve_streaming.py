@@ -40,7 +40,7 @@ def _read_chunks_timed(port, path):
 def serve_instance(ray_start_regular):
     from ray_tpu import serve
 
-    serve.start()
+    serve.start(http_options={"host": "127.0.0.1", "port": 0})
     yield serve
     serve.shutdown()
 

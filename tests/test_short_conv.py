@@ -102,8 +102,9 @@ def test_gradients_against_finite_differences(leaf):
         return jnp.sum(probe * L.apply_short_conv(
             p, inp, compute_dtype=jnp.float32))
 
+    scalar = jax.jit(scalar)
     at = x if leaf == "x" else params[leaf]
-    grad = jax.grad(scalar)(at)
+    grad = jax.jit(jax.grad(scalar))(at)
     direction = jax.random.normal(jax.random.PRNGKey(11), at.shape)
     eps = 1e-2
     numeric = (scalar(at + eps * direction)
@@ -142,7 +143,11 @@ def test_the_mixers_conv_is_the_direct_convolution(monkeypatch):
     params = L.init_mamba(jax.random.PRNGKey(0), 32, cfg)
     params = dict(params, conv_b=params["conv_b"] + 0.1)
     u = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32))
-    got = L.apply_mamba(params, u, cfg, compute_dtype=jnp.float32)
+
+    def mixer():    # a fresh function: what is patched below is traced anew
+        return jax.jit(lambda p, u: L.apply_mamba(
+            p, u, cfg, compute_dtype=jnp.float32))(params, u)
+    got = mixer()
     calls = []
 
     def direct(x, w, b):
@@ -150,7 +155,7 @@ def test_the_mixers_conv_is_the_direct_convolution(monkeypatch):
         return jax.nn.silu(_direct_conv(x, w.astype(jnp.float32)) + b)
 
     monkeypatch.setattr(mamba_stages, "_conv_plain", direct)
-    want = L.apply_mamba(params, u, cfg, compute_dtype=jnp.float32)
+    want = mixer()
     assert calls == [((2, 32, cfg.conv_dim), (4, cfg.conv_dim),
                       (cfg.conv_dim,))]
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
@@ -175,11 +180,11 @@ def test_the_dense_gated_feed_forward(three_pass):
                                 three_pass=three_pass)
     assert rounded.dtype == jnp.float32
     assert compare.rel_l2(rounded, want) < 2e-2
-    grads = jax.grad(lambda p: jnp.sum(L.apply_gated_mlp(
-        p, x, compute_dtype=jnp.float32, three_pass=three_pass) ** 2))(params)
-    want_grads = jax.grad(lambda p: jnp.sum((
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(L.apply_gated_mlp(
+        p, x, compute_dtype=jnp.float32, three_pass=three_pass) ** 2)))(params)
+    want_grads = jax.jit(jax.grad(lambda p: jnp.sum((
         (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"]))
-        @ p["w_down"]) ** 2))(params)
+        @ p["w_down"]) ** 2)))(params)
     for k in params:
         np.testing.assert_allclose(grads[k], want_grads[k], rtol=1e-4,
                                    atol=1e-5)

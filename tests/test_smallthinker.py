@@ -7,26 +7,23 @@ limits. Where the router reads and which layers rotate or have a window is
 test_smallthinker_layout.py's, the program's paths test_smallthinker_mesh.py's,
 the chip's share of the experts test_smallthinker_share.py's."""
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import catalog, compare
+from chipbench import compare
 from chipbench.accounting import smallthinker as accounting
 from chipbench.references import smallthinker as reference
 from ray_tpu.models import smallthinker
+from tests import test_model_checks as checks
 
 # two periods of the layout, as the preset has them, and one
 DEEP = dataclasses.replace(smallthinker.smallthinker_tiny(),
                            attention="reference")
 TINY = dataclasses.replace(DEEP, window_layout=(0, 1, 1, 1),
                            rope_layout=(0, 1, 1, 1))
-with open(os.path.join(catalog.ROOT, "tests", "chipbench_tests", "configs",
-                       "smallthinker-tiny.json")) as f:
-    FILED = json.load(f)
+FILED = checks.filed("smallthinker-tiny")
 SEQ = 64        # longer than the tiny window of 24: the window bites
 
 
@@ -35,24 +32,17 @@ def _params(cfg, seed=0, scale=1.0):
     that a norm applied in the wrong place (ahead of the router) shows;
     `scale` times the blocks' matrices, where a test wants the layers to
     weigh more in the loss than 0.02-sized weights let them."""
-    params = smallthinker.init(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith(("['ln1']", "['ln2']", "['ln_f']")):
-            return a + 0.3 * jax.random.normal(next(keys), a.shape)
-        return a * scale if "['blocks']" in name else a
-    return jax.tree_util.tree_map_with_path(moved, params)
+    norms = ("ln1", "ln2", "ln_f")
+    params = checks.moved_off(
+        smallthinker.init(jax.random.PRNGKey(seed), cfg), seed + 1,
+        lambda key, _: 0.3 * (key in norms))
+    return dict(params, blocks=jax.tree_util.tree_map_with_path(
+        lambda path, a: a if path[-1].key in norms else a * scale,
+        params["blocks"]))
 
 
-def _tokens(cfg, batch=2, seq=SEQ, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
-
-
-def _rel(got, want):
-    return jax.tree_util.tree_map(compare.rel_l2, got, want)
+def _tokens(cfg, seq=SEQ, **kw):
+    return checks.token_ids(cfg.vocab_size, seq=seq, **kw)
 
 
 def test_presets_count_the_published_parameters():
@@ -94,15 +84,10 @@ def test_the_embedding_table_alone_is_drawn_at_embed_std():
 def test_loss_and_every_gradient_match_the_reference_in_float32(remat):
     cfg = dataclasses.replace(TINY, dtype=jnp.float32, remat=remat)
     params, tokens = _params(cfg), _tokens(cfg)
-    (loss, metrics), grads = jax.value_and_grad(
+    (_, metrics), _, _ = checks.against_reference(
         lambda p: smallthinker.loss_fn(p, {"tokens": tokens}, cfg),
-        has_aux=True)(params)
-    want, want_grads = jax.value_and_grad(
-        lambda p: reference.loss(p, tokens, FILED))(params)
-    assert float(loss) == pytest.approx(float(want), rel=2e-6)
-    for path, err in jax.tree_util.tree_leaves_with_path(
-            _rel(grads, want_grads)):
-        assert err <= 2e-5, (jax.tree_util.keystr(path), err)
+        lambda p: reference.loss(p, tokens, FILED), params,
+        loss_rtol=2e-6, grad_tol=2e-5, has_aux=True)
     assert int(metrics["moe_assignments"]) == 2 * SEQ * 3 * 4
     assert 0 < int(metrics["moe_held"]) < int(metrics["moe_assignments"])
 
@@ -112,16 +97,12 @@ def test_bf16_with_remat_is_within_the_benchmarks_bounds():
     on the compared leaves, under `chipbench/compare.py`'s own limits."""
     cfg = dataclasses.replace(DEEP, remat=True)
     params, tokens = _params(cfg), _tokens(cfg)
-    run = compare.loss_and_grads(
+    loss, grads = checks.picked(
         lambda p, t: smallthinker.loss_fn(p, {"tokens": t}, cfg)[0],
-        accounting.pick, accounting.put)
-    ref = compare.loss_and_grads(
-        lambda p, t: reference.loss(p, t, FILED), accounting.pick,
-        accounting.put)
-    loss, grads = run(params, tokens)
-    want, want_grads = ref(params, tokens)
+        accounting, params, tokens)
+    want, want_grads = checks.picked(
+        lambda p, t: reference.loss(p, t, FILED), accounting, params, tokens)
     assert abs(float(loss) - float(want)) / float(want) <= compare.LOSS_RTOL
     assert set(grads) == {"head", "wq_global", "wv_global", "wq_window",
                           "wv_window", "wg", "w_gate", "w_down"}
-    for name, err in _rel(grads, want_grads).items():
-        assert err <= compare.GRAD_RTOL, (name, err)
+    checks.assert_close(grads, want_grads, compare.GRAD_RTOL)

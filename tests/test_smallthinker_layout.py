@@ -6,53 +6,37 @@ with the plain reference is test_smallthinker.py's, the chip's share of the
 experts test_smallthinker_share.py's: three files, each inside the
 conftest's per-file budget when the whole suite loads the machine."""
 import dataclasses
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import catalog, compare
 from chipbench.references import smallthinker as reference
 from ray_tpu.models import layers as L
 from ray_tpu.models import smallthinker
-
-# two periods of the layout, as the preset has them, and one
-DEEP = dataclasses.replace(smallthinker.smallthinker_tiny(),
-                           attention="reference")
-TINY = dataclasses.replace(DEEP, window_layout=(0, 1, 1, 1),
-                           rope_layout=(0, 1, 1, 1))
-with open(os.path.join(catalog.ROOT, "tests", "chipbench_tests", "configs",
-                       "smallthinker-tiny.json")) as f:
-    FILED = json.load(f)
-SEQ = 64        # longer than the tiny window of 24: the window bites
+from tests.test_smallthinker import FILED, SEQ, TINY, _params, _tokens
 
 
-def _params(cfg, seed=0, scale=1.0):
-    """Fresh parameters with every norm's scale moved off its initial 1, so
-    that a norm applied in the wrong place (ahead of the router) shows;
-    `scale` times the blocks' matrices, where a test wants the layers to
-    weigh more in the loss than 0.02-sized weights let them."""
-    params = smallthinker.init(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith(("['ln1']", "['ln2']", "['ln_f']")):
-            return a + 0.3 * jax.random.normal(next(keys), a.shape)
-        return a * scale if "['blocks']" in name else a
-    return jax.tree_util.tree_map_with_path(moved, params)
+def _loss(params, tokens, cfg):
+    return float(jax.jit(lambda p: smallthinker.loss_fn(
+        p, {"tokens": tokens}, cfg)[0])(params))
 
 
-def _tokens(cfg, batch=2, seq=SEQ, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
+def _filed_loss(params, tokens):
+    # a fresh function each time: `reference.layer` as it is patched now
+    return float(jax.jit(lambda p: reference.loss(p, tokens, FILED))(params))
 
 
-def _rel(got, want):
-    return jax.tree_util.tree_map(compare.rel_l2, got, want)
+@functools.cache
+def _weighty():
+    """Parameters whose layers weigh in the loss, tokens, and the filed
+    reference's loss on them: five cases' yardstick, once a process (the
+    layouts are no part of the parameters)."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.float32)
+    params, tokens = _params(cfg, scale=4.0), _tokens(cfg)
+    return params, tokens, _filed_loss(params, tokens)
 
 
 def test_the_router_reads_the_layers_input_as_it_is(monkeypatch):
@@ -107,8 +91,8 @@ def test_the_reference_with_the_router_elsewhere_is_another_model():
     """The same parameters through the reference with its router moved
     behind the norm, or behind attention: a loss the model does not have."""
     cfg = dataclasses.replace(TINY, dtype=jnp.float32)
-    params, tokens = _params(cfg, scale=4.0), _tokens(cfg)
-    loss = float(smallthinker.loss_fn(params, {"tokens": tokens}, cfg)[0])
+    params, tokens, filed = _weighty()
+    loss = _loss(params, tokens, cfg)
     layer = reference.layer
 
     def moved(where):
@@ -125,12 +109,11 @@ def test_the_reference_with_the_router_elsewhere_is_another_model():
                 top_k=config["moe_num_active_primary_experts"],
                 first=config["deployment"]["first_expert"])
         return wrong
-    assert loss == pytest.approx(
-        float(reference.loss(params, tokens, FILED)), rel=2e-6)
+    assert loss == pytest.approx(filed, rel=2e-6)
     for where in ("normed", "post_attention"):
         reference.layer = moved(where)
         try:
-            other = float(reference.loss(params, tokens, FILED))
+            other = _filed_loss(params, tokens)
         finally:
             reference.layer = layer
         assert abs(other - loss) / loss > 2e-5, where
@@ -171,9 +154,8 @@ def test_a_layout_applied_wrongly_is_another_model(wrong):
     }[wrong]
     cfg = dataclasses.replace(TINY, dtype=jnp.float32, rope_layout=rope,
                               window_layout=window)
-    params, tokens = _params(cfg, scale=4.0), _tokens(cfg)
-    loss = float(smallthinker.loss_fn(params, {"tokens": tokens}, cfg)[0])
-    want = float(reference.loss(params, tokens, FILED))
+    params, tokens, want = _weighty()
+    loss = _loss(params, tokens, cfg)
     assert abs(loss - want) / want > 2e-5
 
 
@@ -181,6 +163,5 @@ def test_a_window_as_long_as_the_sequence_is_global_attention():
     cfg = dataclasses.replace(TINY, dtype=jnp.float32, window=SEQ)
     params, tokens = _params(cfg), _tokens(cfg)
     every = dataclasses.replace(cfg, window_layout=(0,) * 4)
-    assert float(smallthinker.loss_fn(params, {"tokens": tokens}, cfg)[0]) \
-        == pytest.approx(float(smallthinker.loss_fn(
-            params, {"tokens": tokens}, every)[0]), rel=1e-6)
+    assert _loss(params, tokens, cfg) == pytest.approx(
+        _loss(params, tokens, every), rel=1e-6)

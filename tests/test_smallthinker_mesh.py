@@ -6,14 +6,11 @@ per-file budget when the whole suite loads the machine: agreement with the
 reference is test_smallthinker.py's, the layouts test_smallthinker_layout.py's,
 the share test_smallthinker_share.py's.)"""
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import catalog, compare
 from chipbench.references import smallthinker as reference
 from ray_tpu.models import smallthinker
 from ray_tpu.ops import flash_attention as fa
@@ -24,42 +21,8 @@ from ray_tpu.parallel.train_step import (
     make_train_state,
     make_train_step,
 )
-
-# two periods of the layout, as the preset has them, and one
-DEEP = dataclasses.replace(smallthinker.smallthinker_tiny(),
-                           attention="reference")
-TINY = dataclasses.replace(DEEP, window_layout=(0, 1, 1, 1),
-                           rope_layout=(0, 1, 1, 1))
-with open(os.path.join(catalog.ROOT, "tests", "chipbench_tests", "configs",
-                       "smallthinker-tiny.json")) as f:
-    FILED = json.load(f)
-SEQ = 64        # longer than the tiny window of 24: the window bites
-
-
-def _params(cfg, seed=0, scale=1.0):
-    """Fresh parameters with every norm's scale moved off its initial 1, so
-    that a norm applied in the wrong place (ahead of the router) shows;
-    `scale` times the blocks' matrices, where a test wants the layers to
-    weigh more in the loss than 0.02-sized weights let them."""
-    params = smallthinker.init(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-
-    def moved(path, a):
-        name = jax.tree_util.keystr(path)
-        if name.endswith(("['ln1']", "['ln2']", "['ln_f']")):
-            return a + 0.3 * jax.random.normal(next(keys), a.shape)
-        return a * scale if "['blocks']" in name else a
-    return jax.tree_util.tree_map_with_path(moved, params)
-
-
-def _tokens(cfg, batch=2, seq=SEQ, seed=1):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq + 1), 0,
-                              cfg.vocab_size)
-
-
-def _rel(got, want):
-    return jax.tree_util.tree_map(compare.rel_l2, got, want)
-
+from tests import test_model_checks as checks
+from tests.test_smallthinker import FILED, TINY, _params, _tokens
 
 @pytest.mark.parametrize("layouts, period", [
     (((0, 1, 1, 1) * 2, (0, 1, 1, 1) * 2), 4),
@@ -73,9 +36,10 @@ def test_the_scan_runs_over_whole_periods_of_the_layout(layouts, period):
     params, tokens = _params(cfg), _tokens(cfg, batch=1)
     filed = dict(FILED, sliding_window_layout=list(layouts[0]),
                  rope_layout=list(layouts[1]))
-    loss = smallthinker.loss_fn(params, {"tokens": tokens}, cfg)[0]
-    assert float(loss) == pytest.approx(
-        float(reference.loss(params, tokens, filed)), rel=2e-6)
+    loss = jax.jit(lambda p: smallthinker.loss_fn(
+        p, {"tokens": tokens}, cfg)[0])(params)
+    assert float(loss) == pytest.approx(float(jax.jit(
+        lambda p: reference.loss(p, tokens, filed))(params)), rel=2e-6)
     with pytest.raises(ValueError, match="one entry a layer each"):
         dataclasses.replace(TINY, rope_layout=(0, 1))
 
@@ -101,13 +65,11 @@ def test_the_model_on_the_kernels_is_the_model_on_the_plain_form(monkeypatch):
     def grad_of(cfg):
         return jax.value_and_grad(
             lambda p: smallthinker.loss_fn(p, {"tokens": tokens}, cfg)[0])
-    loss, grads = grad_of(KERNEL_TINY)(params)
-    want, want_grads = grad_of(dataclasses.replace(
-        KERNEL_TINY, attention="reference"))(params)
+    loss, grads = jax.jit(grad_of(KERNEL_TINY))(params)
+    want, want_grads = jax.jit(grad_of(dataclasses.replace(
+        KERNEL_TINY, attention="reference")))(params)
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
-    for path, err in jax.tree_util.tree_leaves_with_path(
-            _rel(grads, want_grads)):
-        assert err <= 5e-4, (jax.tree_util.keystr(path), err)
+    checks.assert_close(grads, want_grads, 5e-4)
     from tests.test_flash_window import _calls
     names = [name for name, _ in _calls(
         jax.make_jaxpr(grad_of(KERNEL_TINY))(params).jaxpr, [])]
@@ -126,8 +88,8 @@ def test_model_on_a_mesh_agrees_with_one_device(axes):
     import math
     cfg = dataclasses.replace(TINY, dtype=jnp.float32)
     params, tokens = _params(cfg), _tokens(cfg, batch=4, seq=40)
-    want, want_grads = jax.value_and_grad(
-        lambda p: smallthinker.loss_fn(p, {"tokens": tokens}, cfg)[0])(params)
+    want, want_grads = checks.loss_and_grads(
+        lambda p: smallthinker.loss_fn(p, {"tokens": tokens}, cfg)[0], params)
     n = math.prod(axes.values())
     mesh = create_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
     sharded = sh.tree_shard(params, mesh, smallthinker.partition_specs(cfg))
@@ -136,9 +98,7 @@ def test_model_on_a_mesh_agrees_with_one_device(axes):
             lambda p: smallthinker.loss_fn(p, {"tokens": tokens}, cfg,
                                            mesh)[0]))(sharded)
     assert float(loss) == pytest.approx(float(want), rel=1e-6)
-    for path, err in jax.tree_util.tree_leaves_with_path(
-            _rel(grads, want_grads)):
-        assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+    checks.assert_close(grads, want_grads, 1e-5)
 
 
 def test_a_mesh_that_splits_heads_or_sequence_is_refused():

@@ -36,13 +36,6 @@ from tests.test_zz_tp_overlap import _mesh as _mesh_of, _walk
 SEQ = 128
 
 
-@pytest.fixture
-def interpreted(monkeypatch):
-    """`apply_attention(impl="flash")` through the Pallas interpreter."""
-    monkeypatch.setattr(fa, "flash_attention", functools.partial(
-        fa.flash_attention, interpret=True))
-
-
 def _mesh(axes):
     return _mesh_of(axes) if axes else None
 
@@ -542,29 +535,36 @@ SAME_NUMBERS = {
 }
 
 
+@functools.cache
+def _with_the_policy(case):
+    """`case`'s mesh, its compiled step by `remat`, and what the step gives
+    under `L.remat`: once a process, for both comparisons of the case."""
+    axes, make = SAME_NUMBERS[case]
+    module, cfg, params, tokens, kw = make()
+    mesh = _mesh(axes)
+    if mesh is not None:
+        params = jax.tree_util.tree_map(
+            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+            params, module.partition_specs(cfg))
+
+    def run(remat):
+        with _on(mesh):
+            # a fresh function each time: traced under what is patched now
+            return jax.jit(_loss_and_grads(
+                module, dataclasses.replace(cfg, remat=remat), mesh, **kw))(
+                    params, tokens)
+    return run, run(remat=True)
+
+
 @pytest.mark.parametrize("against", ["no_remat", "bare_checkpoint"])
 @pytest.mark.parametrize("case", list(SAME_NUMBERS))
 def test_remat_changes_no_number(interpreted, monkeypatch, case, against):
     """Loss and gradients with `L.remat` against those with remat off, and
     against those of a `jax.checkpoint` that keeps nothing."""
-    axes, make = SAME_NUMBERS[case]
-    module, cfg, params, tokens, kw = make()
-    mesh = _mesh(axes)
-
-    def run(remat):
-        return jax.jit(_loss_and_grads(
-            module, dataclasses.replace(cfg, remat=remat), mesh, **kw))(
-                params, tokens)
-
-    with _on(mesh):
-        if mesh is not None:
-            params = jax.tree_util.tree_map(
-                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-                params, module.partition_specs(cfg))
-        got, got_grads = run(remat=True)
-        if against == "bare_checkpoint":
-            monkeypatch.setattr(L, "remat", jax.checkpoint)
-        want, want_grads = run(remat=against == "bare_checkpoint")
+    run, (got, got_grads) = _with_the_policy(case)
+    if against == "bare_checkpoint":
+        monkeypatch.setattr(L, "remat", jax.checkpoint)
+    want, want_grads = run(remat=against == "bare_checkpoint")
     assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
     errors = jax.tree_util.tree_map(
         lambda g, w: float(jnp.linalg.norm(g - w)

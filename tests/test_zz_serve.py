@@ -371,6 +371,7 @@ def test_router_distribution_admission_and_summary(ray_start_regular):
             return _os.getpid()
 
     try:
+        serve.start(http_options={"host": "127.0.0.1", "port": 0})     # a port of its own
         h = serve.run(Who.bind(), name="zzwho", route_prefix=None)
         pids = {h.remote(i).result(timeout_s=10) for i in range(16)}
         assert len(pids) == 2, f"p2c never reached one replica: {pids}"
@@ -422,6 +423,7 @@ def test_drain_redispatch_no_lost_requests(ray_start_regular):
             return x + 1
 
     try:
+        serve.start(http_options={"host": "127.0.0.1", "port": 0})     # a port of its own
         h = serve.run(Two.bind(), name="zzdrain", route_prefix=None)
         h.remote(0).result(timeout_s=10)   # force router creation
         from ray_tpu.serve.handle import _get_router
@@ -524,6 +526,7 @@ def test_seeded_replica_kill_subsecond_failover():
                 return x * 3
 
         try:
+            serve.start(http_options={"host": "127.0.0.1", "port": 0})     # a port of its own
             h = serve.run(Victim.bind(), name="zzchaos", route_prefix=None)
             results, durations = [], []
             for i in range(12):

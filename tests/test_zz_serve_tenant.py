@@ -486,6 +486,7 @@ def test_serve_tenant_preempts_training_and_returns_capacity_e2e(serve_rt):
                          job="svcE2E-train")
     assert pg.wait(timeout_seconds=15.0), "training gang never placed"
 
+    serve.start(http_options={"host": "127.0.0.1", "port": 0})     # its own
     dep = serve.deployment(_EchoTenant)
     handle = serve.run(dep.bind(), name="echo_app", route_prefix=None,
                        job="svcE2E", job_priority=10, _timeout_s=90.0)
