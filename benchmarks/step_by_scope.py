@@ -45,10 +45,13 @@ PARTS = ("optimizer", "loss_tail", "embed", "router", "dispatch", "experts",
          "combine", "shared_expert", "conv", "gate_norm", "ssd", "mamba",
          "selective_scan", "mamba1", "gmu", "diff_flash", "cross_attn",
          "delta_proj", "delta_conv", "delta_rule", "delta_gate_norm", "gdn",
-         "latent_proj", "attn_gate", "attention", "attn", "mlp", "moe",
+         "latent_proj", "attn_gate", "indexer", "indexer_scores", "select",
+         "sparse_attn", "indexer_loss", "attention", "attn", "mlp", "moe",
          "mtp", "blocks")
-KERNEL = re.compile(r"(flash_(?:window|latent)_(?:fwd|dq|dkv)|"
+KERNEL = re.compile(r"(flash_(?:window|latent|sparse)_(?:fwd|dq|dkv)|"
                     r"flash_(?:fwd|dq|dkv)|"
+                    r"indexer_scores_(?:fwd|dq|dk)|sparse_select|"
+                    r"sparse_mean_probs|indexer_kl_(?:fwd|bwd)|"
                     r"ssd_(?:fwd|bwd)|mamba_(?:conv|gate)_(?:fwd|bwd)|"
                     r"delta_(?:fwd|bwd)|sscan_(?:fwd|bwd)|t?gmm)(?:\.\d+)?$")
 

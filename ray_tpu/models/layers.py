@@ -663,9 +663,8 @@ def _sparse_core(q, k, v, qi, ki, w, *, topk: int, kernel: bool,
         o, lse = sparse_attention.sparse_attention(
             q, k, v, keep, kernel=kernel, interpret=interpret)
     with jax.named_scope("indexer_loss"):
-        probs = sparse_attention.mean_probs(q, k, lse, keep, kernel=kernel,
-                                            interpret=interpret)
-        kl = sparse_attention.indexer_kl(scores, probs, keep)
+        kl = sparse_attention.indexer_loss(q, k, lse, keep, scores,
+                                           kernel=kernel, interpret=interpret)
         kept = jnp.sum(keep, axis=(1, 2), dtype=jnp.int32)
     return o, kl, kept
 
@@ -690,8 +689,8 @@ def apply_sparse_attention(params: Params, x, cfg: SparseConfig, *,
     as a router's; the scores' backward takes `compute_dtype`'s one pass.
 
     impl: "flash" (the flash kernels with the kept set as an operand and
-    the mean-probability kernel) or "reference" (their plain forms: a score
-    a HEAD and pair, for small shapes). The ring paths have no kept set."""
+    the KL's two kernels) or "reference" (their plain forms: a score a HEAD
+    and pair, for small shapes). The ring paths have no kept set."""
     if impl not in ("flash", "reference"):
         raise ValueError(f"sparse attention runs as 'flash' or 'reference', "
                          f"not {impl!r}: a kept set is a whole sequence's")
@@ -762,7 +761,13 @@ def remat(body):
       (`routing_plan`: 4–36 MB a layer where the cells' layers weigh GBs);
     * a sparse attention layer's kept set (`sparse_attention.KEEP_NAME`):
       int8 ``[B, S, S]``, a quarter of ONE float32 score array, whose
-      rebuilding is the selection's 46 passes over the indexer's scores.
+      rebuilding is the selection's 46 passes over the indexer's scores;
+      and the rows its KL's backward kernel reads
+      (`sparse_attention.KL_ROWS_NAME`: each query's log-sum-exp of its kept
+      indexer scores and the sum of its mean attention, float32 ``[B, 2,
+      S]``, 128 KB a layer at 16,384), without which the recompute runs the
+      KL's forward kernel — every head's probabilities over the causal
+      area — a second time for two numbers a row.
 
     Everything else in the block — norms, single-pass q/k/v products, the
     MLP's first product, convs and gates, the scan, the routed experts, the
@@ -776,7 +781,7 @@ def remat(body):
     return jax.checkpoint(
         body, policy=jax.checkpoint_policies.save_only_these_names(
             *RESIDUAL_NAMES, ATTENTION_OUT, THREE_PASS_OUT, ROUTING,
-            sparse_attention.KEEP_NAME))
+            sparse_attention.KEEP_NAME, sparse_attention.KL_ROWS_NAME))
 
 
 # ------------------------------------------------- depthwise causal conv

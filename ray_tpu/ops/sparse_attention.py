@@ -3,7 +3,7 @@ a light indexer scores every causal query-key pair, each query keeps the
 `topk` keys of the highest score, softmax attention runs over the kept keys
 alone, and the indexer learns from the attention it steered.
 
-Five stages, each a function here, each ``[B, …]`` a batch row at a time and
+Four stages, each a function here, each ``[B, …]`` a batch row at a time and
 none of them holding a score a HEAD and pair (``[H, S, S]``) in HBM:
 
 * :func:`index_scores` — ``I[t, s] = Σ_j w[t, j] · ReLU(qI[t, j] · kI[s])``
@@ -23,13 +23,18 @@ none of them holding a score a HEAD and pair (``[H, S, S]``) in HBM:
   `ops.flash_attention` with `keep` as one more operand (a tile of it read
   beside every score tile, the causal tile schedule as it is), their
   plain form elsewhere. Differentiable in q, k, v.
-* :func:`mean_probs` — ``p[t, s] = 1/H · Σ_h A^h[t, s]``, the heads' mean
-  attention over the kept keys, ``[B, S, S]`` float32: on a TPU one Pallas
-  kernel that walks the KV heads innermost and adds the probability tiles of
-  a KV head's query heads, rebuilt from the saved log-sum-exp, into the
-  output block.
-* :func:`indexer_kl` — ``Σ_t KL(p[t, ·] ‖ softmax_kept(I[t, ·]))``, a batch
-  row; a gradient for I alone (``softmax_kept(I) · Σp − p``).
+* :func:`indexer_loss` — ``Σ_t KL(p[t, ·] ‖ softmax_kept(I[t, ·]))``, a
+  batch row, p the heads' mean attention over the kept keys, ``p[t, s] =
+  1/H · Σ_h A^h[t, s]``; a gradient for I alone (``softmax_kept(I) · Σp −
+  p``). On a TPU two kernels that walk the causal tiles with the KV heads
+  innermost and hold a tile of p — the probability tiles of a KV head's
+  query heads, rebuilt from the saved log-sum-exp and added up — in VMEM
+  only: the forward reduces it, beside the tile of I and of `keep`, to each
+  row's KL, the log-sum-exp of its kept I and its Σp (``[B, S]`` each, the
+  last two kept for the backward under `KL_ROWS_NAME`); the backward builds
+  the tile again and writes the gradient, the one ``[B, S, S]`` array the
+  loss writes. Elsewhere the plain forms: :func:`mean_probs`, which DOES
+  write p (``[B, S, S]`` float32), then :func:`indexer_kl`.
 
 Kernel or plain form: `ops.target.where(mesh, interpret=)`, as every op.
 """
@@ -52,7 +57,10 @@ NEG_INF = fa.NEG_INF
 KEEP_NAME = "sparse_keep"
 # queries a block of `index_scores`; its products are [rows, J, keys] float32
 SCORE_ROWS = 512
-# the mean-probability kernel's tile: rows of queries by columns of keys
+# `checkpoint_name` of the rows the KL's backward reads, [B, 2, S] float32:
+# each row's log-sum-exp of its kept scores and the sum of its mean attention
+KL_ROWS_NAME = "indexer_kl_rows"
+# the KL kernels' tile of the heads' mean attention: queries by keys
 PROB_TILE = 512
 # the indexer kernels' tile (queries by keys); 16 heads' blocks a grid step
 SCORE_TILE = 256
@@ -399,6 +407,13 @@ def _plain_select(scores, topk: int):
     return keep.astype(jnp.int8)
 
 
+def _across(per_lane):
+    """[rows, 128] lane-partial sums -> [rows, 128], a row's total in every
+    lane."""
+    return jnp.broadcast_to(jnp.sum(per_lane, axis=1, keepdims=True),
+                            per_lane.shape)
+
+
 def _select_kernel(scores_ref, keep_ref, key_scr, *, topk: int, chunk: int,
                    index_bits: int):
     """`SELECT_ROWS` queries a grid step, their scores [rows, S_pad] in
@@ -433,10 +448,8 @@ def _select_kernel(scores_ref, keep_ref, key_scr, *, topk: int, chunk: int,
                 jnp.int32, (rows, chunk), 1)
             return partial + fa._lane_partial_sum(
                 holds(key_scr[:, chunk_of(c)], index).astype(jnp.int32))
-        partial = jax.lax.fori_loop(0, needed, one,
-                                    jnp.zeros(lanes, jnp.int32))
-        return jnp.broadcast_to(jnp.sum(partial, axis=1, keepdims=True),
-                                lanes)
+        return _across(jax.lax.fori_loop(0, needed, one,
+                                         jnp.zeros(lanes, jnp.int32)))
 
     def wide(v):
         return fa._lanes(v, chunk)
@@ -597,79 +610,14 @@ def sparse_attention(q, k, v, keep, *, scale=None, kernel=None, mesh=None,
 
 
 # ----------------------------------------------- the heads' mean attention
-def _prob_kernel(q_ref, k_ref, lse_ref, scale_ref, keep_ref, out_ref, *,
-                 heads):
-    """One [tile, tile] block of p, the query heads of ONE KV head a grid
-    step (their probability tiles summed in VMEM, then added to the block).
-    The softmax scale is an OPERAND, [1, 1] in SMEM: q, k and the
-    log-sum-exp rows alone are three float operands, the generic reader's
-    flash forward kernel."""
-    i, j, g = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(g == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(j <= i)                # a tile above the diagonal keeps nothing
-    def _add():
-        tile = out_ref.shape[-1]
-        k = k_ref[0]
-        total = jnp.zeros((tile, tile), jnp.float32)
-        for h in range(q_ref.shape[0]):
-            s = fa._dot(q_ref[h], k, fa._NT) * scale_ref[0, 0]
-            lse = fa._lanes(fa._rows_to_cols(lse_ref[h, 0]), tile)
-            total = total + jnp.exp(s - lse)
-        out_ref[0] += jnp.where(fa._kept(keep_ref[0]), total, 0.0) \
-            * (1.0 / heads)
-
-
-def _kernel_probs(q, k, lse, keep, scale, interpret):
-    B, S, H, D = q.shape
-    KV = k.shape[2]
-    group = H // KV
-    tile = min(PROB_TILE, fa._round_up(S, fa._LANES))
-    s_pad = fa._round_up(S, tile)
-    qb, kb = fa._pad_rows((fa._to_bh(q), fa._to_bh(k)), s_pad)
-    lse = jnp.pad(lse, [(0, 0), (0, s_pad - S)])
-    rows = fa._tile_rows(lse, fa.TilePlan(tile, tile, tile, s_pad))
-    n = s_pad // tile
-
-    def low(i, j):      # a tile above the diagonal is not fetched
-        return jnp.minimum(j, i)
-
-    out = pl.pallas_call(
-        functools.partial(_prob_kernel, heads=H),
-        grid=(B, n, n, KV),
-        in_specs=[
-            pl.BlockSpec((group, tile, D),
-                         lambda b, i, j, g: (b * KV + g, i, 0)),
-            pl.BlockSpec((1, tile, D),
-                         lambda b, i, j, g: (b * KV + g, low(i, j), 0)),
-            pl.BlockSpec((group, 1, 8, tile),
-                         lambda b, i, j, g: (b * KV + g, i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, tile, tile),
-                         lambda b, i, j, g: (b, i, low(i, j)))],
-        out_specs=pl.BlockSpec((1, tile, tile), lambda b, i, j, g: (b, i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, s_pad, s_pad), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="sparse_mean_probs",
-    )(qb, kb, rows, jnp.full((1, 1), scale, jnp.float32),
-      fa._pad_keep(keep, s_pad))
-    return out[:, :S, :S]
-
-
-def mean_probs(q, k, lse, keep, *, scale=None, kernel=None, mesh=None,
-               interpret: bool = False):
+def mean_probs(q, k, lse, keep, *, scale=None):
     """The heads' mean attention over the kept keys, [B, S, S] float32:
     ``1/H · Σ_h exp(scale · q_h[t] · k[s] − lse_h[t])`` where kept, 0
-    elsewhere. `lse` as `sparse_attention` gives it. Not differentiable."""
+    elsewhere. `lse` as `sparse_attention` gives it. Not differentiable.
+    The plain form alone (a score a HEAD and pair, for small shapes): the
+    kernels of `indexer_loss` build it a tile at a time and never write it."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
-    if use_kernel(kernel, mesh, interpret=interpret):
-        return _kernel_probs(q, k, lse, keep, scale, interpret)
     s = _plain_scores(q, k, keep, scale)
     B, H = q.shape[0], q.shape[2]
     p = jnp.where(keep[:, None] != 0,
@@ -682,7 +630,7 @@ def mean_probs(q, k, lse, keep, *, scale=None, kernel=None, mesh=None,
 def indexer_kl(scores, probs, keep):
     """``Σ_t Σ_{s kept} p[t, s] · (log p[t, s] − log softmax_kept(I[t,
     ·])[s])`` a batch row, [B] float32: scores, probs [B, S, S] float32,
-    keep int8. A gradient for `scores` alone."""
+    keep int8. A gradient for `scores` alone. The plain form."""
     return _kl_fwd(scores, probs, keep)[0]
 
 
@@ -712,3 +660,239 @@ def _kl_bwd(res, d):
 
 
 indexer_kl.defvjp(_kl_fwd, _kl_bwd)
+
+
+def _group_probs(q_ref, k_ref, lse_ref, scale_ref):
+    """The probability tiles of ONE KV head's query heads, summed: q [group,
+    tile, D], k [1, tile, D], the heads' log-sum-exp rows [group, 1, 8,
+    tile], the softmax scale [1, 1] in SMEM -> [tile, tile] float32."""
+    tile = k_ref.shape[1]
+    k, scale = k_ref[0], scale_ref[0, 0]
+
+    def head(h, total):
+        s = fa._dot(q_ref[h], k, fa._NT) * scale
+        lse = fa._lanes(fa._rows_to_cols(lse_ref[h, 0]), tile)
+        return total + jnp.exp(s - lse)
+    return fa._loop(0, q_ref.shape[0], head,
+                    jnp.zeros((tile, tile), jnp.float32))
+
+
+def _lane_partial_max(x):
+    """`fa._lane_partial_sum`'s maximum: [rows, n·128] -> [rows, 128]."""
+    out = x[:, :fa._LANES]
+    for c in range(1, x.shape[1] // fa._LANES):
+        out = jnp.maximum(out, x[:, c * fa._LANES:(c + 1) * fa._LANES])
+    return out
+
+
+_TINY = float(jnp.finfo(jnp.float32).tiny)
+
+
+def _kl_fwd_kernel(q_ref, k_ref, lse_ref, scale_ref, keep_ref, scores_ref,
+                   out_ref, p_scr, m_scr, l_scr, a_scr, sp_scr, *, heads):
+    """A row of [tile, tile] blocks, key block by key block and the KV heads
+    innermost: a block of p, the heads' mean attention, summed in VMEM over
+    the KV heads and, complete, reduced beside its block of I and of the
+    kept set to a row's running quantities, each 128 lane-partial values a
+    row: the maximum and the sum of ``exp(I − max)`` over the kept keys (a
+    log-sum-exp a LANE, joined at the row's end), ``Σ p · (log p − I)`` and
+    ``Σ p``. Out [1, 1, 3, 8, tile]: a row's KL, the log-sum-exp of its
+    kept I, its Σp, each as 8 identical rows."""
+    i, j, g = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    tile = p_scr.shape[-1]
+
+    @pl.when((j == 0) & (g == 0))
+    def _row():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        for scr in (l_scr, a_scr, sp_scr):
+            scr[...] = jnp.zeros_like(scr)
+
+    @pl.when(j <= i)                # a tile above the diagonal keeps nothing
+    def _add():
+        @pl.when(g == 0)
+        def _tile():
+            p_scr[...] = jnp.zeros_like(p_scr)
+        p_scr[...] += _group_probs(q_ref, k_ref, lse_ref, scale_ref)
+
+        @pl.when(g == pl.num_programs(3) - 1)
+        def _reduce():
+            kept = fa._kept(keep_ref[0])
+            p = jnp.where(kept, p_scr[...], 0.0) * (1.0 / heads)
+            scores = scores_ref[0]
+            sp_scr[...] += fa._lane_partial_sum(p)
+            # a kept pair may hold p = 0: 0 · log(tiny) is the 0 of xlogy
+            a_scr[...] += fa._lane_partial_sum(p * (
+                jnp.log(jnp.maximum(p, _TINY))
+                - jnp.where(kept, scores, 0.0)))
+            masked = jnp.where(kept, scores, NEG_INF)
+            m_old = m_scr[...]
+            m_new = jnp.maximum(m_old, _lane_partial_max(masked))
+            # where a lane has seen no kept key yet, masked − m_new is 0
+            l_scr[...] = l_scr[...] * jnp.exp(m_old - m_new) \
+                + fa._lane_partial_sum(jnp.where(
+                    kept, jnp.exp(masked - fa._lanes(m_new, tile)), 0.0))
+            m_scr[...] = m_new
+
+        @pl.when((j == i) & (g == pl.num_programs(3) - 1))
+        def _finish():
+            m = m_scr[...]
+            top = jnp.broadcast_to(jnp.max(m, axis=1, keepdims=True), m.shape)
+            total = _across(l_scr[...] * jnp.exp(m - top))
+            # a padded row keeps nothing: its Σp is 0 and so is its KL
+            lse = top + jnp.log(jnp.where(total > 0.0, total, 1.0))
+            sp = _across(sp_scr[...])
+            for n, value in enumerate((_across(a_scr[...]) + lse * sp, lse,
+                                       sp)):
+                out_ref[0, 0, n] = fa._cols_to_rows(value)
+
+
+def _kl_bwd_kernel(q_ref, k_ref, lse_ref, scale_ref, keep_ref, scores_ref,
+                   rows_ref, d_ref, out_ref, *, heads):
+    """One [tile, tile] block of the KL's gradient for I: the block of p
+    summed over the KV heads IN the output block, then ``keep · (exp(I −
+    lse_I) · Σp − p) · d`` in its place. rows [1, 1, 2, 8, tile]: lse_I and
+    Σp as the forward kernel wrote them; d [B, 1] in SMEM, a batch row's
+    cotangent."""
+    b, i, j, g = (pl.program_id(n) for n in range(4))
+    tile = out_ref.shape[-1]
+
+    @pl.when(g == 0)        # and what a tile above the diagonal stays
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(j <= i)
+    def _add():
+        out_ref[0] += _group_probs(q_ref, k_ref, lse_ref, scale_ref)
+
+        @pl.when(g == pl.num_programs(3) - 1)
+        def _gradient():
+            kept = fa._kept(keep_ref[0])
+            p = out_ref[0] * (1.0 / heads)
+            lse, sp = (fa._lanes(fa._rows_to_cols(rows_ref[0, 0, r]), tile)
+                       for r in range(2))
+            soft = jnp.exp(scores_ref[0] - lse)
+            out_ref[0] = jnp.where(kept, soft * sp - p, 0.0) * d_ref[b, 0]
+
+
+# a [512, 512] float32 tile of p, of I and of dI, each twice (the pipeline's
+# two buffers), a KV head's 8 q blocks and the body's temporaries: past
+# Mosaic's default 16 MiB
+_KL_VMEM = 48 * 1024 * 1024
+
+
+def _kl_operands(q, k, lse, keep, scores, scale):
+    """What both kernels read, padded to whole tiles -> (operands, their
+    block specs, (B, KV, tile, tiles a side)). A padded query keeps nothing
+    and a padded key is kept by nobody."""
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    group = H // KV
+    tile = min(PROB_TILE, fa._round_up(S, fa._LANES))
+    s_pad = fa._round_up(S, tile)
+    pad = s_pad - S
+    qb, kb = fa._pad_rows((fa._to_bh(q), fa._to_bh(k)), s_pad)
+    rows = fa._tile_rows(jnp.pad(lse, [(0, 0), (0, pad)]),
+                         fa.TilePlan(tile, tile, tile, s_pad))
+    scores = jnp.pad(scores, [(0, 0), (0, pad), (0, pad)])
+
+    def low(i, j):      # a tile above the diagonal is not fetched
+        return jnp.minimum(j, i)
+
+    specs = [
+        pl.BlockSpec((group, tile, D), lambda b, i, j, g: (b * KV + g, i, 0)),
+        pl.BlockSpec((1, tile, D),
+                     lambda b, i, j, g: (b * KV + g, low(i, j), 0)),
+        pl.BlockSpec((group, 1, 8, tile),
+                     lambda b, i, j, g: (b * KV + g, i, 0, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, tile, tile), lambda b, i, j, g: (b, i, low(i, j))),
+        pl.BlockSpec((1, tile, tile), lambda b, i, j, g: (b, i, low(i, j)))]
+    operands = (qb, kb, rows, jnp.full((1, 1), scale, jnp.float32),
+                fa._pad_keep(keep, s_pad), scores)
+    return operands, specs, (B, KV, tile, s_pad // tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kernel_kl(q, k, lse, keep, scores, scale, interpret):
+    return _kernel_kl_fwd(q, k, lse, keep, scores, scale, interpret)[0]
+
+
+def _kernel_kl_fwd(q, k, lse, keep, scores, scale, interpret):
+    S, H = q.shape[1], q.shape[2]
+    operands, specs, (B, KV, tile, n) = _kl_operands(q, k, lse, keep, scores,
+                                                     scale)
+    lanes = pltpu.VMEM((tile, fa._LANES), jnp.float32)
+    out = pl.pallas_call(
+        functools.partial(_kl_fwd_kernel, heads=H),
+        grid=(B, n, n, KV),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((1, 1, 3, 8, tile),
+                               lambda b, i, j, g: (b, i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, n, 3, 8, tile), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((tile, tile), jnp.float32), lanes, lanes,
+                        lanes, lanes],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_KL_VMEM),
+        interpret=interpret,
+        name="indexer_kl_fwd",
+    )(*operands)
+    rows = out[:, :, :, 0].transpose(0, 2, 1, 3).reshape(B, 3, -1)[..., :S]
+    kept_rows = checkpoint_name(rows[:, 1:], KL_ROWS_NAME)
+    return jnp.sum(rows[:, 0], axis=1), (q, k, lse, keep, scores, kept_rows)
+
+
+def _kernel_kl_bwd(scale, interpret, res, d):
+    q, k, lse, keep, scores, rows = res
+    S = q.shape[1]
+    with jax.named_scope("indexer_loss"):  # a backward rule inherits none
+        operands, specs, (B, KV, tile, n) = _kl_operands(
+            q, k, lse, keep, scores, scale)
+        rows = jnp.pad(rows, [(0, 0), (0, 0), (0, n * tile - S)])
+        rows = jnp.broadcast_to(
+            rows.reshape(B, 2, n, 1, tile).transpose(0, 2, 1, 3, 4),
+            (B, n, 2, 8, tile))
+        grad = pl.pallas_call(
+            functools.partial(_kl_bwd_kernel, heads=q.shape[2]),
+            grid=(B, n, n, KV),
+            in_specs=[*specs,
+                      pl.BlockSpec((1, 1, 2, 8, tile),
+                                   lambda b, i, j, g: (b, i, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=pl.BlockSpec((1, tile, tile),
+                                   lambda b, i, j, g: (b, i, j)),
+            out_shape=jax.ShapeDtypeStruct((B, n * tile, n * tile),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
+                vmem_limit_bytes=_KL_VMEM),
+            interpret=interpret,
+            name="indexer_kl_bwd",
+        )(*operands, rows, d.astype(jnp.float32).reshape(B, 1))
+        return None, None, None, None, grad[:, :S, :S]
+
+
+_kernel_kl.defvjp(_kernel_kl_fwd, _kernel_kl_bwd)
+
+
+def indexer_loss(q, k, lse, keep, scores, *, scale=None, kernel=None,
+                 mesh=None, interpret: bool = False):
+    """``Σ_t KL(p[t, ·] ‖ softmax_kept(I[t, ·]))`` a batch row, [B] float32,
+    p the heads' mean attention over the kept keys (`mean_probs`'s, from q,
+    k and the log-sum-exp `sparse_attention` gives) and I the indexer's
+    `scores`. A gradient for `scores` alone: ``keep · (softmax_kept(I) · Σp
+    − p)``.
+
+    On a TPU two kernels that hold every tile of p in VMEM only: the
+    forward reduces it to a row's KL, the log-sum-exp of its kept I and its
+    Σp (the last two `[B, 2, S]` under `KL_ROWS_NAME`: with them kept, a
+    rematerialised layer does not run the forward kernel again), the
+    backward builds it again and writes the gradient. Elsewhere `indexer_kl`
+    of `mean_probs`."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    if use_kernel(kernel, mesh, interpret=interpret):
+        q, k, lse = (jax.lax.stop_gradient(x) for x in (q, k, lse))
+        return _kernel_kl(q, k, lse, keep, scores, scale, interpret)
+    return indexer_kl(scores, mean_probs(q, k, lse, keep, scale=scale), keep)

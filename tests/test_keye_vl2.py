@@ -304,8 +304,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_the_kernel_path_equals_the_reference_path(monkeypatch):
     """`attention` "flash" through the Pallas interpreter — the indexer's
     score kernels, the selection kernel, the flash kernels with the kept
-    set, the mean-probability kernel — against the plain forms: the loss and
-    every gradient; under remat a layer's forward kernel is not run again.
+    set, the KL's two kernels — against the plain forms: the loss and every
+    gradient; under remat neither a layer's flash forward kernel nor its
+    KL's is run again, and the heads' mean attention is no call of its own.
     (The forward scores at the highest precision here: at the module's three
     passes a pair at a row's threshold may change sides.)"""
     monkeypatch.setattr(sa, "SCORE_PASSES", 6)
@@ -328,7 +329,8 @@ def test_the_kernel_path_equals_the_reference_path(monkeypatch):
     for name, calls in (("flash_sparse_fwd", 1), ("flash_sparse_dq", 1),
                         ("flash_sparse_dkv", 1), ("sparse_select", 1),
                         ("indexer_scores_fwd", 2), ("indexer_scores_dq", 1),
-                        ("indexer_scores_dk", 1), ("sparse_mean_probs", 2)):
+                        ("indexer_scores_dk", 1), ("indexer_kl_fwd", 1),
+                        ("indexer_kl_bwd", 1), ("sparse_mean_probs", 0)):
         assert text.count(f"name={name}") == calls * layers, name
 
 

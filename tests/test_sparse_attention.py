@@ -3,7 +3,8 @@
 form, attention over a kept set — the flash kernels with their `keep` operand
 through the Pallas interpreter, forward, dq, dk and dv — against the masked
 plain form at a length that is no multiple of a tile, the heads' mean
-attention, and the indexer's loss with its hand-written gradient."""
+attention, the indexer's loss with its hand-written gradient, and the two
+kernels that fuse the last two (`indexer_loss`) against them."""
 import functools
 
 import jax
@@ -162,19 +163,150 @@ def test_the_kernel_calls_are_named_for_the_trace():
     assert "flash_sparse" not in text and "name=flash_fwd" in text
 
 
-@pytest.mark.parametrize("kernel", [True, False])
-def test_the_heads_mean_attention(kernel):
+def test_the_heads_mean_attention():
     q, k, v, keep, _ = _operands(200, seed=1)
     _, lse = sa.sparse_attention(q, k, v, keep, kernel=False)
-    got = sa.mean_probs(q, k, lse, keep, kernel=kernel, interpret=kernel)
+    got = sa.mean_probs(q, k, lse, keep)
     _, p = _masked_plain(q, k, v, keep)
     np.testing.assert_allclose(got, jnp.mean(p, axis=1), atol=2e-6)
     np.testing.assert_allclose(jnp.sum(got, axis=-1), 1.0, atol=1e-5)
     assert (np.asarray(got)[np.asarray(keep) == 0] == 0).all()
     # nothing flows back through it
     grads = jax.grad(lambda q: jnp.sum(sa.mean_probs(
-        q, k, lse, keep, kernel=False) ** 2))(q)
+        q, k, lse, keep) ** 2))(q)
     assert not np.asarray(grads).any()
+
+
+def _written_out_kl(scores, probs, kept):
+    """The KL a batch row from `log_softmax`, for JAX to differentiate."""
+    logq = jax.nn.log_softmax(jnp.where(kept, scores, -jnp.inf), axis=-1)
+    terms = jnp.where(kept & (probs > 0), probs * (
+        jnp.log(jnp.where(probs > 0, probs, 1.0))
+        - jnp.where(kept, logq, 0.0)), 0.0)
+    return jnp.sum(terms, axis=(1, 2))
+
+
+# S, B, H, KV, topk: 200 is no multiple of the 128-wide tile, 33 lies under
+# one; a KV group of 1, 2 and 4 query heads; rows that keep one key
+_ZEROS = 20     # the row of each case that holds exact zeros among its kept p
+_FUSED = [(200, 2, 4, 2, 24), (33, 1, 4, 4, 7), (33, 2, 2, 2, 1),
+          (130, 2, 4, 1, 40), (256, 1, 4, 2, 200)]
+
+
+@pytest.fixture(scope="module", params=_FUSED,
+                ids=lambda c: "S%d-B%d-H%d-KV%d-top%d" % c)
+def fused(request):
+    """The fused op through the interpreter beside its oracle, `indexer_kl`
+    of `mean_probs`: (operands, the kernel's value and rows, its gradient
+    under a cotangent a row, the oracle's probabilities)."""
+    S, B, H, KV, topk = request.param
+    q, k, v, keep, _ = _operands(S, H=H, KV=KV, topk=topk, B=B, seed=S)
+    if topk > 1:
+        # a query whose heads all look at the first of its kept keys: other
+        # kept pairs of that row hold p = 0 exactly (exp underflows). Its
+        # scores are ~240, so its p is good to ~1e-4 of itself, no better
+        first = jnp.argmax(keep[:, _ZEROS], axis=-1)
+        q = q.at[:, _ZEROS].set(60.0 * jnp.repeat(
+            k[jnp.arange(B), first], H // KV, axis=1))
+    scores = _causal(jax.random.normal(jax.random.PRNGKey(S + 1), (B, S, S)))
+    _, lse = sa.sparse_attention(q, k, v, keep, kernel=False)
+    weights = jnp.arange(1, B + 1, dtype=jnp.float32) * -1.5
+    value, (*_, rows) = sa._kernel_kl_fwd(q, k, lse, keep, scores,
+                                          q.shape[-1] ** -0.5, True)
+    grad = jax.grad(lambda s: jnp.sum(sa.indexer_loss(
+        q, k, lse, keep, s, interpret=True) * weights))(scores)
+    return dict(q=q, k=k, lse=lse, keep=keep, scores=scores, value=value,
+                rows=rows, grad=grad, weights=weights,
+                probs=sa.mean_probs(q, k, lse, keep))
+
+
+def _but_the_row_of_zeros(a, b, atol):
+    """a [B, S, ...] against b at `atol`, the row `_ZEROS` at 1e-3."""
+    a, b = np.broadcast_arrays(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(a, b, atol=1e-3)
+    np.testing.assert_allclose(np.delete(a, _ZEROS, 1),
+                               np.delete(b, _ZEROS, 1), atol=atol)
+
+
+def test_the_fused_loss_is_the_plain_forms_value(fused):
+    f = fused
+    want = sa.indexer_kl(f["scores"], f["probs"], f["keep"])
+    assert f["value"].shape == want.shape == (f["q"].shape[0],)
+    np.testing.assert_allclose(f["value"], want, rtol=2e-5)
+    np.testing.assert_allclose(
+        f["value"], _written_out_kl(f["scores"], f["probs"], f["keep"] != 0),
+        rtol=2e-5)
+    # the public op off the kernel path is the plain form itself
+    plain = sa.indexer_loss(f["q"], f["k"], f["lse"], f["keep"], f["scores"],
+                            kernel=False)
+    np.testing.assert_array_equal(plain, want)
+
+
+def test_the_fused_loss_keeps_each_rows_log_sum_exp_and_sum(fused):
+    f = fused
+    B, S = f["scores"].shape[:2]
+    assert f["rows"].shape == (B, 2, S)
+    want = jax.scipy.special.logsumexp(
+        jnp.where(f["keep"] != 0, f["scores"], -jnp.inf), axis=-1)
+    np.testing.assert_allclose(f["rows"][:, 0], want, atol=1e-5)
+    _but_the_row_of_zeros(f["rows"][:, 1], jnp.sum(f["probs"], axis=-1),
+                          1e-6)
+    _but_the_row_of_zeros(f["rows"][:, 1], 1.0, 1e-5)
+
+
+def test_the_fused_gradient_is_jaxs_of_the_written_out_form(fused):
+    f = fused
+    kept = f["keep"] != 0
+    want = jax.grad(lambda s: jnp.sum(_written_out_kl(s, f["probs"], kept)
+                                      * f["weights"]))(f["scores"])
+    _but_the_row_of_zeros(f["grad"], want, 1e-6)
+    assert np.abs(np.asarray(want)).max() > 0.1 or kept.sum(-1).max() == 1
+    # exactly 0 wherever nothing is kept, the padded rows' tiles included
+    assert not np.asarray(f["grad"])[~np.asarray(kept)].any()
+
+
+def test_the_fused_cases_hold_what_they_are_for(fused):
+    f = fused
+    kept, probs = np.asarray(f["keep"]) != 0, np.asarray(f["probs"])
+    per_row = kept.sum(-1)
+    assert (per_row >= 1).all()
+    if per_row.max() == 1:                  # every row keeps one key: KL of
+        np.testing.assert_allclose(f["value"], 0.0, atol=1e-4)  # two deltas
+        assert not np.asarray(f["grad"]).any()
+    else:                                   # a kept pair with p = 0 exactly
+        assert ((probs == 0) & kept)[:, _ZEROS].all(0).any()
+        assert ((np.asarray(f["grad"]) != 0) & kept)[:, _ZEROS].any()
+        assert np.isfinite(np.asarray(f["value"])).all()
+
+
+def test_nothing_flows_back_to_the_attention_through_the_fused_loss():
+    q, k, v, keep, _ = _operands(128, seed=3)
+    scores = _causal(jax.random.normal(jax.random.PRNGKey(5), (2, 128, 128)))
+    _, lse = sa.sparse_attention(q, k, v, keep, kernel=False)
+    grads = jax.grad(lambda q, k, lse: jnp.sum(sa.indexer_loss(
+        q, k, lse, keep, scores, interpret=True)), argnums=(0, 1, 2))(
+            q, k, lse)
+    assert not any(np.asarray(g).any() for g in grads)
+
+
+def test_under_remat_the_kept_rows_spare_the_forward_kernel():
+    """With `KL_ROWS_NAME` among the saved names the gradient holds the
+    backward kernel once and the forward kernel once (not again in the
+    recompute); without it the forward kernel runs twice."""
+    q, k, v, keep, _ = _operands(128, seed=3)
+    scores = _causal(jax.random.normal(jax.random.PRNGKey(5), (2, 128, 128)))
+    _, lse = sa.sparse_attention(q, k, v, keep, kernel=False)
+
+    def calls(*names):
+        body = jax.checkpoint(
+            lambda s: jnp.sum(sa.indexer_loss(q, k, lse, keep, s * 1.0,
+                                              interpret=True)),
+            policy=jax.checkpoint_policies.save_only_these_names(*names))
+        text = str(jax.make_jaxpr(jax.grad(body))(scores))
+        return (text.count("name=indexer_kl_fwd"),
+                text.count("name=indexer_kl_bwd"))
+    assert calls(sa.KL_ROWS_NAME) == (1, 1)
+    assert calls() == (2, 1)
 
 
 def test_the_indexers_loss_and_its_gradient():
@@ -191,11 +323,7 @@ def test_the_indexers_loss_and_its_gradient():
         jnp.argmax(keep[:, 20], axis=-1), S))
 
     def written_out(scores):
-        logq = jax.nn.log_softmax(jnp.where(kept, scores, -jnp.inf), axis=-1)
-        terms = jnp.where(kept & (probs > 0), probs * (
-            jnp.log(jnp.where(probs > 0, probs, 1.0))
-            - jnp.where(kept, logq, 0.0)), 0.0)
-        return jnp.sum(terms, axis=(1, 2))
+        return _written_out_kl(scores, probs, kept)
     got = sa.indexer_kl(scores, probs, keep)
     assert got.shape == (B,) and (np.asarray(got) > 0).all()
     np.testing.assert_allclose(got, written_out(scores), rtol=1e-5)
@@ -275,8 +403,8 @@ def test_only_the_flash_calls_look_like_flash_calls_to_the_benchmark():
         scores = sa.index_scores(qi, ki, w, interpret=True)
         keep = sa.select(scores, 24, interpret=True)
         o, lse = sa.sparse_attention(q, k, v, keep, interpret=True)
-        probs = sa.mean_probs(q, k, lse, keep, interpret=True)
-        return jnp.sum(o) + jnp.sum(sa.indexer_kl(scores, probs, keep))
+        return jnp.sum(o) + jnp.sum(sa.indexer_loss(q, k, lse, keep, scores,
+                                                    interpret=True))
     jaxpr = jax.make_jaxpr(jax.grad(whole, argnums=(0, 3)))(q, k, v, qi, ki, w)
     floats = {}
 
@@ -293,7 +421,8 @@ def test_only_the_flash_calls_look_like_flash_calls_to_the_benchmark():
     walk(jaxpr.jaxpr)
     assert floats == {
         "indexer_scores_fwd": 4, "sparse_select": 1, "flash_sparse_fwd": 3,
-        "sparse_mean_probs": 4, "flash_sparse_dq": 6, "flash_sparse_dkv": 6,
+        "indexer_kl_fwd": 5, "indexer_kl_bwd": 7, "flash_sparse_dq": 6,
+        "flash_sparse_dkv": 6,
         "indexer_scores_dq": 4, "indexer_scores_dk": 4}
 
 
