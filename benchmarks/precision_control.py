@@ -31,7 +31,12 @@ the one whose name, or whose entry in `_PRESETS`, its own starts with):
                as a model that carries it in the compute dtype would
   bf16_state   (a program that runs `ops.kda`'s rule) the state the rule
                carries from chunk to chunk rounded to bf16 behind every
-               chunk, as a rule that keeps it in the compute dtype would
+               chunk, as a rule that keeps it in the compute dtype would.
+               The rounding patches `kda._chunk`, which the kernels `kda_fwd`
+               / `kda_bwd` never call: the control takes the rule's PLAIN
+               form for its run wherever it runs (and says so, `PLAIN_FORM`),
+               so its `correct` is the rounded carry's and its speed is not
+               the cell's
   one_pass     (a preset with `three_pass`) every product ahead of a router
                in ONE bf16 pass: what the extra passes buy the comparison
   stated       no change: the wrapper alone, which must read as the cell does
@@ -46,6 +51,10 @@ import importlib
 import sys
 
 CONTROLS = ("e4m3", "bf16_stream", "bf16_state", "one_pass", "stated")
+PLAIN_FORM = ("precision_control: bf16_state runs `ops.kda`'s rule in its "
+              "PLAIN form (the kernels kda_fwd / kda_bwd never call "
+              "`kda._chunk`, which the control rounds): `correct` is the "
+              "rounded carry's, the speed is the plain form's")
 # program module -> (its config class, the function that applies one layer
 # to the stream: `(x, layer's leaves, **static)`)
 _PROGRAMS = {
@@ -163,21 +172,24 @@ def _rounded_stream(program, layer_fn: str):
 @contextlib.contextmanager
 def _rounded_state():
     """`ops.kda`'s rule hands every chunk a state rounded to bf16, forward
-    and in its own backward's rebuilding alike."""
+    and in its own backward's rebuilding alike — in its PLAIN form, which
+    the control takes on the chip too: the kernels carry their state in
+    VMEM and never call `kda._chunk`."""
     import jax
 
     from ray_tpu.ops import kda
 
-    chunk = kda._chunk
+    chunk, use_kernel = kda._chunk, kda._use_kernel
 
     def rounded(state, *inputs, **kw):
         after, out = chunk(state, *inputs, **kw)
         return jax.lax.reduce_precision(after, 8, 7), out
-    kda._chunk = rounded
+    kda._chunk, kda._use_kernel = rounded, lambda *shape: False
+    print(PLAIN_FORM, file=sys.stderr, flush=True)
     try:
         yield
     finally:
-        kda._chunk = chunk
+        kda._chunk, kda._use_kernel = chunk, use_kernel
 
 
 def loss_fn(params, batch, cfg, mesh=None):
@@ -207,6 +219,8 @@ def main(argv) -> int:
         print(f"usage: precision_control {'|'.join(CONTROLS)} "
               f"<chipbench.run's arguments>", file=sys.stderr)
         return 2
+    if argv[0] == "bf16_state":
+        print(PLAIN_FORM, flush=True)
     resolve = catalog.resolve_cell
 
     def resolved(manifest, cell, group, *a, **kw):

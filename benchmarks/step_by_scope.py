@@ -54,7 +54,8 @@ KERNEL = re.compile(r"(flash_(?:window|latent|sparse)_(?:fwd|dq|dkv)|"
                     r"indexer_scores_(?:fwd|dq|dk)|sparse_select|"
                     r"sparse_mean_probs|indexer_kl_(?:fwd|bwd)|"
                     r"ssd_(?:fwd|bwd)|mamba_(?:conv|gate)_(?:fwd|bwd)|"
-                    r"delta_(?:fwd|bwd)|sscan_(?:fwd|bwd)|t?gmm)(?:\.\d+)?$")
+                    r"delta_(?:fwd|bwd)|kda_(?:fwd|bwd)|sscan_(?:fwd|bwd)|"
+                    r"t?gmm)(?:\.\d+)?$")
 
 
 def part_of(scopes) -> str:

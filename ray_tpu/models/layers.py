@@ -1267,7 +1267,14 @@ def apply_kda(params: Params, u, cfg: KDAConfig, *,
     `apply_gated_delta` has SiLU; ``·W_out``. The projections, both
     low-rank pairs and the rule's products on the MXU in `compute_dtype`;
     conv, norms, decays and gates in float32. Scopes: `kda_proj`,
-    `kda_conv`, `kda_rule`, `kda_gate_norm`."""
+    `kda_conv`, `kda_rule`, `kda_gate_norm`. mesh: as in
+    `apply_gated_delta` — on one TPU whose tiles divide the shapes
+    (`kda._use_kernel`) the rule is the kernels `kda_fwd` / `kda_bwd`,
+    which read a pair of heads' columns of q, k and v out of the conv's
+    ``[q | k | v]``, and of the decay out of the gate's ``[B, T, H·K]``, IN
+    PLACE: no split, head reshape or chunked copy of them is made; the plain
+    form (the CPU, a mesh that splits the batch, widths off the tiles)
+    splits them itself."""
     B, T, _ = u.shape
     H, K, V = cfg.n_heads, cfg.k_dim, cfg.v_dim
     project = _project(compute_dtype, False)
@@ -1283,8 +1290,10 @@ def apply_kda(params: Params, u, cfg: KDAConfig, *,
         z = low_rank(params["w_g_down"], params["w_g_up"])
         # the gates' own arithmetic with their projections: `kda_rule`
         # holds the rule and nothing else (what its roofline share counts)
-        g = -jnp.exp(params["A_log"].astype(f32))[:, None] * jax.nn.softplus(
-            (f + params["dt_bias"].astype(f32)).reshape(B, T, H, K))
+        # a head's rate on its K columns: g stays [B, T, H·K], as the
+        # rule's kernels read it
+        g = -jnp.repeat(jnp.exp(params["A_log"].astype(f32)), K) \
+            * jax.nn.softplus(f + params["dt_bias"].astype(f32))
         beta = jax.nn.sigmoid(project("btd,dh->bth", u, params["w_beta"],
                                       f32))
     with jax.named_scope("kda_conv"):
@@ -1292,10 +1301,9 @@ def apply_kda(params: Params, u, cfg: KDAConfig, *,
             qkv, params["conv_w"], jnp.zeros((cfg.conv_dim,), f32),
             start=0, mesh=mesh)
     with jax.named_scope("kda_rule"):
-        q, k, v = jnp.split(qkv, [cfg.key_dim, 2 * cfg.key_dim], axis=-1)
-        o = kda.kda(q.reshape(B, T, H, K), k.reshape(B, T, H, K),
-                    v.reshape(B, T, H, V), g, beta, chunk=cfg.chunk,
-                    compute_dtype=compute_dtype, normalize=cfg.l2_eps)
+        o = kda.kda_packed(qkv, g, beta, k_dim=K, chunk=cfg.chunk,
+                           compute_dtype=compute_dtype,
+                           normalize=cfg.l2_eps, mesh=mesh)
     with jax.named_scope("kda_gate_norm"):
         y = rms_norm(o, params["norm"], eps) * jax.nn.sigmoid(
             z.reshape(B, T, H, V))

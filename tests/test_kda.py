@@ -4,7 +4,13 @@ inputs), at chunks of 16 and 64 and a length no chunk divides, with the
 log-decay drawn down to −30 a token and channel (the "no exponent above
 zero" rule: finite, and the recurrence's), a decay constant over a head's
 channels against the scalar rule of `ops.gated_delta`, the backward of its
-own against JAX's derivative of the same walk, and what it refuses."""
+own against JAX's derivative of the same walk, and what it refuses. Each
+in both FORMS: the plain one, and the Pallas kernels `kda_fwd` / `kda_bwd`
+in the interpreter (two heads of 128, blocks of two chunks of 64, the norms
+inside; 150 tokens are padded to two blocks, so the state and its cotangent
+cross a grid step both ways), the latter also at the steepest published
+decay; what decides between the forms; and the kernels' names and scope in
+the layer's jaxpr."""
 import functools
 
 import jax
@@ -15,6 +21,16 @@ from ray_tpu.ops import gated_delta as gd
 from ray_tpu.ops import kda
 
 INPUTS = ("q", "k", "v", "g", "beta")
+# the kernels' tiles: a pair of heads, whole lane tiles
+KERNEL = dict(b=1, H=2, K=128, V=128)
+# the kernel cases' own limit: one interpreted program of ~10 s serves them
+KERNEL_LIMIT = pytest.mark.limit(
+    120, reason="one interpreted kernel program, compiled once a shape")
+
+
+@pytest.fixture(autouse=True)
+def blocks_of_two_chunks(monkeypatch):
+    monkeypatch.setattr(kda, "BLOCK_TOKENS", 128)
 
 
 @pytest.fixture(autouse=True)
@@ -42,10 +58,12 @@ def recurrence(q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1)
 
 
-def _inputs(seed=0, b=2, T=150, H=3, K=16, V=8, decay=1.0, floor=None):
+def _inputs(seed=0, b=2, T=150, H=3, K=16, V=8, decay=1.0, floor=None,
+            steepest=False):
     """`decay`: the scale of the log-decays (1e-3 keeps the state, 20
     forgets it within a token); `floor`: log-decays uniform in [floor, 0]
-    instead, each channel its own."""
+    instead, each channel its own; `steepest`: the published bound, ``A_log
+    = log 16`` on softplus inputs of 3 ± 2."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(ks[0], (b, T, H, K))
     k = jax.random.normal(ks[1], (b, T, H, K))
@@ -54,6 +72,9 @@ def _inputs(seed=0, b=2, T=150, H=3, K=16, V=8, decay=1.0, floor=None):
     g = -jax.nn.softplus(jax.random.normal(ks[3], (b, T, H, K))) * decay
     if floor is not None:
         g = jax.random.uniform(ks[3], (b, T, H, K), minval=floor, maxval=0.0)
+    if steepest:
+        g = -16.0 * jax.nn.softplus(
+            3.0 + 2.0 * jax.random.normal(ks[3], (b, T, H, K)))
     return (q, k, jax.random.normal(ks[2], (b, T, H, V)), g,
             jax.nn.sigmoid(jax.random.normal(ks[4], (b, T, H))))
 
@@ -91,43 +112,96 @@ _recurrence = jax.jit(recurrence)
 DRAWS = {"mild": dict(), "kept": dict(decay=1e-3),
          "forgotten": dict(decay=20.0),
          "down_to_-30": dict(floor=-30.0)}
+EPS = 1e-12
 
 
-@pytest.mark.parametrize("draw", sorted(DRAWS))
-@pytest.mark.parametrize("chunk, T", [(16, 150), (64, 150), (64, 128),
-                                      (16, 7)])
-def test_values_against_the_recurrence(chunk, T, draw):
-    args = _inputs(T=T, **DRAWS[draw])
-    got = _rule(chunk)(*args)
-    want = _recurrence(*args)
+def normed_recurrence(q, k, v, g, beta):
+    """The recurrence on RAW q and k: what `normalize=EPS` computes."""
+    return recurrence(gd._unit(q, EPS) * q.shape[-1] ** -0.5,
+                      gd._unit(k, EPS), v, g, beta)
+
+
+def _raw(args):
+    """Inputs the norms inside the rule undo."""
+    q, k, *rest = args
+    return (3.0 * q, 0.2 * k, *rest)
+
+
+def _kernel_case(draw, **shape):
+    """(raw inputs at the kernels' tiles, `kda`'s keywords): the norms
+    inside, float32 products, the interpreter."""
+    more = dict(steepest=True) if draw == "steepest" else DRAWS[draw]
+    return (_raw(_inputs(**{**KERNEL, **shape, **more})),
+            dict(chunk=64, compute_dtype=jnp.float32, normalize=EPS,
+                 interpret=True))
+
+
+KERNEL_DRAWS = [pytest.param("kernel", 64, 150, draw, marks=KERNEL_LIMIT)
+                for draw in (*sorted(DRAWS), "steepest")]
+
+
+@pytest.mark.parametrize("form, chunk, T, draw", [
+    *(("plain", chunk, T, draw) for chunk, T in [(16, 150), (64, 150),
+                                                 (64, 128), (16, 7)]
+      for draw in sorted(DRAWS)),
+    *KERNEL_DRAWS])
+def test_values_against_the_recurrence(form, chunk, T, draw):
+    if form == "plain":
+        args = _inputs(T=T, **DRAWS[draw])
+        got, want = _rule(chunk)(*args), _recurrence(*args)
+    else:
+        args, kw = _kernel_case(draw, T=T)
+        got, _ = _out_and_weighted(kda.kda, args, **kw)
+        want, _ = _out_and_weighted(normed_recurrence, args)
     assert got.shape == want.shape == args[2].shape
     assert bool(jnp.all(jnp.isfinite(got)))
     assert _rel(got, want) < 1e-5
 
 
-@pytest.mark.parametrize("draw", sorted(DRAWS))
-@pytest.mark.parametrize("chunk", [16, 64])
-def test_every_gradient_against_the_recurrence(chunk, draw):
-    args = _inputs(**DRAWS[draw])
-    _, got = _out_and_weighted(kda.kda, args, chunk=chunk,
-                               compute_dtype=jnp.float32)
-    _, want = _out_and_weighted(recurrence, args)
+@pytest.mark.parametrize("form, chunk, T, draw", [
+    *(("plain", chunk, 150, draw) for chunk in (16, 64)
+      for draw in sorted(DRAWS)), *KERNEL_DRAWS])
+def test_every_gradient_against_the_recurrence(form, chunk, T, draw):
+    """The kernels: 150 tokens in two blocks of 128 (the padding's ``g =
+    0``, ``β = 0`` tokens; the state carried across a grid step forward,
+    its cotangent backward), the norms inside, and at `steepest` no
+    overflow and no `nan` where a token forgets the state whole."""
+    if form == "plain":
+        args = _inputs(**DRAWS[draw])
+        _, got = _out_and_weighted(kda.kda, args, chunk=chunk,
+                                   compute_dtype=jnp.float32)
+        _, want = _out_and_weighted(recurrence, args)
+    else:
+        args, kw = _kernel_case(draw, T=T)
+        _, got = _out_and_weighted(kda.kda, args, **kw)
+        _, want = _out_and_weighted(normed_recurrence, args)
     for name, a, b in zip(INPUTS, got, want):
+        assert a.shape == b.shape
         assert bool(jnp.all(jnp.isfinite(a))), name
         assert _rel(a, b) < 1e-4, (name, _rel(a, b))
 
 
-@pytest.mark.parametrize("chunk", [16, 64])
-def test_the_backward_of_its_own_against_jaxs(chunk):
+@pytest.mark.parametrize("form, chunk", [
+    ("plain", 16), ("plain", 64),
+    pytest.param("kernel", 64, marks=KERNEL_LIMIT)])
+def test_the_backward_of_its_own_against_jaxs(form, chunk):
     """`kda` (custom_vjp: the chunks' start states kept, a chunk's inside
-    rebuilt) against `kda_plain`, the same walk differentiated by JAX."""
-    args = _inputs(seed=3, T=300)
+    rebuilt — by the plain form's `jax.vjp` of a chunk, by `kda_bwd`'s
+    own arithmetic) against `kda_plain`, the same walk differentiated by
+    JAX."""
     kw = dict(chunk=chunk, compute_dtype=jnp.float32)
-    out, got = _out_and_weighted(kda.kda, args, **kw)
+    if form == "plain":
+        args, near = _inputs(seed=3, T=300), (1e-6, 2e-6)
+    else:
+        # the kernels sum a chunk's products in tiles of their own
+        (args, kernel), near = _kernel_case("mild"), (2e-6, 5e-6)
+        kw["normalize"] = kernel["normalize"]
+    out, got = _out_and_weighted(
+        kda.kda, args, **(kw if form == "plain" else kernel))
     plain, want = _out_and_weighted(kda.kda_plain, args, **kw)
-    assert _rel(out, plain) < 1e-6
+    assert _rel(out, plain) < near[0]
     for name, a, b in zip(INPUTS, got, want):
-        assert _rel(a, b) < 2e-6, (name, _rel(a, b))
+        assert _rel(a, b) < near[1], (name, _rel(a, b))
 
 
 @pytest.mark.parametrize("chunk", [16, 64])
@@ -161,6 +235,121 @@ def test_the_norms_inside_the_rule_and_one_pass_in_bf16():
     assert _rel(got, want) < 1e-5
     low = kda.kda(q, k, v, g, beta, chunk=64, compute_dtype=jnp.bfloat16)
     assert 1e-4 < _rel(low, want) < 1e-2
+
+
+@KERNEL_LIMIT
+def test_bf16_products_in_the_kernels_stay_near_the_plain_forms():
+    """One pass in bf16: the kernels round the operands the plain form
+    rounds, so the two stay within a bf16 pass of each other — output and
+    every gradient."""
+    args, kw = _kernel_case("mild")
+    kw = {**kw, "compute_dtype": jnp.bfloat16}
+    out, got = _out_and_weighted(kda.kda, args, **kw)
+    kw.pop("interpret")
+    plain, want = _out_and_weighted(kda.kda, args, **kw)
+    assert _rel(out, plain) < 1e-3
+    for name, a, b in zip(INPUTS, got, want):
+        assert 0 < _rel(a, b) < 2e-2, (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("why, shape, kw", [
+    ("a head of 64 key columns", dict(K=64), {}),
+    ("an odd number of heads", dict(H=3), {}),
+    ("chunk 16: a pair's chunks are not the 128 lanes", {}, dict(chunk=16)),
+    ("chunk 8, which `_BASE` does not divide", {}, dict(chunk=8)),
+    ("a block the chunk does not divide", {}, dict(block=96)),
+    ("a mesh of four devices", {}, dict(devices=4)),
+    ("the CPU", {}, dict(platform="cpu")),
+])
+def test_off_the_tiles_or_off_one_tpu_it_is_the_plain_form(
+        why, shape, kw, monkeypatch):
+    """`_use_kernel`'s table: shapes the kernels' tiles do not divide, a
+    mesh of more than one device and another backend than the TPU trace to
+    the plain form; the cell's widths on one TPU trace to the kernels."""
+    platform, devices = kw.pop("platform", "tpu"), kw.pop("devices", 1)
+    monkeypatch.setattr(kda, "BLOCK_TOKENS", kw.pop("block", 128))
+    monkeypatch.setattr(kda.target, "where",
+                        lambda mesh=None, interpret=False: (platform,
+                                                            devices))
+    args = _inputs(**{**KERNEL, **dict(T=128), **shape})
+    kw = {**dict(chunk=64), **kw}
+    text = str(jax.make_jaxpr(lambda *a: kda.kda(*a, **kw))(*args))
+    assert "pallas_call" not in text, why
+    monkeypatch.setattr(kda.target, "where",
+                        lambda mesh=None, interpret=False: ("tpu", 1))
+    monkeypatch.setattr(kda, "BLOCK_TOKENS", 128)
+    text = str(jax.make_jaxpr(lambda *a: kda.kda(*a, chunk=64))(
+        *_inputs(**KERNEL, T=128)))
+    assert text.count("name=kda_fwd") == 1
+
+
+def test_the_plan_and_the_budget_by_hand(monkeypatch):
+    """A grid step of the backward at the cell's widths, blocks of 256
+    tokens: q, k, g and their cotangents [256, 256], v, o's cotangent and
+    v's [256, 256], β's rows and theirs [4, 8, 128], four chunks' start
+    states [2, 128, 128], float32 and twice; the state's cotangent once."""
+    monkeypatch.setattr(kda, "BLOCK_TOKENS", 256)
+    plan = kda.kda_plan(8192, 32, 128, 128, 64)
+    assert plan["chunks"] == 128 and plan["block_tokens"] == 256
+    assert plan["state_bytes"] == 4 * 128 * 32 * 128 * 128 == 268_435_456
+    assert plan["vmem_bytes"] == 2 * 4 * (
+        9 * 256 * 256 + 2 * 4 * 8 * 128 + 4 * 2 * 128 * 128
+    ) + 4 * 2 * 128 * 128 == 5_963_776
+    assert plan["vmem_bytes"] <= kda.VMEM_BUDGET_BYTES
+    assert kda._use_kernel("tpu", 1, 64, 32, 128, 128)
+    monkeypatch.setattr(kda, "VMEM_BUDGET_BYTES", plan["vmem_bytes"] - 1)
+    assert not kda._use_kernel("tpu", 1, 64, 32, 128, 128)
+
+
+def _pallas_calls(jaxpr, found, outer=""):
+    """(kernel name, the scopes around the call: its own name stack behind
+    those of the calls — a kernel's `jit`, a checkpoint — that hold it)."""
+    for eqn in jaxpr.eqns:
+        scope = f"{outer}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], scope))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, found, scope)
+    return found
+
+
+def test_the_layers_rule_is_the_two_kernels_under_its_scope(runs_on):
+    """On one TPU `apply_kda` at the kernels' widths traces to `kda_fwd`
+    and `kda_bwd` (the names the trace and the ledger's breakdown list),
+    both inside the `kda_rule` scope that `kernels.kda_rule_roofline`
+    reads, and splits neither the conv's output nor the decay for them."""
+    from ray_tpu.models import layers as L
+
+    runs_on("tpu")
+    cfg = L.KDAConfig(n_heads=2, k_dim=128, v_dim=128, gate_rank=16)
+    params = L.init_kda(jax.random.PRNGKey(0), 64, cfg)
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, 128, 64))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(L.apply_kda(
+        p, u, cfg, compute_dtype=jnp.bfloat16))))(params)
+    rule = [(name, scope) for name, scope in _pallas_calls(jaxpr.jaxpr, [])
+            if name.startswith("kda_")]
+    assert sorted(name for name, _ in rule) == ["kda_bwd", "kda_fwd"]
+    assert all("kda_rule" in scope for _, scope in rule), rule
+
+
+def test_the_rounded_carrys_control_takes_the_plain_form(runs_on, capsys):
+    """`benchmarks/precision_control.py`'s `bf16_state` rounds the carry by
+    patching `kda._chunk`, which the kernels never call: inside the control
+    the rule traces to the plain form WITH the rounding even where the
+    kernels would run, and says so; outside it the kernels are back."""
+    from benchmarks import precision_control
+
+    runs_on("tpu")
+    args = _inputs(**KERNEL, T=128)
+
+    def text():
+        return str(jax.make_jaxpr(lambda *a: kda.kda(*a, chunk=64))(*args))
+
+    with precision_control._rounded_state():
+        inside = text()
+    assert "pallas_call" not in inside and "reduce_precision" in inside
+    assert "PLAIN form" in capsys.readouterr().err
+    assert "name=kda_fwd" in text() and "reduce_precision" not in text()
 
 
 def test_a_chunk_the_inverse_cannot_halve_is_refused():
