@@ -1,7 +1,7 @@
 """By step and by routed LAYER (a prediction module's is the last): how many
 assignments reached an expert held here and whether the layer ran on its
 bounded prefix — what decides where a share's bound has to lie
-(`layers._BOUND_FACTORS`). `benchmarks/step_counters.py`'s loop, state and
+(`layers.moe._BOUND_FACTORS`). `benchmarks/step_counters.py`'s loop, state and
 tokens (a benchmark run's of that `--seed`), with `layers.share_metrics`
 wrapped so that the step's metrics carry each layer's count beside the sums;
 any cell whose model holds a share of its experts.
@@ -28,6 +28,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from chipbench import catalog, flops, generate  # noqa: E402
 from ray_tpu.models import layers as L  # noqa: E402
+from ray_tpu.models.layers import ends  # noqa: E402
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh  # noqa: E402
 from ray_tpu.parallel.train_step import (  # noqa: E402
     default_optimizer,
@@ -48,7 +49,8 @@ def by_layer(loss, counts, compact, *, tokens, cfg):
 
 
 def main(cell_name, steps, seeds, overrides):
-    L.share_metrics = by_layer
+    # a model file reads `L.share_metrics`, `share_loss` its own module's
+    L.share_metrics = ends.share_metrics = by_layer
     cell = catalog.resolve_cell(catalog.load_manifest(), cell_name,
                                 "end_to_end")
     traffic = cell["traffic"]

@@ -212,13 +212,13 @@ def init(key, cfg: JoyaiConfig):
         "layers": [_init_layer(k, depth < cfg.n_dense, cfg)
                    for depth, k in enumerate(kl)],
         "ln_f": jnp.ones((d,), dtype),
-        "head": L._init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
+        "head": L.init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
     }
     if cfg.n_mtp:
         params["mtp"] = {
             "enorm": jnp.ones((d,), dtype), "hnorm": jnp.ones((d,), dtype),
             # rows: the token's embedding's d, then the trunk's stream's d
-            "eh_proj": L._init_dense(kp, (2 * d, d), cfg.eh_std, dtype),
+            "eh_proj": L.init_dense(kp, (2 * d, d), cfg.eh_std, dtype),
             "layer": _init_layer(km, False, cfg),
             "ln_f": jnp.ones((d,), dtype)}
     return params
@@ -337,7 +337,7 @@ def loss_fn(params, batch, cfg: JoyaiConfig,
                 [L.rms_norm(L.embed(params["wte"], targets, mesh),
                             mtp["enorm"], eps),
                  L.rms_norm(x, mtp["hnorm"], eps)], axis=-1)
-            y = L._project(cfg.dtype, False)(
+            y = L.project(cfg.dtype, False)(
                 "bse,ed->bsd", joined, mtp["eh_proj"], jnp.float32)
             y, routed = _run_layer(y, mtp["layer"], dense=False, cfg=cfg,
                                    impl=impl, mesh=mesh)

@@ -173,7 +173,7 @@ def init(key, cfg: KeyeVL2Config):
                 * cfg.embed_std).astype(dtype),
         "layers": [_init_layer(k, cfg) for k in kl],
         "ln_f": jnp.ones((d,), dtype),
-        "head": L._init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
+        "head": L.init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
     }
 
 

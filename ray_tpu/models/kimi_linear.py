@@ -218,7 +218,7 @@ def init(key, cfg: KimiLinearConfig):
         "layers": [_init_layer(k, kind, i < cfg.n_dense, cfg)
                    for i, (k, kind) in enumerate(zip(kl, cfg.layer_types))],
         "ln_f": jnp.ones((d,), dtype),
-        "head": L._init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
+        "head": L.init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
     }
 
 

@@ -201,7 +201,7 @@ def init(key, cfg: Phi4FlashConfig):
     ke, *kl = jax.random.split(key, 1 + cfg.n_layer)
     d, dtype = cfg.d_model, cfg.param_dtype
     return {
-        "wte": L._init_dense(ke, (cfg.vocab_size, d), dtype=dtype),
+        "wte": L.init_dense(ke, (cfg.vocab_size, d), dtype=dtype),
         "layers": [_init_layer(k, kind, cfg)
                    for k, kind in zip(kl, cfg.layer_types)],
         "ln_f": jnp.ones((d,), dtype), "ln_f_b": jnp.zeros((d,), dtype),

@@ -215,7 +215,7 @@ def init(key, cfg: Qwen3NextConfig):
         "layers": [_init_layer(k, kind, cfg)
                    for k, kind in zip(kl, cfg.layer_types)],
         "ln_f": jnp.zeros((d,), dtype),
-        "head": L._init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
+        "head": L.init_dense(kh, (cfg.vocab_size, d), dtype=dtype),
     }
 
 

@@ -13,7 +13,7 @@ def einsum(eq, x, w, out_dtype, *, cd, three_pass):
     result is the float32 product to ~2^-16. The backward pass is the
     single product's, as without it — unless a checkpoint has to rebuild
     the forward value first, which costs the three passes again: a layer's
-    products go through `models.layers._project`, which names the result
+    products go through `models.layers.core.project`, which names the result
     so that `layers.remat` keeps it; a caller here keeps nothing."""
     hi_x, hi_w = x.astype(cd), w.astype(cd)
     out = jnp.einsum(eq, hi_x, hi_w, preferred_element_type=out_dtype)

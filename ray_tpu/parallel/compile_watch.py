@@ -261,7 +261,7 @@ def parse_op_name(op_name: str):
     run again inside the backward pass); else ``backward`` under a
     ``transpose(`` wrapper anywhere in the name (so a ``custom_vjp``'s
     backward rule, which JAX calls while it transposes, and the forward
-    that rule differentiates once more, as `layers._one_of`'s does); else
+    that rule differentiates once more, as `layers.moe._one_of`'s does); else
     ``forward``.
 
     A fusion carries ONE ``op_name``, its root's: the table gives a fusion

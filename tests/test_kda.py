@@ -158,9 +158,12 @@ def test_values_against_the_recurrence(form, chunk, T, draw):
     assert _rel(got, want) < 1e-5
 
 
+# the kernel draws FIRST here and last above: the ten cases that meet the one
+# interpreted program (`_program`, compiled once a process) stand in a row,
+# and `--dist load` hands a row of cases to one worker more often than not
 @pytest.mark.parametrize("form, chunk, T, draw", [
-    *(("plain", chunk, 150, draw) for chunk in (16, 64)
-      for draw in sorted(DRAWS)), *KERNEL_DRAWS])
+    *KERNEL_DRAWS, *(("plain", chunk, 150, draw) for chunk in (16, 64)
+                     for draw in sorted(DRAWS))])
 def test_every_gradient_against_the_recurrence(form, chunk, T, draw):
     """The kernels: 150 tokens in two blocks of 128 (the padding's ``g =
     0``, ``β = 0`` tokens; the state carried across a grid step forward,

@@ -9,6 +9,7 @@ the kernels through the interpreter, the 8-bit control, the presets and what
 the model refuses."""
 import dataclasses
 import functools
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -46,16 +47,19 @@ def _setup(seed=3, **fields):
     return cfg, params, tokens
 
 
-@functools.cache
-def _reference():
-    """The reference's half of the comparison: remat is not its business."""
+def _reference(once_a_run):
+    """The reference's half of the comparison, made once a run: remat and
+    the products' dtype are not its business."""
     _, params, tokens = _setup()
-    return checks.reference_side(lambda p, t: reference.loss(p, t, FILED),
-                                 accounting, params, tokens)
+    return checks.once(
+        once_a_run, "keye_vl2_tiny_reference_side",
+        lambda: checks.reference_side(
+            lambda p, t: reference.loss(p, t, FILED), accounting, params,
+            tokens), accounting.pick(params))
 
 
 @pytest.mark.parametrize("remat", [False, True])
-def test_loss_and_picked_gradients_against_the_reference(remat):
+def test_loss_and_picked_gradients_against_the_reference(remat, once_a_run):
     """The loss (cross-entropy AND the indexer's KL) and the gradients the
     benchmark compares, in float32 against
     `chipbench/references/keye_vl2.py`: bisection against `lax.top_k`, the
@@ -64,27 +68,32 @@ def test_loss_and_picked_gradients_against_the_reference(remat):
     assert accounting.ran_sizes(cfg) == accounting.filed_sizes(FILED)
     out = checks.compared(
         lambda p, t: keye_vl2.loss_fn(p, {"tokens": t}, cfg)[0], accounting,
-        params, tokens, _reference())
+        params, tokens, _reference(once_a_run))
     assert set(out["errors"]) == {"loss"} | {"grad_" + k for k in LEAVES}
     assert max(out["errors"].values()) < 2e-5, out["errors"]
     assert out["reference_loss"] > 5.0
 
 
-@functools.cache
-def _every_leaf_of_the_reference():
+def _every_leaf_of_the_reference(once_a_run):
+    """Every leaf's gradient under the reference, once a run: the two
+    cases' yardstick."""
     _, params, tokens = _setup(seed=5)
-    with jax.default_matmul_precision("highest"):
-        return checks.loss_and_grads(
-            lambda p: reference.loss(p, tokens, FILED), params)[1]
+
+    def make():
+        with jax.default_matmul_precision("highest"):
+            return checks.loss_and_grads(
+                lambda p: reference.loss(p, tokens, FILED), params)
+    return checks.once(once_a_run, "keye_vl2_tiny_every_leaf", make,
+                       params)[1]
 
 
 @pytest.mark.parametrize("remat", [False, True])
-def test_every_leafs_gradient_against_the_reference(remat):
+def test_every_leafs_gradient_against_the_reference(remat, once_a_run):
     cfg, params, tokens = _setup(seed=5, remat=remat)
     with jax.default_matmul_precision("highest"):
         (_, metrics), got = checks.loss_and_grads(lambda p: keye_vl2.loss_fn(
             p, {"tokens": tokens}, cfg), params, has_aux=True)
-        want = _every_leaf_of_the_reference()
+    want = _every_leaf_of_the_reference(once_a_run)
     # table, head, last norm; a layer: 2 norms, 6 of attention, 5 of the
     # indexer, 4 of the feed-forward
     assert len(jax.tree_util.tree_leaves(got)) == 3 + 3 * 17
@@ -98,32 +107,36 @@ def test_every_leafs_gradient_against_the_reference(remat):
         float(metrics["lm_loss"]) + float(metrics["indexer_loss"]), rel=1e-6)
 
 
-def test_bf16_products_stay_inside_the_benchmarks_limits():
+@functools.cache
+def _in_bf16():
+    """The model with bf16 operands, compiled as the comparison compiles
+    it: the two cases below run ONE program."""
+    cfg = dataclasses.replace(_setup()[0], dtype=jnp.bfloat16)
+    return checks.picked_program(
+        lambda p, t: keye_vl2.loss_fn(p, {"tokens": t}, cfg)[0], accounting)
+
+
+def test_bf16_products_stay_inside_the_benchmarks_limits(once_a_run):
     """With bf16 operands the compared leaves read inside
     `chipbench/compare.py`'s limits (what a cell's `correct` holds the chip
     to), the indexer's in float32 whatever the compute dtype."""
-    cfg, params, tokens = _setup()
-    cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
-    out = checks.compared(
-        lambda p, t: keye_vl2.loss_fn(p, {"tokens": t}, cfg)[0], accounting,
-        params, tokens, _reference())
+    _, params, tokens = _setup()
+    out = checks.held_to(_in_bf16()(params, tokens), _reference(once_a_run))
     assert out["within"], out["errors"]
     assert max(out["errors"].values()) > 1e-4     # and bf16 is not float32
 
 
-def test_the_comparison_catches_eight_bit_weights():
+def test_the_comparison_catches_eight_bit_weights(once_a_run):
     """Every matmul weight rounded to e4m3, the precision below the stated
-    one: outside the limits on several compared leaves."""
+    one: outside the limits on several compared leaves. The bf16 case's
+    compiled program AT the rounded weights: the control is
+    straight-through, so that is its gradient."""
     from benchmarks import precision_control
 
-    cfg, params, tokens = _setup()
-    cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
-    _, want_grads = _reference()
-
-    def system(leaves):
-        p = precision_control._eight_bit(accounting.put(params, leaves))
-        return keye_vl2.loss_fn(p, {"tokens": tokens}, cfg)[0]
-    grads = jax.jit(jax.grad(system))(accounting.pick(params))
+    _, params, tokens = _setup()
+    _, want_grads = _reference(once_a_run)
+    _, grads = _in_bf16()(jax.jit(precision_control._eight_bit)(params),
+                          tokens)
     eight = {k: compare.rel_l2(grads[k], want_grads[k]) for k in grads}
     over = [k for k, v in eight.items() if v > compare.GRAD_RTOL]
     assert len(over) >= 3, eight
@@ -141,21 +154,21 @@ def test_a_sequence_no_longer_than_topk_is_plain_causal_attention():
     and the kept set is the whole causal triangle."""
     cfg, attn, x = _layer_input(seq=24)
     sparse = cfg.sparse
-    got, (kl, kept) = L.apply_sparse_attention(
-        attn, x, sparse, compute_dtype=jnp.float32)
+    layer = jax.jit(functools.partial(L.apply_sparse_attention, cfg=sparse,
+                                      compute_dtype=jnp.float32))
+    got, (kl, kept) = layer(attn, x)
 
     def qk_fn(q, k):
         return tuple(L.rope(L.rms_norm(t, s, sparse.eps), sparse.rope_theta)
                      for t, s in ((q, attn["q_norm"]), (k, attn["k_norm"])))
-    want = L.apply_attention(attn, x, impl="reference", qk_fn=qk_fn,
-                             compute_dtype=jnp.float32)
+    want = jax.jit(functools.partial(
+        L.apply_attention, impl="reference", qk_fn=qk_fn,
+        compute_dtype=jnp.float32))(attn, x)
     np.testing.assert_allclose(got, want, atol=2e-6)
     assert int(kept) == 2 * 24 * 25 // 2
     assert float(kl) > 0
     # one key more and the last row has to choose
-    longer = L.apply_sparse_attention(
-        attn, jnp.concatenate([x, x[:, :1]], axis=1), sparse,
-        compute_dtype=jnp.float32)[1][1]
+    longer = layer(attn, jnp.concatenate([x, x[:, :1]], axis=1))[1][1]
     assert int(longer) == 2 * (24 * 25 // 2 + 24)
 
 
@@ -176,8 +189,29 @@ def _indexer_scores(attn, x, sparse):
     return jnp.sum(jax.nn.relu(r) * w[..., None], axis=2)
 
 
+@functools.cache
+def _kept_set():
+    """``(attn, x) -> keep``: the layer compiled (once for both cases), the
+    set it hands to `sa.sparse_attention` caught in the trace and returned."""
+    sparse = _setup()[0].sparse
+    plain = sa.sparse_attention
+
+    def layer(attn, x):
+        seen = []
+
+        def catch(q, k, v, keep, **kw):
+            seen.append(keep)
+            return plain(q, k, v, keep, **kw)
+        with mock.patch.object(sa, "sparse_attention", catch):
+            L.apply_sparse_attention(attn, x, sparse,
+                                     compute_dtype=jnp.float32)
+        keep, = seen
+        return keep
+    return jax.jit(layer)
+
+
 @pytest.mark.parametrize("ties", [False, True])
-def test_the_kept_set_is_lax_top_ks(ties, monkeypatch):
+def test_the_kept_set_is_lax_top_ks(ties):
     """The set the layer attends over, caught on its way into the attention,
     against `jax.lax.top_k` of the indexer's scores written out: row t's
     first min(t + 1, 24) indices. With `ties`, the indexer's head weights
@@ -186,16 +220,9 @@ def test_the_kept_set_is_lax_top_ks(ties, monkeypatch):
     if ties:
         attn = dict(attn, indexer=dict(
             attn["indexer"], w_w=jnp.zeros_like(attn["indexer"]["w_w"])))
-    seen = []
-    plain = sa.sparse_attention
-
-    def catch(q, k, v, keep, **kw):
-        seen.append(keep)
-        return plain(q, k, v, keep, **kw)
-    monkeypatch.setattr(sa, "sparse_attention", catch)
-    L.apply_sparse_attention(attn, x, cfg.sparse, compute_dtype=jnp.float32)
-    keep, = seen
-    scores = _indexer_scores(attn, x, cfg.sparse)
+    keep = _kept_set()(attn, x)
+    scores = jax.jit(functools.partial(_indexer_scores, sparse=cfg.sparse))(
+        attn, x)
     below = np.tril(np.ones((SEQ, SEQ), bool))
     scores = jnp.where(below, scores, -jnp.inf)
     _, chosen = jax.lax.top_k(scores, 24)
@@ -223,15 +250,14 @@ def test_three_different_position_streams_against_the_reference():
         jax.random.randint(key, (2, SEQ), 0, 9),
         jnp.broadcast_to(jnp.arange(SEQ) // 5, (2, SEQ))])
     with jax.default_matmul_precision("highest"):
-        checks.against_reference(
+        mixed, _, _ = checks.against_reference(
             lambda p: keye_vl2.loss_fn(
                 p, {"tokens": tokens, "positions": positions}, cfg)[0],
             lambda p: reference.loss(p, tokens, FILED, positions), params,
             loss_rtol=2e-6, grad_tol=5e-5)
         # the streams matter: text positions give another loss
-        text = keye_vl2.loss_fn(params, {"tokens": tokens}, cfg)[0]
-        mixed = keye_vl2.loss_fn(
-            params, {"tokens": tokens, "positions": positions}, cfg)[0]
+        text = jax.jit(lambda p: keye_vl2.loss_fn(
+            p, {"tokens": tokens}, cfg)[0])(params)
     assert abs(float(text) - float(mixed)) > 1e-4
     # by hand: 8 pairs in sections 2 + 3 + 3 at theta 10,000
     x = jax.random.normal(key, (2, SEQ, 3, 16))
@@ -301,6 +327,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert (cfg.moe.stacked, cfg.moe.n_experts, cfg.moe.first) == (4, 16, 0)
 
 
+@functools.cache
+def _on_one_device():
+    """Loss and every gradient on the `reference` path, one device, no
+    remat: the kernel path's yardstick and the dp mesh's."""
+    cfg, params, tokens = _setup()
+    cfg = dataclasses.replace(cfg, attention="reference")
+    return jax.jit(jax.value_and_grad(lambda p: keye_vl2.loss_fn(
+        p, {"tokens": tokens}, cfg)[0]))(params)
+
+
 def test_the_kernel_path_equals_the_reference_path(monkeypatch):
     """`attention` "flash" through the Pallas interpreter — the indexer's
     score kernels, the selection kernel, the flash kernels with the kept
@@ -312,17 +348,14 @@ def test_the_kernel_path_equals_the_reference_path(monkeypatch):
     monkeypatch.setattr(sa, "SCORE_PASSES", 6)
     cfg, params, tokens = _setup()
 
-    def run(attention, remat, interpret):
-        c = dataclasses.replace(cfg, attention=attention, remat=remat)
-        return jax.jit(jax.value_and_grad(lambda p: keye_vl2.loss_fn(
-            p, {"tokens": tokens}, c, None, interpret)[0]))(params)
-    want = run("reference", False, False)
-    got = run("flash", True, True)
+    c = dataclasses.replace(cfg, attention="flash", remat=True)
+    want = _on_one_device()
+    got = jax.jit(jax.value_and_grad(lambda p: keye_vl2.loss_fn(
+        p, {"tokens": tokens}, c, None, True)[0]))(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(got[1]),
                     jax.tree_util.tree_leaves(want[1])):
         np.testing.assert_allclose(a, b, atol=5e-6)
-    c = dataclasses.replace(cfg, attention="flash", remat=True)
     text = str(jax.make_jaxpr(jax.grad(lambda p: keye_vl2.loss_fn(
         p, {"tokens": tokens}, c, None, True)[0]))(params))
     layers = cfg.n_layer
@@ -379,8 +412,7 @@ def test_on_a_dp_mesh_each_device_selects_its_own_rows():
 
     cfg, params, tokens = _setup()
     mesh = create_mesh(MeshConfig(dp=2), devices=jax.devices()[:2])
-    want = jax.jit(jax.value_and_grad(lambda p: keye_vl2.loss_fn(
-        p, {"tokens": tokens}, cfg)[0]))(params)
+    want = _on_one_device()
     got = jax.jit(jax.value_and_grad(lambda p: keye_vl2.loss_fn(
         p, {"tokens": tokens}, cfg, mesh)[0]))(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)

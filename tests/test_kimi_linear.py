@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 
 from chipbench import compare
 from chipbench.accounting import kimi_linear as accounting
@@ -20,6 +21,7 @@ from chipbench.references import kimi_linear as reference
 from ray_tpu.models import joyai, kimi_linear
 from ray_tpu.models import layers as L
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+from ray_tpu.parallel.ring_attention import reference_attention
 from tests import test_model_checks as checks
 from tests.test_zz_tp_overlap import _walk
 
@@ -176,10 +178,11 @@ def test_the_kda_layer_against_the_references_lines():
     assert set(L.KDA_LOGICAL) == set(params)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 64))
     with jax.default_matmul_precision("highest"):
-        got = L.apply_kda(params, x, cfg.kda, compute_dtype=jnp.float32,
-                          eps=FILED["rms_norm_eps"])
-        want = jax.vmap(lambda m: reference.kda_layer(m, params,
-                                                      config=FILED))(x)
+        got = jax.jit(functools.partial(
+            L.apply_kda, cfg=cfg.kda, compute_dtype=jnp.float32,
+            eps=FILED["rms_norm_eps"]))(params, x)
+        want = jax.jit(jax.vmap(
+            lambda m: reference.kda_layer(m, params, config=FILED)))(x)
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
@@ -196,11 +199,13 @@ def test_latent_attention_without_rank_and_rotation():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 64))
     kw = dict(eps=FILED["rms_norm_eps"], compute_dtype=jnp.float32)
     with jax.default_matmul_precision("highest"):
-        got = L.apply_latent_attention(params, x, cfg, **kw)
-        want = jax.vmap(lambda m: reference.attention(m, params,
-                                                      config=FILED))(x)
-        turned = L.apply_latent_attention(
-            params, x, dataclasses.replace(cfg, rotate=True), **kw)
+        got = jax.jit(functools.partial(
+            L.apply_latent_attention, cfg=cfg, **kw))(params, x)
+        want = jax.jit(jax.vmap(
+            lambda m: reference.attention(m, params, config=FILED)))(x)
+        turned = jax.jit(functools.partial(
+            L.apply_latent_attention,
+            cfg=dataclasses.replace(cfg, rotate=True), **kw))(params, x)
     np.testing.assert_allclose(got, want, atol=2e-6)
     assert float(jnp.abs(turned - got).max()) > 1e-4
 
@@ -209,7 +214,7 @@ def _parents_latent_attention(params, x, cfg, *, eps, impl, compute_dtype):
     """`apply_latent_attention`'s reference path as the parent commit had
     it, before `q_rank` could be None and `rotate` False."""
     cd, nope = compute_dtype, cfg.nope_dim
-    project = L._project(cd, False)
+    project = L.project(cd, False)
     turn = functools.partial(L.rope, theta=cfg.rope_theta,
                              interleaved=cfg.rope_interleaved)
     with jax.named_scope("latent_proj"):
@@ -225,9 +230,9 @@ def _parents_latent_attention(params, x, cfg, *, eps, impl, compute_dtype):
         k_nope, v = kv[..., :nope], kv[..., nope:]
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
             k_pe[:, :, None], (*k_nope.shape[:3], cfg.rope_dim))], axis=-1)
-    o = L.reference_attention(q, k, v, causal=True)
+    o = reference_attention(q, k, v, causal=True)
     out = project("bshk,hkd->bsd", o.astype(cd), params["wo"], x.dtype)
-    return L.checkpoint_name(out, L.ATTENTION_OUT)
+    return checkpoint_name(out, L.ATTENTION_OUT)
 
 
 def test_joyais_latent_call_traces_to_the_program_it_was():

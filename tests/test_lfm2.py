@@ -259,11 +259,18 @@ def test_a_bias_changes_the_choice_and_not_the_gate(norm):
     logits = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 16))
     scores = jax.nn.sigmoid(logits)
     none = jnp.zeros((16,))
-    gates0, chosen0, _ = L._route(logits, none, cfg)
+
+    def route(bias):
+        # `moe_route` on logits handed in whole: x · I at HIGHEST is x
+        return L.moe_route({"wg": jnp.eye(16), "bias": bias}, logits, cfg)
+    np.testing.assert_array_equal(
+        jnp.einsum("bsd,de->bse", logits, jnp.eye(16),
+                   precision=jax.lax.Precision.HIGHEST), logits)
+    gates0, chosen0, _ = route(none)
     # a bias that lifts the four weakest experts over every other
     weakest = jnp.argsort(jnp.mean(scores, axis=(0, 1)))[:4]
     bias = none.at[weakest].set(2.0)
-    gates, chosen, _ = L._route(logits, bias, cfg)
+    gates, chosen, _ = route(bias)
     assert not np.array_equal(np.sort(chosen0, -1), np.sort(chosen, -1))
     assert set(np.unique(chosen)) == set(np.asarray(weakest))
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -273,7 +280,7 @@ def test_a_bias_changes_the_choice_and_not_the_gate(norm):
     if norm:
         published = picked / (jnp.sum(picked, -1, keepdims=True) + 1e-6)
         assert compare.rel_l2(gates, published) < 2e-6
-    grad = jax.grad(lambda b: jnp.sum(L._route(logits, b, cfg)[0] ** 2))(bias)
+    grad = jax.grad(lambda b: jnp.sum(route(b)[0] ** 2))(bias)
     assert not grad.any()
     # the model's own routed layer: its config and its bias leaf
     moe = TINY.moe

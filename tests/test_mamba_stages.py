@@ -19,7 +19,7 @@ from chipbench import catalog, compare, flops
 from chipbench.readers import trace_held, trace_ssm
 from ray_tpu.models import layers, nemotron_h
 from ray_tpu.ops import mamba_stages as stages
-from ray_tpu.ops import target
+from ray_tpu.ops import ssd, target
 from tests.test_ssd_kernels import _event_text
 from tests.test_zz_tp_overlap import _walk
 
@@ -233,14 +233,14 @@ def _apply_mamba_parent(params, u, cfg, *, compute_dtype, eps, three_pass):
     written out in it."""
     B, T, _ = u.shape
     H, P, G, N = cfg.n_heads, cfg.head_dim, cfg.n_groups, cfg.d_state
-    project = layers._project(compute_dtype, three_pass)
+    project = layers.project(compute_dtype, three_pass)
     zxbcdt = project("btd,de->bte", u, params["w_in"], jnp.float32)
     z, xbc, dt = jnp.split(zxbcdt, [cfg.inner, cfg.inner + cfg.conv_dim],
                            axis=-1)
     xbc = jax.nn.silu(layers.causal_taps(xbc, params["conv_w"])
                       + params["conv_b"].astype(jnp.float32))
     x, b_in, c_out = jnp.split(xbc, [cfg.inner, cfg.inner + G * N], axis=-1)
-    y = layers.ssd.ssd(
+    y = ssd.ssd(
         x.reshape(B, T, H, P),
         jax.nn.softplus(dt + params["dt_bias"].astype(jnp.float32)),
         -jnp.exp(params["A_log"].astype(jnp.float32)),
@@ -280,9 +280,9 @@ def test_apply_mamba_is_the_parents_on_the_tiny_preset(dtype, three_pass):
 
 
 def _interpreted(monkeypatch):
-    monkeypatch.setattr(layers.mamba_stages, "conv_silu", functools.partial(
+    monkeypatch.setattr(stages, "conv_silu", functools.partial(
         stages.conv_silu, interpret=True))
-    monkeypatch.setattr(layers.mamba_stages, "gate_norm", functools.partial(
+    monkeypatch.setattr(stages, "gate_norm", functools.partial(
         stages.gate_norm, interpret=True))
 
 

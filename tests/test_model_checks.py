@@ -57,12 +57,17 @@ def loss_and_grads(loss_fn, params, **kw):
     return jax.jit(jax.value_and_grad(loss_fn, **kw))(params)
 
 
+def picked_program(loss_fn, accounting):
+    """``(params, tokens) -> (loss, gradients of the leaves `accounting.pick`
+    names)``, compiled as `compare.compare` compiles its system side: for a
+    file to keep where two of its cases run ONE program."""
+    return jax.jit(compare.loss_and_grads(loss_fn, accounting.pick,
+                                          accounting.put))
+
+
 def picked(loss_fn, accounting, params, tokens):
-    """`loss_fn(params, tokens)` and its gradients for the leaves
-    `accounting.pick` names, compiled as `compare.compare` compiles its
-    system side."""
-    return jax.jit(compare.loss_and_grads(
-        loss_fn, accounting.pick, accounting.put))(params, tokens)
+    """`picked_program`, made and run once."""
+    return picked_program(loss_fn, accounting)(params, tokens)
 
 
 def assert_close(got, want, tol, skip=()):
@@ -128,8 +133,12 @@ def once(once_a_run, name, make, like):
 
 def compared(system_fn, accounting, params, tokens, reference):
     """`compare.compare`'s result from a `reference_side` made before."""
-    loss, grads = picked(system_fn, accounting, params, tokens)
-    ref_loss, ref_grads = reference
+    return held_to(picked(system_fn, accounting, params, tokens), reference)
+
+
+def held_to(system, reference):
+    """`compared` from both sides' (loss, picked gradients)."""
+    (loss, grads), (ref_loss, ref_grads) = system, reference
     errors = {"loss": abs(float(loss) - ref_loss) / abs(ref_loss)}
     for name in ref_grads:
         errors[f"grad_{name}"] = compare.rel_l2(grads[name], ref_grads[name])

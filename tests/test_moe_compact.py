@@ -1,8 +1,13 @@
-"""A share that compacts its rows (`layers._local_experts`, PR 37): where
+"""A share that compacts its rows (`layers.moe._local_experts`, PR 37): where
 the leaves hold fewer experts than the router scores, the layer works on a
 bounded prefix of the sorted assignments — the least of its bounds that
 holds the held experts' rows — and on all of them where none does: the
-same result either way."""
+same result either way.
+
+This is the `moe` seam's own test: it imports `ray_tpu.models.layers.moe`
+by path, because what it patches (`_BOUND_FACTORS`, `assignment_bounds`,
+`checkpoint_name`) are globals that `moe.py`'s own code reads — set on the
+package they would reach nothing."""
 import dataclasses
 import hashlib
 import re
@@ -14,6 +19,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import layers as L
+from ray_tpu.models.layers import moe
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.parallel import sharding as sh
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
@@ -28,7 +34,7 @@ BOUNDS = (256, 512)
 
 @pytest.fixture(autouse=True)
 def two_rungs(monkeypatch):
-    monkeypatch.setattr(L, "_BOUND_FACTORS", (2, 4))
+    monkeypatch.setattr(moe, "_BOUND_FACTORS", (2, 4))
 FORMS = {"relu2": dict(activation="relu2"), "relu_gated": dict(gate="relu"),
          "silu_gated": dict(gate="silu")}
 
@@ -81,7 +87,7 @@ def _value_and_grads(params, x, cfg, idx, mesh=None):
 
 def _whole(*args, **kwargs):
     """The same call with the bound switched off: the parent's path."""
-    with mock.patch.object(L, "assignment_bounds", lambda *a: ()):
+    with mock.patch.object(moe, "assignment_bounds", lambda *a: ()):
         return _value_and_grads(*args, **kwargs)
 
 
@@ -147,7 +153,7 @@ def test_the_bounded_path_with_the_layers_own_router(form):
             f, argnums=(0, 1), has_aux=True))(params, x)
         return out, stats, grads
     got = run()
-    with mock.patch.object(L, "assignment_bounds", lambda *a: ()):
+    with mock.patch.object(moe, "assignment_bounds", lambda *a: ()):
         want = run()
     held = int(jnp.sum(got[1]["counts"][:2]))
     assert 0 < held <= BOUNDS[0] and float(got[1]["compact"]) == 1
@@ -193,17 +199,17 @@ def test_the_plans_bounds_are_the_layers_and_multiples_of_the_row_tile(
         assert rows % grouped_matmul.row_tile(T * K) == 0
         assert f"f32[{rows},{F}]" in text
     monkeypatch.undo()              # the cells' bounds, as the layer has them
-    for tokens, d, f, moe, rows, bound in (
+    for tokens, d, f, share, rows, bound in (
             (8192, 2688, 1856, L.MoEConfig(n_experts=128, top_k=6, held=8),
              49_152, 6_144),
             (16_384, 2560, 768, L.MoEConfig(n_experts=64, top_k=6, held=16),
              98_304, 49_152)):
-        plan = L.moe_plan(tokens, d, f, moe, gated=False)
+        plan = L.moe_plan(tokens, d, f, share, gated=False)
         assert (plan["rows"], plan["bounds"]) == (rows, (bound,))
         assert bound % grouped_matmul.row_tile(rows) == 0
         assert grouped_matmul.tile_plan(bound, d, -(-f // 128) * 128,
                                         jnp.bfloat16) is not None
-    monkeypatch.setattr(L, "_BOUND_FACTORS", (2, 4))
+    monkeypatch.setattr(moe, "_BOUND_FACTORS", (2, 4))
     # every expert held, or shared out over `ep` by halves: no bound
     whole = dataclasses.replace(cfg, held=None)
     assert L.moe_plan(T, D, F, whole, gated=False)["bounds"] == ()
@@ -237,7 +243,7 @@ PARENT_JAXPR = {"relu2": "0b5757f09468b930", "silu_gated": "9f386453f66f486b"}
 
 
 def _digest(form, monkeypatch):
-    monkeypatch.setattr(L, "checkpoint_name", lambda x, name: x)
+    monkeypatch.setattr(moe, "checkpoint_name", lambda x, name: x)
     cfg = dataclasses.replace(_config(form), held=None, first=0)
     params = _params(cfg, form)
     x = jnp.zeros((1, T, D))

@@ -200,7 +200,6 @@ def test_the_model_on_the_kernels_is_the_model_on_the_plain_form(
     of differences: 5e-4, as in test_ssd_kernels.py)."""
     import functools
 
-    from ray_tpu.models import layers
     from ray_tpu.ops import ssd as ssd_ops
     cfg = dataclasses.replace(KERNEL_TINY, dtype=jnp.float32, remat=True)
     params, tokens = _params(cfg), _tokens(cfg, batch=1, seq=130)
@@ -209,7 +208,7 @@ def test_the_model_on_the_kernels_is_the_model_on_the_plain_form(
             lambda p: nemotron_h.loss_fn(p, {"tokens": tokens}, cfg)[0],
             params)
     want, want_grads = run()
-    monkeypatch.setattr(layers.ssd, "ssd",
+    monkeypatch.setattr(ssd_ops, "ssd",
                         functools.partial(ssd_ops.ssd, interpret=True))
     got, grads = run()
     assert abs(float(got) - float(want)) <= 2e-6 * abs(float(want))

@@ -94,15 +94,18 @@ def test_a_share_on_the_pallas_kernels_at_a_padded_width(monkeypatch,
                "w2": 0.1 * jax.random.normal(ks[2], (2, 160, 128))}
     gate_idx = jax.random.randint(ks[3], (2, 64, 2), 0, 8)
     gate_vals = jax.random.uniform(ks[4], (2, 64, 2))
+    share = L.MoEConfig(n_experts=8, top_k=2, held=2, first=2,
+                        activation="relu2")
+
     def part(platform):
         runs_on(platform)
         monkeypatch.setattr(grouped_matmul, "grouped_matmul",
                             interpreted if platform == "tpu" else kernel)
 
         def fn(x, gate_vals, experts):
-            return L._local_experts(
-                x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, activation="relu2")[0]
+            return L.apply_moe(
+                experts, x, share, compute_dtype=jnp.float32,
+                routing=(gate_vals, gate_idx, {}))[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 
@@ -152,15 +155,17 @@ def test_a_share_on_xlas_grouped_product_masks_the_rows_of_no_group(
                "w2": 0.1 * jax.random.normal(ks[2], (2, 160, 128))}
     gate_idx = jax.random.randint(ks[3], (2, 50, 2), 0, 8)
     gate_vals = jax.random.uniform(ks[4], (2, 50, 2))
+    share = L.MoEConfig(n_experts=8, top_k=2, held=2, first=2,
+                        activation="relu2")
     # no tile divides 100 rows: XLA's product, on a TPU too
     runs_on("tpu")
     assert grouped_matmul.kernel_width(100, 128, 160, jnp.float32) is None
 
     def part():
         def fn(x, gate_vals, experts):
-            return L._local_experts(
-                x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, activation="relu2")[0]
+            return L.apply_moe(
+                experts, x, share, compute_dtype=jnp.float32,
+                routing=(gate_vals, gate_idx, {}))[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 

@@ -303,10 +303,11 @@ def test_local_experts_on_the_pallas_kernels_against_the_oracle(
     runs_on("tpu")
     assert grouped_matmul.kernel_width(256, 128, 128, jnp.float32) == 128
     parts = [
-        L._local_experts(
-            x, gate_vals, gate_idx,
-            {k: v[first:first + 4] for k, v in experts.items()},
-            n_experts=8, first=first, cd=jnp.float32)[0]
+        L.apply_moe(
+            {k: v[first:first + 4] for k, v in experts.items()}, x,
+            dataclasses.replace(cfg, held=4, first=first),
+            compute_dtype=jnp.float32,
+            routing=(gate_vals, gate_idx, {}))[0]
         for first in (0, 4)]
     # every product of both devices went through the kernel, with all
     # eight groups' sizes and four matrices

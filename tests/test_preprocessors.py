@@ -7,6 +7,16 @@ distributed dataset, transform applied to datasets and raw batches).
 import numpy as np
 import pytest
 
+# Every case fits or transforms four blocks on `ray_start_regular`'s four
+# CPUs: ~2 s alone. A fit on a transformed dataset can deadlock the node's
+# leases (ROADMAP D15: the downstream tasks hold all four CPUs while they
+# wait for the upstream stage's results; the runtime's to repair — seen in
+# `test_chain_fits_on_prior_output` and `test_concatenator_and_batch_mapper`),
+# so a recurrence costs a worker 60 s, not the default 300.
+pytestmark = pytest.mark.limit(
+    60, reason="four blocks on four CPUs, ~2 s alone; a hang is D15's "
+               "lease deadlock")
+
 
 def _toy(ray, n=100, parallelism=4):
     from ray_tpu import data

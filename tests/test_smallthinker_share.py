@@ -115,9 +115,11 @@ def test_a_share_of_gated_experts_on_the_pallas_kernels(gate, monkeypatch,
                             interpreted if platform == "tpu" else kernel)
 
         def fn(x, gate_vals, experts):
-            return L._local_experts(
-                x, gate_vals, gate_idx, experts, n_experts=8, first=2,
-                cd=jnp.float32, gate=gate)[0]
+            return L.apply_moe(
+                experts, x,
+                L.MoEConfig(n_experts=8, top_k=2, held=2, first=2, gate=gate),
+                compute_dtype=jnp.float32,
+                routing=(gate_vals, gate_idx, {}))[0]
         out, vjp = jax.vjp(fn, x, gate_vals, experts)
         return out, vjp(jnp.ones_like(out))
 

@@ -16,6 +16,7 @@ from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops import sparse_attention as sa
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _top_k_mask(scores, topk):
     """The kept set as `jax.lax.top_k` gives it: row t's first
     ``min(t + 1, topk)`` indices."""
@@ -84,19 +85,21 @@ def test_the_indexers_scores_against_the_explicit_form():
         r = jnp.einsum("btje,bse->btjs", qi, ki)
         return jnp.where(below, jnp.sum(jax.nn.relu(r) * w[..., None], 2),
                          sa.NEG_INF)
-    got = sa.index_scores(qi, ki, w, rows=16)
+    blocks = functools.partial(sa.index_scores, rows=16)
+    got = jax.jit(blocks)(qi, ki, w)
     np.testing.assert_allclose(got, plain(qi, ki, w), atol=1e-5)
     assert (np.asarray(got)[:, ~np.asarray(below)] == sa.NEG_INF).all()
 
     def loss(fn):
         return lambda *a: jnp.sum(jnp.where(below, fn(*a) * d, 0.0))
-    want = jax.grad(loss(plain), argnums=(0, 1, 2))(qi, ki, w)
-    grads = jax.grad(loss(functools.partial(sa.index_scores, rows=16)),
-                     argnums=(0, 1, 2))(qi, ki, w)
+    want = jax.jit(jax.grad(loss(plain), argnums=(0, 1, 2)))(qi, ki, w)
+    grads = jax.jit(jax.grad(loss(blocks), argnums=(0, 1, 2)))(qi, ki, w)
     for a, b in zip(grads, want):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
+@functools.partial(jax.jit, static_argnums=0, static_argnames=(
+    "H", "KV", "D", "topk", "seed", "B"))
 def _operands(S, H=4, KV=2, D=16, topk=24, seed=0, B=2):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(ks[0], (B, S, H, D))
@@ -127,14 +130,15 @@ def test_the_kernels_with_a_kept_set_against_the_masked_plain_form(S, topk):
     if S > 128:
         assert (first_tile[:, 128:] == 0).any()   # nothing kept in a tile
     want_o, _ = _masked_plain(q, k, v, keep)
-    want = jax.grad(lambda q, k, v: jnp.sum(_masked_plain(q, k, v, keep)[0]
-                                            * do), argnums=(0, 1, 2))(q, k, v)
+    want = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(_masked_plain(q, k, v, keep)[0] * do),
+        argnums=(0, 1, 2)))(q, k, v)
 
     def run(q, k, v):
         o, lse = sa.sparse_attention(q, k, v, keep, interpret=True)
         return jnp.sum(o * do), (o, lse)
-    (_, (o, lse)), grads = jax.value_and_grad(
-        run, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, (o, lse)), grads = jax.jit(jax.value_and_grad(
+        run, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     np.testing.assert_allclose(o, want_o, atol=2e-5)
     for a, b in zip(grads, want):
         np.testing.assert_allclose(a, b, atol=5e-5)
@@ -172,8 +176,8 @@ def test_the_heads_mean_attention():
     np.testing.assert_allclose(jnp.sum(got, axis=-1), 1.0, atol=1e-5)
     assert (np.asarray(got)[np.asarray(keep) == 0] == 0).all()
     # nothing flows back through it
-    grads = jax.grad(lambda q: jnp.sum(sa.mean_probs(
-        q, k, lse, keep) ** 2))(q)
+    grads = jax.jit(jax.grad(lambda q: jnp.sum(sa.mean_probs(
+        q, k, lse, keep) ** 2)))(q)
     assert not np.asarray(grads).any()
 
 
@@ -193,13 +197,11 @@ _FUSED = [(200, 2, 4, 2, 24), (33, 1, 4, 4, 7), (33, 2, 2, 2, 1),
           (130, 2, 4, 1, 40), (256, 1, 4, 2, 200)]
 
 
-@pytest.fixture(scope="module", params=_FUSED,
-                ids=lambda c: "S%d-B%d-H%d-KV%d-top%d" % c)
-def fused(request):
-    """The fused op through the interpreter beside its oracle, `indexer_kl`
-    of `mean_probs`: (operands, the kernel's value and rows, its gradient
-    under a cotangent a row, the oracle's probabilities)."""
-    S, B, H, KV, topk = request.param
+def _fused_case(S, B, H, KV, topk):
+    """One case's arrays: the operands, what the fused op makes of them
+    through the interpreter (`value`, `rows`, `grad` under a cotangent a
+    row), and its oracles (`indexer_kl` of `mean_probs`, the written-out
+    form and JAX's gradient of it, the op off the kernel path)."""
     q, k, v, keep, _ = _operands(S, H=H, KV=KV, topk=topk, B=B, seed=S)
     if topk > 1:
         # a query whose heads all look at the first of its kept keys: other
@@ -215,9 +217,32 @@ def fused(request):
                                           q.shape[-1] ** -0.5, True)
     grad = jax.grad(lambda s: jnp.sum(sa.indexer_loss(
         q, k, lse, keep, s, interpret=True) * weights))(scores)
-    return dict(q=q, k=k, lse=lse, keep=keep, scores=scores, value=value,
-                rows=rows, grad=grad, weights=weights,
-                probs=sa.mean_probs(q, k, lse, keep))
+    probs, kept = sa.mean_probs(q, k, lse, keep), keep != 0
+    return dict(
+        keep=keep, value=value, rows=rows, grad=grad, probs=probs,
+        want=sa.indexer_kl(scores, probs, keep),
+        written_out=_written_out_kl(scores, probs, kept),
+        plain=sa.indexer_loss(q, k, lse, keep, scores, kernel=False),
+        want_lse=jax.scipy.special.logsumexp(
+            jnp.where(kept, scores, -jnp.inf), axis=-1),
+        want_grad=jax.grad(lambda s: jnp.sum(
+            _written_out_kl(s, probs, kept) * weights))(scores))
+
+
+@pytest.fixture(params=_FUSED, ids=lambda c: "S%d-B%d-H%d-KV%d-top%d" % c)
+def fused(request, once_a_run):
+    """`_fused_case`, made ONCE A RUN: a case's four tests go to whichever
+    workers are free, and each would draw, interpret and differentiate the
+    same arrays again (float32 and int8 survive JSON digit for digit). A
+    fixture a TEST, not a module: pytest then keeps a test's five cases
+    together, so that five workers make five cases, where it would hand
+    one case's four tests to four workers that wait for the first."""
+    made = once_a_run(
+        "sparse_attention_fused_S%d-B%d-H%d-KV%d-top%d" % request.param,
+        lambda: {name: np.asarray(a).tolist() for name, a in jax.jit(
+            functools.partial(_fused_case, *request.param))().items()})
+    return {name: np.asarray(a, np.int8 if name == "keep" else np.float32)
+            for name, a in made.items()}
 
 
 def _but_the_row_of_zeros(a, b, atol):
@@ -230,26 +255,19 @@ def _but_the_row_of_zeros(a, b, atol):
 
 def test_the_fused_loss_is_the_plain_forms_value(fused):
     f = fused
-    want = sa.indexer_kl(f["scores"], f["probs"], f["keep"])
-    assert f["value"].shape == want.shape == (f["q"].shape[0],)
-    np.testing.assert_allclose(f["value"], want, rtol=2e-5)
-    np.testing.assert_allclose(
-        f["value"], _written_out_kl(f["scores"], f["probs"], f["keep"] != 0),
-        rtol=2e-5)
+    assert f["value"].shape == f["want"].shape == (f["keep"].shape[0],)
+    np.testing.assert_allclose(f["value"], f["want"], rtol=2e-5)
+    np.testing.assert_allclose(f["value"], f["written_out"], rtol=2e-5)
     # the public op off the kernel path is the plain form itself
-    plain = sa.indexer_loss(f["q"], f["k"], f["lse"], f["keep"], f["scores"],
-                            kernel=False)
-    np.testing.assert_array_equal(plain, want)
+    np.testing.assert_array_equal(f["plain"], f["want"])
 
 
 def test_the_fused_loss_keeps_each_rows_log_sum_exp_and_sum(fused):
     f = fused
-    B, S = f["scores"].shape[:2]
+    B, S = f["keep"].shape[:2]
     assert f["rows"].shape == (B, 2, S)
-    want = jax.scipy.special.logsumexp(
-        jnp.where(f["keep"] != 0, f["scores"], -jnp.inf), axis=-1)
-    np.testing.assert_allclose(f["rows"][:, 0], want, atol=1e-5)
-    _but_the_row_of_zeros(f["rows"][:, 1], jnp.sum(f["probs"], axis=-1),
+    np.testing.assert_allclose(f["rows"][:, 0], f["want_lse"], atol=1e-5)
+    _but_the_row_of_zeros(f["rows"][:, 1], np.sum(f["probs"], axis=-1),
                           1e-6)
     _but_the_row_of_zeros(f["rows"][:, 1], 1.0, 1e-5)
 
@@ -257,34 +275,32 @@ def test_the_fused_loss_keeps_each_rows_log_sum_exp_and_sum(fused):
 def test_the_fused_gradient_is_jaxs_of_the_written_out_form(fused):
     f = fused
     kept = f["keep"] != 0
-    want = jax.grad(lambda s: jnp.sum(_written_out_kl(s, f["probs"], kept)
-                                      * f["weights"]))(f["scores"])
-    _but_the_row_of_zeros(f["grad"], want, 1e-6)
-    assert np.abs(np.asarray(want)).max() > 0.1 or kept.sum(-1).max() == 1
+    _but_the_row_of_zeros(f["grad"], f["want_grad"], 1e-6)
+    assert np.abs(f["want_grad"]).max() > 0.1 or kept.sum(-1).max() == 1
     # exactly 0 wherever nothing is kept, the padded rows' tiles included
-    assert not np.asarray(f["grad"])[~np.asarray(kept)].any()
+    assert not f["grad"][~kept].any()
 
 
 def test_the_fused_cases_hold_what_they_are_for(fused):
     f = fused
-    kept, probs = np.asarray(f["keep"]) != 0, np.asarray(f["probs"])
+    kept, probs = f["keep"] != 0, f["probs"]
     per_row = kept.sum(-1)
     assert (per_row >= 1).all()
     if per_row.max() == 1:                  # every row keeps one key: KL of
         np.testing.assert_allclose(f["value"], 0.0, atol=1e-4)  # two deltas
-        assert not np.asarray(f["grad"]).any()
+        assert not f["grad"].any()
     else:                                   # a kept pair with p = 0 exactly
         assert ((probs == 0) & kept)[:, _ZEROS].all(0).any()
-        assert ((np.asarray(f["grad"]) != 0) & kept)[:, _ZEROS].any()
-        assert np.isfinite(np.asarray(f["value"])).all()
+        assert ((f["grad"] != 0) & kept)[:, _ZEROS].any()
+        assert np.isfinite(f["value"]).all()
 
 
 def test_nothing_flows_back_to_the_attention_through_the_fused_loss():
     q, k, v, keep, _ = _operands(128, seed=3)
     scores = _causal(jax.random.normal(jax.random.PRNGKey(5), (2, 128, 128)))
     _, lse = sa.sparse_attention(q, k, v, keep, kernel=False)
-    grads = jax.grad(lambda q, k, lse: jnp.sum(sa.indexer_loss(
-        q, k, lse, keep, scores, interpret=True)), argnums=(0, 1, 2))(
+    grads = jax.jit(jax.grad(lambda q, k, lse: jnp.sum(sa.indexer_loss(
+        q, k, lse, keep, scores, interpret=True)), argnums=(0, 1, 2)))(
             q, k, lse)
     assert not any(np.asarray(g).any() for g in grads)
 
@@ -328,9 +344,10 @@ def test_the_indexers_loss_and_its_gradient():
     assert got.shape == (B,) and (np.asarray(got) > 0).all()
     np.testing.assert_allclose(got, written_out(scores), rtol=1e-5)
     weights = jnp.array([1.0, -2.0])
-    grad = jax.grad(lambda s: jnp.sum(sa.indexer_kl(s, probs, keep)
-                                      * weights))(scores)
-    want = jax.grad(lambda s: jnp.sum(written_out(s) * weights))(scores)
+    grad = jax.jit(jax.grad(lambda s: jnp.sum(
+        sa.indexer_kl(s, probs, keep) * weights)))(scores)
+    want = jax.jit(jax.grad(
+        lambda s: jnp.sum(written_out(s) * weights)))(scores)
     np.testing.assert_allclose(grad, want, atol=1e-6)
     assert not np.asarray(grad)[~np.asarray(kept)].any()
 
@@ -353,11 +370,13 @@ def test_the_selection_kernel_is_the_plain_selection(S, topk, values):
     old = sa.SELECT_CHUNK
     sa.SELECT_CHUNK = 128
     try:
-        keep = sa.select(scores, topk, interpret=True)
+        keep = jax.jit(functools.partial(sa.select, topk=topk,
+                                         interpret=True))(scores)
     finally:
         sa.SELECT_CHUNK = old
     np.testing.assert_array_equal(keep, _top_k_mask(scores, topk))
-    np.testing.assert_array_equal(keep, sa.select(scores, topk, kernel=False))
+    np.testing.assert_array_equal(keep, jax.jit(functools.partial(
+        sa.select, topk=topk, kernel=False))(scores))
 
 
 def test_the_indexers_score_kernels_against_the_plain_form(monkeypatch):
@@ -378,7 +397,8 @@ def test_the_indexers_score_kernels_against_the_plain_form(monkeypatch):
         def run(qi, ki, w):
             scores = sa.index_scores(qi, ki, w, **kw)
             return jnp.sum(jnp.where(below, scores * d, 0.0)), scores
-        return jax.value_and_grad(run, argnums=(0, 1, 2), has_aux=True)
+        return jax.jit(jax.value_and_grad(run, argnums=(0, 1, 2),
+                                          has_aux=True))
     (_, want), want_grads = loss(kernel=False)(qi, ki, w)
     (_, got), grads = loss(interpret=True)(qi, ki, w)
     np.testing.assert_allclose(got, want, atol=1e-5)
@@ -450,9 +470,9 @@ def test_the_score_kernels_passes(passes, tol, monkeypatch):
         assert err > 1e-4 * scale
 
     def grads(**kw):
-        return jax.grad(lambda *a: jnp.sum(jnp.where(
+        return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.where(
             below, sa.index_scores(*a, **kw), 0.0) ** 2),
-            argnums=(0, 1, 2))(qi, ki, w)
+            argnums=(0, 1, 2)))(qi, ki, w)
     monkeypatch.setattr(sa, "SCORE_PASSES", 6)
     exact = grads(kernel=False)
     for a, b in zip(grads(interpret=True, backward_dtype=jnp.bfloat16),

@@ -1,4 +1,4 @@
-"""What the layer loops' checkpoint keeps (`models/layers.py:remat`): the
+"""What the layer loops' checkpoint keeps (`models/layers/core.py:remat`): the
 flash forward kernel's `o` and `lse`, the attention sub-layer's output, the
 results of the products whose forward value took three bf16 passes and a
 routed layer's routing (the router's logits, the chosen experts, the
@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding
 from ray_tpu.models import (gpt2, joyai, lfm2, nemotron_h, olmoe, qwen3_next,
                             smallthinker)
 from ray_tpu.models import layers as L
+from ray_tpu.models.layers import attention, core, moe
 from ray_tpu.ops import flash_attention as fa
 from tests.test_zz_tp_overlap import _mesh as _mesh_of, _walk
 
@@ -599,7 +600,9 @@ def test_names_lower_to_nothing_without_remat(interpreted, monkeypatch, case):
                 re.sub(r"@(\w+?)_\d+\b", r"@\1", text))
 
     named_jaxpr, named = lowered()
-    for holder in (L, fa):
+    # every module that names a value reads its own global: a holder left
+    # out shows as its name still in `bare_jaxpr`
+    for holder in (core, attention, moe, fa):
         monkeypatch.setattr(holder, "checkpoint_name", lambda x, name: x)
     bare_jaxpr, bare = lowered()
     for name in names:
