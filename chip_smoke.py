@@ -300,7 +300,7 @@ def train_loop(config: dict):
         # a device's share of the batch, which the tp region of the layer
         # loop runs as two half-batch chains (one kernel call each)
         local_batch = config["batch"] // mesh.shape["dp"]
-        chains = gpt2.tp_exchange_plan(cfg, mesh, local_batch)[2]
+        chains = gpt2.tp_exchange_plan(cfg, mesh, local_batch).chains
         report["flash_partitioning"] = flash_operand_report(hlo, (
             local_batch // chains * cfg.n_head // mesh.shape["tp"],
             config["seq"], cfg.d_model // cfg.n_head))

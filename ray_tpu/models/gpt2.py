@@ -11,9 +11,11 @@ Where the `tp` reductions come from: not from the partitioner. On a mesh
 with `tp` > 1 the dense layer loop of `forward` is per-device code
 (`_tp_blocks`, one `jax.shard_map` around the scan): the model issues each
 block's reductions itself, as neighbour exchanges (`layers.exchange_sum`:
-`ppermute` over `tp` + add) on two independent half-batch chains, because
-the TPU compiler runs a `collective-permute` beside the other chain's
-matmuls and blocks on an `all-reduce`. `tp_exchange_plan` counts them from
+`ppermute` over `tp` + add — one exchange of the whole partial at `tp` 2, a
+reduce-scatter and an all-gather of half-chunks on both ring directions
+beyond) on two independent half-batch chains, because the TPU compiler runs
+a `collective-permute` beside the other chain's matmuls and blocks on an
+`all-reduce`. `tp_exchange_plan` counts the messages and their bytes from
 shapes. Outside the loop (embedding, vocabulary projection, loss), with
 `tp` == 1 and in `forward_pipelined`, collectives are still what the
 sharding rules imply; a routed block (`layers.apply_moe`) sums its experts'
@@ -29,7 +31,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional, Tuple
+import math
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -209,27 +212,44 @@ def _chains(local_batch: int) -> int:
     return 2 if local_batch % 2 == 0 else 1
 
 
-def tp_exchange_plan(cfg: GPT2Config, mesh: Optional[Mesh], local_batch: int,
-                     seq: Optional[int] = None):
-    """(exchanges, bytes, chains) of one training step's layer loops on one
-    device: how often `_tp_blocks` engages, from shapes alone.
+class ExchangePlan(NamedTuple):
+    """`tp_exchange_plan`'s answer, for one device and one training step."""
+    messages: int           # `ppermute`s the layer loops issue
+    bytes: int              # what they carry out of the device, in all
+    chains: int             # independent chains a block runs its batch as
+    bytes_a_direction: int  # the most any one directed ring link carries
 
-    A chain exchanges twice a layer forward (attention and MLP outputs) and
+
+def tp_exchange_plan(cfg: GPT2Config, mesh: Optional[Mesh], local_batch: int,
+                     seq: Optional[int] = None) -> ExchangePlan:
+    """(messages, bytes, chains, bytes a ring direction) of one training
+    step's layer loops on one device: how often `_tp_blocks` engages and in
+    which form, from shapes alone.
+
+    A chain reduces twice a layer forward (attention and MLP outputs) and
     twice backward (their cotangents), with remat or without: the
     checkpoint keeps the reduced attention output (`L.remat`), so the
     recompute issues no exchange for it, and the recomputed MLP output is
-    dead code. A reduction over `tp` devices is tp − 1 exchanges of the
-    chain's whole [batch, seq, d_model] activation. `seq` is the global
-    sequence length (default `cfg.max_seq`)."""
+    dead code. A reduction of the chain's [batch, seq, d_model] activation
+    over `tp` devices takes the form `L.exchange_form` reads from the
+    shapes: tp − 1 messages of the whole activation, all to the next rank
+    (`tp` 2, where both directed links of the pair carry one; or rows that
+    do not divide by 2 · tp), or 4 (tp − 1) messages of 1 / (2 · tp) of it,
+    half of them to each neighbour. `seq` is the global sequence length
+    (default `cfg.max_seq`)."""
     tp = _tp_size(cfg, mesh)
     if tp == 1:
-        return 0, 0, 1
+        return ExchangePlan(0, 0, 1, 0)
     chains = _chains(local_batch)
-    exchanges = cfg.n_layer * chains * 4 * (tp - 1)
-    seq = (seq or cfg.max_seq) // sh.axis_size(mesh, "sp")
-    size = (local_batch // chains) * seq * cfg.d_model \
-        * jnp.dtype(cfg.dtype).itemsize
-    return exchanges, exchanges * size, chains
+    reductions = cfg.n_layer * chains * 4
+    shape = (local_batch // chains,
+             (seq or cfg.max_seq) // sh.axis_size(mesh, "sp"), cfg.d_model)
+    size = math.prod(shape) * jnp.dtype(cfg.dtype).itemsize
+    whole = L.exchange_form(tp, shape) == "whole"
+    messages = reductions * (tp - 1) * (1 if whole else 4)
+    carried = messages * (size if whole else size // (2 * tp))
+    return ExchangePlan(messages, carried, chains,
+                        carried if whole else carried // 2)
 
 
 def remat_saved_plan(cfg: GPT2Config, mesh: Optional[Mesh], local_batch: int,
@@ -264,8 +284,9 @@ def _tp_blocks(blocks, x, cfg: GPT2Config, impl: str, mesh: Mesh):
     implied by the sharding rules — and the local batch runs as two
     independent half-batch chains, so the compiler has the other half's
     matmuls and flash call to run while a half's exchange is in flight.
-    Column-parallel inputs need nothing forward. Backward is JAX's own
-    transpose: the same exchange on each reduced output's cotangent, so a
+    Column-parallel inputs need nothing forward. Backward is the same
+    exchange on each reduced output's cotangent (JAX's own transpose of the
+    whole-partial form, `_ring_sum`'s rule beyond `tp` 2), so a
     device carries its share of the residual stream's cotangent, and the
     shares (and the gradients of what `tp` replicates) are summed once, at
     the region's edge, with the `dp` sum of the stacked weight gradients.

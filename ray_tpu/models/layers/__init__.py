@@ -13,9 +13,9 @@ package reads goes on the module that reads it (`core.rope`,
 `moe.assignment_bounds`), not here, where it would reach model files alone.
 """
 from ray_tpu.models.layers.core import (  # noqa: F401
-    ATTENTION_OUT, Params, ROUTING, THREE_PASS_OUT, exchange_sum, flash_on,
-    init_dense, layer_norm, partition_specs, project, remat, rms_norm,
-    rms_norm_centred, rope,
+    ATTENTION_OUT, Params, ROUTING, THREE_PASS_OUT, exchange_form,
+    exchange_sum, flash_on, init_dense, layer_norm, partition_specs, project,
+    remat, rms_norm, rms_norm_centred, rope,
 )
 from ray_tpu.models.layers.attention import (  # noqa: F401
     ATTENTION_LOGICAL, DIFF_ATTENTION_LOGICAL, DIFF_CROSS_LOGICAL,

@@ -6,6 +6,7 @@ this package."""
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict
 
 import jax
@@ -150,24 +151,101 @@ def rope(x, theta: float = 10000.0, *, interleaved: bool = False,
 # ------------------------------------------------- tensor-parallel reduction
 def exchange_sum(partial, axis_name: str):
     """Sum `partial` over the manual mesh axis `axis_name` by neighbour
-    exchanges: `p + ppermute(p)` at size 2, a ring of size − 1 hops beyond.
-    MUST run in per-device code (`jax.shard_map`, `check_vma=False`).
+    exchanges. MUST run in per-device code (`jax.shard_map`,
+    `check_vma=False`).
 
     This is the `tp` reduction of a row-parallel matmul, written as the one
     collective the TPU compiler runs asynchronously: a `collective-permute`
     is a start/done pair with compute scheduled between, where an
     `all-reduce` (what `psum` or the partitioner gives) blocks. The sum is
-    taken in the partials' dtype, as the all-reduce took it. Its transpose
-    is the same exchange on the cotangent, so the backward pass needs no
-    rule of its own. Beyond size 2 each device adds in ring order from its
-    own rank, so replicas agree to rounding, not to the bit."""
+    taken in the partials' dtype, size − 1 adds an element, as the
+    all-reduce took it.
+
+    One algorithm whose form follows the ring's length (`exchange_form`, from
+    the axis' size and the partial's shape):
+
+    * size 2: `p + ppermute(p)`, one exchange of the whole partial each way.
+      Its transpose is the same exchange on the cotangent.
+    * beyond, where the partial's rows (everything but the last axis)
+      divide by 2 · size: a reduce-scatter and an all-gather on BOTH ring
+      directions (`_ring_sum`). The rows are cut into `size` chunks of two
+      halves; for size − 1 steps a rank hands the running sum of one
+      chunk's first half to rank + 1 and of another chunk's second half to
+      rank − 1 and adds its own partial of what arrives, then for size − 1
+      steps the finished half-chunks go round the same two ways and are
+      written in place. 2 (size − 1) messages of 1 / (2 · size) of the
+      partial each way: 1.5 partials leave a device at size 4, 0.75 on
+      each directed link, where a ring of whole partials sends 3 on one.
+      Every element is summed once, in ring order from its chunk's first
+      rank, so replicas agree to the bit. The backward pass is the same
+      exchange on the cotangent.
+    * beyond, other shapes: size − 1 hops of the whole partial to the next
+      rank; each device adds in ring order from its own rank, so replicas
+      agree to rounding, not to the bit."""
     n = jax.lax.axis_size(axis_name)
+    if exchange_form(n, partial.shape) == "ring_halves":
+        return _ring_sum(partial, axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     total = moving = partial
     for _ in range(n - 1):
         moving = jax.lax.ppermute(moving, axis_name, perm)
         total = total + moving
     return total
+
+
+def exchange_form(size: int, shape) -> str:
+    """Which form `exchange_sum` takes over `size` devices for a partial of
+    `shape`: "whole" partials round one way (at size 2 the two forms send
+    the same bytes a direction and this one takes one step, not two), or
+    "ring_halves", half-chunks round both ways."""
+    rows = math.prod(shape[:-1])
+    return "ring_halves" if size > 2 and rows % (2 * size) == 0 else "whole"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _ring_sum(partial, axis_name):
+    """`exchange_sum`'s reduce-scatter and all-gather of half-chunks. Piece
+    2 c + h of the rows is half h of chunk c; half 0 travels to rank + 1,
+    half 1 to rank − 1. Which piece a rank handles at a step hangs on its
+    rank (`lax.axis_index`), so the pieces are dynamic slices — whose
+    transposes would be updates into zeros of the whole partial; the
+    backward rule is this function on the cotangent instead, which is what
+    the transpose computes."""
+    n = jax.lax.axis_size(axis_name)
+    rank = jax.lax.axis_index(axis_name)
+    pieces = partial.reshape(2 * n, -1, partial.shape[-1])
+    # (half, permutation, the way the chunk index walks from `rank`)
+    ways = [(0, [(i, (i + 1) % n) for i in range(n)], -1),
+            (1, [(i, (i - 1) % n) for i in range(n)], +1)]
+
+    def index(half, walk, steps):
+        return 2 * ((rank + walk * steps) % n) + half
+
+    moving = [jax.lax.dynamic_index_in_dim(pieces, index(half, walk, 0),
+                                           keepdims=False)
+              for half, _, walk in ways]
+    for step in range(1, n):                             # reduce-scatter
+        for half, perm, walk in ways:
+            moving[half] = jax.lax.ppermute(
+                moving[half], axis_name, perm
+            ) + jax.lax.dynamic_index_in_dim(
+                pieces, index(half, walk, step), keepdims=False)
+    # every read of `pieces` ahead of the first write: the finished
+    # half-chunks are then written into the partial's own buffer, where the
+    # compiler otherwise copies the whole partial for them
+    pieces, moving = jax.lax.optimization_barrier((pieces, moving))
+    for step in range(n - 1, 2 * n - 1):                 # all-gather
+        for half, perm, walk in ways:
+            pieces = jax.lax.dynamic_update_index_in_dim(
+                pieces, moving[half], index(half, walk, step), 0)
+            if step < 2 * n - 2:
+                moving[half] = jax.lax.ppermute(moving[half], axis_name, perm)
+    return pieces.reshape(partial.shape)
+
+
+_ring_sum.defvjp(
+    lambda partial, axis_name: (_ring_sum(partial, axis_name), None),
+    lambda axis_name, _, cotangent: (_ring_sum(cotangent, axis_name),))
 
 
 # `checkpoint_name` of the attention sub-layer's output, [B, S, d_model] as
