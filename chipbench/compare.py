@@ -70,3 +70,12 @@ def compare(system_fn, reference_fn, params, tokens, device, *, pick,
                                      for k, v in ref_grads.items()},
             "within": bool(errors["loss"] <= LOSS_RTOL and all(
                 v <= GRAD_RTOL for k, v in errors.items() if k != "loss"))}
+
+
+def beside_limits(errors: dict) -> dict:
+    """``compare``'s errors, each beside the limit it was held to, ``name ->
+    [number, limit]``, the nearest to its limit first: what a run prints
+    last, so that one called not correct says by which number."""
+    beside = {k: [v, LOSS_RTOL if k == "loss" else GRAD_RTOL]
+              for k, v in errors.items()}
+    return dict(sorted(beside.items(), key=lambda kv: -kv[1][0] / kv[1][1]))

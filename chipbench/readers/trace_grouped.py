@@ -9,8 +9,7 @@ A call is told by its instruction's name (PERF.md §3): the compiler's own
 kernel for ``lax.ragged_dot`` is ``ragged-dot-*`` (its ``-metadata`` calls
 are not products and are left out), JAX's Pallas kernels are ``gmm.N``
 (forward, and to the rows) and ``tgmm.N`` (to the weights). So a trace of a
-program on either kernel reads its own share, on one yardstick;
-``trace_moe``'s ``expert_matmul_roofline`` knows the first name alone.
+program on either kernel reads its own share, on one yardstick.
 """
 import importlib
 import re

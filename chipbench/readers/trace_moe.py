@@ -1,10 +1,8 @@
-"""The routed layer in the trace. ``what="routed_share"``: device time of
-the router, the sort, the gather, the grouped matmuls and the combine over
-the device's busy time. ``what="expert_matmul_roofline"``: the least time
-the chip could take for the grouped matmuls' FLOPs and bytes (forward and
-both backward products; ``grouped_matmul_cost`` of the architecture's
-accounting module, from the configuration's and the traffic's shapes) over
-the time they took. Both in percent; None where the trace holds neither.
+"""The routed layer in the trace: device time of the router, the sort, the
+gather, the grouped matmuls and the combine over the device's busy time, in
+percent; None where the trace holds none of them. (The grouped matmuls'
+share of their roofline is ``trace_grouped``'s, whichever kernel runs
+them.)
 
 How an operation is told (PERF.md §3): the event's text is the HLO
 instruction and carries no ``jax.named_scope``. The grouped matmuls are the
@@ -18,11 +16,9 @@ stacked expert weights ([experts, a, b] in bf16, leading 1s aside: the
 casts and transposes the products read). The optimizer's pass over the
 expert weights reads their bf16 gradient and produces float32: not counted.
 """
-import importlib
 import math
 import re
 
-from chipbench import flops
 from chipbench.trace_reduce import _parse
 
 _SHAPE = re.compile(r"\b(pred|s32|u32|bf16|f32)\[([\d,]+)\]")
@@ -53,23 +49,14 @@ def _is_routed(text: str, tokens: int, experts: int, top_k: int) -> bool:
     return False
 
 
-def read(ctx, what):
+def read(ctx):
     trace = ctx["trace"]
     if not trace:
         return None
     model, traffic = ctx["model"], ctx["traffic"]
     tokens = traffic["batch"] * traffic["seq"] // ctx["chips"]
-    if what == "routed_share":
-        seconds = sum(
-            spent for name, spent in trace["per_op_s"].items()
-            if _is_routed(name, tokens, model["num_experts"],
-                          model["num_experts_per_tok"]))
-        return 100.0 * seconds / trace["busy_s"] if seconds else None
-    seconds = sum(spent for name, spent in trace["per_op_s"].items()
-                  if _GROUPED.match(name))
-    if not seconds:
-        return None
-    needed, moved = importlib.import_module(
-        ctx["accounting"]).grouped_matmul_cost(model, tokens)
-    least = flops.least_seconds(needed, moved, ctx["peaks"])[0]
-    return 100.0 * least * model["layers"] * trace["steps"] / seconds
+    seconds = sum(
+        spent for name, spent in trace["per_op_s"].items()
+        if _is_routed(name, tokens, model["num_experts"],
+                      model["num_experts_per_tok"]))
+    return 100.0 * seconds / trace["busy_s"] if seconds else None

@@ -25,6 +25,19 @@ import sys  # noqa: E402
 from chipbench import catalog  # noqa: E402
 
 
+def result_line(record: dict) -> dict:
+    """The contract's line of a job's record. ``compared`` comes last: the
+    verdicts, then each number compared beside its limit (``[number,
+    limit]``), the nearest to its limit first, so that a run called not
+    correct says by which."""
+    line = {k: record[k] for k in
+            ("correct", "attempted", "failed", "metrics", "device")}
+    if "breakdown" in record:
+        line["breakdown"] = record["breakdown"]
+    line["compared"] = dict(record["verdicts"], **record["compared"])
+    return line
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -63,12 +76,13 @@ def main(argv=None) -> int:
         print(f"chipbench: FAILED: the run gave {sorted(record['metrics'])}"
               f", the cell reports {sorted(wanted)}", file=sys.stderr)
         return 1
-    line = {k: record[k] for k in
-            ("correct", "attempted", "failed", "metrics", "device")}
-    if "breakdown" in record:
-        line["breakdown"] = record["breakdown"]
+    line = result_line(record)
     notes = {k: v for k, v in record.items() if k not in line}
     print("notes: " + json.dumps(notes))
+    # what was compared, beside its limit: the last lines of standard error
+    for name, value in line["compared"].items():
+        print(f"compared {name}: {value}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

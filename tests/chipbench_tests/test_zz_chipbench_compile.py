@@ -1,8 +1,20 @@
-"""The three cells' whole train steps, compiled at full size for a v5e
-that is described and not attached (on-chip-measurement guide, section 2):
-what the chip's compiler would refuse, a cell that no longer fits the
-chip's memory, or a kernel that is gone from the step, fails here and costs
-no chip time. A compile is not a chip run and says nothing about speed.
+"""Every cell's whole train step at full size for a v5e that is described
+and not attached (on-chip-measurement guide, section 2), in two tiers over
+ONE construction of the step (`lowered_step`):
+
+- tier 1, a case a cell in every run of the suite: the step is LOWERED.
+  What the lowered text can say fails here and costs no chip time: a flash
+  kernel gone from the step, state and batch that no longer fit a chip, a
+  mesh of another size than the cell's chips, a module laid out over
+  another number of devices. Seconds a cell.
+- the slow tier (`-m slow`, by name for the cells a PR touches and for a
+  new cell before its first chip call): the step is COMPILED, 60–180 s a
+  cell. What the chip's compiler would refuse, a memory plan outside the
+  floor and the chip's limit, the collectives the partitioner inserts.
+  The driver reads the same on the chip in every cell of every PR
+  (`train_step.hbm_plan_gb`; a step that does not fit fails its cell).
+
+Neither is a chip run and neither says anything about speed.
 
 All in this one file, the topology described inside a fixture: one process
 at a time may load the TPU's library, and under xdist only the worker that
@@ -17,6 +29,7 @@ import pytest
 from chipbench import catalog
 
 MANIFEST = catalog.load_manifest()
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
 HBM_LIMIT = 16.9e9          # bytes_limit a v5e chip reports (PR 21)
 MEMORY_FLOOR = 0.25 * 16e9  # the driver refuses a cell that plans less
 
@@ -42,8 +55,11 @@ def topo():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_cell_step_compiles_for_v5e_and_fits(topo, cell):
+def lowered_step(topo, cell):
+    """`(lowered, arguments, mesh)`: the cell's train step as
+    `parallel/train_step.make_train_step` builds it, lowered for the
+    described chips from shapes alone, its `(state, batch)` arguments with
+    their shardings, and the mesh."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -107,18 +123,51 @@ def test_cell_step_compiles_for_v5e_and_fits(topo, cell):
                            opt_state=new_opt),
                 dict(metrics, grad_norm=optax.global_norm(grads)))
 
-    compiled = jax.jit(step, donate_argnums=(0,)).lower(
-        state, {"tokens": tokens}).compile()
+    arguments = (state, {"tokens": tokens})
+    return (jax.jit(step, donate_argnums=(0,)).lower(*arguments), arguments,
+            mesh)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_step_lowers_for_v5e_and_its_state_fits(topo, cell):
+    import jax
+
+    # its mesh is as large as the cell's `chips`, or it raises
+    lowered, arguments, mesh = lowered_step(topo, cell)
+    # state and batch a chip, by shape, dtype and sharding: what the step
+    # holds before its first temporary
+    held = sum(math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(arguments))
+    text = lowered.as_text()
+    print(f"{cell}: arguments {held / 1e9:.2f} GB a chip")
+    assert held < HBM_LIMIT, f"{cell} holds {held / 1e9:.2f} GB a chip"
+    # forward, dq and dk/dv kernels are in the step: `attention="flash"`
+    # resolved to the Pallas kernels, compiled for the TPU
+    assert text.count("tpu_custom_call") >= 3
+    # the module is laid out over the cell's chips (the collectives the
+    # partitioner inserts exist only after a compile: the slow tier's)
+    assert f"mhlo.num_partitions = {mesh.size} : i32" in text
+    if dict(mesh.shape)["tp"] > 1:
+        # the `tp` region's own exchanges are the program's, not the
+        # partitioner's
+        assert "collective_permute" in text
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_step_compiles_for_v5e_and_fits(topo, cell):
+    lowered, _, mesh = lowered_step(topo, cell)
+    compiled = lowered.compile()
     plan = compiled.memory_analysis()
     held = plan.argument_size_in_bytes + plan.temp_size_in_bytes
+    hlo = compiled.as_text()
     print(f"{cell}: plan {held / 1e9:.2f} GB a chip")
     assert MEMORY_FLOOR < held < HBM_LIMIT, \
         f"{cell} plans {held / 1e9:.2f} GB a chip"
-    hlo = compiled.as_text()
     # forward, dq and dk/dv kernels are in the step, and nothing gathers
     # the flash operands (PR 21)
     assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
-    if n > 1:
+    if mesh.size > 1:
         assert "all-reduce" in hlo
         assert " all-gather(" not in hlo
     else:

@@ -84,11 +84,6 @@ def test_the_quotient_by_hand_at_the_cells_shapes():
     parent[NOT_PRODUCTS[0]] = 0.5
     assert trace_grouped.read(_ctx(parent)) == pytest.approx(
         100 * 12.558 / 27.2, rel=1e-3)
-    # and it is the accepted by-name reader's number there
-    from chipbench.readers import trace_moe
-    assert trace_grouped.read(_ctx(parent)) == pytest.approx(
-        trace_moe.read(_ctx(parent), "expert_matmul_roofline"))
-    assert trace_moe.read(_ctx(change), "expert_matmul_roofline") is None
 
 
 def test_nothing_to_read_is_nothing_reported():

@@ -20,6 +20,7 @@ import pytest
 from chipbench import catalog, compare, generate
 from chipbench.jobs import train_fit
 from chipbench.readers import mfu
+from tests.chipbench_tests import tiny_fit
 
 # "No TPU required" is an argument this test passes, not an option of the
 # command. The tiny cell reports what needs no chip and no peak.
@@ -110,8 +111,17 @@ def test_train_fit_job_through_the_trainer(trace):
         assert not os.path.exists(train_fit.TRACE_DIR)
 
 
+@pytest.fixture(scope="module")
+def gated_fit(once_a_run):
+    """ONE traced fit of `gated-tiny` with the metrics of both groups, once
+    a test run: the two cases below read a group each of it (`tiny_fit.py`;
+    the job's untraced path is the `tiny` cell's pair above)."""
+    return once_a_run("gated_tiny_fit", lambda: tiny_fit.traced(
+        TINY_MANIFEST, "gated-tiny", seed=11))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_another_architecture_is_files_only(trace):
+def test_another_architecture_is_files_only(gated_fit, trace):
     """RMSNorm, rotary positions, a gated MLP, an untied head, public key
     names: through the same job, correct against its own float32
     reference on its own leaves, its MFU from its own accounting."""
@@ -119,14 +129,13 @@ def test_another_architecture_is_files_only(trace):
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "tests.chipbench_tests.accounting.gated_lm"
     assert cell["reference"] == "tests.chipbench_tests.references.gated_lm"
-    record = train_fit.run(cell, seed=11, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = gated_fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {
         "loss", "grad_head", "grad_embed", "grad_gate", "grad_wq", "grad_wv"}
     assert record["failed"] == 0 and record["attempted"] >= 8
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         assert values["train_step.compiles_in_window"] == 0
         assert values["tiny.epochs"] == record["attempted"] / 16
@@ -333,6 +342,42 @@ def test_bf16_passes_and_eight_bit_matmuls_would_fail():
     low = compare.compare(eight_bit, reference, params, tokens,
                           jax.devices()[0], **leaves)
     assert not low["within"], low["errors"]
+    # each number beside its limit, the nearest to its limit first: the
+    # one that failed the control leads, and the verdict is theirs alone
+    for check in (got, low):
+        beside = compare.beside_limits(check["errors"])
+        assert {k: v[0] for k, v in beside.items()} == check["errors"]
+        assert all(limit == (compare.LOSS_RTOL if k == "loss"
+                             else compare.GRAD_RTOL)
+                   for k, (_, limit) in beside.items())
+        ratios = [v / limit for v, limit in beside.values()]
+        assert ratios == sorted(ratios, reverse=True)
+        assert check["within"] == (ratios[0] <= 1)
+
+
+def test_the_result_line_ends_with_what_was_compared():
+    """`compared` is the line's LAST key: the verdicts, then each number
+    beside its limit in the comparison's own order; nothing of the record
+    that the driver reads is moved by it."""
+    from chipbench import run
+
+    record = {"correct": False, "attempted": 7, "failed": 0,
+              "metrics": {"setup_s": {"value": 1.5, "unit": "s"}},
+              "device": {"platform": "tpu", "count": 1},
+              "verdicts": {"agrees_with_reference": False,
+                           "every_loss_finite": True, "loss_fell": True},
+              "compared": {"grad_w": [0.09, 0.08], "loss": [1e-5, 3e-4]},
+              "plan": {"temp": 1}}
+    line = run.result_line(record)
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    assert list(line["compared"].items()) == [
+        ("agrees_with_reference", False), ("every_loss_finite", True),
+        ("loss_fell", True), ("grad_w", [0.09, 0.08]),
+        ("loss", [1e-5, 3e-4])]
+    traced = run.result_line(dict(record, breakdown={"device_ops": []}))
+    assert list(traced)[-2:] == ["breakdown", "compared"]
+    json.dumps(line)
 
 
 @pytest.mark.parametrize("name, key, other", [

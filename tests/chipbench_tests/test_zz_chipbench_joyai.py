@@ -17,6 +17,7 @@ from chipbench import catalog, flops
 from chipbench.accounting import joyai_flash as accounting
 from chipbench.jobs import train_fit
 from chipbench.readers import mfu, trace_flash, trace_latent, trace_scope
+from tests.chipbench_tests import tiny_fit
 
 MANIFEST = {
     "paths": ["chipbench", "tests/chipbench_tests"],
@@ -48,20 +49,27 @@ LEAVES = ("head", "wq_b", "wkv_b", "wkv_a", "wo", "wg", "w_gate", "w_down",
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
+@pytest.fixture(scope="module")
+def fit(once_a_run):
+    """ONE traced fit with the metrics of both groups, once a test run: the
+    two cases below read a group each of it (`tiny_fit.py`)."""
+    return once_a_run("joyai_tiny_fit", lambda: tiny_fit.traced(
+        MANIFEST, "joyai-tiny", seed=43))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_joyai_tiny_through_the_trainer(trace):
+def test_joyai_tiny_through_the_trainer(fit, trace):
     cell = catalog.resolve_cell(MANIFEST, "joyai-tiny",
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "chipbench.accounting.joyai_flash"
     assert cell["reference"] == "chipbench.references.joyai_flash"
-    record = train_fit.run(cell, seed=43, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {"loss"} | {
         "grad_" + k for k in LEAVES}
     assert record["failed"] == 0 and record["attempted"] >= 4
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         # no TPU plane in a CPU trace: the cell's own metrics are left
         # out, not invented
@@ -329,12 +337,23 @@ def test_latent_flash_cost_by_hand():
         pytest.approx(192.5e-3, rel=1e-3)
 
 
-def test_pick_and_put_name_their_layers():
+@pytest.fixture(scope="module")
+def tiny_params():
+    """The tiny preset's parameters for the two cases that start from them;
+    one program: leaf by leaf the CPU takes seconds more."""
+    import jax
+
+    from ray_tpu.models import joyai
+    return jax.jit(lambda key: joyai.init(key, joyai.joyai_tiny()))(
+        jax.random.PRNGKey(0))
+
+
+def test_pick_and_put_name_their_layers(tiny_params):
     import jax
 
     from ray_tpu.models import joyai
     cfg = joyai.joyai_tiny()
-    params = joyai.init(jax.random.PRNGKey(0), cfg)
+    params = tiny_params
     leaves = accounting.pick(params)
     assert {k: v.shape for k, v in leaves.items()} == {
         "head": (256, 64), "wq_b": (48, 4, 32), "wkv_b": (32, 4, 40),
@@ -503,7 +522,7 @@ def test_a_precision_control_through_the_job(control, caught):
     assert (len(over) >= 3) is caught, record["check"]["errors"]
 
 
-def test_the_control_reaches_every_matmul_leaf():
+def test_the_control_reaches_every_matmul_leaf(tiny_params):
     import jax
 
     from benchmarks import precision_control
@@ -512,8 +531,8 @@ def test_the_control_reaches_every_matmul_leaf():
     control = precision_control.e4m3__joyai_llm_flash_5l()
     assert isinstance(control, joyai.JoyaiConfig)
     assert control.program == "ray_tpu.models.joyai"
-    params = joyai.init(jax.random.PRNGKey(0), joyai.joyai_tiny())
-    rounded = precision_control._eight_bit(params)
+    params = tiny_params
+    rounded = jax.jit(precision_control._eight_bit)(params)
     changed = {jax.tree_util.keystr(path)
                for (path, a), b in zip(
                    jax.tree_util.tree_leaves_with_path(params),

@@ -16,6 +16,7 @@ from chipbench import catalog, flops
 from chipbench.accounting import lfm2_moe as accounting
 from chipbench.jobs import train_fit
 from chipbench.readers import mfu, trace_scope, trace_short_conv
+from tests.chipbench_tests import later_cell, tiny_fit
 
 MANIFEST = {
     "paths": ["chipbench", "tests/chipbench_tests"],
@@ -41,20 +42,27 @@ NEW_METRICS = ("conv.scoped_share", "kernels.short_conv_roofline",
 LEAVES = ("wte", "w_in", "conv_w", "q_norm", "wv", "wg", "w_gate", "w_down")
 
 
+@pytest.fixture(scope="module")
+def fit(once_a_run):
+    """ONE traced fit with the metrics of both groups, once a test run: the
+    two cases below read a group each of it (`tiny_fit.py`)."""
+    return once_a_run("lfm2_tiny_fit", lambda: tiny_fit.traced(
+        MANIFEST, "lfm2-tiny", seed=40))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_lfm2_tiny_through_the_trainer(trace):
+def test_lfm2_tiny_through_the_trainer(fit, trace):
     cell = catalog.resolve_cell(MANIFEST, "lfm2-tiny",
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "chipbench.accounting.lfm2_moe"
     assert cell["reference"] == "chipbench.references.lfm2_moe"
-    record = train_fit.run(cell, seed=40, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {"loss"} | {
         "grad_" + k for k in LEAVES}
     assert record["failed"] == 0 and record["attempted"] >= 4
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         # no TPU plane in a CPU trace: the cell's own metrics are left
         # out, not invented
@@ -158,42 +166,54 @@ def test_the_published_configuration_is_the_catalog_rows():
                            "train_step.mlp_share", "moe.scoped_share"}
 
 
-def test_the_manifests_new_entries():
+def check_the_manifests_entries(manifest):
     """Appended behind what was there, found by name: a later PR's entries
     behind them break nothing here."""
-    configs = [c["name"] for c in REAL["configs"]]
+    configs = [c["name"] for c in manifest["configs"]]
     assert configs.index("lfm2-24b-a2b-5l") == \
         configs.index("smallthinker-21b-a3b-4l") + 1
-    config = REAL["configs"][configs.index("lfm2-24b-a2b-5l")]
+    config = manifest["configs"][configs.index("lfm2-24b-a2b-5l")]
     assert config["source"] == PUBLISHED["source"] == (
         "https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json")
     assert config["file"] == "chipbench/configs/lfm2-24b-a2b-5l.json"
     assert config["reduced"] == PUBLISHED["reduced"]
-    cells = [w["name"] for w in REAL["workloads"]]
+    cells = [w["name"] for w in manifest["workloads"]]
     assert cells.index(CELL) == cells.index("smallthinker4l-b1s16k") + 1
-    assert REAL["workloads"][cells.index(CELL)]["chips"] == 1
-    names = [m["name"] for m in REAL["per_layer"]]
+    assert manifest["workloads"][cells.index(CELL)]["chips"] == 1
+    names = [m["name"] for m in manifest["per_layer"]]
     at = names.index("ssm.scoped_share") + 1
     assert tuple(names[at:at + 4]) == NEW_METRICS
-    for m, (layer, better) in zip(REAL["per_layer"][at:at + 4], (
+    for m, (layer, better) in zip(manifest["per_layer"][at:at + 4], (
             ("conv", "lower"), ("kernels", "higher"), ("mlp", "lower"),
             ("moe", "lower"))):
         assert (m["layer"], m["better"], m["unit"], m["moves"], m["source"],
                 m["workloads"]) == (layer, better, "%", "mfu", "device_trace",
                                     [CELL])
-    # no accepted metric's list of cells names the new one
-    for m in REAL["per_layer"][:at] + REAL["end_to_end"]:
-        assert CELL not in m.get("workloads", [])
-    for entry in REAL["configs"] + REAL["workloads"]:
+    # beside its own four, the cell is named by the two accepted metrics of
+    # the plain flash calls (a `benchmark` PR's edit) and by no other's
+    naming = {m["name"] for m in manifest["per_layer"] + manifest["end_to_end"]
+              if CELL in m.get("workloads", [])}
+    assert naming == set(NEW_METRICS) | {"kernels.flash_share",
+                                         "kernels.flash_roofline"}
+    for entry in manifest["configs"] + manifest["workloads"]:
         assert len(entry["why"]) <= 200, entry["name"]
     # three of the four are metric files over the scope reader
     for name, scopes in (("conv.scoped_share", ["short_conv"]),
                          ("mlp.gated_scoped_share", ["mlp"]),
                          ("moe.sigmoid_held_share", ["moe"])):
-        assert catalog.load_json(REAL, "metrics", name) == {
+        assert catalog.load_json(manifest, "metrics", name) == {
             "reader": "trace_scope", "args": {"scopes": scopes}}
-    assert catalog.load_json(REAL, "metrics", "kernels.short_conv_roofline") \
+    assert catalog.load_json(manifest, "metrics",
+                             "kernels.short_conv_roofline") \
         == {"reader": "trace_short_conv"}
+
+
+def test_the_manifests_new_entries():
+    check_the_manifests_entries(REAL)
+
+
+def test_a_later_cell_breaks_nothing_here():
+    check_the_manifests_entries(later_cell.with_a_later_cell(REAL))
 
 
 def test_params_and_flops_a_token_by_hand():
@@ -285,12 +305,23 @@ def test_short_conv_cost_by_hand():
     assert 2 * forward + backward == pytest.approx(2.458e-3, rel=1e-3)
 
 
-def test_pick_and_put_name_the_first_layer_of_each_kind():
+@pytest.fixture(scope="module")
+def tiny_params():
+    """The tiny preset's parameters for the two cases that start from them;
+    one program: leaf by leaf the CPU takes seconds more."""
+    import jax
+
+    from ray_tpu.models import lfm2
+    return jax.jit(lambda key: lfm2.init(key, lfm2.lfm2_tiny()))(
+        jax.random.PRNGKey(0))
+
+
+def test_pick_and_put_name_the_first_layer_of_each_kind(tiny_params):
     import jax
 
     from ray_tpu.models import lfm2
     cfg = lfm2.lfm2_tiny()
-    params = lfm2.init(jax.random.PRNGKey(0), cfg)
+    params = tiny_params
     leaves = accounting.pick(params)
     assert {k: v.shape for k, v in leaves.items()} == {
         "wte": (256, 64), "w_in": (64, 192), "conv_w": (3, 64),
@@ -452,7 +483,7 @@ def test_a_precision_control_through_the_job(control, caught):
     assert (len(over) >= 3) is caught, record["check"]["errors"]
 
 
-def test_the_controls_know_their_programs():
+def test_the_controls_know_their_programs(tiny_params):
     from benchmarks import precision_control
     from ray_tpu.models import lfm2, smallthinker
 
@@ -470,8 +501,8 @@ def test_the_controls_know_their_programs():
     # the 8-bit rounding reaches every matmul leaf of the tree, the tied
     # table among them, and no norm, tap, router or bias
     import jax
-    params = lfm2.init(jax.random.PRNGKey(0), lfm2.lfm2_tiny())
-    rounded = precision_control._eight_bit(params)
+    params = tiny_params
+    rounded = jax.jit(precision_control._eight_bit)(params)
     changed = {jax.tree_util.keystr(path)
                for (path, a), b in zip(
                    jax.tree_util.tree_leaves_with_path(params),

@@ -6,15 +6,14 @@ arithmetic at the published sizes against numbers worked out by hand, and
 the trace readers of the state-space and the routed layers' metrics on
 hand-made operations."""
 import json
-import time
 
 import numpy as np
 import pytest
 
 from chipbench import catalog, flops
 from chipbench.accounting import nemotron_h as accounting
-from chipbench.jobs import train_fit
 from chipbench.readers import mfu, trace_held, trace_ssm
+from tests.chipbench_tests import tiny_fit
 
 MANIFEST = {
     "paths": ["chipbench", "tests/chipbench_tests"],
@@ -36,21 +35,28 @@ PUBLISHED = catalog.load_json(REAL, "configs", "nemotron-twotower-30b-a3b-9l")
 CELL = "nemotronh9l-b1s8k"
 
 
+@pytest.fixture(scope="module")
+def fit(once_a_run):
+    """ONE traced fit with the metrics of both groups, once a test run: the
+    two cases below read a group each of it (`tiny_fit.py`)."""
+    return once_a_run("nemotronh_tiny_fit", lambda: tiny_fit.traced(
+        MANIFEST, "nemotronh-tiny", seed=34))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_nemotronh_tiny_through_the_trainer(trace):
+def test_nemotronh_tiny_through_the_trainer(fit, trace):
     cell = catalog.resolve_cell(MANIFEST, "nemotronh-tiny",
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "chipbench.accounting.nemotron_h"
     assert cell["reference"] == "chipbench.references.nemotron_h"
-    record = train_fit.run(cell, seed=34, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {"loss"} | {
         "grad_" + k for k in ("head", "wq", "wv", "w_in", "A_log", "dt_bias",
                               "w_out", "wg", "w1", "w2", "shared_w1")}
     assert record["failed"] == 0 and record["attempted"] >= 4
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         # no TPU plane in a CPU trace: the mixers' metrics are left out,
         # not invented
@@ -201,7 +207,9 @@ def test_pick_and_put_name_a_layer_of_each_kind():
 
     from ray_tpu.models import nemotron_h
     cfg = nemotron_h.nemotron_h_tiny()
-    params = nemotron_h.init(jax.random.PRNGKey(0), cfg)
+    # one program: leaf by leaf the CPU takes seconds more
+    params = jax.jit(lambda key: nemotron_h.init(key, cfg))(
+        jax.random.PRNGKey(0))
     leaves = accounting.pick(params)
     assert {k: v.shape for k, v in leaves.items()} == {
         "head": (256, 64), "wq": (64, 4, 16), "wv": (64, 2, 16),

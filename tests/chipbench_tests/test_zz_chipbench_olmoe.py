@@ -5,15 +5,14 @@ through the ``train_fit`` job on the CPU, the accounting's arithmetic at the
 published sizes against numbers worked out by hand, and the trace reader of
 the two routed-layer metrics on hand-made operations."""
 import json
-import time
 
 import numpy as np
 import pytest
 
 from chipbench import catalog, flops
 from chipbench.accounting import olmoe as accounting
-from chipbench.jobs import train_fit
 from chipbench.readers import mfu, trace_moe
+from tests.chipbench_tests import tiny_fit
 
 MANIFEST = {
     "paths": ["chipbench", "tests/chipbench_tests"],
@@ -26,27 +25,34 @@ MANIFEST = {
     "per_layer": [
         {"name": "train_step.compiles_in_window", "unit": "count"},
         {"name": "moe.routed_share", "unit": "%"},
-        {"name": "kernels.expert_matmul_roofline", "unit": "%"}],
+        {"name": "kernels.grouped_matmul_roofline", "unit": "%"}],
 }
 PUBLISHED = catalog.load_json(catalog.load_manifest(), "configs",
                               "olmoe-1b-7b-1l")
 
 
+@pytest.fixture(scope="module")
+def fit(once_a_run):
+    """ONE traced fit with the metrics of both groups, once a test run: the
+    two cases below read a group each of it (`tiny_fit.py`)."""
+    return once_a_run("olmoe_tiny_fit", lambda: tiny_fit.traced(
+        MANIFEST, "olmoe-tiny", seed=29))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_olmoe_tiny_through_the_trainer(trace):
+def test_olmoe_tiny_through_the_trainer(fit, trace):
     cell = catalog.resolve_cell(MANIFEST, "olmoe-tiny",
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "chipbench.accounting.olmoe"
     assert cell["reference"] == "chipbench.references.olmoe"
-    record = train_fit.run(cell, seed=29, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {
         "loss", "grad_head", "grad_wq", "grad_wv", "grad_wg", "grad_w_gate",
         "grad_w_down"}
     assert record["failed"] == 0 and record["attempted"] >= 4
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         # no TPU plane in a CPU trace: the routed layer's metrics are left
         # out, not invented
@@ -124,7 +130,9 @@ def test_pick_and_put_name_four_experts_of_the_middle_layer():
 
     from ray_tpu.models import olmoe
     cfg = olmoe.olmoe_tiny()
-    params = olmoe.init(jax.random.PRNGKey(0), cfg)
+    # one program: leaf by leaf the CPU takes seconds more
+    params = jax.jit(lambda key: olmoe.init(key, cfg))(
+        jax.random.PRNGKey(0))
     leaves = accounting.pick(params)
     assert {k: v.shape for k, v in leaves.items()} == {
         "head": (256, 64), "wq": (64, 4, 16), "wv": (64, 4, 16),
@@ -184,24 +192,12 @@ def test_routed_share_counts_the_routed_layers_operations_only():
     for text in NOT_ROUTED:
         assert not trace_moe._is_routed(text, 8192, 64, 8), text
     per_op = {text: 0.01 for text in ROUTED + NOT_ROUTED}
-    assert trace_moe.read(_ctx(per_op), "routed_share") == pytest.approx(
+    assert trace_moe.read(_ctx(per_op)) == pytest.approx(
         100 * 0.01 * len(ROUTED) / 0.3)
 
 
-def test_expert_matmul_roofline_is_least_time_over_time_taken():
-    """Three traced steps whose grouped products took 81 ms in all: the nine
-    products of a step need 12.558 ms at the chip's bf16 peak."""
-    per_op = {ROUTED[0]: 0.060,
-              ROUTED[0].replace("none.3", "none"): 0.021,
-              ROUTED[1]: 0.5, NOT_ROUTED[0]: 0.5}
-    assert trace_moe.read(_ctx(per_op), "expert_matmul_roofline") == \
-        pytest.approx(100 * 3 * 12.558e-3 / 0.081, rel=1e-3)
-
-
-@pytest.mark.parametrize("what", ["routed_share", "expert_matmul_roofline"])
-def test_nothing_to_read_is_nothing_reported(what):
+def test_nothing_to_read_is_nothing_reported():
     """No trace (a CPU run), or a trace of a program without the routed
     layer (the parent's): None, never a raise and never a zero."""
-    assert trace_moe.read({"trace": None}, what) is None
-    assert trace_moe.read(_ctx({text: 0.1 for text in NOT_ROUTED}),
-                          what) is None
+    assert trace_moe.read({"trace": None}) is None
+    assert trace_moe.read(_ctx({text: 0.1 for text in NOT_ROUTED})) is None

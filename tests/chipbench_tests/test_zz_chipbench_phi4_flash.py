@@ -380,6 +380,69 @@ def _ctx(per_op_s, busy_s=1.0, model=PUBLISHED,
             "peaks": flops.peaks_for("TPU v5 lite")}
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2**31 + 5])
+def test_the_conditioned_draw_holds_every_lambda_away_from_one(seed):
+    """`accounting.conditioned`, which the comparison's freshly made
+    parameters go through: a lambda pair whose product is over
+    ``LAMBDA_DOT_MOST`` comes down to it, both vectors by one factor and in
+    their directions; a pair within it, and every other leaf, is the draw's
+    own array. So lambda stays under ``lambda_init + 0.1`` on every seed,
+    where the draw alone puts it within 0.02 of 1 on some."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import phi4_flash
+    cfg = phi4_flash.phi4_flash_tiny()
+    drawn = phi4_flash.init(jax.random.PRNGKey(seed), cfg)
+    # the full-size draw's products are normal at 0.08 (64 columns at 0.1);
+    # the tiny model's eight columns give 0.03: one layer's first pair made
+    # as large as the draw that failed the driver's seed, its second left
+    layers = list(drawn["layers"])
+    at = accounting._places(drawn)["window"]
+    mixer = dict(layers[at]["mixer"])
+    mixer["lambda_q1"] = mixer["lambda_q1"] * 0 + 0.3
+    mixer["lambda_k1"] = mixer["lambda_k1"] * 0 - 0.2
+    layers[at] = dict(layers[at], mixer=mixer)
+    drawn = dict(drawn, layers=layers)
+    most = accounting.LAMBDA_DOT_MOST
+
+    def products(m):
+        return [float((m[q] * m[k]).sum()) for q, k in (
+            ("lambda_q1", "lambda_k1"), ("lambda_q2", "lambda_k2"))]
+
+    assert products(mixer)[0] == pytest.approx(-0.06 * cfg.head_dim)
+    got = accounting.conditioned(drawn)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(drawn)
+    seen = 0
+    for before, after in zip(drawn["layers"], got["layers"]):
+        assert after["ff"] is before["ff"]
+        b, a = before["mixer"], after["mixer"]
+        if "lambda_q1" not in b:
+            assert a is b
+            continue
+        seen += 1
+        for (q, k), was, now in zip(
+                (("lambda_q1", "lambda_k1"), ("lambda_q2", "lambda_k2")),
+                products(b), products(a)):
+            if abs(was) <= most:
+                assert a[q] is b[q] and a[k] is b[k]
+                continue
+            assert now == pytest.approx(most if was > 0 else -most, rel=1e-5)
+            scale = (most / abs(was)) ** 0.5
+            np.testing.assert_allclose(a[q], b[q] * scale, rtol=1e-6)
+            np.testing.assert_allclose(a[k], b[k] * scale, rtol=1e-6)
+        assert all(a[name] is b[name] for name in b
+                   if not name.startswith("lambda"))
+        assert max(abs(x) for x in products(a)) <= most * (1 + 1e-5)
+    assert seen == 4 and got["wte"] is drawn["wte"]
+    assert abs(products(got["layers"][at]["mixer"])[0]) == \
+        pytest.approx(most, rel=1e-5)
+    # lambda at the published depths: at most lambda_init + e^m - e^-m
+    import math
+    assert 0.8 + math.exp(most) - math.exp(-most) < 0.91
+
+
 def test_the_scoped_roofline_counts_the_scan_under_its_scope(monkeypatch):
     """Three traced steps of two Mamba layers, each a forward, a recomputed
     forward and a backward: the least time is 3 · 2 · (2 · 0.616 + 1.027) ms

@@ -18,6 +18,7 @@ from chipbench import catalog, flops
 from chipbench.accounting import qwen3_next as accounting
 from chipbench.jobs import train_fit
 from chipbench.readers import mfu, trace_delta, trace_flash, trace_scope
+from tests.chipbench_tests import tiny_fit
 
 MANIFEST = {
     "paths": ["chipbench", "tests/chipbench_tests"],
@@ -55,20 +56,27 @@ LEAVES = ("head", "w_in", "w_out", "A_log", "dt_bias", "conv_w", "wq",
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
+@pytest.fixture(scope="module")
+def fit(once_a_run):
+    """ONE traced fit with the metrics of both groups, once a test run: the
+    two cases below read a group each of it (`tiny_fit.py`)."""
+    return once_a_run("qwen3_next_tiny_fit", lambda: tiny_fit.traced(
+        MANIFEST, "qwen3-next-tiny", seed=43))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_qwen3_next_tiny_through_the_trainer(trace):
+def test_qwen3_next_tiny_through_the_trainer(fit, trace):
     cell = catalog.resolve_cell(MANIFEST, "qwen3-next-tiny",
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "chipbench.accounting.qwen3_next"
     assert cell["reference"] == "chipbench.references.qwen3_next"
-    record = train_fit.run(cell, seed=43, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {"loss"} | {
         "grad_" + k for k in LEAVES}
     assert record["failed"] == 0 and record["attempted"] >= 4
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         # no TPU plane in a CPU trace: the cell's own metrics are left
         # out, not invented
@@ -299,12 +307,24 @@ def test_delta_rule_cost_by_hand():
     assert bound == "memory" and back == pytest.approx(1.649e-3, rel=2e-3)
 
 
-def test_pick_and_put_name_their_layers():
+@pytest.fixture(scope="module")
+def tiny_params():
+    """The tiny preset's parameters for the two cases that start from them;
+    one program: leaf by leaf the CPU takes seconds more."""
     import jax
 
     from ray_tpu.models import qwen3_next
     cfg = qwen3_next.qwen3_next_tiny()
-    params = qwen3_next.init(jax.random.PRNGKey(0), cfg)
+    return jax.jit(lambda key: qwen3_next.init(key, cfg))(
+        jax.random.PRNGKey(0))
+
+
+def test_pick_and_put_name_their_layers(tiny_params):
+    import jax
+
+    from ray_tpu.models import qwen3_next
+    cfg = qwen3_next.qwen3_next_tiny()
+    params = tiny_params
     leaves = accounting.pick(params)
     assert {k: v.shape for k, v in leaves.items()} == {
         "head": (256, 64), "w_in": (64, 192), "w_out": (64, 64),
@@ -501,7 +521,7 @@ def test_a_precision_control_through_the_job(control, caught):
     assert (len(over) >= 3) is caught, record["check"]["errors"]
 
 
-def test_the_control_reaches_every_matmul_leaf():
+def test_the_control_reaches_every_matmul_leaf(tiny_params):
     import jax
 
     from benchmarks import precision_control
@@ -510,9 +530,8 @@ def test_the_control_reaches_every_matmul_leaf():
     control = precision_control.e4m3__qwen3_next_80b_a3b_4l()
     assert isinstance(control, qwen3_next.Qwen3NextConfig)
     assert control.program == "ray_tpu.models.qwen3_next"
-    params = qwen3_next.init(jax.random.PRNGKey(0),
-                             qwen3_next.qwen3_next_tiny())
-    rounded = precision_control._eight_bit(params)
+    params = tiny_params
+    rounded = jax.jit(precision_control._eight_bit)(params)
     changed = {jax.tree_util.keystr(path)
                for (path, a), b in zip(
                    jax.tree_util.tree_leaves_with_path(params),

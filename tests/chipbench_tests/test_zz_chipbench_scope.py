@@ -1,10 +1,12 @@
 """`readers/trace_scope.py` on a hand-made trace and scope table whose
-answers are known, and each of its metrics resolved in exactly the cells
-BENCHMARK.json lists it for."""
+answers are known, and each of its first nine metrics resolved in exactly
+the cells BENCHMARK.json lists it for: in the manifest as it is, and with
+a later cell appended (``later_cell.py``)."""
 import pytest
 
 from chipbench import catalog
 from chipbench.readers import trace_scope
+from tests.chipbench_tests import later_cell
 
 MANIFEST = catalog.load_manifest()
 
@@ -100,37 +102,57 @@ def test_asks_the_newest_train_step_of_the_process(monkeypatch):
     assert trace_scope.read(CTX, phase="optimizer") == 15.0
 
 
-GPT2 = {"gpt2s-b16", "gpt2m-b16-remat", "gpt2l-dp2tp2"}
 ALL = {w["name"] for w in MANIFEST["workloads"]}
-CELLS_OF = {
-    "train_step.backward_share": ALL,
-    "train_step.recompute_share": {"gpt2m-b16-remat", "gpt2l-dp2tp2",
-                                   "nemotronh9l-b1s8k",
-                                   "smallthinker4l-b1s16k"},
-    "train_step.optimizer_share": ALL,
-    "train_step.loss_tail_share": ALL,
-    "train_step.mlp_share": GPT2,
-    "train_step.unscoped_share": ALL,
-    "attn.scoped_share": ALL,
-    "moe.scoped_share": {"olmoe1l-b2s4k", "nemotronh9l-b1s8k",
-                         "smallthinker4l-b1s16k"},
-    "ssm.scoped_share": {"nemotronh9l-b1s8k"},
-}
+# the reader's first nine metrics; which cells report each is the
+# manifest's to say (`workloads`, or every cell), not this file's
+SCOPE_METRICS = (
+    "train_step.backward_share", "train_step.recompute_share",
+    "train_step.optimizer_share", "train_step.loss_tail_share",
+    "train_step.mlp_share", "train_step.unscoped_share",
+    "attn.scoped_share", "moe.scoped_share", "ssm.scoped_share")
+# remats, and no metric of its own or of the first nine reads its
+# recomputation (PERF.md section 7)
+NO_RECOMPUTE_METRIC = {"lfm2moe5l-b2s8k"}
 
 
-@pytest.mark.parametrize("cell", sorted(ALL))
-@pytest.mark.parametrize("metric", sorted(CELLS_OF))
-def test_each_metric_resolves_in_exactly_its_cells(metric, cell):
-    resolved = {m["name"]: m for m in catalog.resolve_cell(
-        MANIFEST, cell, "per_layer")["metrics"]}
-    assert (metric in resolved) == (cell in CELLS_OF[metric])
+def check_metric_in_cell(manifest, metric, cell):
+    entry = next(m for m in manifest["per_layer"] if m["name"] == metric)
+    cells = entry.get("workloads",
+                      [w["name"] for w in manifest["workloads"]])
+    per_layer = catalog.resolve_cell(manifest, cell, "per_layer")
+    resolved = {m["name"]: m for m in per_layer["metrics"]}
+    assert (metric in resolved) == (cell in cells)
     if metric in resolved:
         spec = resolved[metric]
         assert spec["reader"] == "chipbench.readers.trace_scope"
         assert spec["unit"] == "%"
         # the reader takes the file's arguments as they are
         assert set(spec["args"]) <= {"scopes", "phase", "unscoped"}
-    # the cells that remat are the cells that report a recomputation
+    # the cells that remat are the cells that report a recomputation, each
+    # by `train_step.recompute_share` or by a metric of its own
     if metric == "train_step.recompute_share":
-        traffic = catalog.resolve_cell(MANIFEST, cell, "per_layer")["traffic"]
-        assert bool(traffic["remat"]) == (cell in CELLS_OF[metric])
+        recomputed = [m["name"] for m in resolved.values()
+                      if m["reader"] == "chipbench.readers.trace_scope"
+                      and m["args"] == {"phase": "recompute"}]
+        if per_layer["traffic"]["remat"]:
+            assert bool(recomputed) == (cell not in NO_RECOMPUTE_METRIC)
+        else:
+            assert not recomputed, recomputed
+
+
+@pytest.mark.parametrize("cell", sorted(ALL))
+@pytest.mark.parametrize("metric", sorted(SCOPE_METRICS))
+def test_each_metric_resolves_in_exactly_its_cells(metric, cell):
+    check_metric_in_cell(MANIFEST, metric, cell)
+
+
+def test_a_later_remat_cell_breaks_none_of_them():
+    grown = later_cell.with_a_later_cell(MANIFEST)
+    for metric in SCOPE_METRICS:
+        for cell in sorted(ALL | {later_cell.CELL}):
+            check_metric_in_cell(grown, metric, cell)
+    # and the check does fail where a remat cell brings no such metric
+    grown["per_layer"].pop()
+    with pytest.raises(AssertionError):
+        check_metric_in_cell(grown, "train_step.recompute_share",
+                             later_cell.CELL)

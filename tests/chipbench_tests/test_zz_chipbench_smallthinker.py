@@ -17,6 +17,7 @@ from chipbench import catalog, flops
 from chipbench.accounting import smallthinker as accounting
 from chipbench.jobs import train_fit
 from chipbench.readers import mfu, trace_flash, trace_pre_routed, trace_window
+from tests.chipbench_tests import later_cell, tiny_fit
 
 MANIFEST = {
     "paths": ["chipbench", "tests/chipbench_tests"],
@@ -40,21 +41,28 @@ NEW_METRICS = ("attn.window_flash_share", "kernels.window_flash_roofline",
                "moe.pre_routed_share", "kernels.pre_gmm_share")
 
 
+@pytest.fixture(scope="module")
+def fit(once_a_run):
+    """ONE traced fit with the metrics of both groups, once a test run: the
+    two cases below read a group each of it (`tiny_fit.py`)."""
+    return once_a_run("smallthinker_tiny_fit", lambda: tiny_fit.traced(
+        MANIFEST, "smallthinker-tiny", seed=36))
+
+
 @pytest.mark.parametrize("trace", [False, True])
-def test_smallthinker_tiny_through_the_trainer(trace):
+def test_smallthinker_tiny_through_the_trainer(fit, trace):
     cell = catalog.resolve_cell(MANIFEST, "smallthinker-tiny",
                                 "per_layer" if trace else "end_to_end")
     assert cell["accounting"] == "chipbench.accounting.smallthinker"
     assert cell["reference"] == "chipbench.references.smallthinker"
-    record = train_fit.run(cell, seed=36, seconds=1.0, trace=trace,
-                           t_start=time.time(), require_tpu=False)
+    record = fit
     json.dumps(record)
     assert record["correct"], (record["verdicts"], record["check"])
     assert set(record["check"]["errors"]) == {"loss"} | {
         "grad_" + k for k in ("head", "wq_global", "wv_global", "wq_window",
                               "wv_window", "wg", "w_gate", "w_down")}
     assert record["failed"] == 0 and record["attempted"] >= 4
-    values = {k: v["value"] for k, v in record["metrics"].items()}
+    values = tiny_fit.values_of(record, cell)
     if trace:
         # no TPU plane in a CPU trace: the cell's own metrics are left
         # out, not invented
@@ -196,17 +204,28 @@ def test_a_control_of_another_architecture_is_refused():
         precision_control.fp4__smallthinker_tiny
 
 
-def test_the_manifests_new_entries():
-    config = [c for c in REAL["configs"]
-              if c["name"] == "smallthinker-21b-a3b-4l"]
-    assert config == [REAL["configs"][-1]]
-    assert config[0]["source"] == PUBLISHED["source"] == (
+# the accepted metrics that a `benchmark` PR pointed at this cell too
+SHARED_METRICS = {"kernels.flash_share", "kernels.flash_roofline",
+                  "train_step.recompute_share", "moe.scoped_share"}
+
+
+def check_the_manifests_entries(manifest):
+    """Found BY NAME, wherever a later PR's entries put them in their
+    lists: the configuration, the cell and its four metrics."""
+    config = next(c for c in manifest["configs"]
+                  if c["name"] == "smallthinker-21b-a3b-4l")
+    assert config["source"] == PUBLISHED["source"] == (
         "https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct/"
         "blob/main/config.json")
-    assert config[0]["file"] == "chipbench/configs/smallthinker-21b-a3b-4l.json"
-    assert REAL["workloads"][-1]["name"] == CELL
-    assert len(REAL["workloads"]) == 6
-    new = REAL["per_layer"][-4:]
+    assert config["file"] == "chipbench/configs/smallthinker-21b-a3b-4l.json"
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert cells.index(CELL) == 5       # the sixth cell, and it stays so
+    cell = manifest["workloads"][5]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "smallthinker-21b-a3b-4l", "fit-b1-s16384-remat", 1)
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    new = manifest["per_layer"][at:at + 4]
     assert tuple(m["name"] for m in new) == NEW_METRICS
     for m, (layer, better) in zip(new, (("attn", "lower"),
                                         ("kernels", "higher"),
@@ -215,9 +234,19 @@ def test_the_manifests_new_entries():
         assert (m["layer"], m["better"], m["unit"], m["moves"], m["source"],
                 m["workloads"]) == (layer, better, "%", "mfu", "device_trace",
                                     [CELL])
-    # no accepted metric's list of cells names the new one
-    for m in REAL["per_layer"][:-4] + REAL["end_to_end"]:
-        assert CELL not in m.get("workloads", [])
+    # beside its own four, the cell is named by these accepted metrics'
+    # lists and by no other's
+    naming = {m["name"] for m in manifest["per_layer"] + manifest["end_to_end"]
+              if CELL in m.get("workloads", [])}
+    assert naming == set(NEW_METRICS) | SHARED_METRICS
+
+
+def test_the_manifests_new_entries():
+    check_the_manifests_entries(REAL)
+
+
+def test_a_later_cell_breaks_nothing_here():
+    check_the_manifests_entries(later_cell.with_a_later_cell(REAL))
 
 
 def test_params_and_flops_a_token_by_hand():
@@ -319,7 +348,9 @@ def test_pick_and_put_name_a_global_and_a_window_layer():
     from ray_tpu.models import smallthinker
     cfg = smallthinker.smallthinker_tiny()
     assert cfg.kinds[0] == (0, 0) and cfg.kinds[1] == (1, 1)
-    params = smallthinker.init(jax.random.PRNGKey(0), cfg)
+    # one program: leaf by leaf the CPU takes seconds more
+    params = jax.jit(lambda key: smallthinker.init(key, cfg))(
+        jax.random.PRNGKey(0))
     leaves = accounting.pick(params)
     assert {k: v.shape for k, v in leaves.items()} == {
         "head": (256, 64), "wq_global": (64, 4, 16), "wv_global": (64, 2, 16),
@@ -385,8 +416,8 @@ NOT_FLASH = [
 def test_the_window_reader_finds_the_named_calls_and_no_others():
     for text in WINDOWED.values():
         assert trace_window._CALL.match(text), text
-        # `flash_call_cost` goes by signature and counts them too, at half
-        # of S²: what PERF.md §3 says of `kernels.flash_roofline` here
+        # `flash_call_cost` goes by signature and would count them too, at
+        # half of S²: `trace_flash` leaves them out by this name
         assert flops.flash_call_cost(text)
     for text in list(CAUSAL.values()) + NOT_FLASH:
         assert not trace_window._CALL.match(text), text
@@ -405,13 +436,36 @@ def test_the_window_reader_finds_the_named_calls_and_no_others():
     # 26.9 % here, where the causal counter reads 1 / 0.4375 of it
     assert trace_window.read(ctx, "roofline") == pytest.approx(26.96,
                                                                abs=0.01)
-    both = trace_flash.read(ctx, "roofline")
-    assert trace_flash.read(ctx, "share") == pytest.approx(
-        100 * (0.214 + 0.472) / 2.4)
-    causal_only = trace_flash.read(_ctx({k: v for k, v in per_op.items()
-                                         if k in CAUSAL.values()}),
-                                   "roofline")
-    assert both > causal_only and both < 100
+
+
+def test_the_plain_flash_reader_leaves_the_windowed_calls_alone():
+    """A windowed name is not read, a plain one is: `flash_call_cost` goes
+    by signature and would count a window layer's calls at half of S², on
+    top of `trace_window`'s reading of them at the area the window keeps
+    (`kernels.flash_roofline` 138.81 % in this cell: ledger, PR 61). The
+    global layer's calls, named `flash_fwd` / `_dq` / `_dkv` or not at all,
+    are read as in every other cell: 2 / 3 / 4 products of 28 · 16,384² / 2
+    · 128 pairs."""
+    windowed = {WINDOWED["fwd"]: 0.069, WINDOWED["dq"]: 0.044,
+                WINDOWED["dkv"]: 0.101}
+    plain = {CAUSAL["fwd"]: 0.148,
+             CAUSAL["dq"].replace("%attention.8", "%flash_dq.2"): 0.099,
+             CAUSAL["dkv"].replace("%attention.7", "flash_dkv.2"): 0.225}
+    for text in plain:
+        assert flops.flash_call_cost(text) \
+            and not trace_window._CALL.match(text), text
+    least = 3 * (2 + 3 + 4) * 2 * (28 * 16384 * 16384 // 2) * 128 / 197e12
+    for per_op in (plain, dict(plain, **windowed, **{NOT_FLASH[0]: 0.5})):
+        assert trace_flash.read(_ctx(per_op), "share") == pytest.approx(
+            100 * 0.472 / 2.4)
+        assert trace_flash.read(_ctx(per_op), "roofline") == pytest.approx(
+            100 * least / 0.472, rel=1e-9)
+    for what in ("share", "roofline"):
+        assert trace_flash.read(_ctx(windowed), what) is None
+    # the two readers' seconds add up to every flash call's, counted once
+    ctx = _ctx(dict(plain, **windowed))
+    assert trace_flash.read(ctx, "share") + trace_window.read(ctx, "share") \
+        == pytest.approx(100 * (0.472 + 0.214) / 2.4)
 
 
 @pytest.mark.parametrize("what", ["share", "roofline"])

@@ -14,6 +14,7 @@ import pytest
 
 from chipbench import catalog
 from chipbench.readers import program_span, step_activity
+from tests.chipbench_tests import later_cell
 
 # table 6 of the issue that brought them, in the manifest's order
 STARTUP_METRICS = (
@@ -41,14 +42,16 @@ print("RECORD " + json.dumps(record))
 """
 
 
-def _entries():
-    manifest = catalog.load_manifest()
+def _entries(manifest):
+    """The manifest's start-up entries, found by name wherever a later
+    PR's entries put them."""
     return [m for m in manifest["per_layer"]
-            if m["name"] in STARTUP_METRICS], manifest
+            if m["name"] in STARTUP_METRICS]
 
 
 def _one_run():
-    entries, manifest = _entries()
+    manifest = catalog.load_manifest()
+    entries = _entries(manifest)
     tiny = {
         "paths": manifest["paths"],
         "workloads": [{"name": "tiny", "config": "gpt2-tiny",
@@ -74,11 +77,13 @@ def record(once_a_run):
     return once_a_run("startup_record", _one_run)
 
 
-def test_the_manifests_entries_are_the_issues_table():
-    entries, manifest = _entries()
+def check_the_manifests_entries(manifest):
+    entries = _entries(manifest)
     assert tuple(m["name"] for m in entries) == STARTUP_METRICS
-    # appended, every cell reports them (no `workloads`), all lower
-    assert manifest["per_layer"][-len(entries):] == entries
+    # appended in one piece, every cell reports them (no `workloads`), all
+    # lower
+    at = manifest["per_layer"].index(entries[0])
+    assert manifest["per_layer"][at:at + len(entries)] == entries
     for m in entries:
         assert "workloads" not in m and m["better"] == "lower"
         assert m["moves"] == ("tokens_per_s_per_chip"
@@ -89,6 +94,15 @@ def test_the_manifests_entries_are_the_issues_table():
     assert sources.pop("train_step.cache_misses_at_setup") == \
         "program_counter"
     assert set(sources.values()) == {"program_span"}
+
+
+def test_the_manifests_entries_are_the_issues_table():
+    check_the_manifests_entries(catalog.load_manifest())
+
+
+def test_a_later_cell_breaks_nothing_here():
+    check_the_manifests_entries(
+        later_cell.with_a_later_cell(catalog.load_manifest()))
 
 
 @pytest.mark.parametrize("metric", STARTUP_METRICS)
