@@ -7,7 +7,11 @@ shipped):
 
 pieces: base, no_band (the diagonal sub-blocks' element-wise terms and their
 pullback), no_inverse (T = A), one_pass_exact (every six-pass product one
-pass), no_norm (normalize None). Through the chip tool; `PROBE_TINY=1` rehearses on the CPU."""
+pass), no_norm (normalize None). Through the chip tool; `PROBE_TINY=1`
+rehearses on the CPU. Since PR 64 the module takes a block's inverses at once
+between two loops (`_inverse_many`, which `no_inverse` leaves out);
+`PROBE_MODULE=<file>` takes the pieces out of another form of the module (the
+parent's one-loop form, whose inverse is `_inverse_packed`)."""
 import json
 import os
 import sys
@@ -20,6 +24,13 @@ import jax.numpy as jnp  # noqa: E402
 
 from ray_tpu.ops import kda  # noqa: E402
 
+if os.environ.get("PROBE_MODULE"):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "kda_probed", os.environ["PROBE_MODULE"])
+    kda = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kda)
+
 TINY = os.environ.get("PROBE_TINY") == "1"
 B, T, H, K = (1, 256, 2, 128) if TINY else (2, 8192, 32, 128)
 out_file = sys.argv[1]
@@ -30,8 +41,10 @@ qkv = jax.random.normal(ks[0], (B, T, 3 * H * K))
 g = -jax.nn.softplus(jax.random.normal(ks[3], (B, T, H * K)))
 beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, T, H)))
 args = (qkv, g, beta)
+INVERSE = ("_inverse_many" if hasattr(kda, "_inverse_many")
+           else "_inverse_packed")
 kept = {name: getattr(kda, name) for name in (
-    "_band", "_band_pull", "_inverse_packed", "_exact")}
+    "_band", "_band_pull", INVERSE, "_exact")}
 
 
 def clock(fn, runs=5):
@@ -53,7 +66,9 @@ def patch(piece):
         kda._band_pull = lambda qT, kT, cumT, d_kk, d_qk: (
             qT * d_qk[:1], kT * d_kk[:1], cumT)
     elif piece == "no_inverse":
-        kda._inverse_packed = lambda A, *geometry: A
+        setattr(kda, INVERSE, {
+            "_inverse_many": lambda ref, masks: None,
+            "_inverse_packed": lambda A, *geometry: A}[INVERSE])
     elif piece == "one_pass_exact":
         kda._exact = lambda a, b, dims: jax.lax.dot_general(
             a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), dims,

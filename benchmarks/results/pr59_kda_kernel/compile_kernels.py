@@ -2,6 +2,7 @@
 (no chip: what Mosaic refuses shows here), each with the seconds to trace,
 lower and compile:
     JAX_PLATFORMS=cpu python3 benchmarks/results/pr59_kda_kernel/compile_kernels.py [block]
+(`PROBE_MODULE=<file>`: another form of the module, as `rule_probe.py`.)
 A compile is not a chip run."""
 import os
 import sys
@@ -15,6 +16,14 @@ from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from ray_tpu.ops import kda  # noqa: E402
+
+if os.environ.get("PROBE_MODULE"):
+    # another form of the module, from a file (as `rule_probe.py`)
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "kda_probed", os.environ["PROBE_MODULE"])
+    kda = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kda)
 
 jax.config.update("jax_enable_compilation_cache", False)
 block = int(sys.argv[1]) if len(sys.argv) > 1 else kda.BLOCK_TOKENS

@@ -13,8 +13,11 @@ at a time, knobs the module no longer has.)
 
 from the repo root, through the chip tool; `PROBE_TINY=1` rehearses on the
 CPU at a small size (the kernels in the interpreter); `PROBE_MODULE=<file>`
-probes another form of the module (`kda_two_loops.py`). PR 58's probe of the
-plain form alone: `benchmarks/results/pr58_kimi_linear/rule_probe.py`."""
+probes another form of the module from a file — an unshipped one, or the
+parent's (`.bench_tree/parent/ray_tpu/ops/kda.py`: PR 64 probed PR 59's
+`kda_two_loops.py`, a block's chunks in two loops, this way before it became
+the module, and the one-loop module beside it afterwards). PR 58's probe of
+the plain form alone: `benchmarks/results/pr58_kimi_linear/rule_probe.py`."""
 import json
 import os
 import sys

@@ -47,8 +47,32 @@ the cell's 992 ms step: PERF.md §6, PR 59).
 **The kernels** (``kda_fwd``, ``kda_bwd`` in HLO and trace) keep a chunk in
 VMEM. Grid (batch, PAIR of heads, block of `BLOCK_TOKENS` tokens), the
 blocks of a pair in order — the backward's in reverse — with the two states
-``[2, K, V]`` float32 (their cotangents) in VMEM scratch and an inner loop
-over the block's chunks. q, k and v are read out of the conv's ``[q | k |
+``[2, K, V]`` float32 (their cotangents) in VMEM scratch. A grid step walks
+its block's chunks in TWO loops (`_two_loops`):
+
+1. *the first* builds of every chunk what reads no state (`_prepared`: γ's
+   running sum, the diagonal sub-blocks' terms, the scores against earlier
+   sub-blocks, ``A``, ``P``, the decayed q and k, β ∘ v) and keeps it in VMEM
+   scratch (`_kept_shapes`: 0.3 MB a chunk for the forward, whose second
+   loop reads eight of these arrays, 1.7 MB for the backward, which reads
+   all thirty);
+2. *between the loops* ``A → (I + A)⁻¹`` of ALL the block's chunks at once
+   (`_inverse_many`), in place in the scratch;
+3. *the second* takes ``U``, ``W``, the carry over the state and the readout
+   a chunk, in the order the state needs (the backward: from the block's last
+   chunk to its first, with the cotangents), reading the scratch — the
+   forward's four chunks in one loop body, the backward's two a body, so
+   that what of the next chunk reads no state fills this chunk's waits.
+
+Why: a chunk's inverse reads no state, and alone it is a chain of waits —
+fifteen substitution steps, then four dependent six-pass products — that a
+loop body of one chunk sits out: 4.7 of a 14.1 ms forward call and 5.5 of a
+28.6 ms backward call with one loop (`benchmarks/results/pr64_kda_two_loops/
+ablate_shipped.jsonl`; PR 59's `ablate.jsonl` read the same of its earlier
+form), where bodies of two and four chunks were slower. With the tiles of a
+block's four chunks one above the other the chain is paid once a block: 1.3
+ms of a forward call are left of the 4.7 (`final/ablate_change.jsonl`;
+PERF.md §6, PR 64). q, k and v are read out of the conv's ``[q | k |
 v]`` ``[B, T, 3·H·K]``, g out of ``[B, T, H·K]`` IN PLACE by block index
 maps, o is written as ``[B, T, H·V]``: no split, head reshape or chunked
 copy exists (:func:`kda_packed`; :func:`kda` lays its three side by side
@@ -57,8 +81,8 @@ of the pair, token) — `_kernel_rows`, plain JAX on a 2 MB array, which JAX
 differentiates. Inside a chunk the pair's ``[C, ·]`` arrays lie one above
 the other (``[(r, i), ·]``, "stacked": 128 rows for the MXU) and its ``[C,
 C]`` arrays side by side along the lanes (``[C, (r, j)]``, "packed"), as
-`gated_delta`'s two value heads of a key head, whose `_inverse_packed` and
-`_by_head` serve here — but each head has q, k and decays of its own:
+`gated_delta`'s two value heads of a key head, whose `_by_head` and packed
+geometry serve here — but each head has q, k and decays of its own:
 
 * γ is a product with a triangle of ones at the highest precision (and its
   pullback the transposed product);
@@ -100,6 +124,7 @@ accumulation, one pass.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -109,8 +134,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import target
 from ray_tpu.ops.gated_delta import (
     _BASE, _LANES, _NN, _NT, _TN, _by_head, _check, _exact, _inverse,
-    _inverse_packed, _mm, _one_pass, _own, _packed_columns, _packed_geometry,
-    _unit, _whole_blocks)
+    _mm, _one_pass, _own, _packed_columns, _packed_geometry, _unit,
+    _whole_blocks)
 
 
 # ------------------------------------------------------------- the scores
@@ -239,8 +264,16 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 # by side the 128 lanes ("packed", [C, (r, j)]), as `gated_delta`'s two value
 # heads of a key head — here two heads with q, k and decays of their own
 _PAIR = 2
-# tokens a grid step holds (whole chunks, walked in order by an inner loop):
-# chosen on the chip at the cell's layer (PERF.md §6, PR 59)
+# tokens a grid step holds: whole chunks, walked by two loops with all their
+# inverses taken at once between them, so the block sets how many chunks
+# share one chain of waits (`kda_plan`'s `inverses_at_once`: 4). On the chip,
+# each kernel alone at the cell's layer (forward / backward, ms;
+# `benchmarks/results/pr64_kda_two_loops/loop_probe_{b,c,h}.jsonl`): with
+# one chunk a body of the second loop 128 tokens 12.60 / 26.30, 256 11.86 /
+# 25.42, 512 as 256 (the first form of `_inverse_many`: 12.52 / 26.03 for
+# 12.35 / 25.91); with the forward's second loop in one body 128 tokens
+# 11.44, 256 10.50, 512 10.22 — but 512's backward keeps 26 MB of scratch,
+# over `VMEM_BUDGET_BYTES`
 BLOCK_TOKENS = 256
 # what a grid step's double-buffered blocks and the state may take of VMEM
 # (`_vmem_bytes`); a chunk's temporaries are the rest of what Mosaic is asked
@@ -249,29 +282,46 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
+@functools.lru_cache(maxsize=None)
 def _vmem_bytes(block: int, chunk: int, k_dim: int, v_dim: int) -> int:
     """VMEM of a grid step of the backward (the larger of the two): its
     blocks — q, k, g and their cotangents [block, 2·K]; v, o's cotangent and
     v's [block, 2·V]; β's rows and theirs [block / C, 8, 128]; the chunks'
-    start states [block / C, 2, K, V] — all float32 and double-buffered,
-    and the state's cotangent [2, K, V] float32."""
+    start states [block / C, 2, K, V] — all float32 and double-buffered; the
+    state's cotangent [2, K, V] float32; and what the first loop over the
+    block's chunks keeps for the second, once (`_kept_shapes`, in whole
+    tiles, the products' operands counted as float32, the widest they come:
+    at chunk 64 and K = V = 128 nineteen stacked [128, 128] arrays — q and k
+    as they are and transposed, γ transposed, three decays, three columns
+    and three reaches of earlier sub-blocks, `P` by head and the four
+    operands the products read — six packed [64, 128] — `A`, both scores,
+    the three sub-blocks' left operands — and five columns [128, 1], a tile
+    of lanes each: 1.77 MB a chunk so counted)."""
     chunks = block // chunk
     blocks = 4 * (6 * block * _PAIR * k_dim + 3 * block * _PAIR * v_dim
                   + 2 * chunks * 8 * _LANES
                   + chunks * _PAIR * k_dim * v_dim)
-    return 2 * blocks + 4 * _PAIR * k_dim * v_dim
+    kept, _ = _kept_shapes(chunks, chunk, k_dim, v_dim, jnp.dtype("float32"),
+                           1e-6, None)
+    return (2 * blocks + 4 * _PAIR * k_dim * v_dim
+            + 4 * sum(math.prod(held.shape[:-2]) * -(-held.shape[-2] // 8) * 8
+                      * -(-held.shape[-1] // _LANES) * _LANES
+                      for held in kept))
 
 
 def kda_plan(tokens: int, heads: int, k_dim: int, v_dim: int,
              chunk: int) -> dict:
     """What the kernels hold for `tokens` tokens of a sequence, from shapes
-    alone: the chunks, the start states kept for the backward (float32), and
-    `vmem_bytes`, a grid step's blocks and state (`_vmem_bytes`), held
-    against `VMEM_BUDGET_BYTES` by `_use_kernel`. (The rule's FLOPs and
-    bytes are the model's accounting: `accounting/kimi_linear.py`.)"""
+    alone: the chunks, how many of them a grid step inverts at once between
+    its two loops, the start states kept for the backward (float32), and
+    `vmem_bytes`, a grid step's blocks, state and kept scratch
+    (`_vmem_bytes`), held against `VMEM_BUDGET_BYTES` by `_use_kernel`. (The
+    rule's FLOPs and bytes are the model's accounting:
+    `accounting/kimi_linear.py`.)"""
     chunks = -(-tokens // chunk)
     return {
         "chunks": chunks, "block_tokens": BLOCK_TOKENS,
+        "inverses_at_once": BLOCK_TOKENS // chunk,
         "state_bytes": 4 * chunks * heads * k_dim * v_dim,
         "vmem_bytes": _vmem_bytes(BLOCK_TOKENS, chunk, k_dim, v_dim),
     }
@@ -434,13 +484,36 @@ def _packed_to_bands(d_kk, d_qk, same_head, chunk: int):
     return bands[:_BASE], bands[chunk:chunk + _BASE]
 
 
-def _chunk_parts(q, k, g, rows, *, chunk: int, cd, normalize):
+def _masks(chunk: int, k_dim: int) -> dict:
+    """Index arrays and masks of a chunk of two heads (constants of a grid
+    step): `_packed_geometry`'s of the packed ``[C, 2·C]`` tile and the
+    square one, the triangle of ones a head that takes the running sum, a
+    stacked row's token, and which half of the lanes is the second head's
+    on a sub-block's 16 rows."""
+    row, col, second, same_head = _packed_geometry(chunk)
+    square = (_PAIR * chunk, _PAIR * chunk)
+    below = (jax.lax.broadcasted_iota(jnp.int32, square, 0)
+             >= jax.lax.broadcasted_iota(jnp.int32, square, 1))
+    return {
+        "row": row, "col": col, "second": second, "same_head": same_head,
+        "strict": row > col, "lower": row >= col,
+        "sums": (same_head & below).astype(jnp.bfloat16),
+        "token": jax.lax.broadcasted_iota(
+            jnp.int32, (_PAIR * chunk, k_dim), 0) % chunk,
+        "second_half": jax.lax.broadcasted_iota(
+            jnp.int32, (_BASE, _PAIR * chunk), 1) >= chunk,
+    }
+
+
+def _chunk_parts(q, k, g, rows, m, *, chunk: int, cd, normalize):
     """What both kernels build of a chunk of two heads before they read the
-    state, in VMEM: q, k, g stacked ``[(r, i), K]`` float32 as the conv and
-    the gate left them, `rows` the chunk's [8, 128] block (β) -> a dict."""
+    state or invert anything, in VMEM: q, k, g stacked ``[(r, i), K]``
+    float32 as the conv and the gate left them, `rows` the chunk's [8, 128]
+    block (β), `m` `_masks`' -> a dict of arrays (and lists of them), among
+    them `A`, the strictly lower-triangular matrix to invert, packed."""
     C, K = chunk, q.shape[1]
-    row, col, second, same_head = _packed_geometry(C)
-    raw = {"q_raw": q, "k_raw": k}
+    second = m["second"]
+    raw = {}
     if normalize is not None:
         # (the XLU's sum: this heads the chunk's chain, and a trip through
         # the MXU is the longer wait)
@@ -448,13 +521,8 @@ def _chunk_parts(q, k, g, rows, *, chunk: int, cd, normalize):
             jnp.sum(x * x, axis=-1, keepdims=True) + normalize)
             for x in (q, k))
         q, k = q * raw["q_norm"] * K ** -0.5, k * raw["k_norm"]
-    square = (_PAIR * C, _PAIR * C)
-    at_row = jax.lax.broadcasted_iota(jnp.int32, square, 0)
-    at_col = jax.lax.broadcasted_iota(jnp.int32, square, 1)
     # γ: the running sum a head, a product with a triangle of ones
-    sums = (same_head & (at_row >= at_col)).astype(jnp.bfloat16)
-    cum = _summed(sums, g, _NN)
-    token = jax.lax.broadcasted_iota(jnp.int32, (_PAIR * C, K), 0) % C
+    cum = _summed(m["sums"], g, _NN)
     # column l of the transposed tile is row l mod 8
     beta = jnp.transpose(jnp.concatenate([rows] * (_LANES // 8),
                                          axis=0))[:, :1]
@@ -463,18 +531,15 @@ def _chunk_parts(q, k, g, rows, *, chunk: int, cd, normalize):
     kk, qk = _bands_to_packed(*_band(qT, kT, cumT), second, C)
     # ---- rows against every EARLIER sub-block's columns: q's and k's rows
     # of both heads one above the other, both exponents at most 0
-    m = C // _BASE
-    blocks = cum.reshape(_PAIR * m, _BASE, K)
+    blocks = cum.reshape(_PAIR * C // _BASE, _BASE, K)
     shrink = jnp.exp(cum - jnp.broadcast_to(
         blocks[:, :1], blocks.shape).reshape(_PAIR * C, K))
     q_rows, k_rows = q * shrink, k * shrink
-    second_half = jax.lax.broadcasted_iota(
-        jnp.int32, (_BASE, _PAIR * C), 1) >= C
     nothing = jnp.zeros((_BASE, _PAIR * C), jnp.float32)
     kk_off, qk_off, reaches, cols, lefts = [nothing], [nothing], [], [], []
-    for i in range(1, m):
+    for i in range(1, C // _BASE):
         reach = jnp.exp(jnp.where(
-            token < i * _BASE,
+            m["token"] < i * _BASE,
             _row_of_each_head(cum, C, i * _BASE) - cum, -jnp.inf))
         reaches.append(reach)
         cols.append(k * reach)
@@ -482,51 +547,123 @@ def _chunk_parts(q, k, g, rows, *, chunk: int, cd, normalize):
             [_sub_block(q_rows, i, C), _sub_block(k_rows, i, C)],
             axis=0).astype(cd))
         both = _one_pass(lefts[-1], cols[-1].astype(cd), _NT)
-        qk_off.append(jnp.where(second_half, both[_BASE:2 * _BASE],
+        qk_off.append(jnp.where(m["second_half"], both[_BASE:2 * _BASE],
                                 both[:_BASE]))
-        kk_off.append(jnp.where(second_half, both[3 * _BASE:],
+        kk_off.append(jnp.where(m["second_half"], both[3 * _BASE:],
                                 both[2 * _BASE:3 * _BASE]))
-    if m > 1:
+    if C > _BASE:
         kk = kk + jnp.concatenate(kk_off, axis=0)
         qk = qk + jnp.concatenate(qk_off, axis=0)
-    beta_packed = _packed_columns(beta, second, C)
-    T = _inverse_packed(jnp.where(row > col, kk * beta_packed, 0.0),
-                        row, col, second, same_head)
     grow = jnp.exp(cum)                                      # e^γ
     to_end = jnp.exp(_row_of_each_head(cum, C, C - 1) - cum)
     return {
         **raw, "q": q, "k": k, "qT": qT, "kT": kT, "cumT": cumT,
-        "beta": beta, "beta_packed": beta_packed, "kk": kk, "T": T,
-        "shrink": shrink, "reaches": reaches, "cols": cols,
-        "lefts": lefts,
-        "grow": grow, "to_end": to_end, "sums": sums, "token": token,
-        "second": second, "same_head": same_head, "strict": row > col,
-        "lower": row >= col, "second_half": second_half,
+        "beta": beta, "kk": kk, "qk": qk, "shrink": shrink,
+        "reaches": reaches, "cols": cols, "lefts": lefts, "grow": grow,
+        "to_end": to_end,
+        "A": jnp.where(m["strict"],
+                       kk * _packed_columns(beta, second, C), 0.0),
         # e^{γ_C} a key channel, a column a head
         "keep": [jnp.exp(cumT[:, (r + 1) * C - 1:(r + 1) * C])
                  for r in range(_PAIR)],
-        "T_by_head": _by_head(T, same_head).astype(cd),
-        "P_by_head": _by_head(qk.astype(cd), same_head),
+        "P_by_head": _by_head(qk.astype(cd), m["same_head"]),
         "q_grown": (q * grow).astype(cd),
         "k_end": (k * to_end).astype(cd),
         "k_written": (k * grow * beta).astype(cd),
     }
 
 
-def _scores_pull(p, d_kk, d_qk, *, chunk: int, cd):
+# what the forward's second loop reads of `_prepared`'s (the backward's: all)
+_FORWARD_READS = ("A", "keep", "P_by_head", "q_grown", "k_end", "k_written",
+                  "written_v")
+
+
+def _inverse_many(ref, m):
+    """``A -> (I + A)⁻¹`` for EVERY chunk of a block at once, in place: ref
+    [n, C, 2·C] float32 in VMEM (two heads' strictly lower-triangular [C, C]
+    side by side along the lanes a chunk). `gated_delta._inverse_packed`'s
+    arithmetic to the bit — forward substitution on the 16 × 16 diagonal
+    blocks as ``[16, (r, b, j)]`` tiles, right-looking, then the blocks
+    merged by products at the highest precision — with the n chunks' tiles
+    one above the other through the substitution and their products issued
+    side by side at each level of the merge: every step of either is a wait
+    (a row's broadcast, the MXU's), and n chunks share it. A step's factor,
+    ``A``'s column j on all 16 lanes of its block, reads no earlier step: it
+    is a product with a block matrix of ones on the MXU (the column's three
+    bfloat16 parts, which add up to it exactly), a product a column — not a
+    mask, a lane rotation to the block's first lane and four doubling
+    rotations on the XLU, 0.5 ms more a call of either kernel, and not all
+    fifteen columns in one product, which gains nothing
+    (`benchmarks/results/pr64_kda_two_loops/loop_probe_{b,c}.jsonl`)."""
+    n, chunk, width = ref.shape
+    blocks = chunk // _BASE
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_BASE, width), 1)
+    at_tile, block = lane % _BASE, lane % chunk // _BASE
+    at = jnp.concatenate([at_tile] * n, axis=0)
+    A = [ref[c] for c in range(n)]
+    own = jnp.concatenate([
+        sum(jnp.where(block == b, A[c][b * _BASE:(b + 1) * _BASE], 0.0)
+            for b in range(blocks)) for c in range(n)], axis=0)
+    square = (width, width)
+    ones = (jax.lax.broadcasted_iota(jnp.int32, square, 0) // _BASE
+            == jax.lax.broadcasted_iota(jnp.int32, square, 1) // _BASE
+            ).astype(jnp.bfloat16)
+    rows = n * _BASE
+    T = (jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) % _BASE
+         == at).astype(jnp.float32)
+    for j in range(_BASE - 1):
+        parts = _parts(jnp.where(at == j, own, 0.0))
+        spread = _one_pass(jnp.concatenate(parts, axis=0), ones, _NN)
+        factor = sum(spread[part * rows:(part + 1) * rows]
+                     for part in range(len(parts)))
+        T = T - factor * jnp.concatenate([
+            jnp.broadcast_to(T[c * _BASE + j:c * _BASE + j + 1],
+                             (_BASE, width)) for c in range(n)], axis=0)
+    Ts = [jnp.concatenate([
+        jnp.where(block == b, T[c * _BASE:(c + 1) * _BASE], 0.0)
+        for b in range(blocks)], axis=0) for c in range(n)]
+    row, col, same_head = m["row"], m["col"], m["same_head"]
+    side = _BASE
+    while side < chunk:
+        # only the second block of a pair has rows in `A_off`, and so in
+        # both products: half the rows go through the MXU
+        def second_rows(x):
+            return jnp.concatenate(
+                [x[at:at + side] for at in range(side, chunk, 2 * side)],
+                axis=0)
+
+        def placed(x):
+            nothing = jnp.zeros((side, width), jnp.float32)
+            return jnp.concatenate(
+                [part for at in range(0, chunk // 2, side)
+                 for part in (nothing, x[at:at + side])], axis=0)
+
+        off = ((row // (2 * side) == col // (2 * side))
+               & (row // side != col // side))
+        rights = [placed(_exact(second_rows(jnp.where(off, A[c], 0.0)),
+                                _by_head(Ts[c], same_head), _NN))
+                  for c in range(n)]
+        lowers = [_exact(second_rows(Ts[c]), _by_head(rights[c], same_head),
+                         _NN) for c in range(n)]
+        Ts = [Ts[c] - placed(lowers[c]) for c in range(n)]
+        side *= 2
+    for c in range(n):
+        ref[c] = Ts[c]
+
+
+def _scores_pull(p, m, d_kk, d_qk, *, chunk: int, cd):
     """The scores' pullback: the cotangents of `kk` and `qk` (packed; zero
     where the scores are) -> those of the normed q, k and of γ, stacked."""
     C, f32 = chunk, jnp.float32
     q, k, shrink = p["q"], p["k"], p["shrink"]
-    second_half = p["second_half"]
+    second_half = m["second_half"]
     # ---- the diagonal sub-blocks
     d_qT, d_kT, d_cumT = _band_pull(
         p["qT"], p["kT"], p["cumT"],
-        *_packed_to_bands(d_kk, d_qk, p["same_head"], C))
+        *_packed_to_bands(d_kk, d_qk, m["same_head"], C))
     d_q, d_k, d_cum = (jnp.transpose(x) for x in (d_qT, d_kT, d_cumT))
     # ---- the rows against earlier sub-blocks
-    m = C // _BASE
-    if m == 1:
+    if C == _BASE:
         return d_q, d_k, d_cum
     at_row = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
     earlier = jax.lax.broadcasted_iota(
@@ -534,7 +671,7 @@ def _scores_pull(p, d_kk, d_qk, *, chunk: int, cd):
     nothing = jnp.zeros((_BASE, q.shape[1]), f32)
     d_q_rows = [[nothing] for _ in range(_PAIR)]
     d_k_rows = [[nothing] for _ in range(_PAIR)]
-    for i in range(1, m):
+    for i in range(1, C // _BASE):
         cols, left = p["cols"][i - 1], p["lefts"][i - 1]
         own_q, own_k = (x[i * _BASE:(i + 1) * _BASE] for x in (d_qk, d_kk))
         inside = earlier < i * _BASE
@@ -566,7 +703,7 @@ def _scores_pull(p, d_kk, d_qk, *, chunk: int, cd):
     shrunk = (d_q_rows * q + d_k_rows * k) * shrink
     d_cum = d_cum + shrunk
     for r in range(_PAIR):
-        for i in range(1, m):
+        for i in range(1, C // _BASE):
             first = r * C + i * _BASE
             d_cum = d_cum - jnp.where(
                 at_row == first,
@@ -575,21 +712,71 @@ def _scores_pull(p, d_kk, d_qk, *, chunk: int, cd):
     return d_q, d_k, d_cum
 
 
-def _each_chunk(chunks: int, *stages):
-    """The block's chunks in order, each through `stages` (``stage(c)``,
-    then ``stage(c, what the stage before returned)``). One chunk a loop
-    body: bodies of two and four chunks, whole or stage by stage, were
-    slower on the chip (PERF.md §6, PR 59)."""
-    def body(c, _):
-        held = stages[0](c)
-        for stage in stages[1:]:
-            held = stage(c, held)
+def _prepared(q, k, v, g, rows, *, chunk: int, cd, normalize, reads):
+    """A chunk's first stage, which reads neither a state nor an inverse:
+    `_chunk_parts`' and β ∘ v as the products read it; `reads`: the keys the
+    later stages need (None: all). What it returns is what a grid step keeps
+    of each of its chunks in VMEM scratch (`_kept_shapes`)."""
+    p = _chunk_parts(q, k, g, rows, _masks(chunk, q.shape[1]), chunk=chunk,
+                     cd=cd, normalize=normalize)
+    p["written_v"] = (v * p["beta"]).astype(cd)
+    return p if reads is None else {key: p[key] for key in reads}
 
-    jax.lax.fori_loop(0, chunks, body, None)
+
+def _kept_shapes(chunks: int, chunk: int, k_dim: int, v_dim: int, cd,
+                 normalize, reads):
+    """(scratch shapes, tree) of `_prepared`'s result for every chunk of a
+    block, each leaf with the chunks leading."""
+    def operand(columns):
+        return jax.ShapeDtypeStruct((_PAIR * chunk, columns), jnp.float32)
+
+    leaves, tree = jax.tree_util.tree_flatten(jax.eval_shape(
+        functools.partial(_prepared, chunk=chunk, cd=cd, normalize=normalize,
+                          reads=reads),
+        operand(k_dim), operand(k_dim), operand(v_dim), operand(k_dim),
+        jax.ShapeDtypeStruct((8, _LANES), jnp.float32)))
+    return [pltpu.VMEM((chunks, *leaf.shape), leaf.dtype)
+            for leaf in leaves], tree
+
+
+def _two_loops(masks: dict, kept, tree, prepare, finish, *, together: int,
+               reverse=False):
+    """A block's chunks twice: `prepare(c)` of each into the scratch `kept`
+    (`_kept_shapes`' refs and tree, the chunks leading; `masks`: `_masks`');
+    ``A -> (I + A)⁻¹`` of all of them at once (`_inverse_many`); then
+    ``finish(c, the chunk's kept parts, the masks)`` in the order the state
+    needs (`reverse`: from the last chunk to the first), `together` chunks a
+    loop body. The first loop's bodies read no state, so nothing orders
+    them but the scratch, and they are bound by what they do: two or four of
+    them a body gain nothing. The second loop's are chains of trips through
+    the MXU from one state to the next, and what of chunk c + 1 reads no
+    state fills chunk c's waits where one body holds both: the forward's
+    four bodies in ONE (straight behind the inverses: 11.9 -> 10.5 ms a
+    call), the backward's two a body (25.5 -> 24.6; all four: 25.3)
+    (`benchmarks/results/pr64_kda_two_loops/loop_probe_{c,g,h}.jsonl`)."""
+    def first(c, _):
+        for ref, leaf in zip(kept, jax.tree_util.tree_leaves(prepare(c))):
+            ref[c] = leaf
+
+    chunks = kept[0].shape[0]
+    jax.lax.fori_loop(0, chunks, first, None)
+    _inverse_many(jax.tree_util.tree_unflatten(tree, kept)["A"], masks)
+    together = min(together, chunks)
+
+    def second(body, _):
+        for more in range(together):
+            step = body * together + more
+            c = chunks - 1 - step if reverse else step
+            finish(c, jax.tree_util.tree_unflatten(
+                tree, [ref[c] for ref in kept]), masks)
+
+    # (Mosaic unrolls a loop whole or not at all: `together` by hand)
+    jax.lax.fori_loop(0, chunks // together, second, None,
+                      unroll=together == chunks)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, starts_ref,
-                state, *, chunk: int, cd, normalize):
+                state, *kept, chunk: int, cd, normalize, tree):
     @pl.when(pl.program_id(2) == 0)
     def _first_block():
         state[...] = jnp.zeros_like(state)
@@ -602,18 +789,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, starts_ref,
         return pl.ds(pl.multiple_of(c * C, C), C)
 
     def prepare(c):
-        """What does not read the state."""
         at = tokens(c)
-        p = _chunk_parts(_stacked(q_ref, at, K), _stacked(k_ref, at, K),
-                         _stacked(g_ref, at, K), rows_ref[c], chunk=C, cd=cd,
-                         normalize=normalize)
-        U = _one_pass(p["T_by_head"],
-                      (_stacked(v_ref, at, V) * p["beta"]).astype(cd), _NN)
-        W = _one_pass(p["T_by_head"], p["k_written"], _NN).astype(cd)
-        return p, U, W
+        return _prepared(
+            _stacked(q_ref, at, K), _stacked(k_ref, at, K),
+            _stacked(v_ref, at, V), _stacked(g_ref, at, K), rows_ref[c],
+            chunk=C, cd=cd, normalize=normalize, reads=_FORWARD_READS)
 
-    def carry(c, held):
-        p, U, W = held
+    def carry(c, p, m):
+        T_by_head = _by_head(p["A"], m["same_head"]).astype(cd)
+        U = _one_pass(T_by_head, p["written_v"], _NN)
+        W = _one_pass(T_by_head, p["k_written"], _NN).astype(cd)
         values, reads = [], []
         for r, own in enumerate(heads):
             start = state[r]
@@ -632,19 +817,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, o_ref, starts_ref,
                            _NN))
         _unstack(o_ref, tokens(c), out, V)
 
-    _each_chunk(rows_ref.shape[0], prepare, carry)
+    _two_loops(_masks(C, K), kept, tree, prepare, carry,
+               together=rows_ref.shape[0])
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, drows_ref, dstate, *,
-                chunk: int, cd, normalize):
+                dq_ref, dk_ref, dv_ref, dg_ref, drows_ref, dstate, *kept,
+                chunk: int, cd, normalize, tree):
     @pl.when(pl.program_id(2) == 0)
     def _last_block():
         dstate[...] = jnp.zeros_like(dstate)
 
     C = chunk
     K, V = q_ref.shape[1] // _PAIR, v_ref.shape[1] // _PAIR
-    chunks = rows_ref.shape[0]
     f32 = jnp.float32
     heads = [slice(r * C, (r + 1) * C) for r in range(_PAIR)]
 
@@ -652,27 +837,26 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, do_ref,
         """A cotangent that comes back for a `cd` operand: in `cd`."""
         return x.astype(cd).astype(f32)
 
-    def tokens(step):
-        return pl.ds(pl.multiple_of((chunks - 1 - step) * C, C), C)
+    def tokens(c):
+        return pl.ds(pl.multiple_of(c * C, C), C)
 
-    def prepare(step):
+    def prepare(c):
         """The chunk rebuilt as far as nothing reads a state."""
-        at = tokens(step)
-        p = _chunk_parts(_stacked(q_ref, at, K), _stacked(k_ref, at, K),
-                         _stacked(g_ref, at, K), rows_ref[chunks - 1 - step],
-                         chunk=C, cd=cd, normalize=normalize)
-        p["v"] = _stacked(v_ref, at, V)
-        p["written_v"] = (p["v"] * p["beta"]).astype(cd)
-        p["U"] = _one_pass(p["T_by_head"], p["written_v"], _NN)
-        p["W"] = _one_pass(p["T_by_head"], p["k_written"], _NN).astype(cd)
-        return p
+        at = tokens(c)
+        return _prepared(
+            _stacked(q_ref, at, K), _stacked(k_ref, at, K),
+            _stacked(v_ref, at, V), _stacked(g_ref, at, K), rows_ref[c],
+            chunk=C, cd=cd, normalize=normalize, reads=None)
 
-    def carry(step, p):
-        """The carry and the readout, backwards: V' rebuilt from the kept
-        start state, the state's cotangent walked on."""
-        c = chunks - 1 - step
-        U, W, second = p["U"], p["W"], p["second"]
-        d_out = _stacked(do_ref, tokens(step), V).astype(cd)
+    def finish(c, p, m):
+        at = tokens(c)
+        T, second, same_head = p["A"], m["second"], m["same_head"]
+        T_by_head = _by_head(T, same_head).astype(cd)
+        U = _one_pass(T_by_head, p["written_v"], _NN)
+        W = _one_pass(T_by_head, p["k_written"], _NN).astype(cd)
+        # ---- the carry and the readout, backwards: V' rebuilt from the
+        # kept start state, the state's cotangent walked on
+        d_out = _stacked(do_ref, at, V).astype(cd)
         starts = [starts_ref[c, r] for r in range(_PAIR)]
         starts_cd = [s.astype(cd) for s in starts]
         d_after = [dstate[r] for r in range(_PAIR)]
@@ -681,7 +865,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, do_ref,
             [(U[own] - _one_pass(W[own], starts_cd[r], _NN)).astype(cd)
              for r, own in enumerate(heads)], axis=0)
         d_values = _one_pass(p["P_by_head"], d_out, _TN)       # Pᵀ·dO
-        p["d_qk"] = jnp.where(p["lower"], rounded(
+        d_qk = jnp.where(m["lower"], rounded(
             _own(_one_pass(d_out, values, _NT), second, C)), 0.0)
         d_new, d_q_grown, d_W, d_k_end, d_cumT = [], [], [], [], 0.0
         at_lane = jax.lax.broadcasted_iota(jnp.int32, (K, _PAIR * C), 1)
@@ -705,30 +889,25 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, do_ref,
                 jnp.concatenate([p["q_grown"][own], -W[own]], axis=0), both,
                 _TN)
             d_new.append(new)
-        p["d_U"] = jnp.concatenate(d_new, axis=0)               # float32
-        p["d_q_grown"], p["d_W"], p["d_k_end"] = (
+        d_U = jnp.concatenate(d_new, axis=0)                    # float32
+        d_q_grown, d_W, d_k_end = (
             rounded(jnp.concatenate(x, axis=0))
             for x in (d_q_grown, d_W, d_k_end))
-        p["d_cumT"] = d_cumT
-        return p
-
-    def pull(step, p):
-        """What the products read, backwards, down to q, k, v, g and β."""
-        second, beta, grow, to_end = (p[x] for x in (
-            "second", "beta", "grow", "to_end"))
-        q, k, v, T = p["q"], p["k"], p["v"], p["T"]
-        d_q_grown, d_W, d_k_end = p["d_q_grown"], p["d_W"], p["d_k_end"]
+        # ---- what the products read, backwards
+        beta, grow, to_end, q, k = (p[x] for x in (
+            "beta", "grow", "to_end", "q", "k"))
+        v = _stacked(v_ref, at, V)
         d_q = d_q_grown * grow
         d_grow = d_q_grown * q
         d_k = d_k_end * to_end
         d_to_end = d_k_end * k * to_end
-        d_U_cd, d_W_cd = p["d_U"].astype(cd), d_W.astype(cd)
+        d_U_cd, d_W_cd = d_U.astype(cd), d_W.astype(cd)
         d_T = (_own(rounded(_one_pass(d_U_cd, p["written_v"], _NT)), second,
                     C)
                + _own(rounded(_one_pass(d_W_cd, p["k_written"], _NT)),
                       second, C))
-        d_written_v = rounded(_one_pass(p["T_by_head"], d_U_cd, _TN))
-        d_written_k = rounded(_one_pass(p["T_by_head"], d_W_cd, _TN))
+        d_written_v = rounded(_one_pass(T_by_head, d_U_cd, _TN))
+        d_written_k = rounded(_one_pass(T_by_head, d_W_cd, _TN))
         d_v = d_written_v * beta
         d_k = d_k + d_written_k * (grow * beta)
         d_grow = d_grow + d_written_k * k * beta
@@ -736,42 +915,43 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, rows_ref, starts_ref, do_ref,
                   + _over_lanes(d_written_k * k * grow))
         # γ_C − γ: the last row of a head takes the others' sum
         d_cum = d_grow * grow - d_to_end + jnp.where(
-            p["token"] == C - 1, jnp.concatenate([
+            m["token"] == C - 1, jnp.concatenate([
                 jnp.broadcast_to(jnp.sum(d_to_end[own], axis=0,
                                          keepdims=True), (C, K))
                 for own in heads], axis=0), 0.0)
         # the inverse's rule: dA = −Tᵀ·dT·Tᵀ below the diagonal
-        T_t = jnp.transpose(_by_head(T, p["same_head"]))
-        d_A = jnp.where(p["strict"], -_exact(
+        T_t = jnp.transpose(_by_head(T, same_head))
+        d_A = jnp.where(m["strict"], -_exact(
             _own(T_t, second, C),
-            _by_head(_exact(d_T, T_t, _NN), p["same_head"]), _NN), 0.0)
+            _by_head(_exact(d_T, T_t, _NN), same_head), _NN), 0.0)
         along = d_A * p["kk"]
         d_beta = d_beta + jnp.concatenate(
             [_over_lanes(jnp.where(second, 0.0, along)),
              _over_lanes(jnp.where(second, along, 0.0))], axis=0)
         more_q, more_k, more_cum = _scores_pull(
-            p, d_A * p["beta_packed"], p["d_qk"], chunk=C, cd=cd)
+            p, m, d_A * _packed_columns(beta, second, C), d_qk, chunk=C,
+            cd=cd)
         d_q, d_k = d_q + more_q, d_k + more_k
-        d_cum = d_cum + more_cum + jnp.transpose(p["d_cumT"])
+        d_cum = d_cum + more_cum + jnp.transpose(d_cumT)
         if normalize is not None:
             d_q = d_q * K ** -0.5
             d_q, d_k = (
                 norm * d - raw * (norm * norm * norm
                                   * _over_lanes(raw * d)[:, :1])
-                for raw, norm, d in ((p["q_raw"], p["q_norm"], d_q),
-                                     (p["k_raw"], p["k_norm"], d_k)))
-        at = tokens(step)
+                for raw, norm, d in (
+                    (_stacked(q_ref, at, K), p["q_norm"], d_q),
+                    (_stacked(k_ref, at, K), p["k_norm"], d_k)))
         _unstack(dq_ref, at, d_q, K)
         _unstack(dk_ref, at, d_k, K)
         _unstack(dv_ref, at, d_v, V)
         # the running sum's pullback: the triangle of ones, transposed
-        _unstack(dg_ref, at, _summed(p["sums"], d_cum, _TN), K)
+        _unstack(dg_ref, at, _summed(m["sums"], d_cum, _TN), K)
         # β's cotangent as the rows' lanes: one transposed tile
         lane = jax.lax.broadcasted_iota(jnp.int32, (_PAIR * C, _LANES), 1)
-        drows_ref[chunks - 1 - step] = jnp.transpose(
-            jnp.where(lane == 0, d_beta, 0.0))[:8]
+        drows_ref[c] = jnp.transpose(jnp.where(lane == 0, d_beta, 0.0))[:8]
 
-    _each_chunk(chunks, prepare, carry, pull)
+    _two_loops(_masks(C, K), kept, tree, prepare, finish, together=2,
+               reverse=True)
 
 
 def _block_specs(block: int, chunk: int, k_dim: int, v_dim: int, offsets,
@@ -831,9 +1011,11 @@ def _kda_fwd(qkv, g, rows, *, k_dim, v_dim, chunk, block, cd, normalize,
     spec = _block_specs(block, chunk, k_dim, v_dim,
                         _packed_offsets(_PAIR * pairs, k_dim, v_dim),
                         lambda s: s)
+    kept, tree = _kept_shapes(block // chunk, chunk, k_dim, v_dim, cd,
+                              normalize, _FORWARD_READS)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=chunk, cd=cd,
-                          normalize=normalize),
+                          normalize=normalize, tree=tree),
         grid=(b, pairs, T // block),
         in_specs=[spec["q"], spec["k"], spec["v"], spec["g"], spec["rows"]],
         out_specs=[spec["o"], spec["starts"]],
@@ -841,7 +1023,8 @@ def _kda_fwd(qkv, g, rows, *, k_dim, v_dim, chunk, block, cd, normalize,
             jax.ShapeDtypeStruct((b, T, _PAIR * pairs * v_dim), jnp.float32),
             jax.ShapeDtypeStruct(
                 (T // chunk, b, pairs, _PAIR, k_dim, v_dim), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((_PAIR, k_dim, v_dim), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_PAIR, k_dim, v_dim), jnp.float32),
+                        *kept],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="kda_fwd",
@@ -863,9 +1046,11 @@ def _kda_bwd(qkv, g, rows, starts, d_out, *, k_dim, v_dim, chunk, block, cd,
     own = _block_specs(block, chunk, k_dim, v_dim, (0, 0, 0),
                        lambda s: last - s)
     keys = jax.ShapeDtypeStruct((b, T, _PAIR * pairs * k_dim), jnp.float32)
+    kept, tree = _kept_shapes(block // chunk, chunk, k_dim, v_dim, cd,
+                              normalize, None)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=chunk, cd=cd,
-                          normalize=normalize),
+                          normalize=normalize, tree=tree),
         grid=(b, pairs, T // block),
         in_specs=[spec["q"], spec["k"], spec["v"], spec["g"], spec["rows"],
                   spec["starts"], spec["o"]],
@@ -874,7 +1059,8 @@ def _kda_bwd(qkv, g, rows, starts, d_out, *, k_dim, v_dim, chunk, block, cd,
                    jax.ShapeDtypeStruct((b, T, _PAIR * pairs * v_dim),
                                         jnp.float32),
                    keys, jax.ShapeDtypeStruct(rows.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((_PAIR, k_dim, v_dim), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_PAIR, k_dim, v_dim), jnp.float32),
+                        *kept],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="kda_bwd",

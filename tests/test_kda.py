@@ -9,8 +9,11 @@ in both FORMS: the plain one, and the Pallas kernels `kda_fwd` / `kda_bwd`
 in the interpreter (two heads of 128, blocks of two chunks of 64, the norms
 inside; 150 tokens are padded to two blocks, so the state and its cotangent
 cross a grid step both ways), the latter also at the steepest published
-decay; what decides between the forms; and the kernels' names and scope in
-the layer's jaxpr."""
+decay and at blocks of ONE chunk (three grid steps: the two loops of a
+single chunk) and of FOUR (the module's: 150 tokens are three chunks and a
+chunk of padding in one block, all four inverses taken at once, the
+backward's second loop from the fourth chunk to the first); what decides
+between the forms; and the kernels' names and scope in the layer's jaxpr."""
 import functools
 
 import jax
@@ -31,6 +34,18 @@ KERNEL_LIMIT = pytest.mark.limit(
 @pytest.fixture(autouse=True)
 def blocks_of_two_chunks(monkeypatch):
     monkeypatch.setattr(kda, "BLOCK_TOKENS", 128)
+
+
+@pytest.fixture
+def block_of(monkeypatch):
+    """The kernels' block of tokens for a case (the module's constant as it
+    stands when the call is traced: `_out_and_weighted(block=)` keys the
+    compiled program by it)."""
+    def patch(block):
+        monkeypatch.setattr(kda, "BLOCK_TOKENS", block)
+        return block
+
+    return patch
 
 
 @pytest.fixture(autouse=True)
@@ -80,10 +95,11 @@ def _inputs(seed=0, b=2, T=150, H=3, K=16, V=8, decay=1.0, floor=None,
 
 
 @functools.cache
-def _program(fn, kw):
+def _program(fn, kw, block=None):
     """`fn`'s output and the gradients by input of a scalar of it, one
-    jitted program a function and keywords: the draws of a parametrised
-    case meet it compiled."""
+    jitted program a function, keywords and block of tokens (which the
+    kernels read off the module when they are traced): the draws of a
+    parametrised case meet it compiled."""
     def scalar(weights, *a):
         out = fn(*a, **dict(kw))
         return jnp.sum(out * weights), out
@@ -92,9 +108,10 @@ def _program(fn, kw):
                             has_aux=True))
 
 
-def _out_and_weighted(fn, args, **kw):
+def _out_and_weighted(fn, args, block=None, **kw):
     weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-    grads, out = _program(fn, tuple(sorted(kw.items())))(weights, *args)
+    grads, out = _program(fn, tuple(sorted(kw.items())), block)(weights,
+                                                                *args)
     return out, grads
 
 
@@ -136,22 +153,29 @@ def _kernel_case(draw, **shape):
                  interpret=True))
 
 
-KERNEL_DRAWS = [pytest.param("kernel", 64, 150, draw, marks=KERNEL_LIMIT)
-                for draw in (*sorted(DRAWS), "steepest")]
+# (form, chunk, T, draw, block of tokens): every draw at blocks of two chunks,
+# the mild one at blocks of one and of four (`BLOCK_TOKENS` as shipped)
+BLOCKS = (64, 128, 256)
+KERNEL_DRAWS = [
+    *(pytest.param("kernel", 64, 150, draw, 128, marks=KERNEL_LIMIT)
+      for draw in (*sorted(DRAWS), "steepest")),
+    *(pytest.param("kernel", 64, 150, "mild", block, marks=KERNEL_LIMIT)
+      for block in BLOCKS if block != 128)]
 
 
-@pytest.mark.parametrize("form, chunk, T, draw", [
-    *(("plain", chunk, T, draw) for chunk, T in [(16, 150), (64, 150),
-                                                 (64, 128), (16, 7)]
+@pytest.mark.parametrize("form, chunk, T, draw, block", [
+    *(("plain", chunk, T, draw, None) for chunk, T in [(16, 150), (64, 150),
+                                                       (64, 128), (16, 7)]
       for draw in sorted(DRAWS)),
     *KERNEL_DRAWS])
-def test_values_against_the_recurrence(form, chunk, T, draw):
+def test_values_against_the_recurrence(form, chunk, T, draw, block,
+                                       block_of):
     if form == "plain":
         args = _inputs(T=T, **DRAWS[draw])
         got, want = _rule(chunk)(*args), _recurrence(*args)
     else:
         args, kw = _kernel_case(draw, T=T)
-        got, _ = _out_and_weighted(kda.kda, args, **kw)
+        got, _ = _out_and_weighted(kda.kda, args, block_of(block), **kw)
         want, _ = _out_and_weighted(normed_recurrence, args)
     assert got.shape == want.shape == args[2].shape
     assert bool(jnp.all(jnp.isfinite(got)))
@@ -161,14 +185,17 @@ def test_values_against_the_recurrence(form, chunk, T, draw):
 # the kernel draws FIRST here and last above: the ten cases that meet the one
 # interpreted program (`_program`, compiled once a process) stand in a row,
 # and `--dist load` hands a row of cases to one worker more often than not
-@pytest.mark.parametrize("form, chunk, T, draw", [
-    *KERNEL_DRAWS, *(("plain", chunk, 150, draw) for chunk in (16, 64)
+@pytest.mark.parametrize("form, chunk, T, draw, block", [
+    *KERNEL_DRAWS, *(("plain", chunk, 150, draw, None) for chunk in (16, 64)
                      for draw in sorted(DRAWS))])
-def test_every_gradient_against_the_recurrence(form, chunk, T, draw):
+def test_every_gradient_against_the_recurrence(form, chunk, T, draw, block,
+                                               block_of):
     """The kernels: 150 tokens in two blocks of 128 (the padding's ``g =
     0``, ``β = 0`` tokens; the state carried across a grid step forward,
     its cotangent backward), the norms inside, and at `steepest` no
-    overflow and no `nan` where a token forgets the state whole."""
+    overflow and no `nan` where a token forgets the state whole; in three
+    blocks of one chunk; and in ONE block of four, the last chunk all
+    padding, which the backward's second loop walks first."""
     if form == "plain":
         args = _inputs(**DRAWS[draw])
         _, got = _out_and_weighted(kda.kda, args, chunk=chunk,
@@ -176,7 +203,7 @@ def test_every_gradient_against_the_recurrence(form, chunk, T, draw):
         _, want = _out_and_weighted(recurrence, args)
     else:
         args, kw = _kernel_case(draw, T=T)
-        _, got = _out_and_weighted(kda.kda, args, **kw)
+        _, got = _out_and_weighted(kda.kda, args, block_of(block), **kw)
         _, want = _out_and_weighted(normed_recurrence, args)
     for name, a, b in zip(INPUTS, got, want):
         assert a.shape == b.shape
@@ -184,10 +211,11 @@ def test_every_gradient_against_the_recurrence(form, chunk, T, draw):
         assert _rel(a, b) < 1e-4, (name, _rel(a, b))
 
 
-@pytest.mark.parametrize("form, chunk", [
-    ("plain", 16), ("plain", 64),
-    pytest.param("kernel", 64, marks=KERNEL_LIMIT)])
-def test_the_backward_of_its_own_against_jaxs(form, chunk):
+@pytest.mark.parametrize("form, chunk, block", [
+    ("plain", 16, None), ("plain", 64, None),
+    *(pytest.param("kernel", 64, block, marks=KERNEL_LIMIT)
+      for block in BLOCKS)])
+def test_the_backward_of_its_own_against_jaxs(form, chunk, block, block_of):
     """`kda` (custom_vjp: the chunks' start states kept, a chunk's inside
     rebuilt — by the plain form's `jax.vjp` of a chunk, by `kda_bwd`'s
     own arithmetic) against `kda_plain`, the same walk differentiated by
@@ -200,7 +228,8 @@ def test_the_backward_of_its_own_against_jaxs(form, chunk):
         (args, kernel), near = _kernel_case("mild"), (2e-6, 5e-6)
         kw["normalize"] = kernel["normalize"]
     out, got = _out_and_weighted(
-        kda.kda, args, **(kw if form == "plain" else kernel))
+        kda.kda, args,
+        **(kw if form == "plain" else dict(kernel, block=block_of(block))))
     plain, want = _out_and_weighted(kda.kda_plain, args, **kw)
     assert _rel(out, plain) < near[0]
     for name, a, b in zip(INPUTS, got, want):
@@ -241,13 +270,16 @@ def test_the_norms_inside_the_rule_and_one_pass_in_bf16():
 
 
 @KERNEL_LIMIT
-def test_bf16_products_in_the_kernels_stay_near_the_plain_forms():
+@pytest.mark.parametrize("block", BLOCKS)
+def test_bf16_products_in_the_kernels_stay_near_the_plain_forms(block,
+                                                                block_of):
     """One pass in bf16: the kernels round the operands the plain form
     rounds, so the two stay within a bf16 pass of each other — output and
-    every gradient."""
+    every gradient — whatever the block (what the first loop keeps of a
+    chunk's operands is in bf16 then)."""
     args, kw = _kernel_case("mild")
     kw = {**kw, "compute_dtype": jnp.bfloat16}
-    out, got = _out_and_weighted(kda.kda, args, **kw)
+    out, got = _out_and_weighted(kda.kda, args, block_of(block), **kw)
     kw.pop("interpret")
     plain, want = _out_and_weighted(kda.kda, args, **kw)
     assert _rel(out, plain) < 1e-3
@@ -290,18 +322,41 @@ def test_the_plan_and_the_budget_by_hand(monkeypatch):
     """A grid step of the backward at the cell's widths, blocks of 256
     tokens: q, k, g and their cotangents [256, 256], v, o's cotangent and
     v's [256, 256], β's rows and theirs [4, 8, 128], four chunks' start
-    states [2, 128, 128], float32 and twice; the state's cotangent once."""
+    states [2, 128, 128], float32 and twice; the state's cotangent once;
+    and what the first loop keeps of each of the four chunks for the second,
+    once: nineteen stacked [128, 128] arrays, six packed [64, 128] and five
+    columns [128, 1], a tile of 128 lanes each. A block of 512 tokens keeps
+    eight chunks, which the budget does not hold: the plain form, from
+    shapes alone."""
     monkeypatch.setattr(kda, "BLOCK_TOKENS", 256)
     plan = kda.kda_plan(8192, 32, 128, 128, 64)
     assert plan["chunks"] == 128 and plan["block_tokens"] == 256
+    assert plan["inverses_at_once"] == 4
     assert plan["state_bytes"] == 4 * 128 * 32 * 128 * 128 == 268_435_456
-    assert plan["vmem_bytes"] == 2 * 4 * (
+    blocks = 2 * 4 * (
         9 * 256 * 256 + 2 * 4 * 8 * 128 + 4 * 2 * 128 * 128
-    ) + 4 * 2 * 128 * 128 == 5_963_776
+    ) + 4 * 2 * 128 * 128
+    kept_a_chunk = 4 * (19 * 128 * 128 + 6 * 64 * 128 + 5 * 128 * 128)
+    assert blocks == 5_963_776 and kept_a_chunk == 1_769_472
+    assert plan["vmem_bytes"] == blocks + 4 * kept_a_chunk == 13_041_664
     assert plan["vmem_bytes"] <= kda.VMEM_BUDGET_BYTES
     assert kda._use_kernel("tpu", 1, 64, 32, 128, 128)
-    monkeypatch.setattr(kda, "VMEM_BUDGET_BYTES", plan["vmem_bytes"] - 1)
+    with monkeypatch.context() as tighter:
+        tighter.setattr(kda, "VMEM_BUDGET_BYTES", plan["vmem_bytes"] - 1)
+        assert not kda._use_kernel("tpu", 1, 64, 32, 128, 128)
+    # eight chunks' scratch: over the budget as it stands
+    monkeypatch.setattr(kda, "BLOCK_TOKENS", 512)
+    plan = kda.kda_plan(8192, 32, 128, 128, 64)
+    assert plan["inverses_at_once"] == 8
+    assert plan["vmem_bytes"] == 2 * blocks - 4 * 2 * 128 * 128 \
+        + 8 * kept_a_chunk == 25_952_256 > kda.VMEM_BUDGET_BYTES
     assert not kda._use_kernel("tpu", 1, 64, 32, 128, 128)
+    # what the kept scratch counts is what the kernels allocate
+    kept, _ = kda._kept_shapes(4, 64, 128, 128, jnp.dtype(jnp.float32), 1e-6,
+                               None)
+    assert len(kept) == 30
+    assert sorted({held.shape[1:] for held in kept}) == [
+        (64, 128), (128, 1), (128, 128)]
 
 
 def _pallas_calls(jaxpr, found, outer=""):
